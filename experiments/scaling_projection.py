@@ -80,7 +80,7 @@ ICI_BYTES_PER_S = 100e9          # one-way per chip, v5e
 DCN_BYTES_PER_S = 25e9 / 8      # per chip when 8 chips share a host NIC
 ICI_POD_LIMIT = 256              # v5e pod: 256 chips on one ICI fabric
 
-# Measured single-chip step times (experiments/PERF.md protocol; this
+# Measured single-chip step times (PERF.md (older installation) protocol; this
 # round's numbers) and the transformer model zoo. t_comp is the IDEAL
 # per-chip step time at that parallelism (single-chip time / model-split
 # factor); pipeline bubble is charged separately via overhead_factor.
@@ -135,7 +135,7 @@ WORKLOADS = {
 }
 
 # Measured single-chip ms/step anchors (real v5e chip, interleaved
-# differential; experiments/PERF.md "Round 5", dh=128 geometry). d512 and
+# differential; PERF.md (older installation) "Round 5", dh=128 geometry). d512 and
 # d1024 are at the bench shapes; d2048's bs8 group batch is anchored to
 # the measured bs4 step (see _fill_t_comp).
 MEASURED_MS = {

@@ -71,7 +71,7 @@ class Seq2SeqAttention(Module):
         """One decoder step WITHOUT the vocab readout — the readout is 83%
         of decoder FLOPs (2*h*V per token) and, run per scan step as a tiny
         [B, h] @ [h, V] matmul, dominated the step at single-digit MXU
-        efficiency (experiments/PERF.md "Round 5: seq2seq"); training
+        efficiency (PERF.md (older installation) "Round 5: seq2seq"); training
         hoists it out of the scan and applies it once over [B, T, h]."""
         ctx, _ = self.att(state, enc, enc_mask, enc_proj=enc_proj)
         x = jnp.concatenate([y_emb, ctx], axis=-1)

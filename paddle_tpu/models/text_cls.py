@@ -26,7 +26,7 @@ class LSTMTextClassifier(Module):
         self.emb = nn.Embedding(vocab, hidden)
         # unroll measured NEUTRAL-to-worse under the bench's
         # steps-per-call fori_loop (XLA pipelines the rolled loop better);
-        # see experiments/PERF.md "Round 5"
+        # see PERF.md (older installation) "Round 5"
         self.layers = [RNN(LSTMCell(hidden), name=f"lstm{i}")
                        for i in range(num_layers)]
         self.fc = nn.Linear(num_classes, name="fc")

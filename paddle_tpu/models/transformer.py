@@ -301,7 +301,7 @@ class TransformerLM(Module):
                     attn_impl: str = "xla"):
         """Serving decode tick: one new token per slot against the paged
         KV cache. ``token [S]`` int32; ``kv = (pages_k, pages_v,
-        tables)`` with pools ``[L, N, bs, H, hd]`` (the leading layer
+        tables)`` with pools ``[L, N, H, bs, hd]`` (the leading layer
         axis feeds the layer scan) and ``tables [S, MB]``; ``positions
         [S]`` the incoming token's 0-based position (== pre-step length);
         ``active [S]`` bool (default: all). Returns ``(logits [S,

@@ -16,6 +16,7 @@ from typing import Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..core import initializers as I
 from ..core.dtypes import current_policy
@@ -34,28 +35,72 @@ def _tp_paged_kernel(kernel, q, pages_k, pages_v, *rest, head_dim: int):
     and of every pool block — block tables and lengths replicate. With
     no scope active the kernel runs whole, unchanged. ``head_dim`` is
     the axis of ``q`` (and of the kernel's output) carrying heads; pool
-    leaves always carry heads on axis 2 (``[N, bs, H, hd]`` values,
-    ``[N, bs, H]`` scale pages)."""
+    leaves always carry heads on axis 1 (``[N, H, bs, hd]`` values,
+    ``[N, H, bs]`` scale pages)."""
     from ..parallel.sharding import current_tp_shard
     scope = current_tp_shard()
     if scope is None:
         return kernel(q, pages_k, pages_v, *rest)
-    from jax.sharding import PartitionSpec as P
-    from ..parallel.overlap import shard_map_compat
     mesh, axis = scope
     qspec = P(*[axis if i == head_dim else None for i in range(q.ndim)])
 
     def pool_spec(pool):
         return jax.tree_util.tree_map(
-            lambda leaf: P(*[axis if i == 2 else None
+            lambda leaf: P(*[axis if i == 1 else None
                              for i in range(leaf.ndim)]), pool)
 
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         kernel, mesh=mesh,
         in_specs=(qspec, pool_spec(pages_k), pool_spec(pages_v))
         + tuple(P() for _ in rest),
-        out_specs=qspec)
+        out_specs=qspec, check_vma=False)
     return sharded(q, pages_k, pages_v, *rest)
+
+
+def _flash_per_shard(q, k, v, segments, causal):
+    """:func:`~paddle_tpu.nn.pallas_attention.flash_attention` under
+    whatever mesh the caller is traced in. Mosaic kernels cannot be
+    partitioned automatically, so with more than one device the call
+    runs PER SHARD in a ``shard_map`` (the :func:`_tp_paged_kernel`
+    pattern): batch over the mesh's ``data`` axis, heads over the
+    tensor-parallel axis — the serving engine's ``tp_shard_scope``
+    axis, else ``model``. The mesh is the scope's, else the one the
+    Trainer traces its step in (``core.mesh.use_mesh``). Inside someone
+    else's ``shard_map`` (megatron, the pipeline, the Trainer's manual
+    dp region) only the axes still automatic there are wrapped; with
+    none left, or on one device, the kernel runs bare. An axis that
+    does not divide its dimension replicates the work. ``q``/``k``/``v``
+    ``[B, H, T, D]``; ``segments`` ``[B, T]`` or None."""
+    from ..core import mesh as mesh_lib
+    from ..parallel.sharding import current_tp_shard
+    from .pallas_attention import flash_attention
+    scope = current_tp_shard()
+    head_axis = scope[1] if scope is not None else mesh_lib.MODEL_AXIS
+    ctx = jax.sharding.get_abstract_mesh()
+    if not ctx.empty:
+        # already inside a shard_map: nest over its still-automatic axes
+        mesh, sizes, manual = None, dict(ctx.shape), set(ctx.manual_axes)
+    else:
+        mesh = scope[0] if scope is not None else mesh_lib.current_mesh()
+        sizes, manual = (dict(mesh.shape) if mesh is not None else {}), ()
+    free = {a for a, n in sizes.items() if n > 1 and a not in manual}
+    if not free:
+        return flash_attention(q, k, v, segments, causal)
+
+    def axis_for(name, dim):
+        return name if name in free and dim % sizes[name] == 0 else None
+
+    b_ax = axis_for(mesh_lib.DATA_AXIS, q.shape[0])
+    spec = P(b_ax, axis_for(head_axis, q.shape[1]))
+    seg = () if segments is None else (segments,)
+
+    def per_shard(q, k, v, *seg):
+        return flash_attention(q, k, v, seg[0] if seg else None, causal)
+
+    kw = {} if mesh is not None else {"axis_names": frozenset(free)}
+    return jax.shard_map(
+        per_shard, mesh=mesh, in_specs=(spec,) * 3 + (P(b_ax),) * len(seg),
+        out_specs=spec, check_vma=False, **kw)(q, k, v, *seg)
 
 
 def dot_product_attention_weights(q, k, mask=None, scale: Optional[float] = None):
@@ -198,7 +243,6 @@ class MultiHeadAttention(Module):
         impl = self.attention_impl
         if impl == "flash":
             self._fast_path_checks(q_in, kv_in, mask)
-            from .pallas_attention import flash_attention
             T = q.shape[1]
             if next((b for b in (128, 64, 32, 16, 8) if T % b == 0),
                     None) is None:
@@ -206,13 +250,17 @@ class MultiHeadAttention(Module):
                     f"flash path needs seq len divisible by 8; pad T={T}")
             # block sizes auto-select in the kernel (large blocks: the
             # per-grid-step overhead dominated at the old fixed 128 —
-            # measured 5x per-layer, experiments/profile_transformer.py)
+            # measured 5x per-layer, experiments/profile_transformer.py).
+            # The kernel takes its operands in the policy's compute
+            # dtype (bf16 pairs multiply into f32 on the MXU at full
+            # rate) and saves q/k/v/out for its backward in that dtype:
+            # fed the f32 projections, the d1024 step at 16 x 2048
+            # tokens needs 16.06 GB of a v5e's 15.75 GB
             with jax.named_scope("flash_attention"):
-                ctx = flash_attention(jnp.moveaxis(q, 2, 1),
-                                      jnp.moveaxis(k, 2, 1),
-                                      jnp.moveaxis(v, 2, 1),
-                                      segments, causal)
-                ctx = jnp.moveaxis(ctx, 1, 2).astype(pol.compute_dtype)
+                ctx = _flash_per_shard(
+                    *(jnp.moveaxis(pol.cast_compute(t), 2, 1)
+                      for t in (q, k, v)), segments, causal)
+                ctx = jnp.moveaxis(ctx, 1, 2)
         elif impl in ("ring", "seq"):
             self._fast_path_checks(q_in, kv_in, mask)
             if impl == "ring":
@@ -259,7 +307,7 @@ class MultiHeadAttention(Module):
         attend over the slot's whole ragged context.
 
         Args: ``q_in`` [S, 1, D] (one token per serving slot);
-        ``pages_k``/``pages_v`` [N, bs, H, hd] (this layer's pool);
+        ``pages_k``/``pages_v`` [N, H, bs, hd] (this layer's pool);
         ``tables`` [S, MB] block tables; ``positions`` [S] the incoming
         token's 0-based position (== the pre-step sequence length);
         ``active`` [S] bool slot mask (inactive slots scatter to the null
@@ -306,10 +354,10 @@ class MultiHeadAttention(Module):
             with jax.named_scope("kv_scatter"):
                 pages_k = tp_constrain(
                     scatter_token_pages(pages_k, k[:, 0], tables,
-                                        positions, active), 2)
+                                        positions, active), 1)
                 pages_v = tp_constrain(
                     scatter_token_pages(pages_v, v[:, 0], tables,
-                                        positions, active), 2)
+                                        positions, active), 1)
             # the new token sees itself: effective length = position + 1
             eff_len = jnp.where(active, positions + 1, 0)
             if impl == "paged":
@@ -420,10 +468,10 @@ class MultiHeadAttention(Module):
             with jax.named_scope("kv_scatter"):
                 pages_k = tp_constrain(
                     scatter_span_pages(pages_k, k, tables, start,
-                                       n_eff, write_from), 2)
+                                       n_eff, write_from), 1)
                 pages_v = tp_constrain(
                     scatter_span_pages(pages_v, v, tables, start,
-                                       n_eff, write_from), 2)
+                                       n_eff, write_from), 1)
             if impl == "paged":
                 from .pallas_attention import paged_span_attention
                 with jax.named_scope("paged_span_attention"):

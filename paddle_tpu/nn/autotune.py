@@ -20,6 +20,8 @@ Contract (the zero-overhead pin, PR-2/4 style):
 - **Explicit overrides bypass everything.** A caller that passes
   explicit ``block_q``/``block_k`` never reaches :func:`choose` at all
   (the kernels resolve explicit blocks before consulting the tuner).
+- **A kernel no candidate can run raises.** One failing candidate is
+  skipped; when all fail the last error propagates (nothing stored).
 - **Corrupt caches degrade silently.** A truncated, unparseable, or
   schema-stale cache file reads as empty and the key re-tunes; the
   atomic-rename write (merge-with-disk, tmp + ``os.replace``, the
@@ -193,8 +195,10 @@ def choose(kernel: str, *, key: str,
     runs once through :func:`time_kernel` via ``runner(**config)`` (one
     discarded compile iteration + one timed), the winner is persisted,
     and candidates that raise (mis-tiled on this backend) are skipped.
-    If every candidate fails, ``default`` is returned and nothing is
-    stored — a transient failure must not poison the cache."""
+    If EVERY candidate fails, the last failure is raised and nothing is
+    stored: a kernel that cannot compile at all (a Mosaic refusal) must
+    be seen, not papered over with an untried default, and a transient
+    failure must not poison the cache."""
     if not is_enabled():
         return dict(default)
     path = cache_file()
@@ -206,17 +210,19 @@ def choose(kernel: str, *, key: str,
     best: Optional[Dict[str, Any]] = None
     best_t = float("inf")
     tried = 0
+    failure: Optional[Exception] = None
     for cand in (list(candidates) or [dict(default)]):
         try:
             t, _ = time_kernel(lambda: runner(**cand))
-        except Exception:
+        except Exception as e:
+            failure = e
             continue
         tried += 1
         _stats["trials"] += 1
         if t < best_t:
             best, best_t = dict(cand), t
     if best is None:
-        return dict(default)
+        raise failure
     _store(path, key, {"config": best, "best_s": best_t,
                        "trials": tried, "kernel": kernel})
     return best

@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from . import autotune
+from . import autotune, pallas_mode
 from .pallas_attention import _auto_block
 
 __all__ = ["fused_ln_matmul", "ln_matmul_reference"]
@@ -113,7 +113,7 @@ def fused_ln_matmul(x, w, scale=None, bias=None, *, eps: float = 1e-6,
     K2, N = w.shape
     assert K == K2, f"x [{M},{K}] @ w [{K2},{N}]: contraction mismatch"
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_mode.interpret()
     explicit = block_m is not None or block_n is not None
     bm = _auto_block(M, 128) if block_m is None else min(block_m, M)
     bn = _auto_block(N, 512) if block_n is None else min(block_n, N)

@@ -197,7 +197,7 @@ class Conv2D(Module):
             # EXACT space-to-depth rewrite (input 2x2 patches -> channels,
             # end-zero-padded weights re-indexed w2[a,b,(dy,dx,c)] =
             # w[2a+dy, 2b+dx, c], conv 4x4/1 pad (1,2)): same math to f32
-            # roundoff, 1.9x faster measured (experiments/PERF.md "Round
+            # roundoff, 1.9x faster measured (PERF.md (older installation) "Round
             # 5: 3x3 campaign"; the MLPerf-ResNet TPU trick, done
             # weight-compatibly).
             xc, wc = pol.cast_compute(x), pol.cast_compute(w)

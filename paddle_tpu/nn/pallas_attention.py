@@ -35,8 +35,14 @@ Rows with no visible key (segment id 0 = padding) produce an unspecified
 finite output (uniform average of the streamed v blocks) — identical to the
 convention of other public TPU flash kernels; mask padding rows downstream.
 
-``interpret=None`` auto-selects the Pallas interpreter off-TPU, so the same
-tests run on the CPU harness and the kernels compile on real chips.
+Every ``pallas_call`` carries a stable ``name`` (``flash_fwd``,
+``flash_bwd_dq``, ``flash_bwd_dkv``, ``paged_decode``, ``paged_span``):
+it names the Mosaic custom call in compiled HLO text and in a device
+trace, which is how ``chip_smoke.py`` proves the kernels were compiled.
+
+``interpret=None`` asks :func:`pallas_mode.interpret`: the Pallas
+interpreter off-TPU, so the same tests run on the CPU harness; Mosaic on a
+TPU, where a kernel that cannot compile raises.
 """
 
 from __future__ import annotations
@@ -49,7 +55,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import autotune
+from . import autotune, pallas_mode
 
 __all__ = ["flash_attention", "reference_attention",
            "paged_decode_attention", "paged_reference_attention",
@@ -419,7 +425,7 @@ def _flash_forward(q, k, v, segments, causal, scale, block_q, block_k,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, name="flash_fwd",
     )(*operands)
     return out.reshape(B, H, T, D), lse.reshape(B, H, T)
 
@@ -464,7 +470,7 @@ def _flash_backward(q, k, v, segments, out, lse, g, causal, scale, block_q,
         out_specs=pl.BlockSpec((None, bq, D), _row_map()),
         out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="flash_bwd_dq",
     )(*operands)
 
     qmap = _q_index_map(causal, bq, bk)
@@ -498,7 +504,7 @@ def _flash_backward(q, k, v, segments, out, lse, g, causal, scale, block_q,
         ],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="flash_bwd_dkv",
     )(*operands)
 
     return (dq.reshape(B, H, T, D), dk.reshape(B, H, T, D),
@@ -511,7 +517,7 @@ def _resolve_defaults(q, scale, interpret):
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_mode.interpret()
     return scale, interpret
 
 
@@ -540,7 +546,7 @@ def paged_reference_attention(q, pages_k, pages_v, tables, lengths,
     """Numeric oracle for :func:`paged_decode_attention` — gather the
     block-table pages into position order (dequantized for int8 pools)
     and run masked softmax attention for the single query token. ``q``
-    ``[S, H, D]``; pages ``[N, bs, H, D]`` or the quantized
+    ``[S, H, D]``; pages ``[N, H, bs, D]`` or the quantized
     ``(int8, scales)`` tuple; ``tables`` ``[S, MB]``; ``lengths``
     ``[S]`` (0 = inactive slot -> zero output)."""
     S, H, D = q.shape
@@ -653,9 +659,12 @@ def paged_decode_attention(q, pages_k, pages_v, tables, lengths,
     what makes the pool's ragged sharing free.
 
     Args: ``q`` ``[S, H, D]`` (slot-major, one token per slot);
-    ``pages_k``/``pages_v`` ``[N, bs, H, D]`` (one layer's pool), or the
-    quantized ``(int8 values, scales [N, bs, H])`` tuple — scale pages
-    stream beside the value blocks and dequantization happens in VMEM;
+    ``pages_k``/``pages_v`` ``[N, H, bs, D]`` (one layer's pool — heads
+    ahead of the page's tokens, so each K/V block is one head's whole
+    ``[bs, D]`` page: the TPU lowering requires a block's last two
+    dimensions to be tile-aligned or whole), or the quantized
+    ``(int8 values, scales [N, H, bs])`` tuple — scale pages stream
+    beside the value blocks and dequantization happens in VMEM;
     ``tables`` ``[S, MB]`` int32; ``lengths`` ``[S]`` int32 — the number
     of valid tokens INCLUDING the one just scattered; 0 marks an
     inactive slot (zero output). ``interpret`` defaults to True off-TPU
@@ -664,7 +673,7 @@ def paged_decode_attention(q, pages_k, pages_v, tables, lengths,
     pages_k, scale_k = _unpack_pages(pages_k)
     pages_v, scale_v = _unpack_pages(pages_v)
     quant = scale_k is not None
-    N, bs, Hk, Dk = pages_k.shape
+    N, Hk, bs, Dk = pages_k.shape
     assert (H, D) == (Hk, Dk), f"q heads {(H, D)} != pages {(Hk, Dk)}"
     MB = tables.shape[1]
     scale, interpret = _resolve_defaults(q, scale, interpret)
@@ -674,18 +683,18 @@ def paged_decode_attention(q, pages_k, pages_v, tables, lengths,
         return (s, h, 0, 0)
 
     def kv_map(s, h, j, tbl, lens):
-        return (tbl[s, j], 0, h, 0)
+        return (tbl[s, j], h, 0, 0)
 
     in_specs = [
         pl.BlockSpec((None, None, 1, D), q_map),
-        pl.BlockSpec((None, bs, None, D), kv_map),
-        pl.BlockSpec((None, bs, None, D), kv_map),
+        pl.BlockSpec((None, None, bs, D), kv_map),
+        pl.BlockSpec((None, None, bs, D), kv_map),
     ]
     operands = [q4, pages_k, pages_v]
     if quant:
         # trailing unit dim keeps the scale block 2-D ([bs, 1])
-        in_specs += [pl.BlockSpec((None, bs, None, 1), kv_map),
-                     pl.BlockSpec((None, bs, None, 1), kv_map)]
+        in_specs += [pl.BlockSpec((None, None, bs, 1), kv_map),
+                     pl.BlockSpec((None, None, bs, 1), kv_map)]
         operands += [scale_k[..., None], scale_v[..., None]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -703,7 +712,7 @@ def paged_decode_attention(q, pages_k, pages_v, tables, lengths,
                           quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, 1, D), q.dtype),
-        interpret=interpret,
+        interpret=interpret, name="paged_decode",
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), *operands)
     return out.reshape(S, H, D)
 
@@ -793,7 +802,7 @@ def paged_span_attention(q, pages_k, pages_v, tables, start, n,
     pages_k, scale_k = _unpack_pages(pages_k)
     pages_v, scale_v = _unpack_pages(pages_v)
     quant = scale_k is not None
-    N, bs, Hk, Dk = pages_k.shape
+    N, Hk, bs, Dk = pages_k.shape
     assert (H, D) == (Hk, Dk), f"q heads {(H, D)} != pages {(Hk, Dk)}"
     MB = tables.shape[1]
     scale, interpret = _resolve_defaults(q, scale, interpret)
@@ -803,17 +812,17 @@ def paged_span_attention(q, pages_k, pages_v, tables, start, n,
         return (s, h, 0, 0)
 
     def kv_map(s, h, j, tbl, st, nn):
-        return (tbl[s, j], 0, h, 0)
+        return (tbl[s, j], h, 0, 0)
 
     in_specs = [
         pl.BlockSpec((None, None, Q, D), q_map),
-        pl.BlockSpec((None, bs, None, D), kv_map),
-        pl.BlockSpec((None, bs, None, D), kv_map),
+        pl.BlockSpec((None, None, bs, D), kv_map),
+        pl.BlockSpec((None, None, bs, D), kv_map),
     ]
     operands = [qt, pages_k, pages_v]
     if quant:
-        in_specs += [pl.BlockSpec((None, bs, None, 1), kv_map),
-                     pl.BlockSpec((None, bs, None, 1), kv_map)]
+        in_specs += [pl.BlockSpec((None, None, bs, 1), kv_map),
+                     pl.BlockSpec((None, None, bs, 1), kv_map)]
         operands += [scale_k[..., None], scale_v[..., None]]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -831,7 +840,7 @@ def paged_span_attention(q, pages_k, pages_v, tables, start, n,
                           quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, Q, D), q.dtype),
-        interpret=interpret,
+        interpret=interpret, name="paged_span",
     )(tables.astype(jnp.int32), start.astype(jnp.int32),
       n.astype(jnp.int32), *operands)
     return jnp.swapaxes(out, 1, 2)           # [S, Q, H, D]

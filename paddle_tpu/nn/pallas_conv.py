@@ -22,8 +22,8 @@ Reference lineage: the reference's 1x1 convs run as cuDNN GEMMs
 (``gserver/layers/ExpandConvLayer.cpp`` im2col+GEMM path) — the GEMM view
 is the original form; the TPU twist is owning the dW tiling.
 
-``interpret=None`` auto-selects the Pallas interpreter off-TPU (same
-convention as :mod:`.pallas_attention`).
+Compiled on a TPU, interpreted elsewhere: :func:`pallas_mode.interpret`
+decides, as for every kernel here.
 """
 
 from __future__ import annotations
@@ -36,11 +36,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import pallas_mode
+
 __all__ = ["conv1x1", "conv1x1_strided", "dw_pallas"]
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _dw_kernel(x_ref, dy_ref, out_ref):
@@ -74,7 +72,7 @@ def dw_pallas(x2d, dy2d, interpret: Optional[bool] = None):
     m, cin = x2d.shape
     cout = dy2d.shape[1]
     mc = _chunk_rows(m)
-    interp = _interpret() if interpret is None else interpret
+    interp = pallas_mode.interpret() if interpret is None else interpret
     return pl.pallas_call(
         _dw_kernel,
         grid=(m // mc,),

@@ -217,7 +217,7 @@ class RNN(Module):
         # lax.scan unroll factor: an RNN step is a SMALL matmul, so the
         # while-loop iteration overhead (~10 us on TPU) can dominate;
         # unrolling amortizes it and lets XLA fuse across steps at the cost
-        # of compile time (measured in experiments/PERF.md "Round 5")
+        # of compile time (measured in PERF.md (older installation) "Round 5")
         self.unroll = unroll
 
     def forward(self, x, mask=None, segment_starts=None, initial_state=None):
@@ -233,7 +233,7 @@ class RNN(Module):
         # their input-to-hidden gate matmul computed for the WHOLE sequence
         # in one MXU-shaped [B*T, D] @ [D, G] before the scan; only the
         # serial hidden-to-hidden half stays inside (halves LSTM scan FLOPs
-        # — experiments/PERF.md "Round 5").
+        # — PERF.md (older installation) "Round 5").
         use_proj = hasattr(cell, "input_proj")
         if use_proj:
             x = cell.input_proj(x)

@@ -92,7 +92,8 @@ def build_report(analysis: ModuleAnalysis, *,
         (post-SPMD, per-device) HLO.
       device_kind: ``jax.devices()[0].device_kind`` — keys the bandwidth
         and peak-FLOPs tables; unknown kinds substitute
-        ``DEFAULT_DEVICE`` and set ``bandwidth_assumed``.
+        ``DEFAULT_DEVICE`` and set ``bandwidth_assumed`` — off-TPU only
+        (the CPU what-if); an unknown TPU kind raises.
       n_devices: mesh size (the default replica-group size for
         collectives whose groups aren't printed explicitly).
       cost_analysis_flops: ``compiled.cost_analysis()['flops']`` when the
@@ -111,6 +112,12 @@ def build_report(analysis: ModuleAnalysis, *,
     ici = ici_bytes_per_s if ici_bytes_per_s is not None \
         else ICI_BANDWIDTH.get(device_kind)
     if peak is None or hbm is None or ici is None:
+        if device_kind.startswith("TPU"):
+            # a real chip is never modelled by another chip's numbers
+            raise KeyError(
+                f"no peak FLOP/s or bandwidth entry for device kind "
+                f"{device_kind!r}: add it to obs.telemetry.PEAK_FLOPS and "
+                f"obs.hloprof's HBM/ICI tables")
         assumed = True
         peak = peak if peak is not None else PEAK_FLOPS[DEFAULT_DEVICE]
         hbm = hbm if hbm is not None else HBM_BANDWIDTH[DEFAULT_DEVICE]

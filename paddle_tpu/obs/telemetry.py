@@ -88,10 +88,7 @@ def lowered_hlo_flops(lowered) -> Optional[float]:
     (XLA's HLO-level count; no compile needed). Returns None when the
     backend doesn't implement cost analysis."""
     try:
-        ca = lowered.cost_analysis()
-        if isinstance(ca, (list, tuple)):        # older jax returns [dict]
-            ca = ca[0] if ca else {}
-        flops = ca.get("flops")
+        flops = lowered.cost_analysis().get("flops")
         return float(flops) if flops is not None else None
     except Exception:                            # pragma: no cover - backend
         return None
@@ -134,9 +131,7 @@ class Telemetry:
         device; off by default only when the caller says so.
       memory: sample ``device.memory_stats()`` once per fused call.
       fence: block on the call's outputs to measure true device time.
-        Turn off on pathological transports where ``block_until_ready``
-        does not fence (experiments/PERF.md "Incident") — dispatch time
-        and throughput-derived metrics remain.
+        Off, dispatch time and throughput-derived metrics remain.
       flops_per_step: analytic FLOPs per optimizer step (e.g.
         ``bench.transformer_train_flops``). When absent, the HLO
         cost-analysis estimate (per *call*, i.e. K steps) is used.

@@ -116,20 +116,11 @@ def make_megatron_sp_lm_apply(model, mesh: Mesh, data_axis: str = "data",
     per-layer shard params — layer-boundary seq-shards are all that's saved
     across the stack, composing sequence-parallel activation memory with
     rematerialization for long-context training."""
-    try:
-        from jax import shard_map as _shard_map
-    except ImportError:                      # older jax
-        from jax.experimental.shard_map import shard_map as _shard_map
-
     def shard_map(fn, **kw):
-        if not use_flash:
-            return _shard_map(fn, **kw)
         # pallas_call's out_shapes carry no varying-axes info, so
         # shard_map's vma check rejects the flash path — disable it there
-        # via the shared no-check wrapper (the einsum path keeps the
-        # check; the oracle tests pin both)
-        from .overlap import shard_map_compat
-        return shard_map_compat(fn, **kw)
+        # (the einsum path keeps the check; the oracle tests pin both)
+        return jax.shard_map(fn, check_vma=not use_flash, **kw)
 
     from ..nn import activations
     gelu = activations.get("gelu")

@@ -74,7 +74,7 @@ __all__ = [
     "GRAD_SYNC_SCOPE", "GRAD_SYNC_MODES", "Bucket",
     "partition_buckets", "sync_tangent", "mark_buckets",
     "apply_bucket_sync", "scan_sync_scope", "current_scan_sync",
-    "sync_scan_slice", "resolve_grad_sync", "shard_map_compat",
+    "sync_scan_slice", "resolve_grad_sync",
 ]
 
 # Every explicit-sync psum is traced under this jax.named_scope, so the
@@ -85,21 +85,6 @@ __all__ = [
 GRAD_SYNC_SCOPE = "grad_sync"
 
 GRAD_SYNC_MODES = (None, "bucketed", "fused")
-
-
-def shard_map_compat(fn, **kw):
-    """``shard_map`` across jax versions: new-style ``jax.shard_map`` with
-    ``check_vma`` vs the experimental spelling with ``check_rep``. The
-    manual region always disables the replication check: per-device grad
-    sums are *deliberately* device-varying until the marker psums them."""
-    try:
-        from jax import shard_map as _sm
-    except ImportError:                      # older jax
-        from jax.experimental.shard_map import shard_map as _sm
-    try:
-        return _sm(fn, check_vma=False, **kw)
-    except TypeError:                        # older jax spells it check_rep
-        return _sm(fn, check_rep=False, **kw)
 
 
 # ---------------------------------------------------------------------------

@@ -104,11 +104,6 @@ def make_pipeline(mesh: Mesh, stage_fn: Callable, pipe_axis: str = "pipe"):
     (replicated). ``stage_fn(params_one_stage, act)`` must keep the
     activation shape (homogeneous pipeline; the usual transformer-stack
     case)."""
-    try:
-        from jax import shard_map
-    except ImportError:            # older jax
-        from jax.experimental.shard_map import shard_map
-
     S = dict(zip(mesh.axis_names, mesh.devices.shape))[pipe_axis]
 
     def inner(stage_params, x):
@@ -120,7 +115,7 @@ def make_pipeline(mesh: Mesh, stage_fn: Callable, pipe_axis: str = "pipe"):
         squeezed = jax.tree_util.tree_map(squeeze, stage_params)
         return pipeline_apply(stage_fn, squeezed, x, pipe_axis, S)
 
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(pipe_axis), P()),
         out_specs=P())
@@ -176,11 +171,6 @@ def make_pipeline_loss(mesh: Mesh, stage_fn: Callable, final_fn: Callable,
     per-group partial losses sum into the global scalar); ``extras``:
     per-microbatch aux arrays (targets, masks) with specs
     ``extra_specs``."""
-    try:
-        from jax import shard_map
-    except ImportError:            # older jax
-        from jax.experimental.shard_map import shard_map
-
     S = dict(zip(mesh.axis_names, mesh.devices.shape))[pipe_axis]
 
     def inner(stage_params, final_params, x, *extras):
@@ -195,7 +185,7 @@ def make_pipeline_loss(mesh: Mesh, stage_fn: Callable, final_fn: Callable,
                                    reduce_axes=reduce_axes,
                                    comm_dtype=comm_dtype)
 
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(pipe_axis), P(), x_spec) + tuple(extra_specs),
         out_specs=P())
@@ -320,11 +310,6 @@ def make_pipeline_1f1b(mesh: Mesh, stage_fn: Callable, loss_fn: Callable,
     layout as the params — ready for any :mod:`paddle_tpu.optim` rule.
     ``loss_fn(out_mb) -> scalar`` is the per-microbatch loss applied at the
     last stage (sum-reduced over microbatches)."""
-    try:
-        from jax import shard_map
-    except ImportError:            # older jax
-        from jax.experimental.shard_map import shard_map
-
     S = dict(zip(mesh.axis_names, mesh.devices.shape))[pipe_axis]
 
     def inner(stage_params, x):
@@ -333,5 +318,5 @@ def make_pipeline_1f1b(mesh: Mesh, stage_fn: Callable, loss_fn: Callable,
                                           pipe_axis, S)
         return loss, jax.tree_util.tree_map(lambda g: g[None], grads)
 
-    return shard_map(inner, mesh=mesh, in_specs=(P(pipe_axis), P()),
+    return jax.shard_map(inner, mesh=mesh, in_specs=(P(pipe_axis), P()),
                      out_specs=(P(), P(pipe_axis)))

@@ -105,18 +105,13 @@ def wrap_seq_parallel(attn_fn, mesh: Mesh, seq_axis: str,
     ``batch_axis``) and returns the global output. With
     ``with_segments=True`` the wrapped fn takes a fourth global [B, T]
     packed-sequence id argument (sharded over time like q/k/v)."""
-    try:
-        from jax import shard_map
-    except ImportError:            # older jax
-        from jax.experimental.shard_map import shard_map
-
     n = dict(zip(mesh.axis_names, mesh.devices.shape))[seq_axis]
     spec = P(batch_axis, seq_axis, None, None)
     seg_spec = P(batch_axis, seq_axis)
     fn = functools.partial(attn_fn, axis_name=seq_axis, axis_size=n,
                            causal=causal)
     in_specs = (spec, spec, spec) + ((seg_spec,) if with_segments else ())
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=spec)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=spec)
 
 
 def make_ring_attention(mesh: Mesh, seq_axis: str = "seq",

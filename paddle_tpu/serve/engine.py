@@ -90,6 +90,7 @@ import jax
 import jax.numpy as jnp
 
 from .kv_cache import PagedKVCache, scatter_prefill_pages
+from ..nn import pallas_mode
 from ..parallel.sharding import tp_constrain, tp_shard_scope
 
 __all__ = ["DecodeEngine", "AdmitProbe", "SamplingConfig"]
@@ -171,11 +172,14 @@ def _sample_tokens(cfg: SamplingConfig, logits, keys):
 
 
 def _resolve_attention(attention: str) -> str:
-    """``"auto"`` picks the Pallas paged kernel on TPU and the bit-exact
-    XLA gather path elsewhere (the same auto-select rule as the flash
-    kernels' ``interpret=None``)."""
+    """``"auto"`` is the Pallas paged kernel on a TPU — always: a kernel
+    Mosaic refuses raises, it never gives way to ``"xla"``, whose O(W^2)
+    broadcast per slot is a test reference — and the bit-exact XLA
+    gather path where the kernels would only be interpreted
+    (:func:`paddle_tpu.nn.pallas_mode.interpret`, the same rule as the
+    flash kernels' ``interpret=None``)."""
     if attention == "auto":
-        return "paged" if jax.default_backend() == "tpu" else "xla"
+        return "xla" if pallas_mode.interpret() else "paged"
     if attention not in ("paged", "xla"):
         raise ValueError(f"attention must be 'auto'|'paged'|'xla', "
                          f"got {attention!r}")
@@ -321,6 +325,16 @@ class DecodeEngine:
             self.variables = shard_tree(mesh, variables, param_sharding)
         else:
             self.tp_degree = 1
+            # one device, one placement: params and pools committed
+            # alike, so that the pools a program returns hash like the
+            # ones it was given. jax keys its trace cache on placement;
+            # params arriving as a Trainer left them (on its mesh) beside
+            # uncommitted pools would trace each program twice.
+            leaf = jax.tree_util.tree_leaves(variables)[0]
+            dev = (next(iter(leaf.devices())) if isinstance(leaf, jax.Array)
+                   else jax.devices()[0])
+            placement = jax.sharding.SingleDeviceSharding(dev)
+            self.variables = jax.device_put(variables, placement)
         if max_blocks_per_seq is None:
             max_blocks_per_seq = max(1, model.max_len // block_size)
         if max_blocks_per_seq * block_size > model.max_len:
@@ -336,6 +350,9 @@ class DecodeEngine:
             retain_prefix=retain_prefix, tp_degree=self.tp_degree)
         if mesh is not None:
             self.cache.shard_pools(mesh, tp_axis)
+        else:
+            self.cache.k = jax.device_put(self.cache.k, placement)
+            self.cache.v = jax.device_put(self.cache.v, placement)
         self.max_slots = max_slots
         # host-authoritative slot state beside the cache's tables/lengths
         self.active = np.zeros((max_slots,), bool)
@@ -488,7 +505,7 @@ class DecodeEngine:
         def _pin_pools(fn, pool_outs=(0, 1)):
             def pinned(*args):
                 out = fn(*args)
-                return tuple(tp_constrain(o, 3) if i in pool_outs else o
+                return tuple(tp_constrain(o, 2) if i in pool_outs else o
                              for i, o in enumerate(out))
             return pinned
 
@@ -498,7 +515,7 @@ class DecodeEngine:
                                    donate_argnums=(1, 2))
         self._tick_fn = jax.jit(_in_scope(_pin_pools(tick_fn)),
                                 donate_argnums=(1, 2))
-        # COW block copy: [L, bs, H, hd] pages move pool-internally, one
+        # COW block copy: [L, H, bs, hd] pages move pool-internally, one
         # tiny donated program (not an engine entry point — not counted
         # in compile_counts, traced once for the process lifetime).
         # tree_map covers the quantized (values, scales) tuple pools —
@@ -508,7 +525,7 @@ class DecodeEngine:
         def _cow(pages, src, dst):
             out = jax.tree_util.tree_map(
                 lambda p: p.at[:, dst].set(p[:, src]), pages)
-            return tp_constrain(out, 3)
+            return tp_constrain(out, 2)
 
         self._cow_fn = jax.jit(_in_scope(_cow), donate_argnums=(0,))
         self._zero_keys = jnp.zeros((max_slots, 2), jnp.uint32)
@@ -1175,6 +1192,27 @@ class DecodeEngine:
 
     # -- observability -----------------------------------------------------
 
+    def lower_tick(self):
+        """The decode tick lowered at the engine's shapes, not run
+        (``jax.stages.Lowered``): what :meth:`attribution_report` parses
+        and how a caller reads the tick's compiled text
+        (``lower_tick().compile().as_text()``). The pools are untouched."""
+        tables, lengths = self.cache.device_tables()
+        if self.speculative == 0:
+            keys = jnp.zeros((self.max_slots, 2), jnp.uint32)
+            return self._tick_fn.lower(
+                self.variables, self.cache.k, self.cache.v, tables,
+                lengths, jnp.asarray(self.tokens),
+                jnp.asarray(self.active), keys)
+        span_args = (self.variables, self.cache.k, self.cache.v,
+                     tables, lengths,
+                     jnp.zeros((self.max_slots, self._K1), jnp.int32),
+                     jnp.ones((self.max_slots,), jnp.int32),
+                     jnp.asarray(self.active))
+        if self.sampling is not None:       # stochastic verify: + keys
+            span_args += (jnp.zeros((self.max_slots, 2), jnp.uint32),)
+        return self._tick_fn.lower(*span_args)
+
     def attribution_report(self, emit: bool = True) -> Dict[str, Any]:
         """MFU-gap attribution of the compiled decode tick (the
         ``Trainer.attribution_report`` recipe: one AOT
@@ -1185,24 +1223,7 @@ class DecodeEngine:
         from ..obs import attribution as attr_lib
         from ..obs import hloprof
         from ..obs.telemetry import lowered_hlo_flops
-        tables, lengths = self.cache.device_tables()
-        if self.speculative == 0:
-            keys = jnp.zeros((self.max_slots, 2), jnp.uint32)
-            lowered = self._tick_fn.lower(
-                self.variables, self.cache.k, self.cache.v, tables,
-                lengths, jnp.asarray(self.tokens),
-                jnp.asarray(self.active), keys)
-        else:
-            span_args = (self.variables, self.cache.k, self.cache.v,
-                         tables, lengths,
-                         jnp.zeros((self.max_slots, self._K1), jnp.int32),
-                         jnp.ones((self.max_slots,), jnp.int32),
-                         jnp.asarray(self.active))
-            if self.sampling is not None:   # stochastic verify: + keys
-                span_args += (jnp.zeros((self.max_slots, 2),
-                                        jnp.uint32),)
-            lowered = self._tick_fn.lower(*span_args)
-        compiled = lowered.compile()
+        compiled = self.lower_tick().compile()
         analysis = hloprof.parse_module(compiled.as_text())
         report = attr_lib.build_report(
             analysis,

@@ -1062,6 +1062,8 @@ class ServingFleet:
             raise ValueError(
                 f"replica_mode={replica_mode!r} needs proc_spec — use "
                 "ServingFleet.from_model(...) or build_proc_spec()")
+        if replica_mode in ("process", "socket"):
+            _require_parent_off_chip(replica_mode)
         # prefill/decode disaggregation (ISSUE 18): roles[i] is replica
         # i's role; None = every replica serves "both" (the byte-
         # identical colocated fleet). A mixed fleet needs at least one
@@ -2182,7 +2184,6 @@ class ServingFleet:
                 model_spec=model_spec, order=kw.get("order", "fcfs"),
                 est_tick_s=kw.get("est_tick_s"),
                 warmup=kw.pop("warmup", None),
-                compile_cache_dir=kw.pop("compile_cache_dir", None),
                 autotune_cache_dir=kw.pop("autotune_cache_dir", None),
                 telemetry_dir=kw.pop("telemetry_dir", None))
             return cls(None, n_replicas, replica_mode=replica_mode,
@@ -2192,6 +2193,27 @@ class ServingFleet:
             return DecodeEngine(model, variables, **ek)
 
         return cls(mk, n_replicas, **kw)
+
+
+def _require_parent_off_chip(replica_mode: str) -> None:
+    """A chip belongs to one process: a parent that has initialised an
+    accelerator backend holds it, and a replica child that needs it then
+    fails at start-up or waits out the hello timeout. Say so at once, in
+    the parent, before anything is spawned. (``xla_bridge`` is private;
+    the installed jax 0.9 has no public way to ask whether a backend is
+    up without bringing one up.)"""
+    import jax
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized() \
+            and jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"replica_mode={replica_mode!r} starts child processes that "
+            f"each need the accelerator, but this process has already "
+            f"initialised the {jax.default_backend()!r} backend and holds "
+            f"the chip. On a chip host run replicas in-process "
+            f"(replica_mode='inprocess'), or start one process per chip "
+            f"from a parent that never initialises a JAX backend (build "
+            f"the spec under JAX_PLATFORMS=cpu).")
 
 
 def _introspect_lm(model) -> Dict[str, Any]:
@@ -2213,7 +2235,6 @@ def build_proc_spec(model, variables, root: str, *,
                     est_tick_s: Optional[float] = None,
                     mesh_axes: Optional[Dict[str, int]] = None,
                     warmup: Optional[bool] = None,
-                    compile_cache_dir: Optional[str] = None,
                     autotune_cache_dir: Optional[str] = None,
                     telemetry_dir: Optional[str] = None
                     ) -> Dict[str, Any]:
@@ -2230,12 +2251,15 @@ def build_proc_spec(model, variables, root: str, *,
     single-device spec is byte-identical to the pre-tp schema —
     replicas on old and new code agree on the frame bytes.
 
-    ``warmup`` / ``compile_cache_dir`` / ``autotune_cache_dir``
-    (ISSUE 16): the cold-start trio — the child executes both engine
-    programs before its hello reply, against a persistent XLA compile
-    cache and kernel-autotune cache shared across spawns, so autoscaler
+    ``warmup`` / ``autotune_cache_dir`` (ISSUE 16): the child executes
+    both engine programs before its hello reply, against a
+    kernel-autotune cache shared across spawns, so autoscaler
     cold-spawns and supervisor restarts come up warm. Same
     schema-stability rule as ``mesh``: each key is ABSENT when unset.
+    The XLA compile cache is not in the spec: every child turns it on
+    (``obs.xla_cache.setup``) at the directory its ENVIRONMENT names
+    (``JAX_COMPILATION_CACHE_DIR``), else the fixed in-checkout
+    default — the same directory for every spawn either way.
 
     ``telemetry_dir`` (ISSUE 17): a directory where each child replica
     line-flushes its telemetry records to ``replica_<id>.jsonl`` AS
@@ -2254,8 +2278,6 @@ def build_proc_spec(model, variables, root: str, *,
         spec["mesh"] = dict(mesh_axes)
     if warmup is not None:
         spec["warmup"] = bool(warmup)
-    if compile_cache_dir:
-        spec["compile_cache_dir"] = str(compile_cache_dir)
     if autotune_cache_dir:
         spec["autotune_cache_dir"] = str(autotune_cache_dir)
     if telemetry_dir:

@@ -5,7 +5,10 @@ contiguous allocation at ``max_len`` wastes most of it: concurrent sequences
 have ragged lengths, so reserving the worst case per slot strands HBM
 (PagedAttention's motivating measurement — PAPERS.md [S1]). The fix is the
 OS page-table design: the cache is a single pool of fixed-size **blocks**
-(``[num_blocks, block_size, heads, head_dim]`` per layer) and each sequence
+(``[num_blocks, heads, block_size, head_dim]`` per layer — heads ahead of
+the block's tokens, so one head's page is a whole ``[block_size,
+head_dim]`` tile, the shape the TPU's Pallas lowering can block and
+DMA) and each sequence
 holds an ordered **block table** of pool indices; allocation is
 block-granular, so waste is bounded by one partial block per sequence and
 freed blocks are immediately reusable by any other request.
@@ -44,7 +47,7 @@ pool bytes.
 
 **Int8 KV quantization** (ISSUE 14, the capacity lever): with
 ``kv_dtype="int8"`` each pool stores symmetric int8 values plus a
-per-block scale page ``[L, num_blocks, block_size, H]`` (one f32 scale
+per-block scale page ``[L, num_blocks, H, block_size]`` (one f32 scale
 per token row per head — scales live at block granularity beside the
 pools, per-row within the block so incremental scatters NEVER requantize
 resident tokens). Quantization happens on scatter
@@ -112,22 +115,20 @@ def dequantize_rows(q, scale):
 def gather_pages(pages, table):
     """Gather one layer's paged K (or V) into position order.
 
-    ``pages`` ``[N, bs, H, hd]``, ``table`` ``[S, MB]`` int32 ->
+    ``pages`` ``[N, H, bs, hd]``, ``table`` ``[S, MB]`` int32 ->
     ``[S, MB*bs, H, hd]``: row ``s``'s tokens ``0..len-1`` in order, with
     unspecified (null-block / stale) content beyond the sequence length —
     the attention mask owns that boundary. A quantized pool — the tuple
-    ``(int8 values, scales [N, bs, H])`` — gathers DEQUANTIZED f32
+    ``(int8 values, scales [N, H, bs])`` — gathers DEQUANTIZED f32
     values, so every consumer downstream of the gather is
     dtype-oblivious."""
     if isinstance(pages, tuple):
         vals, scales = pages
-        S, MB = table.shape
-        _, bs, H, hd = vals.shape
-        deq = dequantize_rows(vals[table], scales[table])
-        return deq.reshape(S, MB * bs, H, hd)
-    S, MB = table.shape
-    _, bs, H, hd = pages.shape
-    return pages[table].reshape(S, MB * bs, H, hd)
+        pages = dequantize_rows(vals[table], scales[table])
+    else:
+        pages = pages[table]                          # [S, MB, H, bs, hd]
+    S, MB, H, bs, hd = pages.shape
+    return jnp.swapaxes(pages, 2, 3).reshape(S, MB * bs, H, hd)
 
 
 def scatter_prefill(pages, kv, table, length, start=0):
@@ -141,7 +142,7 @@ def scatter_prefill(pages, kv, table, length, start=0):
     never write a multiply-owned page (the COW discipline). Returns the
     updated pool. ``table`` ``[B, MB]``, ``length`` ``[B]``."""
     B, W = kv.shape[:2]
-    bs = pages.shape[1]
+    bs = pages.shape[2]
     start = jnp.broadcast_to(jnp.asarray(start, jnp.int32), (B,))
     pos = jnp.arange(W, dtype=jnp.int32)
     live = ((pos[None, :] < length[:, None])
@@ -150,7 +151,9 @@ def scatter_prefill(pages, kv, table, length, start=0):
                     jnp.take_along_axis(table, pos[None, :] // bs, axis=1),
                     NULL_BLOCK)                                   # [B, W]
     off = jnp.broadcast_to(pos % bs, (B, W))
-    return pages.at[blk, off].set(kv)
+    # heads sit between the block id and the in-block offset; the two
+    # index arrays broadcast to the front, so the update is kv's shape
+    return pages.at[blk, :, off].set(kv)
 
 
 def scatter_token(pages, kv, table, position, active):
@@ -161,12 +164,12 @@ def scatter_token(pages, kv, table, position, active):
     length BEFORE this token); inactive slots scatter to the null block.
     Returns the updated pool."""
     S = kv.shape[0]
-    bs = pages.shape[1]
+    bs = pages.shape[2]
     blk = jnp.where(active,
                     table[jnp.arange(S), position // bs],
                     NULL_BLOCK)                                   # [S]
     off = position % bs
-    return pages.at[blk, off].set(kv)
+    return pages.at[blk, :, off].set(kv)
 
 
 def scatter_span(pages, kv, table, start, n, write_from=None):
@@ -181,7 +184,7 @@ def scatter_span(pages, kv, table, start, n, write_from=None):
     it — a chunk re-reading a fully shared prefix for its logits must
     not write the co-owned pages. Returns the updated pool."""
     S, Q = kv.shape[:2]
-    bs = pages.shape[1]
+    bs = pages.shape[2]
     MB = table.shape[1]
     pos = start[:, None] + jnp.arange(Q, dtype=jnp.int32)[None, :]  # [S, Q]
     live = jnp.arange(Q, dtype=jnp.int32)[None, :] < n[:, None]
@@ -193,14 +196,14 @@ def scatter_span(pages, kv, table, start, n, write_from=None):
     blk = jnp.where(live, jnp.take_along_axis(table, idx, axis=1),
                     NULL_BLOCK)                                   # [S, Q]
     off = pos % bs
-    return pages.at[blk, off].set(kv)
+    return pages.at[blk, :, off].set(kv)
 
 
 # quant-aware scatter wrappers: a plain pool scatters values as-is; a
 # quantized pool (the (values, scales) tuple) quantizes on scatter —
 # int8 rows into the value pages, per-row-per-head scales into the
 # scale pages with the SAME block/offset routing (the scatter functions
-# above are shape-agnostic past the [blocks, block_size] prefix).
+# above are shape-agnostic past the [blocks, heads, block_size] prefix).
 
 def scatter_prefill_pages(pages, kv, table, length, start=0):
     if isinstance(pages, tuple):
@@ -245,7 +248,7 @@ def scatter_span_pages(pages, kv, table, start, n, write_from=None):
 # exactly the ~2.7x-smaller `bytes_per_block`). No base64, no JSON.
 
 def pages_to_blobs(kpages, vpages) -> List[bytes]:
-    """Serialize exported pages (``[L, nb, bs, H, hd]`` arrays, or
+    """Serialize exported pages (``[L, nb, H, bs, hd]`` arrays, or
     ``(values, scales)`` tuples when quantized) into one ``bytes`` blob
     per block: K leaves then V leaves, each C-contiguous. The inverse is
     :func:`blobs_to_pages`; each blob is exactly ``bytes_per_block``
@@ -273,10 +276,10 @@ def blobs_to_pages(blobs: List[bytes], *, num_layers: int,
         raise ValueError("handoff carries zero page blobs")
     L, bs, H, hd = num_layers, block_size, num_heads, head_dim
     if quantized:
-        specs = [((L, bs, H, hd), np.dtype(np.int8)),
-                 ((L, bs, H), np.dtype(np.float32))]
+        specs = [((L, H, bs, hd), np.dtype(np.int8)),
+                 ((L, H, bs), np.dtype(np.float32))]
     else:
-        specs = [((L, bs, H, hd), np.dtype(dtype))]
+        specs = [((L, H, bs, hd), np.dtype(dtype))]
     leaf_bytes = [int(np.prod(s)) * d.itemsize for s, d in specs]
     per_blob = 2 * sum(leaf_bytes)
     nleaves = len(specs)
@@ -545,7 +548,7 @@ class PagedKVCache:
     """Device pools + the authoritative host mirror of block tables and
     sequence lengths for up to ``max_slots`` concurrent sequences.
 
-    ``k``/``v`` are ``[L, num_blocks, block_size, H, hd]`` device arrays
+    ``k``/``v`` are ``[L, num_blocks, H, block_size, hd]`` device arrays
     (the leading layer axis matches the model's scan-over-layers stack, so
     the decode scan consumes one layer's pool per iteration). The compiled
     tick DONATES and returns them; the engine reassigns ``cache.k/.v``
@@ -561,7 +564,7 @@ class PagedKVCache:
         self.num_heads = num_heads
         self.head_dim = head_dim
         # tensor-parallel degree (ISSUE 15): the pools are LOGICALLY
-        # [L, N, bs, H, hd] but physically head-sharded over a tp mesh
+        # [L, N, H, bs, hd] but physically head-sharded over a tp mesh
         # (`shard_pools`), so every per-byte accounting number here is
         # PER SHARD — each device holds H/tp heads of every block, and
         # capacity at equal per-device HBM scales with the mesh. Tables,
@@ -580,7 +583,7 @@ class PagedKVCache:
             raise ValueError(f"kv_dtype must be None|'f32'|'int8', "
                              f"got {kv_dtype!r}")
         self.quantized = kv_dtype == "int8"
-        shape = (num_layers, num_blocks, block_size, num_heads, head_dim)
+        shape = (num_layers, num_blocks, num_heads, block_size, head_dim)
         if self.quantized:
             # int8 value pages + per-block scale pages (one f32 per
             # token row per head) — quantize-on-scatter writes both
@@ -778,8 +781,8 @@ class PagedKVCache:
     def export_pages(self, slot: int):
         """Read out the pool pages covering ``slot``'s current length
         for a prefill→decode handoff: returns ``(block_ids, kpages,
-        vpages)`` where the page arrays are host numpy ``[L, nb, bs, H,
-        hd]`` (plus ``[L, nb, bs, H]`` scale leaves as ``(values,
+        vpages)`` where the page arrays are host numpy ``[L, nb, H, bs,
+        hd]`` (plus ``[L, nb, H, bs]`` scale leaves as ``(values,
         scales)`` tuples when quantized) in TABLE ORDER — physical block
         ids don't travel; the receiver re-homes the pages at its own
         allocations. Shared/adopted blocks export fine (it's a read);
@@ -868,8 +871,8 @@ class PagedKVCache:
 
     def shard_pools(self, mesh, axis: str = "model") -> None:
         """Commit the device pools head-sharded over ``mesh``'s ``axis``
-        (ISSUE 15): values ``[L, N, bs, H, hd]`` split on the H axis,
-        int8 scale pages ``[L, N, bs, H]`` split identically, so every
+        (ISSUE 15): values ``[L, N, H, bs, hd]`` split on the H axis,
+        int8 scale pages ``[L, N, H, bs]`` split identically, so every
         shard owns its head group of EVERY block. The host side — tables,
         lengths, allocator, prefix cache — is untouched and stays
         shard-oblivious: admission/eviction/CoW/retention reason about
@@ -880,8 +883,8 @@ class PagedKVCache:
         # `tp_constrain` pins on the compiled programs' pool outputs, so
         # the carry's sharding hashes identical call to call (padded vs
         # trimmed specs retrace on some jax versions). Covers both the
-        # [L, N, bs, H, hd] value pages and [L, N, bs, H] scale pages.
-        sh = NamedSharding(mesh, P(None, None, None, axis))
+        # [L, N, H, bs, hd] value pages and [L, N, H, bs] scale pages.
+        sh = NamedSharding(mesh, P(None, None, axis))
 
         def put(pool):
             if isinstance(pool, tuple):
@@ -893,5 +896,8 @@ class PagedKVCache:
         self.v = put(self.v)
 
     def device_tables(self) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """The current (tables, lengths) as device operands for a tick."""
-        return jnp.asarray(self.tables), jnp.asarray(self.lengths)
+        """The current (tables, lengths) as device operands for a tick.
+        Copies, never views: on the CPU backend ``jnp.asarray`` may alias
+        a numpy buffer zero-copy, and the engine bumps ``lengths`` in
+        place while the tick that reads them is still in flight."""
+        return jnp.array(self.tables), jnp.array(self.lengths)

@@ -154,14 +154,16 @@ def _build(spec: Dict[str, Any]):
     the JSON wire; a spec without the key is the single-device engine,
     bit-identical to the pre-tp build.
 
-    Cold-start elimination (ISSUE 16): ``spec["compile_cache_dir"]``
-    points jax's persistent compilation cache at a directory shared
-    across spawns, ``spec["autotune_cache_dir"]`` enables the kernel
-    autotuner against its JSON cache, and ``spec["warmup"]`` executes
-    both engine programs before the hello reply — so an autoscaler
-    cold-spawn or supervisor restart answers its first request with
-    zero compiles on the serving path. All three keys are ABSENT from
-    a default spec (build unchanged, byte-identical schema). Returns
+    Cold-start elimination (ISSUE 16): ``main`` turns the persistent
+    compilation cache on before this runs (``obs.xla_cache.setup``: the
+    directory comes from ``JAX_COMPILATION_CACHE_DIR`` in the child's
+    environment, else the fixed in-checkout default — never from the
+    spec — so every spawn shares it), ``spec["autotune_cache_dir"]``
+    enables the kernel autotuner against its JSON cache, and
+    ``spec["warmup"]`` executes both engine programs before the hello
+    reply — so an autoscaler cold-spawn or supervisor restart answers
+    its first request with zero compiles on the serving path. Both keys
+    are ABSENT from a default spec (byte-identical schema). Returns
     ``(engine, sched, buf, clock, startup_ms)`` where ``startup_ms``
     is the build/compile/warmup wall breakdown the hello and heartbeat
     payloads carry."""
@@ -175,9 +177,6 @@ def _build(spec: Dict[str, Any]):
     from .engine import DecodeEngine
     from .scheduler import ContinuousBatchingScheduler
 
-    if spec.get("compile_cache_dir"):
-        from ..obs import xla_cache
-        xla_cache.setup_compilation_cache(spec["compile_cache_dir"])
     if spec.get("autotune_cache_dir"):
         from ..nn import autotune
         autotune.enable(spec["autotune_cache_dir"])
@@ -207,8 +206,13 @@ def _build(spec: Dict[str, Any]):
         ek["mesh"] = Mesh(np.asarray(devs[:need]).reshape(sizes), names)
     engine = DecodeEngine(model, vs, **ek)
     t_built = time.perf_counter()
+    dev = jax.devices()[0]
     startup: Dict[str, Any] = {
-        "build": round((t_built - t_start) * 1e3, 3)}
+        "build": round((t_built - t_start) * 1e3, 3),
+        # where this replica runs, as jax reports it: a startup or
+        # serving number read off the hello names its device
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())}}
     if spec.get("warmup"):
         rep = engine.warmup()
         t_warm = time.perf_counter()
@@ -722,6 +726,8 @@ def main(argv: Optional[List[str]] = None) -> int:
         with open(raw[1:]) as f:
             raw = f.read()
     spec = json.loads(raw)
+    from ..obs import xla_cache
+    xla_cache.setup()
     engine, sched, buf, clock, startup, metrics = _build(spec)
     return serve_loop(
         read_file, out, engine=engine, sched=sched, buf=buf,

@@ -326,6 +326,8 @@ def run(flags: TrainCliFlags) -> dict:
 
 def main(argv: Optional[list] = None) -> None:
     flags = parse_flags(TrainCliFlags, argv)
+    from ..obs import xla_cache
+    xla_cache.setup()               # before the first compile
     metrics = run(flags)
     print(json.dumps({k: (round(v, 6) if isinstance(v, float) else v)
                       for k, v in metrics.items()}))

@@ -122,16 +122,27 @@ def test_schema_bump_ignores_stale_entries(tmp_path):
     assert "kk" in doc["entries"]
 
 
-def test_all_candidates_fail_returns_default_stores_nothing(tmp_path):
+def test_all_candidates_fail_raises_stores_nothing(tmp_path):
+    """One failing candidate is skipped; when every candidate fails the
+    error propagates (a kernel that cannot compile must be seen) and the
+    cache is not poisoned."""
     autotune.enable(str(tmp_path))
 
     def boom(**kw):
         raise ValueError("mis-tiled")
 
-    got = autotune.choose("k", key="kk", candidates=[{"b": 0}, {"b": 1}],
-                          runner=boom, default={"b": 7})
-    assert got == {"b": 7}
+    with pytest.raises(ValueError, match="mis-tiled"):
+        autotune.choose("k", key="kk", candidates=[{"b": 0}, {"b": 1}],
+                        runner=boom, default={"b": 7})
     assert not os.path.exists(autotune.cache_file())   # cache not poisoned
+
+    def one_bad(b):
+        if b == 0:
+            raise ValueError("mis-tiled")
+
+    got = autotune.choose("k", key="kk", candidates=[{"b": 0}, {"b": 1}],
+                          runner=one_bad, default={"b": 7})
+    assert got == {"b": 1}
 
 
 # ---------------------------------------------------------------------------

@@ -81,7 +81,7 @@ def test_cache_capacity_and_free(nprng):
 
 def test_gather_scatter_roundtrip(nprng):
     H, hd = 2, 4
-    pages = jnp.zeros((8, BS, H, hd), jnp.float32)
+    pages = jnp.zeros((8, H, BS, hd), jnp.float32)
     table = jnp.asarray([[3, 1, 5, 0, 0, 0]], jnp.int32)
     kv = jnp.asarray(nprng.randn(1, MB * BS, H, hd).astype(np.float32))
     length = jnp.asarray([9], jnp.int32)
@@ -91,7 +91,7 @@ def test_gather_scatter_roundtrip(nprng):
                                   np.asarray(kv[0, :9]))
     # rows >= length went to the null block, not the sequence's pages:
     # row 8 is block 5 offset 0, so block 5's tail stays untouched
-    assert not np.any(np.asarray(pages[5][1:]))
+    assert not np.any(np.asarray(pages[5][:, 1:]))
 
     tok = jnp.asarray(nprng.randn(1, H, hd).astype(np.float32))
     pages = kvc.scatter_token(pages, tok, table, jnp.asarray([9]),
@@ -115,8 +115,8 @@ def test_paged_decode_attention_matches_reference(nprng):
                                                 paged_reference_attention)
     S, H, D, N = 4, 2, 16, 32
     q = jnp.asarray(nprng.randn(S, H, D).astype(np.float32))
-    pk = jnp.asarray(nprng.randn(N, BS, H, D).astype(np.float32))
-    pv = jnp.asarray(nprng.randn(N, BS, H, D).astype(np.float32))
+    pk = jnp.asarray(nprng.randn(N, H, BS, D).astype(np.float32))
+    pv = jnp.asarray(nprng.randn(N, H, BS, D).astype(np.float32))
     tables = jnp.asarray(nprng.randint(0, N, (S, MB)), jnp.int32)
     # ragged: mid-block, inactive, full capacity, block-boundary
     lengths = jnp.asarray([5, 0, MB * BS, 12], jnp.int32)
@@ -1012,8 +1012,8 @@ def test_quantized_pool_scatter_gather_dequantizes(nprng):
     """The (values, scales) tuple pool: scatter quantizes, gather
     returns dequantized f32 close to the original rows."""
     H, hd = 2, 8
-    pages = (jnp.zeros((8, BS, H, hd), jnp.int8),
-             jnp.zeros((8, BS, H), jnp.float32))
+    pages = (jnp.zeros((8, H, BS, hd), jnp.int8),
+             jnp.zeros((8, H, BS), jnp.float32))
     table = jnp.asarray([[3, 1, 5, 0, 0, 0]], jnp.int32)
     kv = jnp.asarray(nprng.randn(1, MB * BS, H, hd).astype(np.float32))
     pages = kvc.scatter_prefill_pages(pages, kv, table,
@@ -1083,8 +1083,8 @@ def test_quantized_paged_kernel_matches_reference(nprng):
                                                 paged_reference_attention)
     S, H, D, N = 4, 2, 16, 32
     q = jnp.asarray(nprng.randn(S, H, D).astype(np.float32))
-    raw_k = jnp.asarray(nprng.randn(N, BS, H, D).astype(np.float32))
-    raw_v = jnp.asarray(nprng.randn(N, BS, H, D).astype(np.float32))
+    raw_k = jnp.asarray(nprng.randn(N, H, BS, D).astype(np.float32))
+    raw_v = jnp.asarray(nprng.randn(N, H, BS, D).astype(np.float32))
     pk = kvc.quantize_rows(raw_k)
     pv = kvc.quantize_rows(raw_v)
     tables = jnp.asarray(nprng.randint(0, N, (S, MB)), jnp.int32)
@@ -1110,8 +1110,8 @@ def test_paged_span_kernel_matches_oracle_and_q1_bit_exact(nprng):
         paged_decode_attention, paged_span_attention,
         paged_span_reference_attention)
     S, H, D, N = 4, 2, 16, 32
-    pk = jnp.asarray(nprng.randn(N, BS, H, D).astype(np.float32))
-    pv = jnp.asarray(nprng.randn(N, BS, H, D).astype(np.float32))
+    pk = jnp.asarray(nprng.randn(N, H, BS, D).astype(np.float32))
+    pv = jnp.asarray(nprng.randn(N, H, BS, D).astype(np.float32))
     tables = jnp.asarray(nprng.randint(0, N, (S, MB)), jnp.int32)
     for k in (0, 3):
         Q = 1 + k
@@ -1146,9 +1146,9 @@ def test_paged_span_kernel_quantized(nprng):
     S, Q, H, D, N = 3, 4, 2, 16, 32
     q = jnp.asarray(nprng.randn(S, Q, H, D).astype(np.float32))
     pk = kvc.quantize_rows(
-        jnp.asarray(nprng.randn(N, BS, H, D).astype(np.float32)))
+        jnp.asarray(nprng.randn(N, H, BS, D).astype(np.float32)))
     pv = kvc.quantize_rows(
-        jnp.asarray(nprng.randn(N, BS, H, D).astype(np.float32)))
+        jnp.asarray(nprng.randn(N, H, BS, D).astype(np.float32)))
     tables = jnp.asarray(nprng.randint(0, N, (S, MB)), jnp.int32)
     start = jnp.asarray([2, 0, 9], jnp.int32)
     n = jnp.asarray([Q, 0, Q], jnp.int32)
@@ -1592,5 +1592,5 @@ def test_proc_spec_ships_mesh_and_single_device_roundtrip(
     assert startup["build"] > 0 and startup["warmup"] == 0.0
     # warmup/cache fields stay ABSENT from an unconfigured spec (the
     # PR-15 schema-stability rule extends to the ISSUE-16 fields)
-    for k in ("warmup", "compile_cache_dir", "autotune_cache_dir"):
+    for k in ("warmup", "autotune_cache_dir"):
         assert k not in plain
