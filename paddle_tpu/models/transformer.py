@@ -76,7 +76,7 @@ class TransformerBlock(Module):
 
     def forward(self, x, train: bool = False, segments=None,
                 return_kv: bool = False):
-        # named_scope: profiler traces (utils/stats.py:profile_trace) show
+        # named_scope: profiler traces (obs/trace.py:jax_profile) show
         # model structure instead of anonymous fusions — trace-time
         # metadata only, zero runtime effect.
         with jax.named_scope("attn"):

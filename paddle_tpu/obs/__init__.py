@@ -19,6 +19,20 @@ The layers:
   emitted as Chrome Trace Event Format JSON (Perfetto-viewable), with
   flow events linking a group's stager-thread staging to its main-thread
   dispatch and drain, and programmatic ``jax.profiler`` capture windows.
+  ONE rule decides whether the program's spans (trainer loop,
+  scheduler, engine) are recorded, and there is no knob: a tracer is
+  attached to the object, or a ``jax.profiler`` session is active in
+  the process (:func:`~paddle_tpu.obs.trace.live`). In the second case
+  they go to the process-wide :func:`session_tracer` and, as
+  ``TraceAnnotation``s named ``paddle_tpu:<name>``, into the profiler's
+  own trace, on the device operations' clock:
+  ``jax.profiler.start_trace`` round any entry point is all an operator
+  needs to get host and device on one timeline. :func:`self_times`
+  gives each span's duration less its children's.
+- :mod:`~paddle_tpu.obs.xla_cache` — where the persistent compilation
+  cache lives, and ``compile_log()``: every trace, lowering and backend
+  compile JAX reports (``jax.monitoring``), with its function's name,
+  seconds, time and whether the cache answered. Always recorded.
 - :mod:`~paddle_tpu.obs.anomaly` — :class:`AnomalyDetector`: rolling
   robust statistics over the telemetry stream (slow-step outliers,
   retrace bursts, drain stalls, memory high-water, the NaN sentinel);
@@ -54,8 +68,8 @@ all-reduces under the backward pass.
 
 Attach with ``Trainer(..., telemetry=Telemetry(sinks=[JsonlSink(path)]),
 tracer=Tracer(), anomaly=AnomalyDetector(out_dir))``. With none attached
-the hot loop is unchanged: same traced step, same dispatch count, same
-donation, zero extra device fetches.
+(and no profiler session) the hot loop is unchanged: same traced step,
+same dispatch count, same donation, zero extra device fetches.
 """
 
 from . import attribution, hloprof, xla_cache, xla_flags
@@ -77,14 +91,15 @@ from .sinks import InMemorySink, JsonlSink, LoggingSink, Sink
 from .slo import SLOMonitor, SLOTargets
 from .telemetry import (PEAK_FLOPS, Telemetry, device_memory_stats,
                         device_peak_flops, lowered_hlo_flops)
-from .trace import Tracer, jax_profile, tspan
+from .trace import (Tracer, jax_profile, live, self_times, session_tracer,
+                    tspan)
 
 __all__ = [
     "Telemetry", "Sink", "InMemorySink", "JsonlSink", "LoggingSink",
     "HEALTH_KEYS", "health_scalars", "tree_l2_norm", "tree_nonfinite_count",
     "PEAK_FLOPS", "device_peak_flops", "lowered_hlo_flops",
     "device_memory_stats",
-    "Tracer", "tspan", "jax_profile",
+    "Tracer", "tspan", "jax_profile", "live", "session_tracer", "self_times",
     "AnomalyDetector", "ServingAnomalyDetector", "Verdict",
     "ANOMALY_KINDS", "SERVING_ANOMALY_KINDS",
     "hloprof", "attribution", "xla_flags",

@@ -12,11 +12,26 @@ host-side story lines up with device profiles side by side.
 
 Design rules:
 
+- **One rule decides whether a span is recorded, and there is no
+  knob.** A call site's spans are live when a tracer is attached to
+  its object (``Trainer(tracer=)``, ``ContinuousBatchingScheduler(
+  tracer=)``, ``engine.tracer``) OR a ``jax.profiler`` session is
+  active in the process (``jax.profiler.TraceAnnotation.is_enabled()``,
+  which the program can observe by itself). In the second case they go
+  to the one process-wide :func:`session_tracer`. :func:`live` is that
+  rule; :func:`tspan` and every ``self.tracer`` guard of the trainer,
+  the scheduler and the engine go through it. With neither, a call
+  site costs the ``is_enabled()`` test and nothing else
+  (``tests/test_trace.py`` and ``tests/test_program_spans.py`` pin it).
 - **Spans are host-side and cheap.** One `perf_counter_ns` pair + one
-  locked deque append per span; no device interaction, no fences, no
-  extra dispatches. The Trainer guards every span behind ``tracer is
-  None`` (via :func:`tspan`), so tracing off is the byte-identical hot
-  loop (``tests/test_trace.py`` pins it).
+  deque append per span; no device interaction, no fences, no extra
+  dispatches.
+- **On the device trace's clock.** While a profiler session is active
+  every span is also a ``jax.profiler.TraceAnnotation`` named
+  ``paddle_tpu:<name>`` with the span's facts: an event of the trace's
+  ``/host:CPU`` plane, on the same clock as the device's ``XLA Ops``.
+  ``jax.profiler.start_trace`` round any entry point is all an
+  operator needs to get host and device on one timeline.
 - **Thread-aware by construction.** Events carry the OS thread id;
   ``thread_name`` metadata events name the main loop, the
   ``host_pipeline.stager`` thread, and the ``data.buffered.fill`` thread
@@ -50,6 +65,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import functools
 import itertools
 import json
 import logging
@@ -58,22 +74,79 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
-__all__ = ["Tracer", "tspan", "jax_profile"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Tracer", "tspan", "live", "session_tracer", "self_times",
+           "traced", "jax_profile", "ANNOTATION_PREFIX", "NULL_SPAN"]
 
 _log = logging.getLogger("paddle_tpu.trace")
 
 # Shared no-op context for tracer-off call sites: stateless, so one
-# instance is safe across threads and reentrant use.
-_NULL = contextlib.nullcontext()
+# instance is safe across threads and reentrant use. It yields None
+# where a live span yields itself (``sp.set(...)`` needs the test).
+NULL_SPAN = contextlib.nullcontext()
+
+
+# what a span is called in the profiler's trace: ``paddle_tpu:<name>``
+ANNOTATION_PREFIX = "paddle_tpu:"
+
+# True while a ``jax.profiler`` session is active in this process
+_session_active = TraceAnnotation.is_enabled
+
+_session: Optional["Tracer"] = None
+_session_lock = threading.Lock()
+
+
+def session_tracer() -> "Tracer":
+    """The one process-wide tracer (default clock) that call sites with
+    no tracer attached record into while a ``jax.profiler`` session is
+    active. Readers take its spans with ``between(lo, hi)``, two
+    readings of ``time.perf_counter()``."""
+    global _session
+    if _session is None:
+        with _session_lock:
+            if _session is None:
+                _session = Tracer()
+    return _session
+
+
+def live(tracer: Optional["Tracer"]) -> Optional["Tracer"]:
+    """THE rule: the tracer a call site records into, or None. The
+    attached ``tracer`` where there is one; the session tracer while a
+    ``jax.profiler`` session is active; else None, and the call site
+    does nothing more."""
+    if tracer is not None:
+        return tracer
+    if _session_active():
+        return session_tracer()
+    return None
 
 
 def tspan(tracer: Optional["Tracer"], name: str, **kw):
-    """Null-safe span helper: a real ``tracer.span(...)`` when tracing is
-    on, the shared no-op context when ``tracer`` is None — the hot loop
-    never branches further than this."""
+    """Null-safe span helper: a real span of :func:`live`'s tracer, or
+    the shared no-op context (which yields None) when there is none —
+    the hot loop never branches further than this."""
+    tracer = live(tracer)
     if tracer is None:
-        return _NULL
+        return NULL_SPAN
     return tracer.span(name, **kw)
+
+
+def traced(name: str):
+    """Decorator: each call of the method is the span ``name`` of
+    :func:`live`'s tracer for the object's ``tracer`` attribute (None
+    where it has none yet, as inside ``__init__``). For entry points
+    that run once and lower little (the engine's ``__init__`` and
+    ``warmup``): the wrapper is one more Python frame under everything
+    the method calls, and JAX's lowering pays for each frame between
+    the entry point and a jit call (PERF.md, PR 25)."""
+    def decorate(fn):
+        @functools.wraps(fn)
+        def wrapper(self, *args, **kw):
+            with tspan(getattr(self, "tracer", None), name):
+                return fn(self, *args, **kw)
+        return wrapper
+    return decorate
 
 
 @contextlib.contextmanager
@@ -106,6 +179,58 @@ def _json_safe(v):
     return str(v)
 
 
+class _Span:
+    """One span being recorded: the context manager ``Tracer.span``
+    returns. ``t0_ns`` / ``t1_ns`` are its ``perf_counter_ns`` stamps,
+    readable after entry / exit, so a caller that needs the duration
+    for its own books (the trainer's ``StatSet`` and telemetry) reads
+    this one clock pair instead of taking another. :meth:`set` adds
+    facts known only once the body ran (tokens retired, admissions
+    made)."""
+
+    __slots__ = ("_tracer", "_name", "_args", "_flows", "_tid", "_ts",
+                 "_ann", "t0_ns", "t1_ns")
+
+    def __init__(self, tracer, name, flows, args):
+        self._tracer = tracer
+        self._name = name
+        self._flows = flows
+        self._args = args
+        self._ann = None
+
+    def set(self, **facts) -> None:
+        self._args.update(facts)
+        if self._ann is not None:
+            self._ann.set_metadata(**facts)
+
+    def __enter__(self):
+        tr = self._tracer
+        self._tid = tid = threading.get_ident()
+        tr._note_thread(tid)
+        if _session_active():
+            self._ann = TraceAnnotation(ANNOTATION_PREFIX + self._name,
+                                        **self._args)
+            self._ann.__enter__()
+        self.t0_ns = t0 = time.perf_counter_ns()
+        self._ts = (tr._now_us() if tr._clock is not None
+                    else (t0 - tr.epoch_ns) / 1e3)
+        return self
+
+    def __exit__(self, *exc):
+        tr = self._tracer
+        self.t1_ns = t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        end = (tr._now_us() if tr._clock is not None
+               else (t1 - tr.epoch_ns) / 1e3)
+        tr._emit_span(self._name, self._tid, self._ts, end, *self._flows,
+                      self._args)
+        return False
+
+
+_NO_FLOWS = (None, None, None)
+
+
 class Tracer:
     """Thread-aware span recorder emitting Chrome Trace Event Format.
 
@@ -114,7 +239,11 @@ class Tracer:
     optional flow ids attach ``s``/``t``/``f`` flow events at the span's
     start timestamp (inside the slice, so viewers bind the arrow to it).
     All methods are thread-safe; spans may begin and end on any thread
-    (each span's events carry the thread it ran on).
+    (each span's events carry the thread it ran on). While a
+    ``jax.profiler`` session is active a span is also a
+    ``TraceAnnotation`` (``paddle_tpu:<name>``) of the profiler's trace;
+    :meth:`complete` and :meth:`instant` stay tracer-only (an annotation
+    cannot be written after the fact).
 
     Args:
       max_events: ring-buffer bound on retained events (oldest dropped;
@@ -127,14 +256,16 @@ class Tracer:
         child replicas all stamp spans with the message-carried fleet
         clock, so a SimClock drill's merged timeline is deterministic
         and cross-process spans land on one comparable axis. Default
-        (None) keeps the PR-4 behavior: ``perf_counter_ns`` relative to
-        tracer construction.
+        (None): ``perf_counter_ns`` less :attr:`epoch_ns`, the
+        ``perf_counter_ns`` reading at construction — so an event's
+        ``epoch_ns / 1e9 + ts / 1e6`` is a ``time.perf_counter()``
+        reading.
     """
 
     def __init__(self, max_events: int = 200_000, clock=None):
         self.pid = os.getpid()
         self._clock = clock
-        self._t0 = time.perf_counter_ns()
+        self.epoch_ns = time.perf_counter_ns()
         self._events: collections.deque = collections.deque(
             maxlen=int(max_events))
         self._meta: List[Dict[str, Any]] = [
@@ -150,12 +281,20 @@ class Tracer:
     def _now_us(self) -> float:
         if self._clock is not None:
             return float(self._clock()) * 1e6
-        return (time.perf_counter_ns() - self._t0) / 1e3
+        return (time.perf_counter_ns() - self.epoch_ns) / 1e3
 
     def now_us(self) -> float:
         """The tracer's current timestamp (us) — for callers recording
         already-timed spans via :meth:`complete`."""
         return self._now_us()
+
+    def at_us(self, seconds: float) -> float:
+        """The timestamp (us) of ``seconds``, an earlier reading of the
+        tracer's clock (``time.perf_counter()`` where none was
+        injected): how a retroactive span is put on the time base."""
+        if self._clock is not None:
+            return float(seconds) * 1e6
+        return float(seconds) * 1e6 - self.epoch_ns / 1e3
 
     def _note_thread(self, tid: int) -> None:
         # Compare the LIVE name every call, not just first-seen: OS thread
@@ -172,12 +311,14 @@ class Tracer:
                     {"ph": "M", "name": "thread_name", "pid": self.pid,
                      "tid": tid, "args": {"name": name}})
 
-    def _append(self, evs: List[Dict[str, Any]]) -> None:
-        with self._lock:
-            room = self._events.maxlen - len(self._events)
-            if room < len(evs):
-                self.dropped_events += len(evs) - room
-            self._events.extend(evs)
+    def _append(self, ev: Dict[str, Any]) -> None:
+        # deque.append is atomic, so recording takes no lock (the lock
+        # is for the readers' snapshots); under contention the eviction
+        # count may run a few short
+        events = self._events
+        if len(events) == events.maxlen:
+            self.dropped_events += 1
+        events.append(ev)
 
     # -- recording -----------------------------------------------------------
 
@@ -185,22 +326,17 @@ class Tracer:
         """A fresh flow id for linking spans across threads."""
         return next(self._flow_seq)
 
-    @contextlib.contextmanager
     def span(self, name: str, flow_start: Optional[int] = None,
              flow_step: Optional[int] = None, flow_end: Optional[int] = None,
-             **args):
+             **args) -> _Span:
         """Record one span around the ``with`` body. ``flow_start`` /
         ``flow_step`` / ``flow_end`` emit the matching flow event (phases
         ``s``/``t``/``f``) bound to this span, linking it to the other
-        spans carrying the same id."""
-        tid = threading.get_ident()
-        self._note_thread(tid)
-        t0 = self._now_us()
-        try:
-            yield
-        finally:
-            self._emit_span(name, tid, t0, self._now_us(), flow_start,
-                            flow_step, flow_end, args)
+        spans carrying the same id. ``with ... as sp`` gives the
+        :class:`_Span` (``sp.set(**facts)``, ``sp.t0_ns``)."""
+        flows = (_NO_FLOWS if flow_start is flow_step is flow_end is None
+                 else (flow_start, flow_step, flow_end))
+        return _Span(self, name, flows, args)
 
     def complete(self, name: str, t0_us: float,
                  t1_us: Optional[float] = None,
@@ -208,10 +344,10 @@ class Tracer:
                  flow_step: Optional[int] = None,
                  flow_end: Optional[int] = None, **args) -> None:
         """Record an ALREADY-TIMED span with explicit microsecond
-        timestamps (the tracer's time base — with an injected clock,
-        ``seconds * 1e6``). This is how retroactive spans are stamped:
-        a scheduler records a request's queue wait only at admit time,
-        from the request's own submit timestamp (ISSUE 17)."""
+        timestamps (the tracer's time base, see :meth:`at_us`). This is
+        how retroactive spans are stamped: a scheduler records a
+        request's queue wait only at admit time, from the request's own
+        submit timestamp (ISSUE 17)."""
         tid = threading.get_ident()
         self._note_thread(tid)
         self._emit_span(name, tid, float(t0_us),
@@ -226,7 +362,7 @@ class Tracer:
             "ts": t0, "dur": max(t1 - t0, 0.001)}
         if args:
             ev["args"] = {k: _json_safe(v) for k, v in args.items()}
-        evs = [ev]
+        self._append(ev)
         for fid, ph in ((flow_start, "s"), (flow_step, "t"),
                         (flow_end, "f")):
             if fid is None:
@@ -235,8 +371,7 @@ class Tracer:
                   "id": int(fid), "pid": self.pid, "tid": tid, "ts": t0}
             if ph == "f":
                 fe["bp"] = "e"       # bind to the enclosing slice
-            evs.append(fe)
-        self._append(evs)
+            self._append(fe)
 
     def instant(self, name: str, **args) -> None:
         """A zero-duration marker (``ph="i"``) — e.g. an anomaly verdict
@@ -248,7 +383,7 @@ class Tracer:
             "pid": self.pid, "tid": tid, "ts": self._now_us()}
         if args:
             ev["args"] = {k: _json_safe(v) for k, v in args.items()}
-        self._append([ev])
+        self._append(ev)
 
     @contextlib.contextmanager
     def profile_window(self, log_dir: str, name: str = "jax_profile"):
@@ -267,15 +402,29 @@ class Tracer:
         with self._lock:
             return list(self._meta) + list(self._events)
 
+    def between(self, lo_s: float, hi_s: float) -> List[Dict[str, Any]]:
+        """The retained spans (``ph="X"``) that start and end between
+        two readings of the tracer's clock (``time.perf_counter()``
+        where none was injected), in the order recorded: how a reader
+        keeps what lies inside its own window."""
+        lo, hi = self.at_us(lo_s), self.at_us(hi_s)
+        with self._lock:
+            evs = list(self._events)
+        return [e for e in evs if e["ph"] == "X" and e["ts"] >= lo
+                and e["ts"] + e["dur"] <= hi]
+
     def drain_events(self) -> List[Dict[str, Any]]:
         """Pop every buffered span/flow/instant event (metadata stays).
         The child→parent span-batch shipping primitive (ISSUE 17): a
         process replica drains its tracer into each tick reply, so
         spans ride the transport the work already uses — no
         side-channel files, nothing to garbage-collect on a SIGKILL."""
+        evs, events = [], self._events
         with self._lock:
-            evs = list(self._events)
-            self._events.clear()
+            # popleft, not list-then-clear: an append from another
+            # thread between the two would be lost
+            while events:
+                evs.append(events.popleft())
         return evs
 
     def tail(self, n: int) -> List[Dict[str, Any]]:
@@ -311,3 +460,36 @@ class Tracer:
     def clear(self) -> None:
         with self._lock:
             self._events.clear()
+
+
+def self_times(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
+    """Each span (``ph="X"``) of ``events``, in order of start, copied
+    with a ``"self"`` key: its duration (us) less what its child spans
+    on the same thread cover. A layer's own time is the sum of the self
+    times of its spans; filter ``events`` to a few names first and a
+    span's self time is its duration less just those (``train_step``
+    less its ``loss_fetch``).
+
+    A child is a span that starts and ends inside another on one
+    ``(pid, tid)``. A span that only overlaps the one before it (a
+    retroactive ``queue_wait``, stamped from a request's submit time)
+    is nobody's child and nobody's parent from where it is overrun."""
+    spans = sorted((e for e in events if e.get("ph") == "X"),
+                   key=lambda e: (e["pid"], e["tid"], e["ts"], -e["dur"]))
+    out: List[Dict[str, Any]] = []
+    stack: List[Dict[str, Any]] = []         # open ancestors, one thread
+    lane = None
+    for ev in spans:
+        if (ev["pid"], ev["tid"]) != lane:
+            lane, stack = (ev["pid"], ev["tid"]), []
+        end = ev["ts"] + ev["dur"]
+        # drop what ended before this span starts, and what it overruns
+        while stack and stack[-1]["ts"] + stack[-1]["dur"] < end:
+            stack.pop()
+        row = dict(ev, self=ev["dur"])
+        if stack:
+            stack[-1]["self"] -= ev["dur"]
+        stack.append(row)
+        out.append(row)
+    out.sort(key=lambda e: e["ts"])
+    return out

@@ -91,6 +91,7 @@ import jax.numpy as jnp
 
 from .kv_cache import PagedKVCache, scatter_prefill_pages
 from ..nn import pallas_mode
+from ..obs.trace import live, traced, tspan
 from ..parallel.sharding import tp_constrain, tp_shard_scope
 
 __all__ = ["DecodeEngine", "AdmitProbe", "SamplingConfig"]
@@ -261,6 +262,7 @@ class DecodeEngine:
         (default ``"model"``, the framework's standard axis).
     """
 
+    @traced("engine_init")
     def __init__(self, model, variables, *, max_slots: int = 4,
                  block_size: int = 16, num_blocks: Optional[int] = None,
                  max_blocks_per_seq: Optional[int] = None,
@@ -276,7 +278,9 @@ class DecodeEngine:
         self.variables = variables
         self.telemetry = telemetry
         # optional Tracer (ISSUE 17): assigned by the fleet/replica when
-        # request tracing is on; None costs one attribute test per tick
+        # request tracing is on. With None the engine's spans are live
+        # while a jax.profiler session is active (obs.trace.live) and
+        # cost that one test a call site otherwise
         self.tracer = None
         # optional metrics registry handle (ISSUE 19): assigned by the
         # fleet (replica-scoped facade) or the replica child (its local
@@ -554,6 +558,7 @@ class DecodeEngine:
 
     # -- warmup (ISSUE 16) -------------------------------------------------
 
+    @traced("engine_warmup")
     def warmup(self) -> Dict[str, Any]:
         """Pay both programs' compiles NOW, before the first request.
 
@@ -810,14 +815,19 @@ class DecodeEngine:
         P = len(prompt)
         if not 0 < P <= self._W:
             raise ValueError(f"prompt length {P} not in [1, {self._W}]")
-        stats = self._reserve(slot, prompt, reserve_len)
-        shared = stats["shared_len"]
-        # an exact-duplicate prompt shares every block; still re-attend
-        # the final position (writes masked) for the first-token logits
-        cursor = min(shared, P - 1)
-        self._prefilling[slot] = {
-            "prompt": list(prompt), "cursor": cursor,
-            "shared_len": shared, "staged": staged}
+        with tspan(self.tracer, "begin_prefill", slot=slot,
+                   prompt_len=P) as sp:
+            stats = self._reserve(slot, prompt, reserve_len)
+            shared = stats["shared_len"]
+            # an exact-duplicate prompt shares every block; still
+            # re-attend the final position (writes masked) for the
+            # first-token logits
+            cursor = min(shared, P - 1)
+            self._prefilling[slot] = {
+                "prompt": list(prompt), "cursor": cursor,
+                "shared_len": shared, "staged": staged}
+            if sp is not None:
+                sp.set(prefix_hit_blocks=stats["prefix_hit_blocks"])
 
     def prefill_step(self, slot: int) -> Optional[int]:
         """Run ONE compiled prefill call for a :meth:`begin_prefill`'d
@@ -828,52 +838,53 @@ class DecodeEngine:
         st = self._prefilling[slot]
         prompt, P = st["prompt"], len(st["prompt"])
         stats = self.slot_stats[slot]
-        tr0 = self.tracer.now_us() if self.tracer is not None else None
-        if self.prefill_chunk is None:
-            ids = st["staged"] if st["staged"] is not None \
-                else self.stage_prompt(prompt)
-            self.cache.k, self.cache.v, tok = self._prefill_fn(
-                self.variables, self.cache.k, self.cache.v,
-                jnp.asarray(ids), jnp.asarray([P], jnp.int32),
-                jnp.asarray([st["shared_len"]], jnp.int32),
-                jnp.asarray(self.cache.tables[slot:slot + 1]),
-                self._prefill_key())
+        tr = live(self.tracer)
+        # the enqueue: staging the operands and the compiled call
+        with tspan(tr, "prefill_dispatch", slot=slot) as sp:
+            if self.prefill_chunk is None:
+                ids = st["staged"] if st["staged"] is not None \
+                    else self.stage_prompt(prompt)
+                self.cache.k, self.cache.v, tok = self._prefill_fn(
+                    self.variables, self.cache.k, self.cache.v,
+                    jnp.asarray(ids), jnp.asarray([P], jnp.int32),
+                    jnp.asarray([st["shared_len"]], jnp.int32),
+                    jnp.asarray(self.cache.tables[slot:slot + 1]),
+                    self._prefill_key())
+                done = True
+            else:
+                C = self.prefill_chunk
+                cur = st["cursor"]
+                n = min(C, P - cur)
+                ids = np.zeros((1, C), np.int32)
+                ids[0, :n] = prompt[cur:cur + n]
+                self.cache.k, self.cache.v, tok = self._prefill_fn(
+                    self.variables, self.cache.k, self.cache.v,
+                    jnp.asarray(ids), jnp.asarray([cur], jnp.int32),
+                    jnp.asarray([n], jnp.int32),
+                    jnp.asarray([st["shared_len"]], jnp.int32),
+                    jnp.asarray(self.cache.tables[slot:slot + 1]),
+                    self._prefill_key())
+                st["cursor"] = cur + n
+                done = st["cursor"] >= P
             stats["prefill_chunks"] += 1
             self.prefill_chunks += 1
-            done = True
-        else:
-            C = self.prefill_chunk
-            cur = st["cursor"]
-            n = min(C, P - cur)
-            ids = np.zeros((1, C), np.int32)
-            ids[0, :n] = prompt[cur:cur + n]
-            self.cache.k, self.cache.v, tok = self._prefill_fn(
-                self.variables, self.cache.k, self.cache.v,
-                jnp.asarray(ids), jnp.asarray([cur], jnp.int32),
-                jnp.asarray([n], jnp.int32),
-                jnp.asarray([st["shared_len"]], jnp.int32),
-                jnp.asarray(self.cache.tables[slot:slot + 1]),
-                self._prefill_key())
-            st["cursor"] = cur + n
-            stats["prefill_chunks"] += 1
-            self.prefill_chunks += 1
-            done = st["cursor"] >= P
-        if tr0 is not None:
-            self.tracer.complete("prefill_dispatch", tr0,
-                                 self.tracer.now_us(), slot=slot,
-                                 done=done)
+            if sp is not None:
+                sp.set(done=done)
         if not done:
             return None
-        del self._prefilling[slot]
-        self.cache.lengths[slot] = P
-        self.active[slot] = True
-        tok = int(tok)
-        self.tokens[slot] = tok
-        self.history[slot] = []
-        self._bigram_idx[slot] = {}
-        self._unigram_idx[slot] = {}
-        self._history_append(slot, list(prompt) + [tok])
-        self.cache.register_prefix(slot, prompt)
+        # the drain: int(tok) waits for the device, then the slot goes
+        # live and its prefix is registered
+        with tspan(tr, "prefill_drain", slot=slot):
+            del self._prefilling[slot]
+            self.cache.lengths[slot] = P
+            self.active[slot] = True
+            tok = int(tok)
+            self.tokens[slot] = tok
+            self.history[slot] = []
+            self._bigram_idx[slot] = {}
+            self._unigram_idx[slot] = {}
+            self._history_append(slot, list(prompt) + [tok])
+            self.cache.register_prefix(slot, prompt)
         return tok
 
     def evict(self, slot: int) -> None:
@@ -1024,106 +1035,111 @@ class DecodeEngine:
         active slot to the list of tokens it retired this tick — one for
         the plain tick, up to ``k+1`` under speculation."""
         t0 = time.perf_counter()
-        tr0 = self.tracer.now_us() if self.tracer is not None else None
-        n = self._pre_tick_guard()
-        tables, lengths = self.cache.device_tables()
-        drafted_tick, accepted_tick = 0, 0
+        tr = live(self.tracer)
         stochastic = self.speculative > 0 and self.sampling is not None
-        if self.speculative == 0:
-            if self.sampling is None:
-                keys = self._zero_keys      # greedy: unused operand
-            else:
-                keys = self._tick_keys(self.ticks)
-            self.cache.k, self.cache.v, nxt = self._tick_fn(
-                self.variables, self.cache.k, self.cache.v, tables,
-                lengths, jnp.asarray(self.tokens),
-                jnp.asarray(self.active), keys)
-        else:
-            toks = np.zeros((self.max_slots, self._K1), np.int32)
-            for slot in np.flatnonzero(self.active):
-                drafts = self._propose_drafts(slot)
-                toks[slot, 0] = self.tokens[slot]
-                toks[slot, 1:] = drafts
-                drafted_tick += int(n[slot]) - 1
-            if stochastic:
-                self.cache.k, self.cache.v, acc_d, res_d, bon_d = \
-                    self._tick_fn(
-                        self.variables, self.cache.k, self.cache.v,
-                        tables, lengths, jnp.asarray(toks),
-                        jnp.asarray(n), jnp.asarray(self.active),
-                        self._tick_keys(self.ticks))
-            else:
-                self.cache.k, self.cache.v, nxt = self._tick_fn(
-                    self.variables, self.cache.k, self.cache.v, tables,
-                    lengths, jnp.asarray(toks), jnp.asarray(n),
-                    jnp.asarray(self.active))
-        # the dispatch is async: host bookkeeping that doesn't need the
-        # sampled tokens runs UNDER the in-flight device call (the PR-3
-        # overlap move at tick scale) — the plain tick advances every
-        # active slot by exactly one, so its length bump overlaps;
-        # speculative lengths depend on acceptance and must wait.
-        # np.asarray(nxt) is the drain.
-        n_active = int(self.active.sum())
-        if self.speculative == 0:
-            self.cache.lengths[self.active] += 1
-        if stochastic:
-            acc_d, res_d, bon_d = (np.asarray(acc_d), np.asarray(res_d),
-                                   np.asarray(bon_d))
-        else:
-            nxt = np.asarray(nxt)                # [S, 1] or [S, 1+k]
-        self.last_accepted = {}
-        front = np.zeros((self.max_slots,), np.int32)
-        tokens_tick = 0
-        for slot in np.flatnonzero(self.active):
-            if self.speculative == 0:
-                accepted = [int(nxt[slot, 0])]
-            elif stochastic:
-                # [S3] walk: accept drafts while the per-row coin lands
-                # under p(draft); the stopping row's token is the
-                # residual resample, or the bonus sample from the last
-                # live row when every draft survived
-                live = int(n[slot])
-                take = 0
-                while take < live - 1 and bool(acc_d[slot, take]):
-                    take += 1
-                accepted = [int(toks[slot, j + 1]) for j in range(take)]
-                if take < live - 1:
-                    accepted.append(int(res_d[slot, take]))
+        with tspan(tr, "engine_tick", tick=self.ticks + 1) as tick_sp:
+            # stage: the host guard, the block tables and every operand
+            # of the compiled call
+            with tspan(tr, "tick_stage"):
+                n = self._pre_tick_guard()
+                tables, lengths = self.cache.device_tables()
+                drafted_tick, accepted_tick = 0, 0
+                if self.speculative == 0:
+                    if self.sampling is None:
+                        keys = self._zero_keys      # greedy: unused operand
+                    else:
+                        keys = self._tick_keys(self.ticks)
+                    operands = (jnp.asarray(self.tokens),
+                                jnp.asarray(self.active), keys)
                 else:
-                    accepted.append(int(bon_d[slot, live - 1]))
-                accepted_tick += take
-                self.cache.lengths[slot] += len(accepted)
-            else:
-                # accept the longest draft prefix the model reproduced,
-                # plus the model's own token after it — identical to
-                # the sequential greedy stream by induction
-                take = 1
-                while (take < int(n[slot])
-                       and int(toks[slot, take]) == int(nxt[slot,
-                                                            take - 1])):
-                    take += 1
-                accepted = [int(t) for t in nxt[slot, :take]]
-                accepted_tick += take - 1
-                self.cache.lengths[slot] += len(accepted)
-            self.last_accepted[slot] = accepted
-            front[slot] = accepted[-1]
-            self._history_append(slot, accepted)
-            tokens_tick += len(accepted)
-            st = self.slot_stats[slot]
-            st["draft_proposed"] = st.get("draft_proposed", 0) \
-                + (int(n[slot]) - 1 if self.speculative else 0)
-            st["draft_accepted"] = st.get("draft_accepted", 0) \
-                + len(accepted) - 1
-        self.tokens = front
-        self.ticks += 1
-        self.tokens_generated += tokens_tick
-        self.draft_proposed += drafted_tick
-        self.draft_accepted += accepted_tick
-        if tr0 is not None:
-            self.tracer.complete("engine_tick", tr0,
-                                 self.tracer.now_us(), tick=self.ticks,
-                                 active=n_active, tokens=tokens_tick,
-                                 accepted_drafts=accepted_tick)
+                    toks = np.zeros((self.max_slots, self._K1), np.int32)
+                    for slot in np.flatnonzero(self.active):
+                        drafts = self._propose_drafts(slot)
+                        toks[slot, 0] = self.tokens[slot]
+                        toks[slot, 1:] = drafts
+                        drafted_tick += int(n[slot]) - 1
+                    operands = (jnp.asarray(toks), jnp.asarray(n),
+                                jnp.asarray(self.active))
+                    if stochastic:
+                        operands += (self._tick_keys(self.ticks),)
+            # the enqueue: returns once XLA has the program
+            with tspan(tr, "tick_dispatch"):
+                out = self._tick_fn(self.variables, self.cache.k,
+                                    self.cache.v, tables, lengths,
+                                    *operands)
+            self.cache.k, self.cache.v = out[0], out[1]
+            # the dispatch is async: host bookkeeping that doesn't need
+            # the sampled tokens runs UNDER the in-flight device call (the
+            # PR-3 overlap move at tick scale) — the plain tick advances
+            # every active slot by exactly one, so its length bump
+            # overlaps; speculative lengths depend on acceptance and must
+            # wait.
+            n_active = int(self.active.sum())
+            if tick_sp is not None:
+                tick_sp.set(active=n_active, live_tokens=int(
+                    self.cache.lengths[self.active].sum()) + n_active)
+            if self.speculative == 0:
+                self.cache.lengths[self.active] += 1
+            # the drain: the host waits for the device here
+            with tspan(tr, "tick_drain"):
+                if stochastic:
+                    acc_d, res_d, bon_d = (np.asarray(o) for o in out[2:])
+                else:
+                    nxt = np.asarray(out[2])         # [S, 1] or [S, 1+k]
+            with tspan(tr, "tick_retire"):
+                self.last_accepted = {}
+                front = np.zeros((self.max_slots,), np.int32)
+                tokens_tick = 0
+                for slot in np.flatnonzero(self.active):
+                    if self.speculative == 0:
+                        accepted = [int(nxt[slot, 0])]
+                    elif stochastic:
+                        # [S3] walk: accept drafts while the per-row coin
+                        # lands under p(draft); the stopping row's token
+                        # is the residual resample, or the bonus sample
+                        # from the last live row when every draft survived
+                        rows = int(n[slot])
+                        take = 0
+                        while take < rows - 1 and bool(acc_d[slot, take]):
+                            take += 1
+                        accepted = [int(toks[slot, j + 1])
+                                    for j in range(take)]
+                        if take < rows - 1:
+                            accepted.append(int(res_d[slot, take]))
+                        else:
+                            accepted.append(int(bon_d[slot, rows - 1]))
+                        accepted_tick += take
+                        self.cache.lengths[slot] += len(accepted)
+                    else:
+                        # accept the longest draft prefix the model
+                        # reproduced, plus the model's own token after it
+                        # — identical to the sequential greedy stream by
+                        # induction
+                        take = 1
+                        while (take < int(n[slot])
+                               and int(toks[slot, take])
+                               == int(nxt[slot, take - 1])):
+                            take += 1
+                        accepted = [int(t) for t in nxt[slot, :take]]
+                        accepted_tick += take - 1
+                        self.cache.lengths[slot] += len(accepted)
+                    self.last_accepted[slot] = accepted
+                    front[slot] = accepted[-1]
+                    self._history_append(slot, accepted)
+                    tokens_tick += len(accepted)
+                    st = self.slot_stats[slot]
+                    st["draft_proposed"] = st.get("draft_proposed", 0) \
+                        + (int(n[slot]) - 1 if self.speculative else 0)
+                    st["draft_accepted"] = st.get("draft_accepted", 0) \
+                        + len(accepted) - 1
+                self.tokens = front
+                self.ticks += 1
+                self.tokens_generated += tokens_tick
+                self.draft_proposed += drafted_tick
+                self.draft_accepted += accepted_tick
+            if tick_sp is not None:
+                tick_sp.set(tokens=tokens_tick,
+                            accepted_drafts=accepted_tick)
         if self.telemetry is not None:
             wall = time.perf_counter() - t0
             # sharing/chunk counters are emitted as PER-TICK DELTAS

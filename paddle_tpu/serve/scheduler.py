@@ -41,6 +41,8 @@ import itertools
 import time
 from typing import Any, Dict, List, Optional
 
+from ..obs.trace import NULL_SPAN, live, tspan
+
 __all__ = ["Request", "ContinuousBatchingScheduler", "ORDERS"]
 
 # queue-order policies: arrival order, shortest-decode-budget-first,
@@ -176,8 +178,10 @@ class ContinuousBatchingScheduler:
         # distributed request tracing (ISSUE 17): a Tracer sharing the
         # scheduler's clock. Spans carry the GLOBAL rid as their flow
         # id, so the fleet merge links a request's queue wait, prefill
-        # chunks, and decode ticks across replicas. None = zero
-        # overhead (every call site guards on it).
+        # chunks, and decode ticks across replicas. With None the same
+        # spans are live while a jax.profiler session is active (every
+        # call site goes through obs.trace.live) and cost that one
+        # test otherwise.
         self.tracer = tracer
         # typed metrics registry handle (ISSUE 19): a MetricsHub or a
         # replica-scoped facade. Queue-depth gauges per step, one
@@ -336,11 +340,11 @@ class ContinuousBatchingScheduler:
                 "sched_requests_finished",
                 "terminal requests by finish reason",
                 reason=reason).inc()
-        if self.tracer is not None:
-            self.tracer.complete("finish", req.finish_ts * 1e6,
-                                 flow_step=req.rid, rid=req.rid,
-                                 reason=reason,
-                                 new_tokens=len(req.tokens))
+        tr = live(self.tracer)
+        if tr is not None:
+            tr.complete("finish", tr.at_us(req.finish_ts),
+                        flow_step=req.rid, rid=req.rid, reason=reason,
+                        new_tokens=len(req.tokens))
         if self.telemetry is not None:
             self.telemetry.emit_event(req.record())
 
@@ -403,11 +407,15 @@ class ContinuousBatchingScheduler:
             return sorted(self.queue, key=lambda r: (-r.priority, r.seq))
         return list(self.queue)
 
-    def _admit(self) -> None:
+    def _admit(self) -> int:
+        """Admit from the queue in policy order while slots and blocks
+        last; returns how many requests were admitted."""
         self.last_backpressure = None    # cleared even on the gang wait
         if self.policy == "static" and (self.running or self.prefilling):
-            return                       # gang: wait for the whole batch
+            return 0                     # gang: wait for the whole batch
         free = self.engine.free_slots()
+        tr = live(self.tracer)
+        admitted = 0
         for req in self._admit_order():
             if not free:
                 break
@@ -421,18 +429,16 @@ class ContinuousBatchingScheduler:
                 # pool backpressure: stop in strict policy order (no
                 # smaller-request bypass — bypass would starve the head)
                 self.last_backpressure = probe.reason
-                if self.tracer is not None:
-                    self.tracer.instant("backpressure", rid=req.rid,
-                                        reason=probe.reason,
-                                        queued=len(self.queue))
+                if tr is not None:
+                    tr.instant("backpressure", rid=req.rid,
+                               reason=probe.reason, queued=len(self.queue))
                 break
             self.queue.remove(req)
-            if self.tracer is not None:
+            if tr is not None:
                 # retroactive queue-wait span: submit_ts -> now, in the
                 # shared clock's time base
-                self.tracer.complete("queue_wait", req.submit_ts * 1e6,
-                                     self.tracer.now_us(),
-                                     flow_step=req.rid, rid=req.rid)
+                tr.complete("queue_wait", tr.at_us(req.submit_ts),
+                            tr.now_us(), flow_step=req.rid, rid=req.rid)
             slot = free.pop(0)
             self.engine.begin_prefill(slot, req.prompt,
                                       reserve_len=target,
@@ -444,16 +450,14 @@ class ContinuousBatchingScheduler:
             # engine (admission behavior unchanged), the first chunk
             # on a chunked one — the rest interleave with decode ticks
             self._advance_prefill(slot)
+            admitted += 1
+        return admitted
 
     def _advance_prefill(self, slot: int) -> None:
         """One compiled prefill call for a reserved slot; promotes the
         request to running when its first token lands."""
         req = self.prefilling[slot]
-        if self.tracer is not None:
-            with self.tracer.span("prefill_chunk", rid=req.rid,
-                                  slot=slot):
-                tok = self.engine.prefill_step(slot)
-        else:
+        with tspan(self.tracer, "prefill_chunk", rid=req.rid, slot=slot):
             tok = self.engine.prefill_step(slot)
         if tok is None:
             return
@@ -497,11 +501,11 @@ class ContinuousBatchingScheduler:
         self.engine.evict(slot)
         req.slot = None
         self.handoffs.append((req, meta, kpages, vpages))
-        if self.tracer is not None:
-            now_us = self._clock() * 1e6
-            self.tracer.complete("handoff_out", now_us,
-                                 flow_step=req.rid, rid=req.rid,
-                                 blocks=meta["blocks"])
+        tr = live(self.tracer)
+        if tr is not None:
+            tr.complete("handoff_out", tr.at_us(self._clock()),
+                        flow_step=req.rid, rid=req.rid,
+                        blocks=meta["blocks"])
 
     def pop_handoffs(self) -> List[tuple]:
         """Drain finished prefills awaiting transfer (fleet-facing)."""
@@ -551,10 +555,11 @@ class ContinuousBatchingScheduler:
         self.engine.slot_stats[slot].update(
             meta.get("prefill_stats") or {})
         self.last_backpressure = None
-        if self.tracer is not None:
-            self.tracer.complete("adopt", self._clock() * 1e6,
-                                 flow_step=req.rid, rid=req.rid,
-                                 slot=slot, blocks=meta.get("blocks"))
+        tr = live(self.tracer)
+        if tr is not None:
+            tr.complete("adopt", tr.at_us(self._clock()),
+                        flow_step=req.rid, rid=req.rid, slot=slot,
+                        blocks=meta.get("blocks"))
         return req
 
     def _maybe_finish(self, slot: int, tok: int) -> None:
@@ -567,63 +572,72 @@ class ContinuousBatchingScheduler:
     def step(self) -> bool:
         """Expire deadlines, admit, run one decode tick, collect
         finished requests. Returns True while work remains."""
-        now = self._clock()
-        if self._last_step_ts is not None and self._was_busy:
-            # EMA over inter-step deltas: the shed predictor's tick-time
-            # evidence (deterministic under a fake clock — the injected
-            # advances ARE the observations). Only deltas between
-            # consecutive BUSY steps count: after an idle lull the gap
-            # is think time, not tick time, and folding it in would make
-            # the predictor shed against an empty engine.
-            dt = now - self._last_step_ts
-            if dt > 0:
-                self.est_tick_s = (dt if self.est_tick_s is None
-                                   else 0.7 * self.est_tick_s + 0.3 * dt)
-        self._last_step_ts = now
-        self._expire()
-        # chunked prefill: ONE chunk per already-prefilling slot per
-        # step, BETWEEN decode ticks — a 4k-token admit becomes many
-        # cheap calls instead of one monolithic stall of every running
-        # slot (fresh admissions below run their first chunk inside
-        # _admit)
-        for slot in list(self.prefilling):
-            self._advance_prefill(slot)
-        self._admit()
-        if self.running:
-            active = len(self.running)
-            t0 = (self.tracer.now_us()
-                  if self.tracer is not None else None)
-            self.engine.decode_tick()
-            # the tick may retire several tokens per slot (speculative
-            # accepts); feed them through the same finish rules one at
-            # a time so eos/length semantics match the sequential
-            # engine exactly
-            accepted = self.engine.last_accepted
-            n_tok = 0
-            for slot, req in list(self.running.items()):
-                for tok in accepted.get(slot, ()):
-                    req.tokens.append(tok)
-                    n_tok += 1
-                    self._maybe_finish(slot, tok)
-                    if req.done:
-                        break
-            if t0 is not None:
-                self.tracer.complete("decode_tick", t0,
-                                     self.tracer.now_us(),
-                                     active=active, tokens=n_tok)
-        if self.metrics is not None:
-            self.metrics.gauge("sched_queue_depth",
-                               "requests queued for admission").set(
-                len(self.queue))
-            self.metrics.gauge("sched_running",
-                               "requests holding a decode slot").set(
-                len(self.running))
-            self.metrics.gauge("sched_prefilling",
-                               "slots mid chunked prefill").set(
-                len(self.prefilling))
-        self._was_busy = bool(self.queue or self.running
-                              or self.prefilling)
-        return self._was_busy
+        tr = live(self.tracer)
+        with (NULL_SPAN if tr is None else tr.span(
+                "sched_step", queued=len(self.queue),
+                running=len(self.running),
+                prefilling=len(self.prefilling))):
+            now = self._clock()
+            if self._last_step_ts is not None and self._was_busy:
+                # EMA over inter-step deltas: the shed predictor's
+                # tick-time evidence (deterministic under a fake clock —
+                # the injected advances ARE the observations). Only
+                # deltas between consecutive BUSY steps count: after an
+                # idle lull the gap is think time, not tick time, and
+                # folding it in would make the predictor shed against an
+                # empty engine.
+                dt = now - self._last_step_ts
+                if dt > 0:
+                    self.est_tick_s = (
+                        dt if self.est_tick_s is None
+                        else 0.7 * self.est_tick_s + 0.3 * dt)
+            self._last_step_ts = now
+            with tspan(tr, "expire"):
+                self._expire()
+            # chunked prefill: ONE chunk per already-prefilling slot per
+            # step, BETWEEN decode ticks — a 4k-token admit becomes many
+            # cheap calls instead of one monolithic stall of every running
+            # slot (fresh admissions below run their first chunk inside
+            # _admit)
+            for slot in list(self.prefilling):
+                self._advance_prefill(slot)
+            with tspan(tr, "admit") as sp:
+                admitted = self._admit()
+                if sp is not None:
+                    sp.set(admitted=admitted,
+                           backpressure=self.last_backpressure)
+            if self.running:
+                with tspan(tr, "decode_tick",
+                           active=len(self.running)) as sp:
+                    self.engine.decode_tick()
+                    # the tick may retire several tokens per slot
+                    # (speculative accepts); feed them through the same
+                    # finish rules one at a time so eos/length semantics
+                    # match the sequential engine exactly
+                    accepted = self.engine.last_accepted
+                    n_tok = 0
+                    for slot, req in list(self.running.items()):
+                        for tok in accepted.get(slot, ()):
+                            req.tokens.append(tok)
+                            n_tok += 1
+                            self._maybe_finish(slot, tok)
+                            if req.done:
+                                break
+                    if sp is not None:
+                        sp.set(tokens=n_tok)
+            if self.metrics is not None:
+                self.metrics.gauge("sched_queue_depth",
+                                   "requests queued for admission").set(
+                    len(self.queue))
+                self.metrics.gauge("sched_running",
+                                   "requests holding a decode slot").set(
+                    len(self.running))
+                self.metrics.gauge("sched_prefilling",
+                                   "slots mid chunked prefill").set(
+                    len(self.prefilling))
+            self._was_busy = bool(self.queue or self.running
+                                  or self.prefilling)
+            return self._was_busy
 
     def run(self, max_ticks: int = 100000) -> List[Request]:
         """Drive ticks until the queue drains; returns completed

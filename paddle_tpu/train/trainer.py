@@ -31,7 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core import mesh as mesh_lib
 from ..core.module import Module
-from ..obs.trace import tspan
+from ..obs.trace import live, tspan
 from ..optim.optimizers import Optimizer, apply_updates
 from ..utils.stats import StatSet
 from . import checkpoint as ckpt_lib
@@ -39,6 +39,56 @@ from . import events as ev
 from .faults import Preempted
 
 __all__ = ["Trainer", "TrainState"]
+
+
+class _Timed:
+    """One timed region of the loop, with ONE clock pair: read once on
+    entry and once on exit. The pair goes to the ``StatSet`` row
+    (always; ``key`` None = no row), to the live tracer's span and its
+    profiler annotation (``span`` is None unless ``obs.trace.live``
+    found a tracer), and stays on ``seconds`` for the telemetry
+    record. With ``jitted`` (the compiled function the region calls) a
+    live span says ``compiled`` when its jit cache grew inside the
+    region: which step recompiled."""
+
+    __slots__ = ("_stats", "_key", "span", "_jitted", "_programs", "_t0",
+                 "seconds")
+
+    def __init__(self, stats: StatSet, key: Optional[str], span,
+                 jitted=None):
+        self._stats = stats
+        self._key = key
+        self.span = span
+        self._jitted = jitted if span is not None else None
+
+    def __enter__(self):
+        if self.span is not None:
+            if self._jitted is not None:
+                self._programs = _jit_cache_size(self._jitted)
+            self._t0 = self.span.__enter__().t0_ns
+        else:
+            self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        if self.span is not None:
+            if self._jitted is not None \
+                    and _jit_cache_size(self._jitted) > self._programs:
+                self.span.set(compiled=True)
+            self.span.__exit__(*exc)
+            t1 = self.span.t1_ns
+        else:
+            t1 = time.perf_counter_ns()
+        self.seconds = (t1 - self._t0) / 1e9
+        if self._key is not None:
+            self._stats.add(self._key, self.seconds)
+        return False
+
+
+def _jit_cache_size(fn) -> int:
+    """Programs in a jitted function's cache (0 where it cannot say)."""
+    size = getattr(fn, "_cache_size", None)
+    return int(size()) if size is not None else 0
 
 
 def _batch_fingerprint(host_batch) -> int:
@@ -207,9 +257,14 @@ class Trainer:
         serializes them as Chrome Trace Event JSON
         (``tracer.save(path)`` — open in Perfetto), so host/device
         overlap is visually auditable. Spans are host-side wall clocks
-        only: no extra dispatch, no fence, and with ``tracer=None`` the
-        hot loop is byte-identical (pinned by tests/test_trace.py
-        alongside tests/test_obs.py's telemetry-off invariant).
+        only: no extra dispatch, no fence. With ``tracer=None`` the same
+        spans are live while a ``jax.profiler`` session is active in the
+        process (they go to ``obs.trace.session_tracer()`` and into the
+        profiler's trace as ``paddle_tpu:<name>``), and otherwise the hot
+        loop does the same work and dispatches (pinned by
+        tests/test_trace.py alongside tests/test_obs.py's telemetry-off
+        invariant). The plain loop adds ``train_step`` / ``reader_wait`` /
+        ``loss_fetch`` / ``events``.
       anomaly: optional :class:`paddle_tpu.obs.AnomalyDetector`. Consumes
         every telemetry step record (requires ``telemetry``); on a
         detected anomaly (slow-step outlier, retrace burst, drain stall,
@@ -298,10 +353,10 @@ class Trainer:
         # the pre-obs build (no health outputs in the traced step, no
         # fencing, no extra fetches — pinned by tests/test_obs.py).
         self.telemetry = telemetry
-        # tracer/anomaly: same rule — None means the hot loop is the
-        # pre-obs build exactly (tspan(None, ...) is a shared no-op
+        # tracer/anomaly: same rule — with None (and no jax.profiler
+        # session, see obs.trace.live) a span site is a shared no-op
         # context; anomaly observation only ever follows a telemetry
-        # emit, which telemetry=None already gates).
+        # emit, which telemetry=None already gates.
         self.tracer = tracer
         if anomaly is not None and telemetry is None:
             raise ValueError(
@@ -452,6 +507,19 @@ class Trainer:
             jax.block_until_ready(out[:5])   # capture the compute,
         return out, True                     # not just the enqueue
 
+    def _timed(self, stat_key: Optional[str], span_name: str,
+               jitted=None, **facts) -> _Timed:
+        """A timed region: the ``StatSet`` row ``stat_key`` and, where a
+        tracer is live, the span ``span_name`` with ``facts``. A region
+        that calls a compiled step is opened where the call is made and
+        not in a helper of its own: every Python frame between the entry
+        point and the jit call costs the step's lowering seconds (4 to 5
+        s a frame for the 590M step on the v5e host, PERF.md, PR 25)."""
+        tracer = live(self.tracer)
+        return _Timed(self.stats, stat_key,
+                      None if tracer is None
+                      else tracer.span(span_name, **facts), jitted)
+
     def _anomaly_context(self) -> Dict[str, Any]:
         """The config/env/mesh snapshot frozen into a forensics bundle."""
         import os
@@ -490,42 +558,43 @@ class Trainer:
         """Initialize params/state/optimizer from one (host) batch. Models with
         non-standard inputs (custom ``forward=`` arg) implement
         ``init_variables(rng, batch)``."""
-        batch = jax.tree_util.tree_map(jnp.asarray, sample_batch)
-        if self._param_sharding is not None:
-            from ..parallel import sharding as shard_lib
-            if hasattr(self.model, "init_variables"):
-                variables = self.model.init_variables(rng, batch)
-                specs = self._param_sharding
-                if isinstance(specs, shard_lib.ShardingRules):
-                    specs = specs(variables["params"])
-                params = shard_lib.shard_tree(self.mesh, variables["params"],
-                                              specs)
-                state = shard_lib.shard_tree(self.mesh,
-                                             variables.get("state", {}))
+        with tspan(self.tracer, "trainer_init"):
+            batch = jax.tree_util.tree_map(jnp.asarray, sample_batch)
+            if self._param_sharding is not None:
+                from ..parallel import sharding as shard_lib
+                if hasattr(self.model, "init_variables"):
+                    variables = self.model.init_variables(rng, batch)
+                    specs = self._param_sharding
+                    if isinstance(specs, shard_lib.ShardingRules):
+                        specs = specs(variables["params"])
+                    params = shard_lib.shard_tree(
+                        self.mesh, variables["params"], specs)
+                    state = shard_lib.shard_tree(self.mesh,
+                                                 variables.get("state", {}))
+                else:
+                    # Materialize params directly in their sharded
+                    # layout — no full replicated copy on one device first.
+                    variables, specs = shard_lib.sharded_init(
+                        self.model, rng, batch["x"], mesh=self.mesh,
+                        rules=self._param_sharding, train=True)
+                    params = variables["params"]
+                    state = variables.get("state", {})
+                self._param_specs = specs
             else:
-                # Materialize params directly in their sharded layout — no
-                # full replicated copy on one device first.
-                variables, specs = shard_lib.sharded_init(
-                    self.model, rng, batch["x"], mesh=self.mesh,
-                    rules=self._param_sharding, train=True)
+                if hasattr(self.model, "init_variables"):
+                    variables = self.model.init_variables(rng, batch)
+                else:
+                    variables = self.model.init(rng, batch["x"], train=True)
                 params = variables["params"]
                 state = variables.get("state", {})
-            self._param_specs = specs
-        else:
-            if hasattr(self.model, "init_variables"):
-                variables = self.model.init_variables(rng, batch)
-            else:
-                variables = self.model.init(rng, batch["x"], train=True)
-            params = variables["params"]
-            state = variables.get("state", {})
-        # Param-shaped optimizer slots inherit each param's committed layout:
-        # eager zeros_like/ops on sharded arrays propagate sharding (under
-        # jit they would be value-independent constants and land on one
-        # device).
-        opt_state = self.optimizer.init(params)
-        self.train_state = TrainState(*self._commit(
-            (params, state, opt_state, jnp.zeros((), jnp.int32))))
-        return self.train_state
+            # Param-shaped optimizer slots inherit each param's committed
+            # layout: eager zeros_like/ops on sharded arrays propagate
+            # sharding (under jit they would be value-independent constants
+            # and land on one device).
+            opt_state = self.optimizer.init(params)
+            self.train_state = TrainState(*self._commit(
+                (params, state, opt_state, jnp.zeros((), jnp.int32))))
+            return self.train_state
 
     def _commit(self, tree):
         """Place every leaf not yet laid out on the trainer's mesh
@@ -1075,7 +1144,13 @@ class Trainer:
                                           ts.step)
         buf, buf_start = [], 0
         pending = []              # plain deferred-fetch in-flight window
-        for batch_id, host_batch in enumerate(reader()):
+        batches = enumerate(reader())
+        while True:
+            with tspan(self.tracer, "reader_wait"):
+                item = next(batches, None)
+            if item is None:
+                break
+            batch_id, host_batch = item
             if pass_id == start_pass and batch_id < skip_batches:
                 # Deterministic replay skip on resume. On the last
                 # skipped batch, compare against the fingerprint the
@@ -1166,116 +1241,121 @@ class Trainer:
             # body for the deferred-fetch window (divergences are the
             # point: BeginIteration pre-dispatch here, the per-call fence,
             # int(step) fetches) — a bookkeeping change here must be
-            # mirrored there.
-            handler(ev.BeginIteration(pass_id, batch_id))
-            is_new, fp = False, None
-            if tel is not None:
-                fp = ((1, 1),) + _step_fingerprint(host_batch)
-                is_new = tel.observe_fingerprint(fp)
-            t0 = time.perf_counter()
-            with self.stats.time("shard_batch"), \
-                    tspan(self.tracer, "device_put", batch=batch_id):
-                batch = self._shard(host_batch)
-            t1 = time.perf_counter()
-            hlo_flops = None
-            if is_new:
-                from ..obs.telemetry import lowered_hlo_flops
-                try:
-                    hlo_flops = lowered_hlo_flops(self._train_step.lower(
-                        params, state, opt_state, step, batch, rng))
-                except Exception:
-                    hlo_flops = None
-            # dispatch timing starts AFTER the FLOPs lowering — the
-            # measurement layer must not bill its own extra trace to
-            # the step it measures (the fused path does the same)
-            t_disp = time.perf_counter()
-            with self.stats.time("train_step"), \
-                    tspan(self.tracer, "dispatch", batch=batch_id,
-                          new_compile=is_new):
-                out, profiled = self._maybe_profiled_call(
-                    self._train_step, params, state, opt_state, step,
-                    batch, rng)
-            params, state, opt_state, step = out[:4]
-            loss, stats = out[4], out[5]
-            health = out[6] if len(out) > 6 else None
-            t2 = time.perf_counter()
-            device_s = None
-            if tel is not None and tel.fence:
-                # the fencing rule: the dispatch above returned as soon
-                # as the program was enqueued — device time needs a sync
-                with tspan(self.tracer, "fence", batch=batch_id):
-                    jax.block_until_ready((params, loss))
-                device_s = time.perf_counter() - t2
-                self.stats.add("device_wait", device_s)
-            if is_new:
-                tel.record_compile(
-                    fp, wall_s=(t2 - t_disp) + (device_s or 0.0),
-                    hlo_flops=hlo_flops, meta={"k_steps": 1, "m": 1})
-            # Refresh train_state every step: with buffer donation the
-            # previous arrays are invalidated, and event handlers may read
-            # trainer.train_state (e.g. to save) mid-pass.
-            self.train_state = TrainState(params, state, opt_state, step)
-            self._host_step += 1
-            cost = float(loss)
-            if tel is not None:
-                if health is not None:
-                    tel.update_health(jax.device_get(health))
-                rec = tel.emit_step(
-                    {"pass": pass_id, "step": int(step),
-                     "k_steps": 1, "m": 1, "loss": cost,
-                     "profiled": profiled,
-                     "host_stack_ms": None,
-                     "shard_ms": round((t1 - t0) * 1e3, 3),
-                     "dispatch_ms": round((t2 - t_disp) * 1e3, 3),
-                     "device_ms": (round(device_s * 1e3, 3)
-                                   if device_s is not None else None),
-                     "replay_ms": None})
-                handler(ev.TelemetryRecord(record=rec))
-                self._anomaly_observe(rec)
-            if self._nan_check and not np.isfinite(cost):
-                from ..utils import debug as dbg
-                bad = dbg.nonfinite_leaves(
-                    {"params": params, "state": state})
-                raise FloatingPointError(
-                    f"non-finite loss {cost} at pass {pass_id} batch "
-                    f"{batch_id} (step {int(step)}); non-finite leaves: "
-                    f"{bad[:8] or 'none (loss only)'}")
-            costs.append(cost)
-            metrics = {}
-            if self.evaluator is not None:
-                self.evaluator.update(jax.device_get(stats))
-                metrics = self.evaluator.result()
-            if log_period and (batch_id + 1) % log_period == 0:
-                msg = " ".join(f"{k}={v:.4f}" for k, v in metrics.items())
-                if tel is not None and tel.last_health:
-                    # health monitors are fetched per call (riding the
-                    # same sync as the loss) but LOGGED only here
-                    msg += " " + " ".join(
-                        f"{k}={v:.3g}"
-                        for k, v in tel.last_health.items())
-                _log.info("pass %d batch %d cost=%.4f %s",
-                          pass_id, batch_id + 1, cost, msg)
-                self._log_stat_report()
-            if self._param_stats_period and \
-                    (batch_id + 1) % self._param_stats_period == 0:
-                self._log_param_stats(pass_id, batch_id)
-            if saving_period and checkpoint_dir and \
-                    (batch_id + 1) % saving_period == 0:
-                with tspan(self.tracer, "checkpoint_save",
-                           next_batch=batch_id + 1):
-                    save_fn(
-                        checkpoint_dir, pass_id,
-                        {**self.train_state.as_dict(),
-                         "iter": {"pass": pass_id,
-                                  "next_batch": batch_id + 1,
-                                  "completed": 0,
-                                  "batch_crc":
-                                      _batch_fingerprint(host_batch)}},
-                        keep_last=checkpoint_keep)
-            handler(ev.EndIteration(pass_id, batch_id, int(step), cost,
-                                    metrics))
-            if self.faults is not None:
-                self._fire_step_faults(self._host_step)
+            # mirrored there. It stays in this frame: a method of its own
+            # would put one more Python frame under the jit call (see
+            # _timed) and keep the donated trees alive in this one until
+            # it returned, after the loss fetch, where the chip waits.
+            with tspan(self.tracer, "train_step", batch=batch_id,
+                       step=self._host_step):
+                with tspan(self.tracer, "events", event="BeginIteration"):
+                    handler(ev.BeginIteration(pass_id, batch_id))
+                is_new, fp = False, None
+                if tel is not None:
+                    fp = ((1, 1),) + _step_fingerprint(host_batch)
+                    is_new = tel.observe_fingerprint(fp)
+                with self._timed("shard_batch", "device_put",
+                                 batch=batch_id) as shard:
+                    batch = self._shard(host_batch)
+                hlo_flops = None
+                if is_new:
+                    from ..obs.telemetry import lowered_hlo_flops
+                    try:
+                        hlo_flops = lowered_hlo_flops(self._train_step.lower(
+                            params, state, opt_state, step, batch, rng))
+                    except Exception:
+                        hlo_flops = None
+                # dispatch timing starts AFTER the FLOPs lowering — the
+                # measurement layer must not bill its own extra trace to
+                # the step it measures (the fused path does the same)
+                with self._timed("train_step", "dispatch", self._train_step,
+                                 batch=batch_id, new_compile=is_new) as disp:
+                    out, profiled = self._maybe_profiled_call(
+                        self._train_step, params, state, opt_state, step,
+                        batch, rng)
+                dispatch_s = disp.seconds
+                params, state, opt_state, step = out[:4]
+                loss, stats = out[4], out[5]
+                health = out[6] if len(out) > 6 else None
+                device_s = None
+                if tel is not None and tel.fence:
+                    # the fencing rule: the dispatch above returned as soon
+                    # as the program was enqueued — device time needs a sync
+                    with self._timed("device_wait", "fence",
+                                     batch=batch_id) as fence:
+                        jax.block_until_ready((params, loss))
+                    device_s = fence.seconds
+                if is_new:
+                    tel.record_compile(
+                        fp, wall_s=dispatch_s + (device_s or 0.0),
+                        hlo_flops=hlo_flops, meta={"k_steps": 1, "m": 1})
+                # Refresh train_state every step: with buffer donation the
+                # previous arrays are invalidated, and event handlers may read
+                # trainer.train_state (e.g. to save) mid-pass.
+                self.train_state = TrainState(params, state, opt_state, step)
+                self._host_step += 1
+                with tspan(self.tracer, "loss_fetch", batch=batch_id):
+                    cost = float(loss)     # the host waits for the device
+                if tel is not None:
+                    if health is not None:
+                        tel.update_health(jax.device_get(health))
+                    rec = tel.emit_step(
+                        {"pass": pass_id, "step": int(step),
+                         "k_steps": 1, "m": 1, "loss": cost,
+                         "profiled": profiled,
+                         "host_stack_ms": None,
+                         "shard_ms": round(shard.seconds * 1e3, 3),
+                         "dispatch_ms": round(dispatch_s * 1e3, 3),
+                         "device_ms": (round(device_s * 1e3, 3)
+                                       if device_s is not None else None),
+                         "replay_ms": None})
+                    with tspan(self.tracer, "events", event="TelemetryRecord"):
+                        handler(ev.TelemetryRecord(record=rec))
+                    self._anomaly_observe(rec)
+                if self._nan_check and not np.isfinite(cost):
+                    from ..utils import debug as dbg
+                    bad = dbg.nonfinite_leaves(
+                        {"params": params, "state": state})
+                    raise FloatingPointError(
+                        f"non-finite loss {cost} at pass {pass_id} batch "
+                        f"{batch_id} (step {int(step)}); non-finite leaves: "
+                        f"{bad[:8] or 'none (loss only)'}")
+                costs.append(cost)
+                metrics = {}
+                if self.evaluator is not None:
+                    self.evaluator.update(jax.device_get(stats))
+                    metrics = self.evaluator.result()
+                if log_period and (batch_id + 1) % log_period == 0:
+                    msg = " ".join(f"{k}={v:.4f}" for k, v in metrics.items())
+                    if tel is not None and tel.last_health:
+                        # health monitors are fetched per call (riding the
+                        # same sync as the loss) but LOGGED only here
+                        msg += " " + " ".join(
+                            f"{k}={v:.3g}"
+                            for k, v in tel.last_health.items())
+                    _log.info("pass %d batch %d cost=%.4f %s",
+                              pass_id, batch_id + 1, cost, msg)
+                    self._log_stat_report()
+                if self._param_stats_period and \
+                        (batch_id + 1) % self._param_stats_period == 0:
+                    self._log_param_stats(pass_id, batch_id)
+                if saving_period and checkpoint_dir and \
+                        (batch_id + 1) % saving_period == 0:
+                    with tspan(self.tracer, "checkpoint_save",
+                               next_batch=batch_id + 1):
+                        save_fn(
+                            checkpoint_dir, pass_id,
+                            {**self.train_state.as_dict(),
+                             "iter": {"pass": pass_id,
+                                      "next_batch": batch_id + 1,
+                                      "completed": 0,
+                                      "batch_crc":
+                                          _batch_fingerprint(host_batch)}},
+                            keep_last=checkpoint_keep)
+                with tspan(self.tracer, "events", event="EndIteration"):
+                    handler(ev.EndIteration(pass_id, batch_id, int(step), cost,
+                                            metrics))
+                if self.faults is not None:
+                    self._fire_step_faults(self._host_step)
             self._maybe_stop(None, pending, pass_id, batch_id + 1, handler,
                              costs, log_period, checkpoint_dir,
                              checkpoint_keep, save_fn,
@@ -1316,11 +1396,9 @@ class Trainer:
         if tel is not None:
             fp = ((1, 1),) + _step_fingerprint(host_batch)
             is_new = tel.observe_fingerprint(fp)
-        t0 = time.perf_counter()
-        with self.stats.time("shard_batch"), \
-                tspan(self.tracer, "device_put", batch=batch_id):
+        with self._timed("shard_batch", "device_put",
+                         batch=batch_id) as shard:
             batch = self._shard(host_batch)
-        t1 = time.perf_counter()
         hlo_flops = None
         if is_new:
             from ..obs.telemetry import lowered_hlo_flops
@@ -1329,17 +1407,15 @@ class Trainer:
                     params, state, opt_state, step, batch, rng))
             except Exception:
                 hlo_flops = None
-        t_disp = time.perf_counter()
-        with self.stats.time("train_step"), \
-                tspan(self.tracer, "dispatch", batch=batch_id,
-                      new_compile=is_new):
+        with self._timed("train_step", "dispatch", self._train_step,
+                         batch=batch_id, new_compile=is_new) as disp:
             out, profiled = self._maybe_profiled_call(
                 self._train_step, params, state, opt_state, step, batch,
                 rng)
+        dispatch_s = disp.seconds
         params, state, opt_state, step = out[:4]
-        t2 = time.perf_counter()
         if is_new:
-            tel.record_compile(fp, wall_s=t2 - t_disp, hlo_flops=hlo_flops,
+            tel.record_compile(fp, wall_s=dispatch_s, hlo_flops=hlo_flops,
                                meta={"k_steps": 1, "m": 1})
         self.train_state = TrainState(params, state, opt_state, step)
         self._host_step += 1
@@ -1350,8 +1426,8 @@ class Trainer:
             rec = {"pass": pass_id, "step": self._host_step,
                    "k_steps": 1, "m": 1, "profiled": profiled,
                    "host_stack_ms": None,
-                   "shard_ms": round((t1 - t0) * 1e3, 3),
-                   "dispatch_ms": round((t2 - t_disp) * 1e3, 3),
+                   "shard_ms": round(shard.seconds * 1e3, 3),
+                   "dispatch_ms": round(dispatch_s * 1e3, 3),
                    "device_ms": None, "replay_ms": None}
         pending.append({
             "batch_id": batch_id, "step": self._host_step,
@@ -1371,11 +1447,10 @@ class Trainer:
         tel = self.telemetry
         batch_id = entry["batch_id"]
         handler(ev.BeginIteration(pass_id, batch_id))
-        t0 = time.perf_counter()
-        with tspan(self.tracer, "drain_wait", batch=batch_id):
+        with self._timed("drain_wait", "drain_wait",
+                         batch=batch_id) as drain:
             cost = float(np.asarray(jax.device_get(entry["loss"])))
-        drain_wait = time.perf_counter() - t0
-        self.stats.add("drain_wait", drain_wait)
+        drain_wait = drain.seconds
         if tel is not None:
             if entry["health"] is not None:
                 tel.update_health(jax.device_get(entry["health"]))
@@ -1446,7 +1521,7 @@ class Trainer:
             # the failure travels GroupStager's producer-error path and
             # surfaces in the training thread at the next submit/get
             self.faults.maybe_stager_error(buf_start)
-        tracer = self.tracer
+        tracer = live(self.tracer)
         # the group's flow id links THIS thread's staging span to the main
         # thread's later dispatch + drain spans in the trace viewer
         flow = tracer.new_flow() if tracer is not None else None
@@ -1455,19 +1530,17 @@ class Trainer:
                    batches=len(buf)):
             for off, take, m_eff in self._plan_group(len(buf),
                                                      self.grad_accum):
-                t0 = time.perf_counter()
-                with tspan(tracer, "stack", group=buf_start, offset=off):
+                with self._timed("stage_stack", "stack", group=buf_start,
+                                 offset=off) as stack:
                     stacked = self._stack_group(buf[off:off + take],
                                                 take // m_eff, m_eff)
-                t1 = time.perf_counter()
-                with tspan(tracer, "shard", group=buf_start, offset=off):
+                with self._timed("stage_shard", "shard", group=buf_start,
+                                 offset=off) as shard:
                     staged = self._shard_fused(stacked)
-                t2 = time.perf_counter()
-                self.stats.add("stage_stack", t1 - t0)
-                self.stats.add("stage_shard", t2 - t1)
                 units.append(StagedUnit(offset=off, m_eff=m_eff,
                                         batches=staged,
-                                        stack_s=t1 - t0, shard_s=t2 - t1))
+                                        stack_s=stack.seconds,
+                                        shard_s=shard.seconds))
             crc = _batch_fingerprint(buf[-1]) if boundary else None
         return StagedGroup(buf_start=buf_start, buf_len=len(buf),
                            units=units, boundary=boundary, crc=crc,
@@ -1553,11 +1626,9 @@ class Trainer:
         if staged is not None:
             batches, shard_s = staged.batches, staged.shard_s
         else:
-            with self.stats.time("shard_batch"), \
-                    tspan(self.tracer, "device_put"):
-                t_sh = time.perf_counter()
+            with self._timed("shard_batch", "device_put") as shard:
                 batches = self._shard_fused(stacked)
-                shard_s = time.perf_counter() - t_sh
+            shard_s = shard.seconds
         ts = self.train_state
         args = (ts.params, ts.state, ts.opt_state, ts.step, batches, rng)
         if is_new:
@@ -1568,13 +1639,12 @@ class Trainer:
                 hlo_flops = lowered_hlo_flops(self._fused_step.lower(*args))
             except Exception:
                 hlo_flops = None
-        t_disp = time.perf_counter()
-        with self.stats.time("train_step"), \
-                tspan(self.tracer, "dispatch", flow_step=flow,
-                      step=self._host_step, new_compile=is_new):
+        with self._timed("train_step", "dispatch", self._fused_step,
+                         flow_step=flow, step=self._host_step,
+                         new_compile=is_new) as disp:
             out, profiled = self._maybe_profiled_call(self._fused_step,
                                                       *args)
-        dispatch_s = time.perf_counter() - t_disp
+        dispatch_s = disp.seconds
         params, state, opt_state, step = out[:4]
         losses, stats = out[4], out[5]
         health = out[6] if len(out) > 6 else None
@@ -1585,10 +1655,9 @@ class Trainer:
             # it measures dispatch, not compute. True device time is the
             # extra wait until the outputs are ready. Telemetry owns this
             # sync; without telemetry the loop never fences.
-            with tspan(self.tracer, "fence"):
+            with self._timed("device_wait", "fence") as fence:
                 jax.block_until_ready((params, losses))
-            device_s = time.perf_counter() - t_disp - dispatch_s
-            self.stats.add("device_wait", device_s)
+            device_s = fence.seconds
         k_eff = int(losses.shape[0])
         self._host_step += k_eff       # host mirror of the device step
         if is_new:
@@ -1648,12 +1717,11 @@ class Trainer:
         with tspan(self.tracer, "plan", group=buf_start, batches=len(buf)):
             plans = self._plan_group(len(buf), self.grad_accum)
         for off, take, m_eff in plans:
-            t_stack = time.perf_counter()
-            with tspan(self.tracer, "stack", group=buf_start, offset=off):
+            with self._timed("stack_group", "stack", group=buf_start,
+                             offset=off) as stack:
                 stacked = self._stack_group(buf[off:off + take],
                                             take // m_eff, m_eff)
-            stack_s = time.perf_counter() - t_stack
-            self.stats.add("stack_group", stack_s)
+            stack_s = stack.seconds
             losses, stats, health, rec = self._dispatch_fused(
                 stacked, rng, stack_s=stack_s)
             # record THIS dispatch's post-call step count: a group split
@@ -1684,11 +1752,10 @@ class Trainer:
             timed = []
             for i, (start, m_eff, losses, stats, step_after, health,
                     rec) in enumerate(results):
-                t0 = time.perf_counter()
-                with tspan(self.tracer, "drain_wait", step=step_after):
+                with self._timed("drain_wait", "drain_wait",
+                                 step=step_after) as drain:
                     losses = np.asarray(jax.device_get(losses))
-                wait = time.perf_counter() - t0
-                self.stats.add("drain_wait", wait)
+                wait = drain.seconds
                 if rec is not None:
                     rec["drain_wait_ms"] = round(wait * 1e3, 3)
                     if overlap_frac is not None:
@@ -1725,10 +1792,10 @@ class Trainer:
             health_np = (jax.device_get(health)
                          if (tel is not None and health is not None)
                          else None)
-            t_replay = time.perf_counter()
+            replay = self._timed(None, "events_replay", step=step_after)
             replay_ok = False
             try:
-                with tspan(self.tracer, "events_replay", step=step_after):
+                with replay:
                     self._post_fused(pass_id, start, m_eff, losses, stats,
                                      step_after, handler, costs, log_period,
                                      health_np=health_np)
@@ -1755,8 +1822,7 @@ class Trainer:
                         rec["step"] = step_after
                         rec["loss"] = float(np.asarray(
                             jax.device_get(losses)).ravel()[-1])
-                        rec["replay_ms"] = round(
-                            (time.perf_counter() - t_replay) * 1e3, 3)
+                        rec["replay_ms"] = round(replay.seconds * 1e3, 3)
                         rec = tel.emit_step(rec)
                         handler(ev.TelemetryRecord(record=rec))
                         self._anomaly_observe(rec)
@@ -1936,10 +2002,10 @@ class Trainer:
         from ..obs import xla_cache
         entries_before = xla_cache.cache_entry_count()
         trials_before = autotune.stats()["trials"]
-        t0 = time.perf_counter()
-        lowered, fp = self.lower_step(sample_batches, rng)
-        lowered.compile()
-        wall = time.perf_counter() - t0
+        with self._timed(None, "trainer_warmup") as reg:
+            lowered, fp = self.lower_step(sample_batches, rng)
+            lowered.compile()
+        wall = reg.seconds
         added = xla_cache.cache_entry_count() - entries_before
         cache_hit = (None if xla_cache.active_dir() is None
                      else added == 0)

@@ -4,5 +4,4 @@ hardening (debug) — the paddle/utils tier."""
 from . import debug, flags, gradcheck, interop, stats
 from .flags import TrainerFlags, parse_flags
 from .gradcheck import check_gradients
-from .stats import (BarrierStat, StatSet, global_stats,
-                    profile_trace, timer)
+from .stats import BarrierStat, StatSet, global_stats, timer

@@ -3,9 +3,9 @@
 Reference: ``/root/reference/paddle/utils/Stat.h:63,230`` (``StatSet`` with
 ``REGISTER_TIMER*`` macros, periodic ``printAllStatus``) used through the hot
 loop. TPU-native notes: device work is async, so timers that should include
-device time must fence via ``jax.block_until_ready`` (the ``sync`` flag); the
-jax profiler (``start_trace``/``stop_trace``) is surfaced for kernel-level
-traces (the analog of ``hl_profiler_start``/nvprof).
+device time must fence via ``jax.block_until_ready`` (the ``sync`` flag). For
+kernel-level traces (the analog of ``hl_profiler_start``/nvprof) see
+``paddle_tpu.obs.trace.jax_profile``.
 """
 
 from __future__ import annotations
@@ -17,8 +17,7 @@ from typing import Dict, Optional
 
 import jax
 
-__all__ = ["StatSet", "BarrierStat", "global_stats", "timer",
-           "profile_trace"]
+__all__ = ["StatSet", "BarrierStat", "global_stats", "timer"]
 
 
 class _Stat:
@@ -100,17 +99,6 @@ def global_stats() -> StatSet:
 
 def timer(key: str, sync=None):
     return _global.time(key, sync=sync)
-
-
-@contextlib.contextmanager
-def profile_trace(logdir: str):
-    """jax profiler trace (view in TensorBoard/Perfetto) — the GPU-profiler
-    analog (``hl_profiler_start/end``)."""
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
 
 
 class BarrierStat:
