@@ -295,7 +295,9 @@ def kernel_leg(sizes: Sizes, seed: int = 0) -> None:
     # every slot owns a distinct run of pool blocks, in shuffled order
     tables = jnp.asarray(
         1 + rng.permutation(S * MB).reshape(S, MB), jnp.int32)
-    raw_k, raw_v = normal(N, H, bs, D), normal(N, H, bs, D)
+    # two layers' pools; the kernels read the second by index
+    layer = jnp.int32(1)
+    raw_k, raw_v = normal(2, N, H, bs, D), normal(2, N, H, bs, D)
     pools = {
         "float32": (raw_k, raw_v),
         "bfloat16": (raw_k.astype(jnp.bfloat16), raw_v.astype(jnp.bfloat16)),
@@ -322,9 +324,11 @@ def kernel_leg(sizes: Sizes, seed: int = 0) -> None:
         errs[name] = err
 
     for kind, (pk, pv) in pools.items():
-        args = (q1, pk, pv, tables, jnp.asarray(lengths))
-        got = jax.jit(paged_decode_attention)(*args)
-        want = oracle(paged_reference_attention, *args)
+        # the oracles take one layer's pool
+        lk, lv = jax.tree_util.tree_map(lambda p: p[layer], (pk, pv))
+        rest = (tables, jnp.asarray(lengths))
+        got = jax.jit(paged_decode_attention)(q1, pk, pv, *rest, layer)
+        want = oracle(paged_reference_attention, q1, lk, lv, *rest)
         check(f"paged_decode/{kind}", kind, got, want)
         assert not np.asarray(got)[lengths == 0].any(), \
             "an inactive slot must read zeros"
@@ -338,10 +342,11 @@ def kernel_leg(sizes: Sizes, seed: int = 0) -> None:
             if slots > 2:
                 start[1], n[1], n[2] = 7, 0, max(1, Q - 1)
             qs = normal(slots, Q, H, D)
-            args = (qs, pk, pv, tables[:slots], jnp.asarray(start),
-                    jnp.asarray(n))
-            got = np.asarray(jax.jit(paged_span_attention)(*args))
-            want = np.asarray(oracle(paged_span_reference_attention, *args))
+            rest = (tables[:slots], jnp.asarray(start), jnp.asarray(n))
+            got = np.asarray(jax.jit(paged_span_attention)(
+                qs, pk, pv, *rest, layer))
+            want = np.asarray(oracle(paged_span_reference_attention,
+                                     qs, lk, lv, *rest))
             live = np.arange(Q)[None, :] < n[:, None]     # rows >= n: pad
             check(f"paged_span/Q{Q}/{kind}", kind, got[live], want[live])
             assert not got[n == 0].any(), \

@@ -102,17 +102,19 @@ class TransformerBlock(Module):
             return out, aux, kv
         return out, aux
 
-    def decode_step(self, x, pages_k, pages_v, tables, positions, active,
-                    attn_impl: str = "xla"):
+    def decode_step(self, x, pages_k, pages_v, layer, tables, positions,
+                    active, attn_impl: str = "xla"):
         """One serving decode step: the forward block with the attention
         sublayer swapped for :meth:`MultiHeadAttention.decode` (paged KV
-        scatter + q_len=1 attention). Returns ``(out, pages_k, pages_v)``
-        with this layer's updated pool pages. No dropout — serving is
-        inference-only by construction."""
+        write + q_len=1 attention). ``pages_k``/``pages_v`` are every
+        layer's pools and ``layer`` this block's number; returns
+        ``(out, pages_k, pages_v)`` with the pools whole, this layer's
+        rows written. No dropout — serving is inference-only by
+        construction."""
         with jax.named_scope("attn"):
             a, pages_k, pages_v = self.attn.decode(
-                self.ln1(x), pages_k, pages_v, tables, positions, active,
-                impl=attn_impl)
+                self.ln1(x), pages_k, pages_v, layer, tables, positions,
+                active, impl=attn_impl)
             h = x + a
         if self.residual_sharding is not None:
             h = self.residual_sharding(h)
@@ -127,18 +129,19 @@ class TransformerBlock(Module):
             out = self.residual_sharding(out)
         return out, pages_k, pages_v
 
-    def decode_span(self, x, pages_k, pages_v, tables, start, n, active,
-                    attn_impl: str = "xla", write_from=None):
+    def decode_span(self, x, pages_k, pages_v, layer, tables, start, n,
+                    active, attn_impl: str = "xla", write_from=None):
         """A span of consecutive new tokens per slot: the forward block
         with the attention sublayer swapped for
         :meth:`MultiHeadAttention.decode_span` (multi-token paged
-        scatter + per-row q_len=1-exact attention). Shared by the
+        write + per-row q_len=1-exact attention). Shared by the
         speculative verify tick and chunked prefill (ISSUE 12).
-        ``x`` [S, Q, D]; returns ``(out, pages_k, pages_v)``."""
+        ``x`` [S, Q, D]; pools and ``layer`` as in :meth:`decode_step`;
+        returns ``(out, pages_k, pages_v)``."""
         with jax.named_scope("attn"):
             a, pages_k, pages_v = self.attn.decode_span(
-                self.ln1(x), pages_k, pages_v, tables, start, n, active,
-                impl=attn_impl, write_from=write_from)
+                self.ln1(x), pages_k, pages_v, layer, tables, start, n,
+                active, impl=attn_impl, write_from=write_from)
             h = x + a
         if self.residual_sharding is not None:
             h = self.residual_sharding(h)
@@ -301,8 +304,11 @@ class TransformerLM(Module):
                     attn_impl: str = "xla"):
         """Serving decode tick: one new token per slot against the paged
         KV cache. ``token [S]`` int32; ``kv = (pages_k, pages_v,
-        tables)`` with pools ``[L, N, H, bs, hd]`` (the leading layer
-        axis feeds the layer scan) and ``tables [S, MB]``; ``positions
+        tables)`` with pools ``[L, N, H, bs, hd]`` (the layer scan
+        CARRIES them whole beside the residual stream: each layer writes
+        its rows in place and reads its pages by layer index, so no
+        pool-sized array is ever sliced out, copied or collected) and
+        ``tables [S, MB]``; ``positions
         [S]`` the incoming token's 0-based position (== pre-step length);
         ``active [S]`` bool (default: all). Returns ``(logits [S,
         vocab], kv')`` with the updated pools — same structure, so the
@@ -321,17 +327,19 @@ class TransformerLM(Module):
                                  + self.pos(pos_idx[:, None]))
             block0, stacked = self._stacked_blocks()
 
-            def body(h, xs):
-                bp, pk, pv = xs
+            def body(carry, xs):
+                h, pk, pv = carry
+                bp, layer = xs
                 y, pk, pv = block0.apply(
-                    {"params": {block0._name: bp}}, h, pk, pv, tables,
-                    positions, active, attn_impl=attn_impl,
+                    {"params": {block0._name: bp}}, h, pk, pv, layer,
+                    tables, positions, active, attn_impl=attn_impl,
                     method="decode_step")
-                return tp_constrain(y), (pk, pv)
+                return (tp_constrain(y), pk, pv), None
 
             with jax.named_scope("block_scan"):
-                x, (pages_k, pages_v) = lax.scan(
-                    body, x, (stacked, pages_k, pages_v))
+                (x, pages_k, pages_v), _ = lax.scan(
+                    body, (x, pages_k, pages_v),
+                    (stacked, jnp.arange(len(self.blocks))))
             with jax.named_scope("head"):
                 logits = tp_constrain(self.emb.attend(self.ln_f(x)))
         return logits[:, 0], (pages_k, pages_v, tables)
@@ -363,17 +371,19 @@ class TransformerLM(Module):
                 x = tp_constrain(self.emb(tokens) + self.pos(pos))
             block0, stacked = self._stacked_blocks()
 
-            def body(h, xs):
-                bp, pk, pv = xs
+            def body(carry, xs):
+                h, pk, pv = carry
+                bp, layer = xs
                 y, pk, pv = block0.apply(
-                    {"params": {block0._name: bp}}, h, pk, pv, tables,
-                    start, n, active, attn_impl=attn_impl,
+                    {"params": {block0._name: bp}}, h, pk, pv, layer,
+                    tables, start, n, active, attn_impl=attn_impl,
                     write_from=write_from, method="decode_span")
-                return tp_constrain(y), (pk, pv)
+                return (tp_constrain(y), pk, pv), None
 
             with jax.named_scope("block_scan"):
-                x, (pages_k, pages_v) = lax.scan(
-                    body, x, (stacked, pages_k, pages_v))
+                (x, pages_k, pages_v), _ = lax.scan(
+                    body, (x, pages_k, pages_v),
+                    (stacked, jnp.arange(len(self.blocks))))
             with jax.named_scope("head"):
                 logits = tp_constrain(self.emb.attend(self.ln_f(x)))
         return logits, (pages_k, pages_v, tables)

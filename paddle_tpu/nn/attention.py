@@ -35,8 +35,8 @@ def _tp_paged_kernel(kernel, q, pages_k, pages_v, *rest, head_dim: int):
     and of every pool block — block tables and lengths replicate. With
     no scope active the kernel runs whole, unchanged. ``head_dim`` is
     the axis of ``q`` (and of the kernel's output) carrying heads; pool
-    leaves always carry heads on axis 1 (``[N, H, bs, hd]`` values,
-    ``[N, H, bs]`` scale pages)."""
+    leaves always carry heads on axis 2 (``[L, N, H, bs, hd]`` values,
+    ``[L, N, H, bs]`` scale pages)."""
     from ..parallel.sharding import current_tp_shard
     scope = current_tp_shard()
     if scope is None:
@@ -46,7 +46,7 @@ def _tp_paged_kernel(kernel, q, pages_k, pages_v, *rest, head_dim: int):
 
     def pool_spec(pool):
         return jax.tree_util.tree_map(
-            lambda leaf: P(*[axis if i == 1 else None
+            lambda leaf: P(*[axis if i == 2 else None
                              for i in range(leaf.ndim)]), pool)
 
     sharded = jax.shard_map(
@@ -300,17 +300,21 @@ class MultiHeadAttention(Module):
             return out, (tp_constrain(k, 2), tp_constrain(v, 2))
         return out
 
-    def decode(self, q_in, pages_k, pages_v, tables, positions, active,
-               impl: str = "xla"):
+    def decode(self, q_in, pages_k, pages_v, layer, tables, positions,
+               active, impl: str = "xla"):
         """One decode step (q_len = 1) against a paged KV cache: project
-        the new token, scatter its K/V into this layer's pool pages, and
+        the new token, write its K/V into this layer's pool pages, and
         attend over the slot's whole ragged context.
 
         Args: ``q_in`` [S, 1, D] (one token per serving slot);
-        ``pages_k``/``pages_v`` [N, H, bs, hd] (this layer's pool);
+        ``pages_k``/``pages_v`` [L, N, H, bs, hd]: EVERY layer's pool,
+        the layer scan's carry, of which this layer is number ``layer``
+        (a traced int32 scalar). The pools are written in place and read
+        by index; the layer's pool is never taken out of them or put
+        back (that was two fifths of a decode tick, PERF.md PR 26);
         ``tables`` [S, MB] block tables; ``positions`` [S] the incoming
         token's 0-based position (== the pre-step sequence length);
-        ``active`` [S] bool slot mask (inactive slots scatter to the null
+        ``active`` [S] bool slot mask (inactive slots write the null
         block and output zeros). ``impl``: ``"paged"`` = the Pallas
         decode kernel (:func:`~paddle_tpu.nn.pallas_attention.
         paged_decode_attention`); ``"xla"`` = the gather + masked-softmax
@@ -322,14 +326,14 @@ class MultiHeadAttention(Module):
         the serving engine reaches it via
         ``model.apply(..., method="decode_step")``. Quantized pools (the
         ``(int8, scales)`` tuples, ISSUE 14) flow through transparently:
-        the scatter quantizes, the kernel/gather dequantizes. Under an
+        the write quantizes, the kernel/gather dequantizes. Under an
         active ``tp_shard_scope`` (ISSUE 15) the projections and pools
         are constrained head-sharded — qkv column-parallel, attention on
         local heads, the out projection's row-parallel partial sums
         all-reduced — the Megatron tp recipe with the partitioner
         inserting the collectives; the paged kernel path runs per shard
         via :func:`_tp_paged_kernel`."""
-        from ..serve.kv_cache import gather_pages, scatter_token_pages
+        from ..serve.kv_cache import gather_pages, write_token
         from ..parallel.sharding import tp_constrain
         with self.scope():
             pol = current_policy()
@@ -353,11 +357,11 @@ class MultiHeadAttention(Module):
                     proj("wv", q_in, h * hd).reshape(S, 1, h, hd), 2)
             with jax.named_scope("kv_scatter"):
                 pages_k = tp_constrain(
-                    scatter_token_pages(pages_k, k[:, 0], tables,
-                                        positions, active), 1)
+                    write_token(pages_k, layer, k[:, 0], tables,
+                                positions, active), 2)
                 pages_v = tp_constrain(
-                    scatter_token_pages(pages_v, v[:, 0], tables,
-                                        positions, active), 1)
+                    write_token(pages_v, layer, v[:, 0], tables,
+                                positions, active), 2)
             # the new token sees itself: effective length = position + 1
             eff_len = jnp.where(active, positions + 1, 0)
             if impl == "paged":
@@ -365,12 +369,13 @@ class MultiHeadAttention(Module):
                 with jax.named_scope("paged_attention"):
                     ctx = _tp_paged_kernel(
                         paged_decode_attention, q[:, 0], pages_k,
-                        pages_v, tables, eff_len, head_dim=1)
+                        pages_v, tables, eff_len, layer, head_dim=1)
                     ctx = ctx.reshape(S, 1, h, hd).astype(pol.compute_dtype)
             else:
                 with jax.named_scope("sdpa_xla"):
-                    kg = gather_pages(pages_k, tables)      # [S, W, h, hd]
-                    vg = gather_pages(pages_v, tables)
+                    # [S, W, h, hd]
+                    kg = gather_pages(pages_k, tables, layer)
+                    vg = gather_pages(pages_v, tables, layer)
                     ctx = self._sdpa_row(q, kg, vg, eff_len, pol, hd)
             ctx = tp_constrain(ctx, 2).reshape(S, 1, h * hd)
             with jax.named_scope("out_proj"):
@@ -409,18 +414,20 @@ class MultiHeadAttention(Module):
         # paged kernel's convention; live lanes pass through unchanged
         return jnp.where((eff_len > 0)[:, None, None, None], ctx, 0.0)
 
-    def decode_span(self, q_in, pages_k, pages_v, tables, start, n,
-                    active, impl: str = "xla", write_from=None):
+    def decode_span(self, q_in, pages_k, pages_v, layer, tables, start,
+                    n, active, impl: str = "xla", write_from=None):
         """A SPAN of consecutive new tokens per slot against the paged
         KV cache — the multi-query generalization of :meth:`decode`
         shared by the speculative verify tick (``Q = 1 + draft_k``) and
         chunked prefill (``Q = chunk``), ISSUE 12.
 
         Args: ``q_in`` [S, Q, D] (token ``j`` of slot ``s`` sits at
-        position ``start[s] + j``); ``n`` [S] live token count per slot
-        (rows ``>= n`` are padding: null-block scatter, garbage logits
+        position ``start[s] + j``); ``pages_k``/``pages_v``/``layer``
+        the carried pools and this layer's number, as in :meth:`decode`;
+        ``n`` [S] live token count per slot
+        (rows ``>= n`` are padding: null-block write, garbage logits
         the host ignores); ``active`` [S]; ``write_from`` [S] optional
-        absolute position below which the scatter is masked (a chunk
+        absolute position below which the write is masked (a chunk
         re-attending a shared prefix must not write co-owned pages).
         Returns ``(out [S, Q, out_d], pages_k, pages_v)``.
 
@@ -435,11 +442,11 @@ class MultiHeadAttention(Module):
         ISSUE 14) — streams only the slot's own pages instead of the
         O(W)-per-row gather; tolerance-accurate vs the oracle, bit-equal
         to the q_len=1 kernel at Q=1. Quantized pools flow through both
-        (scatter quantizes, kernel/gather dequantizes). Under an active
+        (the write quantizes, kernel/gather dequantizes). Under an active
         ``tp_shard_scope`` (ISSUE 15) the span runs tp-sharded exactly
         like :meth:`decode` — head-sharded projections/pools/kernel,
         all-reduced out projection."""
-        from ..serve.kv_cache import gather_pages, scatter_span_pages
+        from ..serve.kv_cache import gather_pages, write_span
         from ..parallel.sharding import tp_constrain
         if impl not in ("xla", "paged"):
             raise ValueError(
@@ -467,22 +474,23 @@ class MultiHeadAttention(Module):
             n_eff = jnp.where(active, n, 0)
             with jax.named_scope("kv_scatter"):
                 pages_k = tp_constrain(
-                    scatter_span_pages(pages_k, k, tables, start,
-                                       n_eff, write_from), 1)
+                    write_span(pages_k, layer, k, tables, start, n_eff,
+                               write_from), 2)
                 pages_v = tp_constrain(
-                    scatter_span_pages(pages_v, v, tables, start,
-                                       n_eff, write_from), 1)
+                    write_span(pages_v, layer, v, tables, start, n_eff,
+                               write_from), 2)
             if impl == "paged":
                 from .pallas_attention import paged_span_attention
                 with jax.named_scope("paged_span_attention"):
                     ctx = _tp_paged_kernel(
                         paged_span_attention, q, pages_k, pages_v,
-                        tables, start, n_eff, head_dim=2)
+                        tables, start, n_eff, layer, head_dim=2)
                     ctx = ctx.astype(pol.compute_dtype)
             else:
                 with jax.named_scope("sdpa_xla"):
-                    kg = gather_pages(pages_k, tables)  # [S, W, h, hd]
-                    vg = gather_pages(pages_v, tables)
+                    # [S, W, h, hd]
+                    kg = gather_pages(pages_k, tables, layer)
+                    vg = gather_pages(pages_v, tables, layer)
                     ctxs = []
                     for j in range(Q):
                         # row j sees context start+j+1 (itself

@@ -533,6 +533,22 @@ def _unpack_pages(pages):
     return pages, None
 
 
+def _layer_operand(layer):
+    """The layer number as the ``[1]`` int32 array a scalar-prefetch
+    operand has to be."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def _scale_spec(H, bs, kv_map):
+    """A block's whole ``[H, bs]`` scale page (every head's: the block's
+    last two dimensions have to be whole or tile-aligned), at the pool
+    block the K/V index map names."""
+    def scale_map(*grid_and_prefetch):
+        layer, block, _, _, _ = kv_map(*grid_and_prefetch)
+        return (layer, block, 0, 0)
+    return pl.BlockSpec((None, None, H, bs), scale_map)
+
+
 def _gathered(pages, tables):
     """Dequantized position-order gather for the reference oracles —
     the serving pool's own gather, so the oracles can never drift from
@@ -588,8 +604,25 @@ def paged_span_reference_attention(q, pages_k, pages_v, tables, start, n,
     return jnp.einsum("sqhk,skhd->sqhd", p, v)
 
 
-def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                         scale, bs, quant):
+def _kv_blocks(k_ref, v_ref, sk_ref, sv_ref, head):
+    """``(K block, V block, K scales, V scales)`` of one grid step. A
+    plain pool's blocks go to the products as they are. A quantized
+    pool's int8 blocks are widened in VMEM and their per-token scales
+    come as ``[1, bs]`` rows, this head's row of the block's ``[H, bs]``
+    scale page: a token's scale multiplies its score and its
+    probability, which is the same product as scaling its K and V rows
+    and needs no ``[bs, 1]`` column (as an operand that column is a
+    relayout of the whole scale pool, 128 times its size in the TPU's
+    tiling)."""
+    if sk_ref is None:
+        return k_ref[:], v_ref[:], None, None
+    row = pl.ds(head, 1)
+    return (k_ref[:].astype(jnp.float32), v_ref[:].astype(jnp.float32),
+            sk_ref[row, :], sv_ref[row, :])
+
+
+def _paged_decode_kernel(tbl_ref, len_ref, lay_ref, q_ref, k_ref, v_ref,
+                         *rest, scale, bs, quant):
     """One (slot, head) row's online softmax over its block table. Grid
     ``(S, H, MB)``: the innermost axis streams the slot's KV blocks
     (sequential on TPU — the m/l/acc scratch carries across it), with the
@@ -597,12 +630,14 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     so the DMA fetches exactly the pages the sequence owns. With
     ``quant`` the K/V blocks arrive int8 with per-row scale pages and
     are dequantized IN VMEM (never in HBM — the whole point of the int8
-    pool is HBM bytes)."""
+    pool is HBM bytes; :func:`_kv_blocks`)."""
     if quant:
         sk_ref, sv_ref, o_ref, m_s, l_s, acc_s = rest
     else:
+        sk_ref = sv_ref = None
         o_ref, m_s, l_s, acc_s = rest
     s_idx = pl.program_id(0)
+    head = pl.program_id(1)
     j = pl.program_id(2)
     nkb = pl.num_programs(2)
 
@@ -619,13 +654,11 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     @pl.when(j * bs < length)
     def _():
         # native-dtype matmul operands + f32 accumulate (see _attn_kernel)
-        if quant:
-            kb = k_ref[:].astype(jnp.float32) * sk_ref[:]
-            vb = v_ref[:].astype(jnp.float32) * sv_ref[:]
-        else:
-            kb, vb = k_ref[:], v_ref[:]
+        kb, vb, sk, sv = _kv_blocks(k_ref, v_ref, sk_ref, sv_ref, head)
         s = jax.lax.dot_general(q_ref[:], kb, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
+        if quant:
+            s = s * sk
         k_idx = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
         s = jnp.where(k_idx < length, s, _NEG)
         m = m_s[:]
@@ -633,8 +666,9 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
         l_s[:] = l_s[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        pv = p * sv if quant else p
         acc_s[:] = acc_s[:] * corr + jax.lax.dot_general(
-            p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+            pv.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_s[:] = m_new
 
@@ -644,7 +678,7 @@ def _paged_decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
         o_ref[:] = (acc_s[:] / l).astype(o_ref.dtype)
 
 
-def paged_decode_attention(q, pages_k, pages_v, tables, lengths,
+def paged_decode_attention(q, pages_k, pages_v, tables, lengths, layer,
                            scale: Optional[float] = None,
                            interpret: Optional[bool] = None):
     """Decode-shaped (q_len = 1) flash attention over a paged KV cache.
@@ -659,45 +693,47 @@ def paged_decode_attention(q, pages_k, pages_v, tables, lengths,
     what makes the pool's ragged sharing free.
 
     Args: ``q`` ``[S, H, D]`` (slot-major, one token per slot);
-    ``pages_k``/``pages_v`` ``[N, H, bs, D]`` (one layer's pool — heads
-    ahead of the page's tokens, so each K/V block is one head's whole
-    ``[bs, D]`` page: the TPU lowering requires a block's last two
-    dimensions to be tile-aligned or whole), or the quantized
-    ``(int8 values, scales [N, H, bs])`` tuple — scale pages stream
-    beside the value blocks and dequantization happens in VMEM;
-    ``tables`` ``[S, MB]`` int32; ``lengths`` ``[S]`` int32 — the number
-    of valid tokens INCLUDING the one just scattered; 0 marks an
-    inactive slot (zero output). ``interpret`` defaults to True off-TPU
-    (same contract as :func:`flash_attention`)."""
+    ``pages_k``/``pages_v`` ``[L, N, H, bs, D]``: EVERY layer's pool,
+    of which the kernel reads layer ``layer`` (heads ahead of the page's
+    tokens, so each K/V block is one head's whole ``[bs, D]`` page: the
+    TPU lowering requires a block's last two dimensions to be
+    tile-aligned or whole), or the quantized ``(int8 values, scales
+    [L, N, H, bs])`` tuple — scale pages stream beside the value blocks
+    and dequantization happens in VMEM; ``tables`` ``[S, MB]`` int32;
+    ``lengths`` ``[S]`` int32 — the number of valid tokens INCLUDING the
+    one just written; 0 marks an inactive slot (zero output); ``layer``
+    an int32 scalar, traced or not: the third prefetched operand, so
+    the serving tick's layer scan hands the kernel its carried pools
+    whole and no layer is ever sliced out of them. ``interpret``
+    defaults to True off-TPU (same contract as
+    :func:`flash_attention`)."""
     S, H, D = q.shape
     pages_k, scale_k = _unpack_pages(pages_k)
     pages_v, scale_v = _unpack_pages(pages_v)
     quant = scale_k is not None
-    N, Hk, bs, Dk = pages_k.shape
+    L, N, Hk, bs, Dk = pages_k.shape
     assert (H, D) == (Hk, Dk), f"q heads {(H, D)} != pages {(Hk, Dk)}"
     MB = tables.shape[1]
     scale, interpret = _resolve_defaults(q, scale, interpret)
     q4 = q.reshape(S, H, 1, D)
 
-    def q_map(s, h, j, tbl, lens):
+    def q_map(s, h, j, tbl, lens, lay):
         return (s, h, 0, 0)
 
-    def kv_map(s, h, j, tbl, lens):
-        return (tbl[s, j], h, 0, 0)
+    def kv_map(s, h, j, tbl, lens, lay):
+        return (lay[0], tbl[s, j], h, 0, 0)
 
     in_specs = [
         pl.BlockSpec((None, None, 1, D), q_map),
-        pl.BlockSpec((None, None, bs, D), kv_map),
-        pl.BlockSpec((None, None, bs, D), kv_map),
+        pl.BlockSpec((None, None, None, bs, D), kv_map),
+        pl.BlockSpec((None, None, None, bs, D), kv_map),
     ]
     operands = [q4, pages_k, pages_v]
     if quant:
-        # trailing unit dim keeps the scale block 2-D ([bs, 1])
-        in_specs += [pl.BlockSpec((None, None, bs, 1), kv_map),
-                     pl.BlockSpec((None, None, bs, 1), kv_map)]
-        operands += [scale_k[..., None], scale_v[..., None]]
+        in_specs += [_scale_spec(H, bs, kv_map)] * 2
+        operands += [scale_k, scale_v]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(S, H, MB),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((None, None, 1, D), q_map),
@@ -713,12 +749,13 @@ def paged_decode_attention(q, pages_k, pages_v, tables, lengths,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, 1, D), q.dtype),
         interpret=interpret, name="paged_decode",
-    )(tables.astype(jnp.int32), lengths.astype(jnp.int32), *operands)
+    )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
+      _layer_operand(layer), *operands)
     return out.reshape(S, H, D)
 
 
-def _paged_span_kernel(tbl_ref, start_ref, n_ref, q_ref, k_ref, v_ref,
-                       *rest, scale, bs, quant):
+def _paged_span_kernel(tbl_ref, start_ref, n_ref, lay_ref, q_ref, k_ref,
+                       v_ref, *rest, scale, bs, quant):
     """One (slot, head) SPAN's online softmax over its block table — the
     q_len = 1+k generalization of :func:`_paged_decode_kernel` (ISSUE
     14). Grid ``(S, H, MB)`` with the span's ``Q`` rows resident in one
@@ -730,9 +767,11 @@ def _paged_span_kernel(tbl_ref, start_ref, n_ref, q_ref, k_ref, v_ref,
     if quant:
         sk_ref, sv_ref, o_ref, m_s, l_s, acc_s = rest
     else:
+        sk_ref = sv_ref = None
         o_ref, m_s, l_s, acc_s = rest
     Q, d = q_ref.shape
     s_idx = pl.program_id(0)
+    head = pl.program_id(1)
     j = pl.program_id(2)
     nkb = pl.num_programs(2)
 
@@ -753,13 +792,11 @@ def _paged_span_kernel(tbl_ref, start_ref, n_ref, q_ref, k_ref, v_ref,
     # start+j >= 0 is always visible)
     @pl.when((n > 0) & (j * bs < start + n))
     def _():
-        if quant:
-            kb = k_ref[:].astype(jnp.float32) * sk_ref[:]
-            vb = v_ref[:].astype(jnp.float32) * sv_ref[:]
-        else:
-            kb, vb = k_ref[:], v_ref[:]
+        kb, vb, sk, sv = _kv_blocks(k_ref, v_ref, sk_ref, sv_ref, head)
         s = jax.lax.dot_general(q_ref[:], kb, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
+        if quant:
+            s = s * sk
         k_idx = j * bs + jax.lax.broadcasted_iota(jnp.int32, (Q, bs), 1)
         q_idx = jax.lax.broadcasted_iota(jnp.int32, (Q, bs), 0)
         s = jnp.where(k_idx <= start + q_idx, s, _NEG)
@@ -768,8 +805,9 @@ def _paged_span_kernel(tbl_ref, start_ref, n_ref, q_ref, k_ref, v_ref,
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
         l_s[:] = l_s[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        pv = p * sv if quant else p
         acc_s[:] = acc_s[:] * corr + jax.lax.dot_general(
-            p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+            pv.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_s[:] = m_new
 
@@ -779,7 +817,7 @@ def _paged_span_kernel(tbl_ref, start_ref, n_ref, q_ref, k_ref, v_ref,
         o_ref[:] = (acc_s[:] / l).astype(o_ref.dtype)
 
 
-def paged_span_attention(q, pages_k, pages_v, tables, start, n,
+def paged_span_attention(q, pages_k, pages_v, tables, start, n, layer,
                          scale: Optional[float] = None,
                          interpret: Optional[bool] = None):
     """Multi-query (q_len = 1+k) flash attention over a paged KV cache —
@@ -790,9 +828,11 @@ def paged_span_attention(q, pages_k, pages_v, tables, start, n,
     gather-everything XLA path on TPU.
 
     Args: ``q`` ``[S, Q, H, D]`` (row ``j`` of slot ``s`` sits at
-    position ``start[s] + j``); ``pages_k``/``pages_v`` one layer's pool
-    (plain or the quantized ``(int8, scales)`` tuple — dequantized in
-    VMEM); ``tables`` ``[S, MB]``; ``start``/``n`` ``[S]`` int32 — rows
+    position ``start[s] + j``); ``pages_k``/``pages_v`` every layer's
+    pool ``[L, N, H, bs, D]`` (plain or the quantized ``(int8, scales)``
+    tuple — dequantized in VMEM) and ``layer`` the one to read, as in
+    :func:`paged_decode_attention`; ``tables`` ``[S, MB]``;
+    ``start``/``n`` ``[S]`` int32 — rows
     ``>= n[s]`` are padding (finite garbage output the host ignores),
     ``n == 0`` marks an inactive slot (zero output). At ``Q = 1`` the
     kernel runs the exact op sequence of
@@ -802,30 +842,29 @@ def paged_span_attention(q, pages_k, pages_v, tables, start, n,
     pages_k, scale_k = _unpack_pages(pages_k)
     pages_v, scale_v = _unpack_pages(pages_v)
     quant = scale_k is not None
-    N, Hk, bs, Dk = pages_k.shape
+    L, N, Hk, bs, Dk = pages_k.shape
     assert (H, D) == (Hk, Dk), f"q heads {(H, D)} != pages {(Hk, Dk)}"
     MB = tables.shape[1]
     scale, interpret = _resolve_defaults(q, scale, interpret)
     qt = jnp.swapaxes(q, 1, 2)               # [S, H, Q, D]
 
-    def q_map(s, h, j, tbl, st, nn):
+    def q_map(s, h, j, tbl, st, nn, lay):
         return (s, h, 0, 0)
 
-    def kv_map(s, h, j, tbl, st, nn):
-        return (tbl[s, j], h, 0, 0)
+    def kv_map(s, h, j, tbl, st, nn, lay):
+        return (lay[0], tbl[s, j], h, 0, 0)
 
     in_specs = [
         pl.BlockSpec((None, None, Q, D), q_map),
-        pl.BlockSpec((None, None, bs, D), kv_map),
-        pl.BlockSpec((None, None, bs, D), kv_map),
+        pl.BlockSpec((None, None, None, bs, D), kv_map),
+        pl.BlockSpec((None, None, None, bs, D), kv_map),
     ]
     operands = [qt, pages_k, pages_v]
     if quant:
-        in_specs += [pl.BlockSpec((None, None, bs, 1), kv_map),
-                     pl.BlockSpec((None, None, bs, 1), kv_map)]
-        operands += [scale_k[..., None], scale_v[..., None]]
+        in_specs += [_scale_spec(H, bs, kv_map)] * 2
+        operands += [scale_k, scale_v]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(S, H, MB),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((None, None, Q, D), q_map),
@@ -842,7 +881,7 @@ def paged_span_attention(q, pages_k, pages_v, tables, start, n,
         out_shape=jax.ShapeDtypeStruct((S, H, Q, D), q.dtype),
         interpret=interpret, name="paged_span",
     )(tables.astype(jnp.int32), start.astype(jnp.int32),
-      n.astype(jnp.int32), *operands)
+      n.astype(jnp.int32), _layer_operand(layer), *operands)
     return jnp.swapaxes(out, 1, 2)           # [S, Q, H, D]
 
 

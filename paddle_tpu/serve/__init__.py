@@ -77,9 +77,9 @@ doctrine):
 from .kv_cache import (BlockAllocator, PagedKVCache, PrefixCache,
                        PrefixMatch, gather_pages, scatter_prefill,
                        scatter_token, scatter_span,
-                       scatter_prefill_pages, scatter_token_pages,
-                       scatter_span_pages, quantize_rows,
-                       dequantize_rows, pages_to_blobs, blobs_to_pages)
+                       scatter_prefill_pages, write_token, write_span,
+                       quantize_rows, dequantize_rows, pages_to_blobs,
+                       blobs_to_pages)
 from .engine import AdmitProbe, DecodeEngine, SamplingConfig
 from .scheduler import ContinuousBatchingScheduler, Request
 from .router import FleetRouter, RouteDecision
@@ -101,8 +101,8 @@ __all__ = ["BlockAllocator", "PagedKVCache", "PrefixCache", "PrefixMatch",
            "DecodeEngine", "AdmitProbe", "SamplingConfig",
            "ContinuousBatchingScheduler", "Request", "gather_pages",
            "scatter_prefill", "scatter_token", "scatter_span",
-           "scatter_prefill_pages", "scatter_token_pages",
-           "scatter_span_pages", "quantize_rows", "dequantize_rows",
+           "scatter_prefill_pages", "write_token", "write_span",
+           "quantize_rows", "dequantize_rows",
            "FleetRouter", "RouteDecision", "ServingFleet",
            "ReplicaWorker", "ProcReplicaWorker", "FleetRequest",
            "build_proc_spec",

@@ -513,8 +513,11 @@ class DecodeEngine:
                              for i, o in enumerate(out))
             return pinned
 
-        # donate the KV pools: the tick's carry flips between two
-        # allocations instead of growing HBM per token
+        # donate the KV pools: the tick writes its rows into the buffers
+        # it was handed and returns them (its layer scan carries the
+        # pools and nothing in it has a pool-sized result,
+        # tests/test_chip_lowering.py); the prefill's scatter still
+        # copies them
         self._prefill_fn = jax.jit(_in_scope(_pin_pools(prefill_fn)),
                                    donate_argnums=(1, 2))
         self._tick_fn = jax.jit(_in_scope(_pin_pools(tick_fn)),
@@ -1208,26 +1211,26 @@ class DecodeEngine:
 
     # -- observability -----------------------------------------------------
 
+    def _tick_args(self):
+        """The tick's operands at the engine's shapes (zeros where the
+        host stages them per tick)."""
+        tables, lengths = self.cache.device_tables()
+        args = (self.variables, self.cache.k, self.cache.v, tables, lengths)
+        active = jnp.asarray(self.active)
+        keys = jnp.zeros((self.max_slots, 2), jnp.uint32)
+        if self.speculative == 0:
+            return args + (jnp.asarray(self.tokens), active, keys)
+        args += (jnp.zeros((self.max_slots, self._K1), jnp.int32),
+                 jnp.ones((self.max_slots,), jnp.int32), active)
+        # the stochastic verify tick takes keys, the greedy one does not
+        return args + (keys,) if self.sampling is not None else args
+
     def lower_tick(self):
         """The decode tick lowered at the engine's shapes, not run
         (``jax.stages.Lowered``): what :meth:`attribution_report` parses
         and how a caller reads the tick's compiled text
         (``lower_tick().compile().as_text()``). The pools are untouched."""
-        tables, lengths = self.cache.device_tables()
-        if self.speculative == 0:
-            keys = jnp.zeros((self.max_slots, 2), jnp.uint32)
-            return self._tick_fn.lower(
-                self.variables, self.cache.k, self.cache.v, tables,
-                lengths, jnp.asarray(self.tokens),
-                jnp.asarray(self.active), keys)
-        span_args = (self.variables, self.cache.k, self.cache.v,
-                     tables, lengths,
-                     jnp.zeros((self.max_slots, self._K1), jnp.int32),
-                     jnp.ones((self.max_slots,), jnp.int32),
-                     jnp.asarray(self.active))
-        if self.sampling is not None:       # stochastic verify: + keys
-            span_args += (jnp.zeros((self.max_slots, 2), jnp.uint32),)
-        return self._tick_fn.lower(*span_args)
+        return self._tick_fn.lower(*self._tick_args())
 
     def attribution_report(self, emit: bool = True) -> Dict[str, Any]:
         """MFU-gap attribution of the compiled decode tick (the

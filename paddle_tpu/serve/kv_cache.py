@@ -20,8 +20,9 @@ Split of responsibilities (the framework's static-shapes contract):
   of block tables and lengths). Admission/eviction mutate ONLY these small
   host arrays between decode ticks — nothing here is traced.
 - **Device side, pure**: :func:`gather_pages`, :func:`scatter_prefill`,
-  :func:`scatter_token` — ``jnp``-pure gather/scatter the compiled
-  prefill/decode programs call with fixed shapes. Block tables enter the
+  :func:`write_token`, :func:`write_span` — ``jnp``-pure gather/scatter
+  and in-place row writes the compiled prefill/decode programs call with
+  fixed shapes. Block tables enter the
   compiled step as ordinary int32 operands, so the program never retraces
   as sequences come and go.
 
@@ -49,9 +50,10 @@ pool bytes.
 ``kv_dtype="int8"`` each pool stores symmetric int8 values plus a
 per-block scale page ``[L, num_blocks, H, block_size]`` (one f32 scale
 per token row per head — scales live at block granularity beside the
-pools, per-row within the block so incremental scatters NEVER requantize
-resident tokens). Quantization happens on scatter
-(:func:`quantize_rows` inside the ``*_pages`` wrappers) and
+pools, per-row within the block so incremental writes NEVER requantize
+resident tokens). Quantization happens on the way in
+(:func:`quantize_rows` inside :func:`scatter_prefill_pages`,
+:func:`write_token` and :func:`write_span`) and
 dequantization inside the consumer — the Pallas kernels rescale blocks
 in VMEM, the XLA path in :func:`gather_pages` — so HBM holds ~1 byte
 per KV element instead of 4 and resident capacity roughly triples at
@@ -78,11 +80,12 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 import jax.numpy as jnp
+from jax import lax
 
 __all__ = ["BlockAllocator", "PagedKVCache", "PrefixCache", "PrefixMatch",
            "gather_pages", "scatter_prefill", "scatter_token",
-           "scatter_span", "scatter_prefill_pages", "scatter_token_pages",
-           "scatter_span_pages", "quantize_rows", "dequantize_rows",
+           "scatter_span", "scatter_prefill_pages", "write_token",
+           "write_span", "quantize_rows", "dequantize_rows",
            "pages_to_blobs", "blobs_to_pages", "NULL_BLOCK"]
 
 # block 0 never holds live data: it is the scatter target for padding rows
@@ -112,21 +115,24 @@ def dequantize_rows(q, scale):
     return q.astype(jnp.float32) * scale[..., None]
 
 
-def gather_pages(pages, table):
+def gather_pages(pages, table, layer=None):
     """Gather one layer's paged K (or V) into position order.
 
-    ``pages`` ``[N, H, bs, hd]``, ``table`` ``[S, MB]`` int32 ->
-    ``[S, MB*bs, H, hd]``: row ``s``'s tokens ``0..len-1`` in order, with
-    unspecified (null-block / stale) content beyond the sequence length —
-    the attention mask owns that boundary. A quantized pool — the tuple
-    ``(int8 values, scales [N, H, bs])`` — gathers DEQUANTIZED f32
-    values, so every consumer downstream of the gather is
-    dtype-oblivious."""
+    ``pages`` ``[N, H, bs, hd]`` — or the stacked pools ``[L, N, H, bs,
+    hd]`` with ``layer`` (a traced scalar is fine: the layer is one more
+    gather index, nothing is sliced out of the pool) — ``table``
+    ``[S, MB]`` int32 -> ``[S, MB*bs, H, hd]``: row ``s``'s tokens
+    ``0..len-1`` in order, with unspecified (null-block / stale) content
+    beyond the sequence length — the attention mask owns that boundary.
+    A quantized pool — the tuple ``(int8 values, scales [N, H, bs])`` —
+    gathers DEQUANTIZED f32 values, so every consumer downstream of the
+    gather is dtype-oblivious."""
+    idx = table if layer is None else (layer, table)
     if isinstance(pages, tuple):
         vals, scales = pages
-        pages = dequantize_rows(vals[table], scales[table])
+        pages = dequantize_rows(vals[idx], scales[idx])
     else:
-        pages = pages[table]                          # [S, MB, H, bs, hd]
+        pages = pages[idx]                            # [S, MB, H, bs, hd]
     S, MB, H, bs, hd = pages.shape
     return jnp.swapaxes(pages, 2, 3).reshape(S, MB * bs, H, hd)
 
@@ -156,35 +162,20 @@ def scatter_prefill(pages, kv, table, length, start=0):
     return pages.at[blk, :, off].set(kv)
 
 
-def scatter_token(pages, kv, table, position, active):
-    """Write one decode step's per-layer K (or V) for every slot.
-
-    ``kv`` ``[S, H, hd]`` is the new token's projection per slot;
-    ``position`` ``[S]`` the 0-based index it occupies (the sequence
-    length BEFORE this token); inactive slots scatter to the null block.
-    Returns the updated pool."""
-    S = kv.shape[0]
-    bs = pages.shape[2]
-    blk = jnp.where(active,
-                    table[jnp.arange(S), position // bs],
-                    NULL_BLOCK)                                   # [S]
-    off = position % bs
-    return pages.at[blk, :, off].set(kv)
+def _token_route(table, position, active, bs):
+    """``(blk [S], off [S])``: the pool block and in-block row the new
+    token of every slot lands in; inactive slots route to the null
+    block."""
+    S = table.shape[0]
+    blk = jnp.where(active, table[jnp.arange(S), position // bs],
+                    NULL_BLOCK)
+    return blk, position % bs
 
 
-def scatter_span(pages, kv, table, start, n, write_from=None):
-    """Write a span of consecutive tokens' per-layer K (or V) for every
-    slot — the multi-token generalization of :func:`scatter_token` used
-    by the speculative verify tick and chunked prefill.
-
-    ``kv`` ``[S, Q, H, hd]``: token ``j`` of slot ``s`` lands at
-    position ``start[s] + j``; only tokens ``j < n[s]`` are live (the
-    rest — draft padding, chunk tail — route to the null block).
-    ``write_from`` ``[S]`` (optional) additionally masks positions below
-    it — a chunk re-reading a fully shared prefix for its logits must
-    not write the co-owned pages. Returns the updated pool."""
-    S, Q = kv.shape[:2]
-    bs = pages.shape[2]
+def _span_route(table, start, n, write_from, Q, bs):
+    """``(blk [S, Q], off [S, Q])`` for token ``j`` of slot ``s`` at
+    position ``start[s] + j``; rows ``j >= n[s]`` (draft padding, chunk
+    tail) and positions below ``write_from`` route to the null block."""
     MB = table.shape[1]
     pos = start[:, None] + jnp.arange(Q, dtype=jnp.int32)[None, :]  # [S, Q]
     live = jnp.arange(Q, dtype=jnp.int32)[None, :] < n[:, None]
@@ -194,18 +185,93 @@ def scatter_span(pages, kv, table, start, n, write_from=None):
     # width; they route to the null block anyway
     idx = jnp.clip(pos // bs, 0, MB - 1)
     blk = jnp.where(live, jnp.take_along_axis(table, idx, axis=1),
-                    NULL_BLOCK)                                   # [S, Q]
-    off = pos % bs
+                    NULL_BLOCK)
+    return blk, pos % bs
+
+
+def scatter_token(pages, kv, table, position, active):
+    """ONE layer's pool ``[N, H, bs, hd]`` with one decode step's K (or
+    V) written for every slot, as an XLA scatter: the plain statement of
+    what :func:`write_token` does in place on the stacked pools, kept as
+    the oracle the tests hold it to. ``kv`` ``[S, H, hd]`` is the new
+    token's projection per slot; ``position`` ``[S]`` the 0-based index
+    it occupies (the sequence length BEFORE this token); inactive slots
+    scatter to the null block."""
+    blk, off = _token_route(table, position, active, pages.shape[2])
     return pages.at[blk, :, off].set(kv)
 
 
-# quant-aware scatter wrappers: a plain pool scatters values as-is; a
-# quantized pool (the (values, scales) tuple) quantizes on scatter —
-# int8 rows into the value pages, per-row-per-head scales into the
-# scale pages with the SAME block/offset routing (the scatter functions
-# above are shape-agnostic past the [blocks, heads, block_size] prefix).
+def scatter_span(pages, kv, table, start, n, write_from=None):
+    """The multi-token generalization of :func:`scatter_token`, and
+    :func:`write_span`'s oracle. ``kv`` ``[S, Q, H, hd]``: token ``j``
+    of slot ``s`` lands at position ``start[s] + j``; only tokens
+    ``j < n[s]`` are live. ``write_from`` ``[S]`` (optional)
+    additionally masks positions below it — a chunk re-reading a fully
+    shared prefix for its logits must not write the co-owned pages."""
+    blk, off = _span_route(table, start, n, write_from, kv.shape[1],
+                           pages.shape[2])
+    return pages.at[blk, :, off].set(kv)
+
+
+# The compiled tick's writes. The pools ``[L, N, H, bs, hd]`` are the
+# layer scan's CARRY, so a write has to leave them where they are: an XLA
+# scatter over the carried pool makes the compiler give the whole carry a
+# scatter-friendly layout and copy both pools to it and back every layer
+# (DESIGN_DECISIONS, PR 26). A row is therefore written as one
+# ``dynamic_update_slice`` of a small slab, which XLA does in place.
+
+def _block_size(pages):
+    """``bs`` of stacked pools ``[L, N, H, bs, ...]``, plain or the
+    quantized tuple."""
+    return (pages[0] if isinstance(pages, tuple) else pages).shape[3]
+
+
+def _write_rows(pages, layer, kv, blk, off):
+    """``kv [R, H, hd]`` written into layer ``layer`` of the stacked
+    pools at ``(blk[r], :, off[r])``, one ``[1, 1, H, 1, hd]`` slab a
+    row, in row order (the null block takes every masked row; what it
+    holds is never read unmasked). Straight-line code whatever ``R``:
+    inside a loop XLA re-lays an int8 pool round the writes."""
+    def write(pool, rows):
+        zeros = (0,) * (rows.ndim - 2)
+        for r in range(rows.shape[0]):
+            pool = lax.dynamic_update_slice(
+                pool, rows[r][None, None, :, None],
+                (layer, blk[r], 0, off[r]) + zeros)
+        return pool
+
+    if isinstance(pages, tuple):
+        # int8 rows into the value pages, their per-row-per-head scales
+        # into the scale pages, at the same block and offset
+        return tuple(write(pool, rows)
+                     for pool, rows in zip(pages, quantize_rows(kv)))
+    return write(pages, kv.astype(pages.dtype))
+
+
+def write_token(pages, layer, kv, table, position, active):
+    """The stacked pools ``[L, N, H, bs, hd]`` (or the quantized tuple)
+    with layer ``layer``'s new-token K (or V) ``kv [S, H, hd]`` written
+    in place: :func:`scatter_token` on ``pages[layer]``, without taking
+    the layer out. ``layer`` may be traced."""
+    blk, off = _token_route(table, position, active, _block_size(pages))
+    return _write_rows(pages, layer, kv, blk, off)
+
+
+def write_span(pages, layer, kv, table, start, n, write_from=None):
+    """:func:`scatter_span` on ``pages[layer]`` in place: ``kv``
+    ``[S, Q, H, hd]``, one row write a token."""
+    S, Q = kv.shape[:2]
+    blk, off = _span_route(table, start, n, write_from, Q,
+                           _block_size(pages))
+    return _write_rows(pages, layer, kv.reshape(S * Q, *kv.shape[2:]),
+                       blk.reshape(S * Q), off.reshape(S * Q))
+
 
 def scatter_prefill_pages(pages, kv, table, length, start=0):
+    """:func:`scatter_prefill`, quantizing on the way into a quantized
+    ``(values, scales)`` pool (the scatter is shape-agnostic past the
+    ``[blocks, heads, block_size]`` prefix, so the scale pages take the
+    same routing)."""
     if isinstance(pages, tuple):
         vals, scales = pages
         q, s = quantize_rows(kv)
@@ -213,26 +279,6 @@ def scatter_prefill_pages(pages, kv, table, length, start=0):
                 scatter_prefill(scales, s, table, length, start))
     return scatter_prefill(pages, kv.astype(pages.dtype), table, length,
                            start)
-
-
-def scatter_token_pages(pages, kv, table, position, active):
-    if isinstance(pages, tuple):
-        vals, scales = pages
-        q, s = quantize_rows(kv)
-        return (scatter_token(vals, q, table, position, active),
-                scatter_token(scales, s, table, position, active))
-    return scatter_token(pages, kv.astype(pages.dtype), table, position,
-                         active)
-
-
-def scatter_span_pages(pages, kv, table, start, n, write_from=None):
-    if isinstance(pages, tuple):
-        vals, scales = pages
-        q, s = quantize_rows(kv)
-        return (scatter_span(vals, q, table, start, n, write_from),
-                scatter_span(scales, s, table, start, n, write_from))
-    return scatter_span(pages, kv.astype(pages.dtype), table, start, n,
-                        write_from)
 
 
 # ---------------------------------------------------------------------------

@@ -1,4 +1,5 @@
-"""Every Pallas kernel on the chip path lowers for the TPU from a CPU host.
+"""Every Pallas kernel on the chip path lowers for the TPU from a CPU host,
+and the serving tick compiles for it without moving its KV pools.
 
 ``jax.jit(f).trace(*args).lower(lowering_platforms=("tpu",))`` runs the
 Pallas-to-Mosaic lowering without a TPU, at ``chip_smoke.py``'s shapes and
@@ -9,9 +10,16 @@ went heads-major) and a Mosaic kernel left to the partitioner
 (``MultiHeadAttention``'s flash call did, before it ran per shard). What
 it cannot see is Mosaic's own compile; ``chip_smoke.py`` proves that on
 the chip.
+
+``test_tick_leaves_the_pools_in_place`` goes one step further for the
+decode tick: XLA's TPU compiler (libtpu is installed here) compiles a toy
+engine's tick against a device-less ``v5e:2x2`` topology, and the compiled
+text and ``memory_analysis()`` are held to the property PR 26 bought: the
+layer scan carries the pools and nothing in the program copies one.
 """
 
 import functools
+import re
 
 import numpy as np
 import jax
@@ -20,11 +28,13 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu.core import mesh as mesh_lib
+from paddle_tpu.models import TransformerLM
 from paddle_tpu.nn import MultiHeadAttention, pallas_mode
 from paddle_tpu.nn.pallas_attention import (flash_attention,
                                             paged_decode_attention,
                                             paged_span_attention)
 from paddle_tpu.parallel.sharding import tp_shard_scope
+from paddle_tpu.serve import DecodeEngine
 
 # chip_smoke.py's model: transformer_big width, dh = 128
 HEADS, DH, T = 8, 128, 2048
@@ -43,11 +53,11 @@ def sds(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def pool(kind, heads, dh):
+def pool(kind, heads, dh, layers=2):
     if kind == "int8":
-        return (sds((N, heads, BS, dh), jnp.int8),
-                sds((N, heads, BS), jnp.float32))
-    return sds((N, heads, BS, dh), jnp.dtype(kind))
+        return (sds((layers, N, heads, BS, dh), jnp.int8),
+                sds((layers, N, heads, BS), jnp.float32))
+    return sds((layers, N, heads, BS, dh), jnp.dtype(kind))
 
 
 @pytest.mark.parametrize("kind", ["float32", "bfloat16", "int8"])
@@ -59,13 +69,14 @@ def test_paged_kernels_lower(kind, heads, dh):
     pages = pool(kind, heads, dh)
     tables = sds((SLOTS, MB), jnp.int32)
     vec = sds((SLOTS,), jnp.int32)
+    layer = sds((), jnp.int32)
     lower_tpu(functools.partial(paged_decode_attention, interpret=False),
               sds((SLOTS, heads, dh), jnp.float32), pages, pages, tables,
-              vec)
+              vec, layer)
     for q_len in (5, 256):
         lower_tpu(functools.partial(paged_span_attention, interpret=False),
                   sds((SLOTS, q_len, heads, dh), jnp.float32), pages, pages,
-                  tables, vec, vec)
+                  tables, vec, vec, layer)
 
 
 @pytest.mark.parametrize("segmented", [False, True])
@@ -111,3 +122,105 @@ def test_flash_through_attention_layer_lowers_on_four_devices(axis,
                               sharding=NamedSharding(mesh, spec))
     text = lower_tpu(jax.grad(loss), variables, xs)
     assert text.count("tpu_custom_call") >= 3
+
+
+# ---------------------------------------------------------------------------
+# the compiled tick: the pools stay where they are
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One device of a described, not attached, v5e host. The topology is
+    asked for only here, never at import: one process at a time may load
+    libtpu, and every xdist worker imports this file."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# an instruction: its name, whether its result is a tuple, the (first)
+# result shape; then the operation and its first operand
+_RESULT = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = (\(?)([a-z]+[0-9]*\[[0-9,]*\])")
+_OPERATION = re.compile(r" ([a-z][a-z\-]*)\(%?([\w.\-]*)")
+# what may have a pool-sized result: the carry's plumbing (``tuple`` and
+# ``while`` results are tuples and never match) and the in-place row write
+_MAY_HOLD_A_POOL = {"parameter", "get-tuple-element", "bitcast",
+                    "dynamic-update-slice"}
+
+
+def pool_sized_results(text, sizes):
+    """``[(operation, shape)]`` of every instruction of the compiled
+    module, fused computations' insides left out, whose result has as
+    many elements as a pool or as one layer of one. What the compiler
+    prefetches into the chip's fast memory (``S(1)`` in a layout: a toy
+    pool fits there, a deployment's does not) is left out, with the
+    ``copy-done`` that brings it back."""
+    found, fused, prefetches = [], False, set()
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            fused = "fused_computation" in line.split("(")[0]
+        m = None if fused else _RESULT.match(line)
+        if not m:
+            continue
+        name, is_tuple, shape = m.groups()
+        op, operand = _OPERATION.search(line, m.end()).groups()
+        if "S(1)" in line.split(f" {op}(")[0]:
+            prefetches.add(name)
+            continue
+        dims = [int(d) for d in re.findall(r"[0-9]+", shape.split("[")[1])]
+        if (int(np.prod(dims)) in sizes and not is_tuple
+                and not (op == "copy-done" and operand in prefetches)):
+            found.append((op, shape))
+    return found
+
+
+# pools of 160 MB: one that fits the chip's 128 MiB of fast memory is
+# prefetched there whole, and its writes with it
+@pytest.mark.parametrize("kv_dtype,blocks,speculative",
+                         [(None, 2449, 0), ("int8", 9793, 0),
+                          (None, 2449, 4)],
+                         ids=["float32", "int8", "float32-speculative4"])
+def test_tick_leaves_the_pools_in_place(one_chip, monkeypatch, kv_dtype,
+                                        blocks, speculative):
+    """The decode tick (and speculation's verify tick) of a toy engine,
+    compiled for the TPU: (a) nothing but the carry's plumbing and the
+    in-place ``dynamic-update-slice`` row writes has a result the size
+    of a pool or of one layer's pool, (b) the program's temporaries are
+    smaller than one pool. The tick that scanned the pools as ``xs`` /
+    ``ys`` sliced every layer out, copied it to the scatter's layout and
+    back, collected it, and copied both pools whole at the end."""
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+    layers, heads, dh = 2, 4, 128
+    model = TransformerLM(vocab=512, dim=heads * dh, num_layers=layers,
+                          num_heads=heads, ffn_hidden=1024, max_len=256)
+    variables = model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))
+    engine = DecodeEngine(model, variables, max_slots=4, block_size=BS,
+                          num_blocks=blocks, attention="paged",
+                          kv_dtype=kv_dtype, speculative=speculative)
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        engine._tick_args())
+    compiled = engine._tick_fn.lower(*args).compile()
+
+    # a quantized pool's values; its scale pages, a thirty-second of its
+    # bytes, XLA re-lays once a tick at the program's entry
+    leaves = jax.tree_util.tree_leaves(engine.cache.k)
+    values = leaves[0].size
+    held = pool_sized_results(compiled.as_text(),
+                              {values, values // layers})
+    assert held, "the pools are not in the compiled text"
+    moved = [h for h in held if h[0] not in _MAY_HOLD_A_POOL]
+    assert not moved, f"pool-sized results besides the row writes: {moved}"
+    writes = sum(op == "dynamic-update-slice" for op, _ in held)
+    assert writes > 0, "no in-place row write in the compiled tick"
+    pool_bytes = sum(leaf.nbytes for leaf in leaves)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < pool_bytes, \
+        f"temporaries {temp} B, one pool {pool_bytes} B"

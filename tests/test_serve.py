@@ -10,6 +10,7 @@ the two acceptance contracts —
   across arbitrary admission/eviction churn.
 """
 
+import functools
 import logging
 import os
 
@@ -106,22 +107,116 @@ def test_gather_scatter_roundtrip(nprng):
     np.testing.assert_array_equal(before[1:], after[1:])
 
 
+def _stacked_pool(nprng, kind, L, N, H, hd):
+    raw = jnp.asarray(nprng.randn(L, N, H, BS, hd).astype(np.float32))
+    return kvc.quantize_rows(raw) if kind == "int8" else raw
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _reference_write(scatter, pool, kv, *route):
+    """The XLA scatter on one layer's pool, quantizing as the in-place
+    writes do (compiled, as they are: XLA's division by a constant
+    differs from the eager one in the last bit of a scale)."""
+    if isinstance(pool, tuple):
+        q, sc = kvc.quantize_rows(kv)
+        return (scatter(pool[0], q, *route), scatter(pool[1], sc, *route))
+    return scatter(pool, kv, *route)
+
+
+def _assert_only_layer_written(got, pool, want, layer):
+    """``got[layer] == want`` outside the null block (masked rows land
+    there in an order the scatter does not fix), every other layer
+    untouched."""
+    for g, p, w in zip(*(jax.tree_util.tree_leaves(t)
+                         for t in (got, pool, want))):
+        g, p, w = np.asarray(g), np.asarray(p), np.asarray(w)
+        assert g.dtype == p.dtype
+        np.testing.assert_array_equal(g[layer, 1:], w[1:])
+        others = [l for l in range(g.shape[0]) if l != layer]
+        np.testing.assert_array_equal(g[others], p[others])
+
+
+@pytest.mark.parametrize("slots", [3, 11])
+@pytest.mark.parametrize("kind", ["float32", "int8"])
+def test_write_token_equals_scatter_token_on_every_layer(nprng, kind,
+                                                         slots):
+    """The tick's in-place row writes into the stacked pools against the
+    reference scatter on each layer's slice: inactive slots (null block
+    only), plain and quantized pools, the layer traced."""
+    L, N, H, hd = 3, 16, 2, 8
+    pool = _stacked_pool(nprng, kind, L, N, H, hd)
+    blocks = 1 + nprng.permutation(N - 1)[:slots]
+    table = np.zeros((slots, MB), np.int32)
+    table[:, 1] = blocks                       # position 4..7 -> block
+    table = jnp.asarray(table)
+    position = jnp.asarray(nprng.randint(BS, 2 * BS, slots), jnp.int32)
+    active = jnp.asarray(np.arange(slots) % 3 != 1)
+    write = jax.jit(kvc.write_token)
+    for layer in range(L):
+        kv = jnp.asarray(nprng.randn(slots, H, hd).astype(np.float32))
+        got = write(pool, jnp.int32(layer), kv, table, position, active)
+        want = _reference_write(kvc.scatter_token, layer_of(pool, layer),
+                                kv, table, position, active)
+        _assert_only_layer_written(got, pool, want, layer)
+        inactive = np.asarray(blocks)[~np.asarray(active)]
+        for g, p in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(pool)):
+            np.testing.assert_array_equal(np.asarray(g)[:, inactive],
+                                          np.asarray(p)[:, inactive])
+
+
+@pytest.mark.parametrize("kind", ["float32", "int8"])
+def test_write_span_equals_scatter_span_on_every_layer(nprng, kind):
+    """Span writes (speculation's verify tick, a prefill chunk): rows
+    past ``n``, an inactive slot and positions under ``write_from`` go
+    to the null block; a span that crosses a block boundary lands in
+    both blocks."""
+    L, N, H, hd, S, Q = 2, 16, 2, 8, 3, 5
+    pool = _stacked_pool(nprng, kind, L, N, H, hd)
+    table = jnp.asarray([[3, 1, 5, 0, 0, 0], [7, 2, 0, 0, 0, 0],
+                         [9, 4, 6, 0, 0, 0]], jnp.int32)
+    start = jnp.asarray([2, 1, 6], jnp.int32)
+    n = jnp.asarray([Q, 0, 3], jnp.int32)
+    write = jax.jit(kvc.write_span)
+    for layer in range(L):
+        for write_from in (None, jnp.asarray([4, 0, 0], jnp.int32)):
+            kv = jnp.asarray(nprng.randn(S, Q, H, hd).astype(np.float32))
+            got = write(pool, jnp.int32(layer), kv, table, start, n,
+                        write_from)
+            want = _reference_write(kvc.scatter_span,
+                                    layer_of(pool, layer), kv, table,
+                                    start, n, write_from)
+            _assert_only_layer_written(got, pool, want, layer)
+
+
 # ---------------------------------------------------------------------------
 # the decode-shaped Pallas kernel vs its oracle
 # ---------------------------------------------------------------------------
 
-def test_paged_decode_attention_matches_reference(nprng):
+def layer_of(pool, layer):
+    """One layer's pool out of the stacked pools (plain or quantized):
+    what the kernels' oracles and the reference scatters take."""
+    return jax.tree_util.tree_map(lambda leaf: leaf[layer], pool)
+
+
+# a wrong index map reads layer 0 and passes a one-layer test: the pools
+# hold three layers of different rows
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_decode_attention_matches_reference(nprng, layer):
     from paddle_tpu.nn.pallas_attention import (paged_decode_attention,
                                                 paged_reference_attention)
     S, H, D, N = 4, 2, 16, 32
     q = jnp.asarray(nprng.randn(S, H, D).astype(np.float32))
-    pk = jnp.asarray(nprng.randn(N, H, BS, D).astype(np.float32))
-    pv = jnp.asarray(nprng.randn(N, H, BS, D).astype(np.float32))
+    pk = jnp.asarray(nprng.randn(3, N, H, BS, D).astype(np.float32))
+    pv = jnp.asarray(nprng.randn(3, N, H, BS, D).astype(np.float32))
     tables = jnp.asarray(nprng.randint(0, N, (S, MB)), jnp.int32)
     # ragged: mid-block, inactive, full capacity, block-boundary
     lengths = jnp.asarray([5, 0, MB * BS, 12], jnp.int32)
-    out = paged_decode_attention(q, pk, pv, tables, lengths)
-    ref = paged_reference_attention(q, pk, pv, tables, lengths)
+    # the layer arrives traced, as the tick's scan hands it over
+    out = jax.jit(paged_decode_attention)(q, pk, pv, tables, lengths,
+                                          jnp.int32(layer))
+    ref = paged_reference_attention(q, pk[layer], pv[layer], tables,
+                                    lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-6, atol=2e-6)
     assert not np.any(np.asarray(out[1]))    # inactive slot: zeros
@@ -1075,7 +1170,8 @@ def test_quantized_engine_drift_bound_and_token_agreement(model_and_vars,
     assert np.max(np.abs(lf - lq)) < 0.05 * max(1.0, np.ptp(lf))
 
 
-def test_quantized_paged_kernel_matches_reference(nprng):
+@pytest.mark.parametrize("layer", [0, 2])
+def test_quantized_paged_kernel_matches_reference(nprng, layer):
     """paged_decode_attention with an int8 (values, scales) pool matches
     the dequantizing oracle — dequant-in-kernel is numerically the same
     as dequant-then-attend."""
@@ -1083,14 +1179,15 @@ def test_quantized_paged_kernel_matches_reference(nprng):
                                                 paged_reference_attention)
     S, H, D, N = 4, 2, 16, 32
     q = jnp.asarray(nprng.randn(S, H, D).astype(np.float32))
-    raw_k = jnp.asarray(nprng.randn(N, H, BS, D).astype(np.float32))
-    raw_v = jnp.asarray(nprng.randn(N, H, BS, D).astype(np.float32))
+    raw_k = jnp.asarray(nprng.randn(3, N, H, BS, D).astype(np.float32))
+    raw_v = jnp.asarray(nprng.randn(3, N, H, BS, D).astype(np.float32))
     pk = kvc.quantize_rows(raw_k)
     pv = kvc.quantize_rows(raw_v)
     tables = jnp.asarray(nprng.randint(0, N, (S, MB)), jnp.int32)
     lengths = jnp.asarray([5, 0, MB * BS, 12], jnp.int32)
-    out = paged_decode_attention(q, pk, pv, tables, lengths)
-    ref = paged_reference_attention(q, pk, pv, tables, lengths)
+    out = paged_decode_attention(q, pk, pv, tables, lengths, layer)
+    ref = paged_reference_attention(q, layer_of(pk, layer),
+                                    layer_of(pv, layer), tables, lengths)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-6, atol=2e-6)
     assert not np.any(np.asarray(out[1]))
@@ -1109,9 +1206,9 @@ def test_paged_span_kernel_matches_oracle_and_q1_bit_exact(nprng):
     from paddle_tpu.nn.pallas_attention import (
         paged_decode_attention, paged_span_attention,
         paged_span_reference_attention)
-    S, H, D, N = 4, 2, 16, 32
-    pk = jnp.asarray(nprng.randn(N, H, BS, D).astype(np.float32))
-    pv = jnp.asarray(nprng.randn(N, H, BS, D).astype(np.float32))
+    S, H, D, N, layer = 4, 2, 16, 32, 1
+    pk = jnp.asarray(nprng.randn(3, N, H, BS, D).astype(np.float32))
+    pv = jnp.asarray(nprng.randn(3, N, H, BS, D).astype(np.float32))
     tables = jnp.asarray(nprng.randint(0, N, (S, MB)), jnp.int32)
     for k in (0, 3):
         Q = 1 + k
@@ -1120,8 +1217,9 @@ def test_paged_span_kernel_matches_oracle_and_q1_bit_exact(nprng):
         # block boundary, clamped tail
         start = jnp.asarray([3, 7, 8, MB * BS - Q], jnp.int32)
         n = jnp.asarray([Q, 0, max(1, Q - 1), Q], jnp.int32)
-        out = paged_span_attention(q, pk, pv, tables, start, n)
-        ref = paged_span_reference_attention(q, pk, pv, tables, start, n)
+        out = paged_span_attention(q, pk, pv, tables, start, n, layer)
+        ref = paged_span_reference_attention(q, pk[layer], pv[layer],
+                                             tables, start, n)
         for s in range(S):
             live = int(n[s])
             if live == 0:
@@ -1133,7 +1231,7 @@ def test_paged_span_kernel_matches_oracle_and_q1_bit_exact(nprng):
         if Q == 1:
             lengths = jnp.where(n > 0, start + 1, 0)
             single = paged_decode_attention(q[:, 0], pk, pv, tables,
-                                            lengths)
+                                            lengths, layer)
             np.testing.assert_array_equal(np.asarray(out[:, 0]),
                                           np.asarray(single))
 
@@ -1143,17 +1241,18 @@ def test_paged_span_kernel_quantized(nprng):
     oracle (int8 pools)."""
     from paddle_tpu.nn.pallas_attention import (
         paged_span_attention, paged_span_reference_attention)
-    S, Q, H, D, N = 3, 4, 2, 16, 32
+    S, Q, H, D, N, layer = 3, 4, 2, 16, 32, 2
     q = jnp.asarray(nprng.randn(S, Q, H, D).astype(np.float32))
     pk = kvc.quantize_rows(
-        jnp.asarray(nprng.randn(N, H, BS, D).astype(np.float32)))
+        jnp.asarray(nprng.randn(3, N, H, BS, D).astype(np.float32)))
     pv = kvc.quantize_rows(
-        jnp.asarray(nprng.randn(N, H, BS, D).astype(np.float32)))
+        jnp.asarray(nprng.randn(3, N, H, BS, D).astype(np.float32)))
     tables = jnp.asarray(nprng.randint(0, N, (S, MB)), jnp.int32)
     start = jnp.asarray([2, 0, 9], jnp.int32)
     n = jnp.asarray([Q, 0, Q], jnp.int32)
-    out = paged_span_attention(q, pk, pv, tables, start, n)
-    ref = paged_span_reference_attention(q, pk, pv, tables, start, n)
+    out = paged_span_attention(q, pk, pv, tables, start, n, layer)
+    ref = paged_span_reference_attention(
+        q, layer_of(pk, layer), layer_of(pv, layer), tables, start, n)
     for s in range(S):
         live = int(n[s])
         if live:
