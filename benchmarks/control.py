@@ -25,39 +25,35 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 
-def train_readings(ctx, control: bool):
+def train_readings(ctx, driver, control: bool):
     """The control and the two planted faults, each put in the program's
     place against the reference the run already computed."""
-    from benchmarks import reference
-    from benchmarks.drivers import train
-    spec = ctx.cell.file["train"]
+    cell, spec = ctx.cell, ctx.cell.file["train"]
     out = {}
     if not control:
         return out
     for name, kw in (("control", {"quant": "int8"}),
                      ("half_batch", {"fault": "half_batch"}),
                      ("state_unchanged", {"fault": "state_unchanged"})):
-        alt = reference.train_reference(ctx.cell.config, ctx.seed,
-                                        ctx.facts["followed"],
-                                        spec["optimizer"], **kw)
+        alt = cell.reference.train_reference(cell.config, ctx.seed,
+                                             ctx.facts["followed"],
+                                             spec["optimizer"], **kw)
         loss1 = abs(alt["losses"][0] - ctx.facts["reference"]["losses"][0]) \
             / ctx.facts["reference"]["losses"][0]
         out[name] = {c["name"]: (c["value"], c.get("leaf", ""))
-                     for c in train.compare(train.as_program(alt),
-                                            ctx.facts["reference"],
-                                            ctx.cell.file["limits"])}
+                     for c in driver.compare(
+                         cell.layout, driver.as_program(cell.layout, alt),
+                         ctx.facts["reference"], cell.file["limits"])}
         out[name]["loss1_gap"] = (loss1, "")
     return out
 
 
-def serve_readings(ctx, control: bool):
-    from benchmarks import reference
-    from benchmarks.drivers import serve
+def serve_readings(ctx, driver, control: bool):
     if not control:
         return {}
-    ctl = reference.serve_reference(ctx.cell.config, ctx.seed,
-                                    ctx.facts["sample"], quant="int8")
-    return {"control": {"served_logit_gap": serve.control_gap(
+    ctl = ctx.cell.reference.serve_reference(
+        ctx.cell.config, ctx.seed, ctx.facts["sample"], quant="int8")
+    return {"control": {"served_logit_gap": driver.control_gap(
         ctx.facts["reference"], ctl)}}
 
 
@@ -83,18 +79,19 @@ def main(argv=None) -> int:
     peaks = harness.load_peaks(devices[0].device_kind)
     xla_cache.setup()
     readings = train_readings if cell.driver == "train" else serve_readings
+    driver = harness.load_driver(cell)
     for seed in seeds:
         ctx = harness.make_context(cell, seed, args.seconds, False,
                                    time.perf_counter(),
                                    devices[:cell.chips], peaks)
-        out = harness.load_driver(cell).run(ctx)
+        out = driver.run(ctx)
         line = {"seed": seed, "correct": out["correct"],
                 "failed": out["failed"], "attempted": out["attempted"],
                 "metrics": out["metrics"],
                 "program": {c["name"]: c["value"] for c in out["checks"]}}
         line["leaves"] = {c["name"]: c["leaf"] for c in out["checks"]
                           if "leaf" in c}
-        line.update(readings(ctx, seed in control))
+        line.update(readings(ctx, driver, seed in control))
         print(json.dumps(line), flush=True)
         del ctx, out
         harness.free_device_memory()

@@ -1,7 +1,9 @@
-"""Operations and bytes the algorithms NEED, from shapes: the numerators
-of every utilisation and roofline share the benchmark reports. Nothing
-here is read from the compiler (XLA's count includes recomputation and
-sees no FLOPs inside a Mosaic custom call) and nothing from the program.
+"""Operations and bytes the KERNELS need, from shapes, and the chip's
+roofline: the numerators of every roofline share the benchmark reports.
+Nothing here is read from the compiler (XLA's count includes
+recomputation and sees no FLOPs inside a Mosaic custom call), nothing
+from the program, and nothing of a model: a model's FLOPs are in the
+``costs`` module its configuration names (``benchmarks/gpt2_costs.py``).
 
 Conventions: a multiply-add is 2 FLOPs; a backward pass costs twice its
 forward; recomputation never counts; causal attention counts the lower
@@ -10,38 +12,7 @@ triangle only (half of the square).
 
 from __future__ import annotations
 
-from typing import Dict, Iterable, Tuple
-
-from benchmarks.reference import Dims
-
-
-def matmul_params(z: Dims) -> int:
-    """Weights that sit in a matrix product once per token: the blocks'
-    six matrices and the tied readout. (The embedding LOOKUP is a gather,
-    the positions are added; biases and LayerNorm are elementwise.)"""
-    return z.L * (4 * z.D * z.D + 2 * z.D * z.F) + z.V * z.D
-
-
-def train_flops_per_token(z: Dims, seq_len: int) -> float:
-    """Forward and backward model FLOPs per trained token at ``seq_len``
-    (causal: a token attends to (seq_len + 1) / 2 keys on average)."""
-    pairs_per_token = (seq_len + 1) / 2.0
-    fwd = 2 * matmul_params(z) + 4 * z.D * z.L * pairs_per_token
-    return 3.0 * fwd
-
-
-def prefill_flops(z: Dims, prompt_len: int) -> float:
-    """One forward over a prompt at its TRUE length (not the padded
-    width), readout for the last row only."""
-    pairs = prompt_len * (prompt_len + 1) / 2.0
-    body = 2 * (matmul_params(z) - z.V * z.D) * prompt_len
-    return body + 2 * z.V * z.D + 4 * z.D * z.L * pairs
-
-
-def decode_flops(z: Dims, context_len: int) -> float:
-    """One forward for one new token that attends to ``context_len``
-    keys (itself included)."""
-    return 2 * matmul_params(z) + 4 * z.D * z.L * context_len
+from typing import Dict, Tuple
 
 
 def roofline_seconds(flops: float, nbytes: float,
@@ -78,11 +49,3 @@ def paged_decode_cost(live_tokens: int, slots: int, heads: int,
     nbytes = (2 * live_tokens * heads * head_dim * pool_bytes
               + 2 * slots * heads * head_dim * act_bytes)
     return {"flops": flops, "bytes": nbytes}
-
-
-def serve_flops(z: Dims, prompt_lens: Iterable[int],
-                decode_contexts: Iterable[int]) -> float:
-    """Model FLOPs of the tokens really processed: each prefill at its
-    true length, one forward per decoded token at its context."""
-    return (sum(prefill_flops(z, p) for p in prompt_lens)
-            + sum(decode_flops(z, c) for c in decode_contexts))

@@ -6,7 +6,9 @@ at the mix's arrival times, timed from when they were due).
 
 The engine returns tokens, never logits, so the benchmark stamps every
 token itself when ``step()`` returns: that is when a caller of this loop
-can see it. Every size comes from the cell's files.
+can see it. Every size comes from the cell's files, and whatever depends
+on the architecture (the model, its weights, the reference, the pool's
+facts) from the modules the configuration names (``ctx.cell``).
 """
 
 from __future__ import annotations
@@ -16,7 +18,7 @@ from typing import Any, Dict, List
 
 import numpy as np
 
-from benchmarks import harness, layout, reference, traffic
+from benchmarks import harness, traffic
 
 
 class Client:
@@ -30,17 +32,18 @@ class Client:
 
 
 def build(ctx):
-    from paddle_tpu.models import TransformerLM
+    """The engine and its scheduler. The cell file's ``serve`` block gives
+    the slots and the pool; its optional ``engine`` object goes to
+    ``DecodeEngine`` as further keyword arguments."""
     from paddle_tpu.serve import ContinuousBatchingScheduler, DecodeEngine
-    z, spec = ctx.dims, ctx.cell.file["serve"]
-    model = TransformerLM(vocab=z.V, dim=z.D, num_layers=z.L,
-                          num_heads=z.H, ffn_hidden=z.F, max_len=z.P,
-                          use_flash=True)
-    variables = {"params": layout.program_params(z, ctx.seed), "state": {}}
-    engine = DecodeEngine(model, variables,
+    layout, spec = ctx.cell.layout, ctx.cell.file["serve"]
+    variables = {"params": layout.program_params(ctx.dims, ctx.seed),
+                 "state": {}}
+    engine = DecodeEngine(layout.build_model(ctx.dims), variables,
                           max_slots=int(spec["max_slots"]),
                           block_size=int(spec["block_size"]),
-                          num_blocks=spec.get("num_blocks"))
+                          num_blocks=spec.get("num_blocks"),
+                          **spec.get("engine", {}))
     del variables
     return engine, ContinuousBatchingScheduler(engine)
 
@@ -203,20 +206,18 @@ def run(ctx) -> Dict[str, Any]:
                if c.stamps and in_win(c.stamps[0])]
     contexts = [len(c.req.prompt) + i for c in clients
                 for i, t in enumerate(c.stamps) if i > 0 and in_win(t)]
-    pool_leaf = engine.cache.k[0] if isinstance(engine.cache.k, tuple) \
-        else engine.cache.k
     ctx.facts.update(
         tokens=tokens, requests_finished=len(finished),
         requests_submitted=len(new), ttft_samples=len(ttft),
         gap_samples=len(gaps), prompts=prompts, contexts=contexts,
         slots=int(spec["max_slots"]), num_blocks=engine.cache.num_blocks,
-        pool_bytes=int(np.dtype(pool_leaf.dtype).itemsize),
-        compile_counts=counts, attention=attention)
+        compile_counts=counts, attention=attention,
+        **ctx.cell.layout.engine_facts(engine))
     harness.log(
         f"serve: {len(finished)} requests finished, {len(new)} submitted, "
         f"{tokens} tokens, {len(gaps)} gaps in {t1 - t0:.3f}s; "
         f"{engine.ticks} ticks, {engine.prefill_chunks} prefills; pool "
-        f"{engine.cache.num_blocks} blocks of {pool_leaf.dtype}; "
+        f"{engine.cache.num_blocks} blocks of {ctx.facts['pool_dtype']}; "
         f"attention {attention}; compiles {counts}")
     peak = harness.memory_peak_bytes(ctx.devices)
     sample = [(list(c.req.prompt), list(c.req.tokens)) for c in pick_sample(
@@ -231,8 +232,8 @@ def run(ctx) -> Dict[str, Any]:
     t_ref = time.perf_counter()
     checks = []
     if sample:
-        ref_logits = reference.serve_reference(ctx.cell.config, ctx.seed,
-                                               sample)
+        ref_logits = ctx.cell.reference.serve_reference(
+            ctx.cell.config, ctx.seed, sample)
         checks = serve_checks(sample, ref_logits,
                               ctx.cell.file["limits"]["served_logit_gap"])
         ctx.facts.update(sample=sample, reference=ref_logits)

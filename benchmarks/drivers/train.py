@@ -8,6 +8,9 @@ measured window; the reader yields until the window's deadline.
 Every size comes from the cell's files: the model from the configuration,
 sequence length and token statistics from the traffic mix, the batch,
 the optimizer and the limits from ``benchmarks/workloads/<cell>.json``.
+Whatever depends on the architecture (the model, its loss, its weights
+and their leaves, the reference) comes from the modules the configuration
+names (``ctx.cell``).
 """
 
 from __future__ import annotations
@@ -17,7 +20,7 @@ from typing import Any, Dict, List
 
 import numpy as np
 
-from benchmarks import harness, layout, reference, traffic
+from benchmarks import harness, traffic
 
 FOLLOWED_STEPS = 3         # the reference follows the first three
 
@@ -26,31 +29,24 @@ def build(ctx):
     """The model and the Trainer of the cell, nothing run yet."""
     from paddle_tpu import optim
     from paddle_tpu.core import mesh as mesh_lib
-    from paddle_tpu.models import TransformerLM
-    from paddle_tpu.nn import costs as nn_costs
     from paddle_tpu.train import Trainer
-    z, spec = ctx.dims, ctx.cell.file["train"]
-    opt = spec["optimizer"]
+    layout, opt = ctx.cell.layout, ctx.cell.file["train"]["optimizer"]
     if opt["name"] != "adam":
         raise ValueError(f"optimizer {opt['name']!r}: only adam has a "
                          f"reference")
-    model = TransformerLM(vocab=z.V, dim=z.D, num_layers=z.L,
-                          num_heads=z.H, ffn_hidden=z.F, max_len=z.P,
-                          use_flash=True)
     mesh = (mesh_lib.single_device_mesh(ctx.devices[0])
             if len(ctx.devices) == 1
             else mesh_lib.make_mesh({"data": len(ctx.devices)},
                                     devices=ctx.devices))
     return Trainer(
-        model,
-        loss_fn=lambda out, b: nn_costs.softmax_cross_entropy(
-            out.reshape(-1, z.V), b["y"].reshape(-1)),
+        layout.build_model(ctx.dims),
+        loss_fn=layout.loss_fn(ctx.dims),
         optimizer=optim.adam(opt["lr"], b1=opt["b1"], b2=opt["b2"],
                              eps=opt["eps"]),
         mesh=mesh)
 
 
-def install_weights(trainer, z, seed: int) -> None:
+def install_weights(trainer, layout, z, seed: int) -> None:
     """Replace what ``Trainer.init`` drew by the benchmark's weights for
     the seed, leaf for leaf in the placement the Trainer chose."""
     import jax
@@ -61,7 +57,7 @@ def install_weights(trainer, z, seed: int) -> None:
         jax.device_put, layout.program_params(z, seed), placement)
 
 
-def norm_programs(z):
+def norm_programs(layout, z):
     """Two small jitted programs: per-leaf norms of a params-shaped tree,
     and per-leaf norms of (params - the seed's initial weights), the
     initial weights made again on the device and not kept."""
@@ -71,13 +67,12 @@ def norm_programs(z):
     norm = lambda x: jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
     norms = jax.jit(lambda tree: tm(norm, tree))
     change = jax.jit(lambda params, seed: tm(
-        lambda a, b: norm(a - b), params,
-        layout.to_program_tree(reference.make_weights(z, seed), z.L)))
+        lambda a, b: norm(a - b), params, layout.seed_params(z, seed)))
     return norms, change
 
 
-def as_program(ref_result: Dict[str, Any]) -> Dict[str, Any]:
-    """What ``reference.train_reference`` returns, in the shape of the
+def as_program(layout, ref_result: Dict[str, Any]) -> Dict[str, Any]:
+    """What the reference's ``train_reference`` returns, in the shape of the
     program's readings: how the control and the planted faults are put in
     the program's place."""
     return {"losses": ref_result["losses"],
@@ -86,7 +81,7 @@ def as_program(ref_result: Dict[str, Any]) -> Dict[str, Any]:
                 ref_result["change_norms"])}
 
 
-def compare(program: Dict[str, Any], ref: Dict[str, Any],
+def compare(layout, program: Dict[str, Any], ref: Dict[str, Any],
             limits: Dict[str, float]) -> List[Dict[str, Any]]:
     """The numbers that decide ``correct`` for a training cell, each
     beside its limit. Loss gaps are relative to the reference's loss.
@@ -123,15 +118,16 @@ def run(ctx) -> Dict[str, Any]:
     from paddle_tpu.core.dtypes import bfloat16_compute, use_policy
     from paddle_tpu.train import events
     z, rec, spec = ctx.dims, ctx.rec, ctx.cell.file["train"]
-    mix = ctx.cell.traffic
+    mix, layout = ctx.cell.traffic, ctx.cell.layout
     B, T = int(spec["batch"]), int(mix["seq_len"])
     warm = max(int(spec["warm_steps"]), FOLLOWED_STEPS + 1)
     b1 = float(spec["optimizer"]["b1"])
     stream = traffic.train_batches(mix, z.V, B, ctx.seed)
     followed = [next(stream) for _ in range(FOLLOWED_STEPS)]
     trainer = build(ctx)
-    norms, change = norm_programs(z)
-    seed32 = reference.seed32(ctx.seed)
+    norms, change = norm_programs(layout, z)
+    # a jit argument, not a constant: one program serves every seed
+    seed32 = np.uint32(ctx.seed % 2 ** 32)
     stamps: List[float] = []
     losses: List[float] = []
     program: Dict[str, Any] = {}
@@ -186,7 +182,7 @@ def run(ctx) -> Dict[str, Any]:
         with rec.span("trainer_init"):
             x0, y0 = followed[0]
             trainer.init(jax.random.PRNGKey(0), {"x": x0, "y": y0})
-            install_weights(trainer, z, ctx.seed)
+            install_weights(trainer, layout, z, ctx.seed)
         trainer.train(reader, num_passes=1, event_handler=on_event,
                       log_period=0)
     i0 = state["i0"]                     # the stamp that opened the window
@@ -215,9 +211,9 @@ def run(ctx) -> Dict[str, Any]:
     del trainer
     harness.free_device_memory()
     t_ref = time.perf_counter()
-    ref = reference.train_reference(ctx.cell.config, ctx.seed, followed,
-                                    spec["optimizer"])
-    checks = compare(program, ref, ctx.cell.file["limits"])
+    ref = ctx.cell.reference.train_reference(ctx.cell.config, ctx.seed,
+                                             followed, spec["optimizer"])
+    checks = compare(layout, program, ref, ctx.cell.file["limits"])
     ctx.facts.update(followed=followed, reference=ref)
     harness.log(f"train: reference followed {FOLLOWED_STEPS} steps in "
                 f"{time.perf_counter() - t_ref:.1f}s, losses "

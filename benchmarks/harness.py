@@ -6,7 +6,8 @@ JSON line the contract asks for.
 Everything that belongs to one configuration, one traffic mix, one cell
 or one per-layer metric is a file of its own:
 
-- ``BENCHMARK.json`` ``configs[].file``         the sizes, as run
+- ``BENCHMARK.json`` ``configs[].file``         the sizes, as run, and the
+  paths of the three modules that know the ARCHITECTURE (below)
 - ``benchmarks/traffic/<traffic>.json``         the mix's parameters
 - ``benchmarks/workloads/<cell>.json``          driver, sizing, limits
 - ``benchmarks/layer_metrics/<metric>.py``      ``read(ctx)``
@@ -14,6 +15,30 @@ or one per-layer metric is a file of its own:
 
 so a later PR adds a cell, a configuration or a per-layer metric with new
 files and new ``BENCHMARK.json`` entries only.
+
+Of a configuration the harness, the drivers, the traffic generator and the
+readers know its NAME and the modules its file names, by path, under three
+keys. Whatever depends on the architecture lives in those:
+
+- ``reference``  ``dims(cfg)`` (hashable, read from the file's own keys; the
+  one attribute others may read is ``V``, the vocabulary held here),
+  ``serve_reference(cfg, seed, sequences, quant=None)``,
+  ``train_reference(cfg, seed, batches, opt, quant=None, fault=None)``
+- ``layout``     the program's side: ``build_model(z)``, ``loss_fn(z)``,
+  ``program_params(z, seed)`` and ``seed_params(z, seed)`` (the same tree
+  inside a jit), ``flatten_reference`` / ``flatten_program`` (per-leaf
+  readings of both sides under common keys), ``engine_facts(engine)``
+  (what only this architecture's readers use of the built engine)
+- ``costs``      model FLOPs from shapes: ``train_flops_per_token(z,
+  seq_len)``, ``serve_flops(z, prompts, contexts)``, and
+  ``attention_shape(z)`` (layers, heads, head size) for the kernel readers
+
+A configuration of a NEW architecture therefore comes as new files and
+appended entries only: its configuration file, its three modules, its cell
+and traffic files, the readers of the per-layer metrics it adds, its
+``BENCHMARK.json`` entries, and its cell's name appended to the
+``workloads`` lists of the end-to-end and per-layer metrics it reports.
+Nothing else is touched (``tests/benchmark`` does it with a toy).
 """
 
 from __future__ import annotations
@@ -32,6 +57,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HERE = os.path.join(ROOT, "benchmarks")
 TRACE_DIR = os.path.join(ROOT, ".bench_trace")      # git-ignored, removed
+# the keys under which a configuration's file names its architecture's modules
+ARCHITECTURE = ("reference", "layout", "costs")
 
 
 def log(msg: str) -> None:
@@ -81,6 +108,12 @@ class Cell:
         self.file = load_json(os.path.join(bdir, "workloads",
                                            name + ".json"))
         self.driver = self.file["driver"]
+        missing = [k for k in ARCHITECTURE if k not in self.config]
+        if missing:
+            raise KeyError(f"configuration {self.entry['config']!r} names "
+                           f"no {missing} module")
+        self.reference, self.layout, self.costs = (
+            load_module(self.config[key], root) for key in ARCHITECTURE)
 
     def _reports(self, metric: Dict[str, Any]) -> bool:
         return "workloads" not in metric or self.name in metric["workloads"]
@@ -99,14 +132,29 @@ class Cell:
                     else m["moves"] in mine)]
 
 
-def load_reader(metric: str, root: str = ROOT) -> Callable:
-    """``benchmarks/layer_metrics/<metric>.py``'s ``read``."""
-    path = os.path.join(root, "benchmarks", "layer_metrics", metric + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "benchmarks.layer_metrics." + metric.replace(".", "_"), path)
+def load_module(path: str, root: str = ROOT):
+    """The module a data file names by its path in the checkout: looked up
+    under ``root`` and, if it is not there, under the harness's own
+    checkout (a toy root brings modules of its own and still names
+    ``benchmarks/reference.py``)."""
+    for base in (root, ROOT):
+        full = os.path.join(base, path)
+        if os.path.isfile(full):
+            break
+    else:
+        raise FileNotFoundError(f"no module {path!r} under {root}"
+                                + ("" if root == ROOT else f" or {ROOT}"))
+    name = os.path.splitext(path)[0].replace(".", "_").replace("/", ".")
+    spec = importlib.util.spec_from_file_location(name, full)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(metric: str, root: str = ROOT) -> Callable:
+    """``benchmarks/layer_metrics/<metric>.py``'s ``read``."""
+    return load_module(os.path.join("benchmarks", "layer_metrics",
+                                    metric + ".py"), root).read
 
 
 class Recorder:
@@ -218,7 +266,7 @@ class Context:
     def __init__(self, cell: Cell, seed: int, seconds: float, trace: bool,
                  t_start: float, device: Dict[str, Any],
                  peaks: Dict[str, Any]):
-        from benchmarks import costs, reference
+        from benchmarks import costs
         self.cell = cell
         self.seed = int(seed)
         self.seconds = float(seconds)
@@ -226,8 +274,8 @@ class Context:
         self.t_start = t_start
         self.device = device
         self.peaks = peaks
-        self.costs = costs
-        self.dims = reference.dims(cell.config)
+        self.costs = costs      # kernels' and chip's (cell.costs: the model's)
+        self.dims = cell.reference.dims(cell.config)
         self.rec = Recorder()
         self.profile = Profile(self.rec, trace, float(
             cell.file.get("trace_seconds", 3.0)))

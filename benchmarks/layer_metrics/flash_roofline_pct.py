@@ -13,10 +13,10 @@ def read(ctx):
     seconds = sum(ctx.trace["kernel_seconds"].get(k, 0.0) for k in KERNELS)
     if seconds <= 0:
         return None
-    z = ctx.dims
+    layers, heads, head_dim = ctx.cell.costs.attention_shape(ctx.dims)
     per_chip = ctx.facts["batch"] // ctx.device["count"]
-    cost = ctx.costs.flash_cost(per_chip, z.H, ctx.facts["seq_len"],
-                                z.D // z.H)
+    cost = ctx.costs.flash_cost(per_chip, heads, ctx.facts["seq_len"],
+                                head_dim)
     least, _ = ctx.costs.roofline_seconds(cost["flops"], cost["bytes"],
                                           ctx.peaks)
-    return 100.0 * least * z.L * steps / seconds
+    return 100.0 * least * layers * steps / seconds

@@ -12,12 +12,12 @@ def read(ctx):
     ticks = ctx.rec.traced("tick")
     if seconds <= 0 or not ticks:
         return None
-    z = ctx.dims
+    layers, heads, head_dim = ctx.cell.costs.attention_shape(ctx.dims)
     least = 0.0
     for _, _, facts in ticks:
         cost = ctx.costs.paged_decode_cost(
-            facts["live_tokens"], ctx.facts["slots"], z.H, z.D // z.H,
+            facts["live_tokens"], ctx.facts["slots"], heads, head_dim,
             ctx.facts["pool_bytes"])
-        least += z.L * ctx.costs.roofline_seconds(
+        least += layers * ctx.costs.roofline_seconds(
             cost["flops"], cost["bytes"], ctx.peaks)[0]
     return 100.0 * least / seconds

@@ -7,7 +7,7 @@ def read(ctx):
     tokens = ctx.facts.get("tokens")
     if not tokens:
         return None
-    flops = tokens * ctx.costs.train_flops_per_token(ctx.dims,
-                                                    ctx.facts["seq_len"])
+    flops = tokens * ctx.cell.costs.train_flops_per_token(
+        ctx.dims, ctx.facts["seq_len"])
     peak = ctx.peaks["bf16_flops_per_s"] * ctx.device["count"]
     return 100.0 * flops / (ctx.window_s * peak)

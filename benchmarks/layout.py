@@ -1,5 +1,7 @@
-"""How the program under test lays out a ``TransformerLM``'s parameters,
-against the reference's stacked layout. The benchmark makes the weights
+"""The program's side of the GPT-2-shaped configurations (their ``layout``
+module): which model of the program runs them, its training loss, and how
+it lays out a ``TransformerLM``'s parameters against the reference's
+stacked layout. The benchmark makes the weights
 (``reference.make_weights``) and hands the program this tree; the program
 hands nothing back to the reference. Per-leaf readings of both sides are
 flattened to the same keys (``wq/3``, ``wte``) before they are compared."""
@@ -13,6 +15,7 @@ import numpy as np
 import jax
 
 from benchmarks import reference
+from benchmarks.reference import Dims
 
 # program block leaf -> reference stacked leaf
 BLOCK_LEAVES = {
@@ -25,6 +28,30 @@ BLOCK_LEAVES = {
 TOP_LEAVES = {("emb", "w"): "wte", ("pos", "w"): "wpe",
               ("ln_f", "scale"): "ln_f_g", ("ln_f", "bias"): "ln_f_b"}
 MODEL_NAME = "transformer_lm"
+
+
+def build_model(z: Dims):
+    """The program's model for these sizes."""
+    from paddle_tpu.models import TransformerLM
+    return TransformerLM(vocab=z.V, dim=z.D, num_layers=z.L,
+                         num_heads=z.H, ffn_hidden=z.F, max_len=z.P,
+                         use_flash=True)
+
+
+def loss_fn(z: Dims):
+    """The program's training loss on the model's output and a batch
+    ``{"x", "y"}``: mean next-token cross-entropy over every position."""
+    from paddle_tpu.nn import costs as nn_costs
+    return lambda out, b: nn_costs.softmax_cross_entropy(
+        out.reshape(-1, z.V), b["y"].reshape(-1))
+
+
+def engine_facts(engine) -> Dict[str, Any]:
+    """What this architecture's readers need of a built ``DecodeEngine``:
+    the K / V pools' dtype and its width in bytes."""
+    k = engine.cache.k
+    dtype = np.dtype((k[0] if isinstance(k, tuple) else k).dtype)
+    return {"pool_dtype": str(dtype), "pool_bytes": int(dtype.itemsize)}
 
 
 def to_program_tree(w: Dict[str, Any], n_layers: int) -> Dict[str, Any]:
@@ -40,12 +67,18 @@ def to_program_tree(w: Dict[str, Any], n_layers: int) -> Dict[str, Any]:
     return {MODEL_NAME: model}
 
 
-@functools.partial(jax.jit, static_argnames=("z",))
-def _program_params(z, seed):
+def seed_params(z: Dims, seed) -> Dict[str, Any]:
+    """The seed's weights in the program's layout. Trace it inside a jit
+    (``seed`` a uint32): it is all device work."""
     return to_program_tree(reference.make_weights(z, seed), z.L)
 
 
-def program_params(z: reference.Dims, seed: int) -> Dict[str, Any]:
+@functools.partial(jax.jit, static_argnames=("z",))
+def _program_params(z, seed):
+    return seed_params(z, seed)
+
+
+def program_params(z: Dims, seed: int) -> Dict[str, Any]:
     """The seed's weights in the program's layout, made on the device in
     one jitted call."""
     return _program_params(z, reference.seed32(seed))

@@ -1,7 +1,9 @@
 """The benchmark's own tests (``BENCHMARK.json`` lists this directory
 under ``paths``): both drivers at a toy size on the CPU through the same
 cell runner the chip runs use, the contract's character rules, discovery
-of every file by name, the trace reduction on a recorded trace, the cost
+of every file by name (a configuration's three architecture modules
+among them, and a toy configuration of ANOTHER architecture that comes as
+new files only), the trace reduction on a recorded trace, the cost
 functions against hand counts, the float32 reference against
 ``TransformerLM``, and the planted faults that ``correct`` has to catch.
 Nothing here touches a TPU library or describes a topology.
@@ -23,8 +25,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmarks import costs, harness, layout, reference, trace_reduce  # noqa: E402
-from benchmarks import traffic  # noqa: E402
+from benchmarks import costs, gpt2_costs, harness, layout, reference  # noqa: E402
+from benchmarks import trace_reduce, traffic  # noqa: E402
 
 BENCH = harness.load_json(os.path.join(ROOT, "BENCHMARK.json"))
 XPLANE = glob.glob(os.path.join(
@@ -38,8 +40,101 @@ TOY_CONFIG = {
     "n_head": 2, "n_inner": 64, "n_positions": 32, "vocab_size": 128,
     "activation_function": "gelu", "layer_norm_epsilon": 1e-5,
     "initializer_range": 0.02,
+    "reference": "benchmarks/reference.py", "layout": "benchmarks/layout.py",
+    "costs": "benchmarks/gpt2_costs.py",
     "departures": {"activation_function": "gelu_tanh",
                    "layer_norm_epsilon": 1e-6}}
+# ANOTHER architecture, as a later PR would bring it: a file with none of
+# the GPT-2 keys and three modules of its own, whose Dims shares one
+# attribute name with the GPT-2 one: V. (The program has one model today,
+# so the toy's modules translate and delegate to the GPT-2 ones.)
+ALT_CONFIG = {
+    "source": "a toy of another architecture for the CPU tests",
+    "num_hidden_layers": 2, "hidden_size": 32, "num_attention_heads": 2,
+    "intermediate_size": 64, "max_position_embeddings": 32,
+    "padded_vocab_size": 128, "layer_norm_eps": 1e-6, "init_std": 0.02,
+    "reference": "benchmarks/alt/reference.py",
+    "layout": "benchmarks/alt/layout.py", "costs": "benchmarks/alt/costs.py"}
+ALT_MODULES = {
+    "reference.py": '''
+from typing import NamedTuple
+from benchmarks import reference as gpt2
+
+
+class AltDims(NamedTuple):
+    depth: int
+    width: int
+    heads: int
+    inner: int
+    positions: int
+    V: int
+    ln_eps: float
+    std: float
+
+    def gpt2(self):
+        return gpt2.Dims(L=self.depth, D=self.width, H=self.heads,
+                         F=self.inner, P=self.positions, V=self.V,
+                         eps=self.ln_eps, act="gelu_tanh", init_std=self.std)
+
+
+def dims(cfg):
+    return AltDims(cfg["num_hidden_layers"], cfg["hidden_size"],
+                   cfg["num_attention_heads"], cfg["intermediate_size"],
+                   cfg["max_position_embeddings"], cfg["padded_vocab_size"],
+                   cfg["layer_norm_eps"], cfg["init_std"])
+
+
+def _gpt2_config(cfg):
+    z = dims(cfg).gpt2()
+    return {"n_layer": z.L, "n_embd": z.D, "n_head": z.H, "n_inner": z.F,
+            "n_positions": z.P, "vocab_size": z.V,
+            "layer_norm_epsilon": z.eps, "activation_function": z.act,
+            "initializer_range": z.init_std}
+
+
+def serve_reference(cfg, seed, sequences, quant=None):
+    return gpt2.serve_reference(_gpt2_config(cfg), seed, sequences, quant)
+
+
+def train_reference(cfg, seed, batches, opt, quant=None, fault=None):
+    return gpt2.train_reference(_gpt2_config(cfg), seed, batches, opt,
+                                quant, fault)
+''',
+    "layout.py": '''
+from benchmarks import layout as gpt2
+from benchmarks.layout import engine_facts, flatten_program, flatten_reference
+
+
+def build_model(z):
+    return gpt2.build_model(z.gpt2())
+
+
+def loss_fn(z):
+    return gpt2.loss_fn(z.gpt2())
+
+
+def program_params(z, seed):
+    return gpt2.program_params(z.gpt2(), seed)
+
+
+def seed_params(z, seed):
+    return gpt2.seed_params(z.gpt2(), seed)
+''',
+    "costs.py": '''
+from benchmarks import gpt2_costs as gpt2
+
+
+def train_flops_per_token(z, seq_len):
+    return gpt2.train_flops_per_token(z.gpt2(), seq_len)
+
+
+def serve_flops(z, prompts, contexts):
+    return gpt2.serve_flops(z.gpt2(), prompts, contexts)
+
+
+def attention_shape(z):
+    return gpt2.attention_shape(z.gpt2())
+'''}
 TOY_TRAIN = {
     "driver": "train", "trace_seconds": 0.2,
     "kernels": ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"],
@@ -62,35 +157,43 @@ TOY_TRAFFIC = {
                  "rate_rps": 20.0, "arrival": "poisson",
                  "prompt_len": [4, 20], "max_new": [3, 8], "sigma": 0.6,
                  "max_total": 32}}
-TOY_CELLS = {"toy-train": ("toy-seq", TOY_TRAIN),
-             "toy-serve": ("toy-closed", TOY_SERVE),
-             "toy-serve-open": ("toy-open", TOY_SERVE)}
+TOY_CONFIGS = {"toy": TOY_CONFIG, "alt": ALT_CONFIG}
+TOY_CELLS = {"toy-train": ("toy", "toy-seq", TOY_TRAIN),
+             "toy-serve": ("toy", "toy-closed", TOY_SERVE),
+             "toy-serve-open": ("toy", "toy-open", TOY_SERVE),
+             "alt-train": ("alt", "toy-seq", TOY_TRAIN),
+             "alt-serve": ("alt", "toy-closed", TOY_SERVE)}
 
 
 def make_toy_root(root):
-    """A checkout-shaped directory holding ONLY new files: a toy
-    configuration, three toy cells and their mixes, added beside a copy of
-    the per-layer readers. The harness finds each by name."""
+    """A checkout-shaped directory holding ONLY new files: two toy
+    configurations (the second of another architecture, with its three
+    modules), five toy cells and their mixes, added beside a copy of the
+    per-layer readers. The harness finds each by name."""
     bdir = os.path.join(root, "benchmarks")
-    for sub in ("configs", "traffic", "workloads"):
+    for sub in ("configs", "traffic", "workloads", "alt"):
         os.makedirs(os.path.join(bdir, sub))
     shutil.copytree(os.path.join(ROOT, "benchmarks", "layer_metrics"),
                     os.path.join(bdir, "layer_metrics"))
-    with open(os.path.join(bdir, "configs", "toy.json"), "w") as f:
-        json.dump(TOY_CONFIG, f)
+    for name, config in TOY_CONFIGS.items():
+        with open(os.path.join(bdir, "configs", name + ".json"), "w") as f:
+            json.dump(config, f)
+    for name, text in ALT_MODULES.items():
+        with open(os.path.join(bdir, "alt", name), "w") as f:
+            f.write(text)
     for name, mix in TOY_TRAFFIC.items():
         with open(os.path.join(bdir, "traffic", name + ".json"), "w") as f:
             json.dump(mix, f)
     bench = copy.deepcopy(BENCH)
-    bench["configs"] = [{"name": "toy", "source": "none", "reduced": [],
-                         "file": "benchmarks/configs/toy.json",
-                         "why": "toy"}]
+    bench["configs"] = [{"name": name, "source": "none", "reduced": [],
+                         "file": f"benchmarks/configs/{name}.json",
+                         "why": "toy"} for name in TOY_CONFIGS]
     bench["workloads"] = []
-    for name, (mix, spec) in TOY_CELLS.items():
+    for name, (config, mix, spec) in TOY_CELLS.items():
         with open(os.path.join(bdir, "workloads", name + ".json"),
                   "w") as f:
             json.dump(spec, f)
-        bench["workloads"].append({"name": name, "config": "toy",
+        bench["workloads"].append({"name": name, "config": config,
                                    "traffic": mix, "chips": 1,
                                    "why": "toy"})
     real = {w["name"]: harness.Cell(w["name"]).driver
@@ -98,7 +201,7 @@ def make_toy_root(root):
     for m in bench["end_to_end"] + bench["per_layer"]:
         if "workloads" in m:
             drivers = {real[w] for w in m["workloads"]}
-            m["workloads"] = [n for n, (_, s) in TOY_CELLS.items()
+            m["workloads"] = [n for n, (_, _, s) in TOY_CELLS.items()
                               if s["driver"] in drivers]
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(bench, f)
@@ -183,6 +286,33 @@ def test_serve_driver_toy(toy_root, cell_name):
     assert "paged_decode_roofline_pct" not in layers["metrics"]
 
 
+@pytest.mark.parametrize("cell_name, mfu", [
+    ("alt-train", "train_step_mfu_pct"), ("alt-serve", "serve_step_mfu_pct")])
+def test_other_architecture_comes_as_new_files_only(toy_root, cell_name, mfu):
+    """A configuration that is not GPT-2-shaped (other keys, a Dims of its
+    own, its three modules in the toy root) runs through the same harness,
+    drivers and readers, reads ``correct`` and reports the step's MFU."""
+    cell = harness.Cell(cell_name, root=toy_root)
+    assert not {"n_layer", "n_embd", "n_head", "n_inner", "n_positions",
+                "vocab_size"} & set(cell.config)
+    z = cell.reference.dims(cell.config)
+    assert set(z._fields) & set(reference.Dims._fields) == {"V"}
+    assert os.path.dirname(cell.layout.__file__) == os.path.join(
+        toy_root, "benchmarks", "alt")
+    result, traced = run_toy(toy_root, cell_name, seconds=1.0, trace=True)
+    line = assert_contract_line(result, [m["name"] for m in cell.end_to_end])
+    assert line["correct"] is True, line["checks"]
+    assert line["attempted"] > 0 and line["failed"] == 0
+    assert set(line["checks"]) == set(cell.file["limits"])
+    layers = assert_contract_line(traced,
+                                  [m["name"] for m in cell.per_layer])
+    assert layers["metrics"][mfu]["value"] > 0
+    # the same arithmetic as the GPT-2 toy's cell of the same sizes
+    twin = harness.Cell(cell_name.replace("alt", "toy"), root=toy_root)
+    assert cell.costs.attention_shape(z) == twin.costs.attention_shape(
+        twin.reference.dims(twin.config)) == (2, 2, 16)
+
+
 def test_same_seed_same_inputs_and_large_seeds():
     mix = TOY_TRAFFIC["toy-closed"]
     big = 2 ** 31 + 12345
@@ -256,9 +386,11 @@ def test_control_int8_fails_the_comparison(toy_root):
     ref = reference.train_reference(TOY_CONFIG, 5, batches, opt)
     ctl = reference.train_reference(TOY_CONFIG, 5, batches, opt,
                                     quant="int8")
-    checks = train.compare(train.as_program(ctl), ref, TOY_TRAIN["limits"])
+    checks = train.compare(layout, train.as_program(layout, ctl), ref,
+                           TOY_TRAIN["limits"])
     assert not all(c["ok"] for c in checks), checks
-    same = train.compare(train.as_program(ref), ref, TOY_TRAIN["limits"])
+    same = train.compare(layout, train.as_program(layout, ref), ref,
+                         TOY_TRAIN["limits"])
     assert all(c["value"] == 0 for c in same)
     # serving, on a vocabulary wide enough for int8 to change a choice
     wide = dict(TOY_CONFIG, vocab_size=8192)
@@ -325,14 +457,81 @@ def test_every_file_is_found_by_name():
     for c in cells:
         assert os.path.exists(os.path.join(bdir, "drivers",
                                            c.driver + ".py"))
-        for key in ("n_layer", "n_embd", "n_head", "n_inner", "n_positions",
-                    "vocab_size"):
-            assert isinstance(c.config[key], int)
-        assert c.config["n_embd"] // c.config["n_head"] == 128
+        # its three architecture modules load from the paths its file
+        # names, and its own dims reads its own file
+        for key in harness.ARCHITECTURE:
+            assert getattr(c, key).__file__ == os.path.join(
+                ROOT, c.config[key])
+        z = c.reference.dims(c.config)
+        assert isinstance(z.V, int) and z.V > 0 and hash(z) == hash(
+            c.reference.dims(c.config))
+        assert all(n > 0 for n in c.costs.attention_shape(z))
         assert set(c.file["limits"]) and c.file["sizing"]
     with pytest.raises(KeyError):
         harness.load_peaks("TPU v9 imaginary")
     assert harness.load_peaks("TPU v5 lite")["bf16_flops_per_s"] == 197e12
+
+
+@pytest.mark.parametrize("name", ["cerebras-gpt-590m", "cerebras-gpt-1.3b"])
+def test_cerebras_configurations_keep_their_published_shape(name):
+    entry = {c["name"]: c for c in BENCH["configs"]}[name]
+    config = harness.load_json(os.path.join(ROOT, entry["file"]))
+    for key in ("n_layer", "n_embd", "n_head", "n_inner", "n_positions",
+                "vocab_size"):
+        assert isinstance(config[key], int)
+    assert config["n_embd"] // config["n_head"] == 128
+    assert entry["reduced"] == [] and entry["source"] == config["source"]
+    assert [config[k] for k in harness.ARCHITECTURE] == [
+        "benchmarks/reference.py", "benchmarks/layout.py",
+        "benchmarks/gpt2_costs.py"]
+    z = reference.dims(config)
+    assert gpt2_costs.attention_shape(z) == (config["n_layer"],
+                                             config["n_head"], 128)
+
+
+def test_configuration_naming_a_missing_module_fails_with_its_path(tmp_path):
+    root = make_toy_root(str(tmp_path))
+    path = os.path.join(root, "benchmarks", "configs", "alt.json")
+    for key, named in (("costs", "benchmarks/alt/no_such_costs.py"),
+                       ("layout", None)):
+        config = dict(ALT_CONFIG)
+        if named:
+            config[key] = named
+        else:
+            del config[key]
+        with open(path, "w") as f:
+            json.dump(config, f)
+        with pytest.raises((FileNotFoundError, KeyError),
+                           match=named or key):
+            harness.Cell("alt-serve", root=root)
+        assert harness.Cell("toy-serve", root=root).costs is not None
+    # a module of the harness's own checkout is found from a toy root
+    assert harness.Cell("toy-serve", root=root).reference.__file__ \
+        == os.path.join(ROOT, "benchmarks", "reference.py")
+
+
+def test_harness_drivers_and_readers_spell_no_architecture():
+    """Of a configuration they know its name and the modules its file
+    names: no GPT-2 key, model class or leaf name outside those modules."""
+    bdir = os.path.join(ROOT, "benchmarks")
+    files = [os.path.join(bdir, f) for f in (
+        "harness.py", "run.py", "traffic.py", "trace_reduce.py",
+        "control.py")]
+    files += glob.glob(os.path.join(bdir, "drivers", "*.py"))
+    files += glob.glob(os.path.join(bdir, "layer_metrics", "*.py"))
+    assert len(files) > 20
+    word = re.compile(r"n_embd|n_head|n_layer|TransformerLM|wte")
+    found = [(os.path.relpath(f, ROOT), m) for f in files
+             for m in word.findall(open(f).read())]
+    assert not found, found
+    imports = re.compile(r"^\s*(?:from|import)\s+(benchmarks[\w.]*)"
+                         r"(?:\s+import\s+(.*))?", re.M)
+    for f in glob.glob(os.path.join(bdir, "drivers", "*.py")) \
+            + [os.path.join(bdir, "control.py")]:
+        for module, names in imports.findall(open(f).read()):
+            assert module == "benchmarks" and set(
+                n.strip() for n in names.split(",")) <= {"harness", "traffic"}, (
+                    os.path.relpath(f, ROOT), module, names)
 
 
 # -- the yardstick ------------------------------------------------------------
@@ -357,12 +556,14 @@ def test_cost_functions_against_a_hand_count():
     z = reference.Dims(L=2, D=8, H=2, F=16, P=4, V=10, eps=1e-5,
                        act="gelu", init_std=0.02)
     # per layer 4*8*8 + 2*8*16 = 512; readout 10*8 = 80
-    assert costs.matmul_params(z) == 2 * 512 + 80 == 1104
+    assert gpt2_costs.matmul_params(z) == 2 * 512 + 80 == 1104
     # forward per token at T=4: 2*1104 + 4*D*L*(T+1)/2 = 2208 + 160
-    assert costs.train_flops_per_token(z, 4) == 3 * (2208 + 160)
-    assert costs.decode_flops(z, 3) == 2208 + 4 * 8 * 2 * 3
+    assert gpt2_costs.train_flops_per_token(z, 4) == 3 * (2208 + 160)
+    assert gpt2_costs.decode_flops(z, 3) == 2208 + 4 * 8 * 2 * 3
     # prompt of 3: body 2*1024*3, one readout row 160, pairs 6 -> 4*8*2*6
-    assert costs.prefill_flops(z, 3) == 6144 + 160 + 384
+    assert gpt2_costs.prefill_flops(z, 3) == 6144 + 160 + 384
+    assert gpt2_costs.serve_flops(z, [3], [3]) == 6688 + 2400
+    assert gpt2_costs.attention_shape(z) == (2, 2, 4)
     f = costs.flash_cost(batch=1, heads=2, seq_len=4, head_dim=4)
     assert f["flops"] == 3 * 4 * 4 * (2 * 10)       # 10 pairs a head
     assert f["bytes"] == 12 * (2 * 4 * 4 * 2) + 2 * 2 * 4 * 4
@@ -375,7 +576,7 @@ def test_cost_functions_against_a_hand_count():
     assert costs.roofline_seconds(500, 20, peaks) == (5.0, "flops")
     # the 590M configuration: ISSUE 24's 3.86 GFLOP a token
     big = reference.dims(harness.Cell("train-590m-seq2048").config)
-    assert costs.train_flops_per_token(big, 2048) == pytest.approx(
+    assert gpt2_costs.train_flops_per_token(big, 2048) == pytest.approx(
         3.86e9, rel=0.01)
 
 
@@ -386,6 +587,10 @@ def test_reference_agrees_with_transformer_lm():
     z = reference.dims(TOY_CONFIG)
     model = TransformerLM(vocab=z.V, dim=z.D, num_layers=z.L,
                           num_heads=z.H, ffn_hidden=z.F, max_len=z.P)
+    built = layout.build_model(z)        # what the drivers build, with flash
+    assert type(built) is TransformerLM
+    assert (built.emb.vocab, len(built.blocks), built.max_len) == (
+        z.V, z.L, z.P)
     w = reference.init_weights(z, 11)
     variables = {"params": layout.to_program_tree(w, z.L), "state": {}}
     ids = jnp.asarray(np.random.RandomState(0).randint(0, z.V, (2, 24)))
