@@ -1,0 +1,224 @@
+"""Decoder-only LM with latent attention and expert layers: the block of
+the DeepSeek-V3 line of models (multi-head latent attention, rotary
+positions, RMS norms, SiLU-gated feed-forwards, a sigmoid router over many
+small experts beside a shared one), with the sandwich norms of
+openPangu-Ultra-MoE, as ONE CHIP of an expert-parallel deployment runs it.
+
+What differs from :class:`~paddle_tpu.models.TransformerLM`, by layer:
+
+- positions are rotary (``nn/rotary.py``): there is no position table, and
+  ``max_len`` only bounds a serving slot's block table;
+- a block is ``h = x + RMS(Attn(RMS(x)))``, ``y = h + RMS(FFN(RMS(h)))``:
+  four norms (``sandwich_norm``); a final RMS norm and an UNTIED head;
+- attention is :class:`~paddle_tpu.nn.attention.LatentAttention`, and what
+  a token leaves in the paged cache is one latent row a layer, not per-head
+  K and V: :meth:`LatentMoELM.cache_spec` says so and the engine asks;
+- the stack is not homogeneous: ``num_dense_layers`` leading blocks have a
+  dense feed-forward, the others an expert layer
+  (:class:`~paddle_tpu.nn.moe.HeldExpertsFFN`: told which experts it
+  holds, it routes over all of them and computes its own experts' part)
+  beside a shared expert that every chip computes whole. The layers run
+  unrolled, each on its own parameters: nothing is stacked, sliced or cast
+  inside a compiled program, so weights held in bfloat16 stay where they
+  are.
+
+Serving entry points keep :class:`TransformerLM`'s signatures; ``kv`` is
+``(latent pool, tables)`` and each returns, as a third result, the
+counters the spec declares: ``expert_tokens [expert layers, experts
+held]``, the rows each held expert received in this call.
+"""
+
+from __future__ import annotations
+
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.core import initializers as I
+from paddle_tpu.core.module import Module
+from paddle_tpu.nn.attention import LatentAttention
+from paddle_tpu.nn.layers import Embedding, GatedFFN, Linear, RMSNorm
+from paddle_tpu.nn.moe import HeldExpertsFFN
+
+__all__ = ["LatentMoEBlock", "LatentMoELM"]
+
+
+class LatentMoEBlock(Module):
+    """One sandwich-norm block; ``moe`` (the :class:`HeldExpertsFFN`
+    arguments and ``shared_hidden``) makes its feed-forward an expert
+    layer, ``dense_hidden`` a dense one."""
+
+    def __init__(self, dim: int, attn: dict, dense_hidden: Optional[int],
+                 moe: Optional[dict], eps: float, w_init, name=None):
+        super().__init__(name=name)
+        self.attn = LatentAttention(dim, eps=eps, w_init=w_init, **attn)
+        self.norm_in, self.norm_post_attn = RMSNorm(eps), RMSNorm(eps)
+        self.norm_pre_mlp, self.norm_post_mlp = RMSNorm(eps), RMSNorm(eps)
+        self.is_moe = moe is not None
+        if self.is_moe:
+            moe = dict(moe)
+            self.shared = GatedFFN(dim, moe.pop("shared_hidden"), w_init)
+            self.experts = HeldExpertsFFN(dim, w_init=w_init, **moe)
+        else:
+            self.ffn = GatedFFN(dim, dense_hidden, w_init)
+
+    def _ffn(self, h, live=None):
+        """``h [B, T, D] -> (y, rows each held expert received | None)``;
+        ``live [B, T]`` marks the rows that are not padding."""
+        z = self.norm_pre_mlp(h)
+        if not self.is_moe:
+            with jax.named_scope("dense_ffn"):
+                return self.norm_post_mlp(self.ffn(z)), None
+        with jax.named_scope("moe_shared"):
+            y = self.shared(z)
+        routed, counts = self.experts(
+            z.reshape(-1, z.shape[-1]),
+            None if live is None else live.reshape(-1))
+        return self.norm_post_mlp(y + routed.reshape(z.shape)), counts
+
+    def forward(self, x, positions=None):
+        h = x + self.norm_post_attn(self.attn(self.norm_in(x), positions))
+        y, counts = self._ffn(h)
+        return h + y, counts
+
+    def decode_step(self, x, pool, layer, tables, positions, active,
+                    attn_impl: str = "xla"):
+        with self.scope():
+            a, pool = self.attn.decode(self.norm_in(x), pool, layer, tables,
+                                       positions, active, impl=attn_impl)
+            h = x + self.norm_post_attn(a)
+            y, counts = self._ffn(h, active[:, None])
+            return h + y, pool, counts
+
+    def decode_span(self, x, pool, layer, tables, start, n, active,
+                    write_from=None):
+        with self.scope():
+            a, pool = self.attn.decode_span(self.norm_in(x), pool, layer,
+                                            tables, start, n, active,
+                                            write_from=write_from)
+            h = x + self.norm_post_attn(a)
+            live = active[:, None] & (jnp.arange(x.shape[1])[None]
+                                      < n[:, None])
+            y, counts = self._ffn(h, live)
+            return h + y, pool, counts
+
+
+class LatentMoELM(Module):
+    """``ids [B, T] -> logits [B, T, vocab]``.
+
+    ``experts_held = (first id, count)`` is this chip's share of every
+    expert layer's ``num_experts`` (default: all of them, the uncut
+    layer); ``vocab`` is the slice of the vocabulary held here (embedding
+    and head alike). ``forward(ids, return_aux=True)`` also returns
+    ``expert_tokens``."""
+
+    def __init__(self, vocab: int, dim: int, num_layers: int,
+                 num_dense_layers: int, num_heads: int, q_rank: int,
+                 kv_rank: int, nope_dim: int, rope_dim: int, v_dim: int,
+                 dense_hidden: int, expert_hidden: int, num_experts: int,
+                 top_k: int, experts_held: Optional[Tuple[int, int]] = None,
+                 num_shared: int = 1, routed_scaling: float = 1.0,
+                 rope_base: float = 10000.0, eps: float = 1e-5,
+                 max_len: int = 131072, w_init=I.fan_in_uniform,
+                 name="latent_moe_lm"):
+        super().__init__(name=name)
+        assert 0 <= num_dense_layers <= num_layers
+        self.max_len = max_len
+        self.emb = Embedding(vocab, dim)
+        attn = dict(num_heads=num_heads, q_rank=q_rank, kv_rank=kv_rank,
+                    nope_dim=nope_dim, rope_dim=rope_dim, v_dim=v_dim,
+                    rope_base=rope_base)
+        moe = dict(hidden=expert_hidden, num_experts=num_experts,
+                   top_k=top_k, experts_held=experts_held,
+                   scaling=routed_scaling,
+                   shared_hidden=expert_hidden * num_shared)
+        self.blocks = [
+            LatentMoEBlock(dim, attn, dense_hidden,
+                           None if i < num_dense_layers else moe, eps,
+                           w_init, name=f"block{i}")
+            for i in range(num_layers)]
+        self.norm_f = RMSNorm(eps)
+        self.head = Linear(vocab, use_bias=False, w_init=w_init)
+
+    def cache_spec(self):
+        """What the serving engine asks a model (``serve/engine.py``):
+        ``layers``; ``pools``, the paged state a token leaves in one
+        layer, as named rows (here ONE latent row, ``[c_kv | k_rope]``
+        padded to the lane tile); ``counters``, what ``decode_step`` and
+        ``decode_span`` return beside logits and pools."""
+        moe = [b for b in self.blocks if b.is_moe]
+        spec = {"layers": len(self.blocks),
+                "pools": {"latent": (self.blocks[0].attn.row_width,)}}
+        if moe:
+            spec["counters"] = {
+                "expert_tokens": (len(moe), moe[0].experts.count)}
+        return spec
+
+    def _counters(self, counts):
+        counts = [c for c in counts if c is not None]
+        return {"expert_tokens": jnp.stack(counts)} if counts else {}
+
+    def _embed(self, ids):
+        with jax.named_scope("embed"):
+            return self.emb(ids).astype(jnp.float32)
+
+    def _logits(self, x):
+        with jax.named_scope("head"):
+            return self.head(self.norm_f(x))
+
+    def forward(self, ids, return_aux: bool = False, positions=None):
+        x = self._embed(ids)
+        counts = []
+        for blk in self.blocks:
+            with jax.named_scope(blk._name):
+                x, c = blk(x, positions)
+            counts.append(c)
+        logits = self._logits(x)
+        return (logits, self._counters(counts)) if return_aux else logits
+
+    # -- serving entry points (paddle_tpu.serve) ---------------------------
+
+    def decode_step(self, token, kv, positions, active=None,
+                    attn_impl: str = "xla"):
+        """One new token a slot: ``token [S]``, ``kv = (pool [L, N, bs,
+        row], tables [S, MB])``, ``positions [S]``. Returns ``(logits [S,
+        vocab], kv', counters)``; the pool is written in place, a row a
+        slot a layer."""
+        pool, tables = kv
+        if active is None:
+            active = jnp.ones(token.shape, bool)
+        with jax.named_scope("decode/step"):
+            x = self._embed(token[:, None])
+            counts = []
+            for i, blk in enumerate(self.blocks):
+                with jax.named_scope(blk._name):
+                    x, pool, c = blk.decode_step(x, pool, i, tables,
+                                                 positions, active,
+                                                 attn_impl=attn_impl)
+                counts.append(c)
+            logits = self._logits(x)
+        return logits[:, 0], (pool, tables), self._counters(counts)
+
+    def decode_span(self, tokens, kv, start, n, active=None,
+                    attn_impl: str = "xla", write_from=None):
+        """``Q`` consecutive new tokens a slot (a prefill chunk, a
+        speculative tick): ``tokens [S, Q]`` at positions ``start[s] +
+        j``, of which ``n[s]`` are live. Returns ``(logits [S, Q, vocab],
+        kv', counters)``. The span attends in the expanded form on every
+        ``attn_impl`` (``LatentAttention.decode_span``); padding rows keep
+        no expert pair and count for none."""
+        pool, tables = kv
+        if active is None:
+            active = jnp.ones(tokens.shape[:1], bool)
+        with jax.named_scope("decode/span"):
+            x = self._embed(tokens)
+            counts = []
+            for i, blk in enumerate(self.blocks):
+                with jax.named_scope(blk._name):
+                    x, pool, c = blk.decode_span(x, pool, i, tables, start,
+                                                 n, active,
+                                                 write_from=write_from)
+                counts.append(c)
+            logits = self._logits(x)
+        return logits, (pool, tables), self._counters(counts)

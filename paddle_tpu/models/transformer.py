@@ -204,6 +204,16 @@ class TransformerLM(Module):
                        for i in range(num_layers)]
         self.ln_f = LayerNorm()
 
+    def cache_spec(self):
+        """What the serving engine asks a model (``serve/engine.py``):
+        ``layers``, and ``pools``: the paged state a token leaves in one
+        layer, as named rows. Multi-head attention keeps a ``k`` and a
+        ``v`` row of ``[heads, head size]``; the entry points take and
+        return them in this order, ``kv = (k, v, tables)``."""
+        attn = self.blocks[0].attn
+        row = (attn.num_heads, attn.head_dim or self.emb.dim // attn.num_heads)
+        return {"layers": len(self.blocks), "pools": {"k": row, "v": row}}
+
     def embed(self, ids, positions=None):
         """Token + positional embedding only (the pipeline-parallel entry:
         stage 0's input is produced outside the block pipeline)."""

@@ -6,7 +6,7 @@ from .fused_ln import fused_ln_matmul, ln_matmul_reference
 from .attention import (AdditiveAttention, DotProductAttention,
                         MultiHeadAttention)
 from .crf import CRF, crf_decode, crf_log_likelihood
-from .moe import MoEFFN, moe_sharding_rules
+from .moe import HeldExpertsFFN, MoEFFN, moe_sharding_rules
 from .detection import (DetectionOutput, MultiBoxLoss, ROIPool,
                         decode_boxes, encode_boxes, iou_matrix, nms,
                         prior_box)
@@ -22,6 +22,6 @@ __all__ = list(_layers_all) + [
     "ctc_loss", "ctc_greedy_decode", "AdditiveAttention", "DotProductAttention",
     "MultiHeadAttention", "detection", "DetectionOutput", "MultiBoxLoss",
     "ROIPool", "prior_box", "nms", "iou_matrix", "encode_boxes", "decode_boxes",
-    "MoEFFN", "moe_sharding_rules", "moe",
+    "MoEFFN", "HeldExpertsFFN", "moe_sharding_rules", "moe",
     "autotune", "fused_ln_matmul", "ln_matmul_reference",
 ]

@@ -24,7 +24,7 @@ from ..core.module import Module
 from .layers import Linear
 
 __all__ = ["AdditiveAttention", "DotProductAttention", "MultiHeadAttention",
-           "dot_product_attention_weights"]
+           "LatentAttention", "dot_product_attention_weights"]
 
 
 def _tp_paged_kernel(kernel, q, pages_k, pages_v, *rest, head_dim: int):
@@ -508,3 +508,229 @@ class MultiHeadAttention(Module):
             with jax.named_scope("out_proj"):
                 out = tp_constrain(proj("wo", ctx, out_d))
             return out, pages_k, pages_v
+
+
+class LatentAttention(Module):
+    """Multi-head latent attention: queries through a low-rank latent
+    ``c_q`` (``q_rank``), keys and values through one shared latent
+    ``c_kv`` (``kv_rank``) beside ONE rotary key ``k_rope`` (``rope_dim``)
+    that every head shares. A head's query and key are a part without
+    position (``nope_dim``, from the latents) beside a rotated part
+    (``rope_dim``); its value has ``v_dim``. No biases; both latents are
+    RMS-normalised; scores are scaled by ``1 / sqrt(nope_dim + rope_dim)``.
+
+    What a token leaves in the cache is ONE row, ``[c_kv | k_rope]``
+    (after the norm and after the rotation), ``kv_rank + rope_dim`` values
+    padded with zeros to ``row_width``, a multiple of ``ROW_ALIGN``: the
+    chip stores a ``[.., bs, 576]`` bfloat16 array as 640 columns anyway,
+    and the decode kernel's page copies want the array's shape to say so.
+
+    Two forms of one arithmetic:
+
+    - :meth:`forward` and :meth:`decode_span` EXPAND the context's latents
+      to per-head keys and values (``c_kv W_kvb``) and attend as any
+      multi-head attention does: the cheaper form for many queries
+      (``2 H (nope + rope + v)`` FLOPs a query-key pair, plus the
+      expansion once a chunk).
+    - :meth:`decode` ABSORBS the up-projections: the query is carried into
+      the latent space (``q_nope W_kvb,k^T``), scored against the cached
+      rows themselves, the probabilities weigh the rows' ``c_kv`` part and
+      the result goes through ``W_kvb,v``. No per-head key or value of a
+      cached token is ever formed, and a row is read once for all heads
+      (:func:`~paddle_tpu.nn.pallas_attention.latent_paged_decode`).
+
+    ``W_kvb`` is held as its key and value parts, ``kv_b_k [kv_rank, H,
+    nope_dim]`` and ``kv_b_v [kv_rank, H, v_dim]``, so that neither form
+    slices a weight."""
+
+    SPAN_TILE = 512      # context rows expanded at a time by decode_span
+    ROW_ALIGN = 128      # the lane tile a cached row is padded to
+
+    def __init__(self, dim: int, num_heads: int, q_rank: int, kv_rank: int,
+                 nope_dim: int, rope_dim: int, v_dim: int,
+                 rope_base: float = 10000.0, eps: float = 1e-5,
+                 w_init=I.fan_in_uniform, name=None):
+        super().__init__(name=name)
+        from .layers import RMSNorm
+        self.dim, self.num_heads = dim, num_heads
+        self.q_rank, self.kv_rank = q_rank, kv_rank
+        self.nope_dim, self.rope_dim, self.v_dim = nope_dim, rope_dim, v_dim
+        self.rope_base = float(rope_base)
+        self.scale = 1.0 / float(np.sqrt(nope_dim + rope_dim))
+        self.row_width = -(-(kv_rank + rope_dim) // self.ROW_ALIGN) \
+            * self.ROW_ALIGN
+        self.w_init = w_init
+        lin = lambda n: Linear(n, use_bias=False, w_init=w_init)
+        self.q_a, self.q_norm = lin(q_rank), RMSNorm(eps)
+        self.q_b = lin(num_heads * (nope_dim + rope_dim))
+        self.kv_a, self.kv_norm = lin(kv_rank + rope_dim), RMSNorm(eps)
+        self.o = lin(dim)
+
+    # -- the parts both forms share ----------------------------------------
+
+    def _kv_b(self):
+        pol = current_policy()
+        h = self.num_heads
+        k = self.param("kv_b_k", self.w_init,
+                       (self.kv_rank, h, self.nope_dim))
+        v = self.param("kv_b_v", self.w_init, (self.kv_rank, h, self.v_dim))
+        return pol.cast_compute(k), pol.cast_compute(v)
+
+    def _project(self, x, positions):
+        """``x [B, T, D]``, ``positions [B, T]`` -> ``(q_nope [B, T, H,
+        nope], q_rope [B, T, H, rope], c_kv [B, T, kv_rank], k_rope [B, T,
+        rope])``, latents normalised, rotary parts rotated."""
+        from .rotary import apply_rotary, rotary_angles
+        B, T = x.shape[:2]
+        h = self.num_heads
+        q = self.q_b(self.q_norm(self.q_a(x))).reshape(
+            B, T, h, self.nope_dim + self.rope_dim)
+        kv = self.kv_a(x)
+        cos, sin = rotary_angles(positions, self.rope_dim, self.rope_base)
+        q_rope = apply_rotary(q[..., self.nope_dim:], cos[:, :, None],
+                              sin[:, :, None])
+        k_rope = apply_rotary(kv[..., self.kv_rank:], cos, sin)
+        return (q[..., :self.nope_dim], q_rope,
+                self.kv_norm(kv[..., :self.kv_rank]), k_rope)
+
+    def _rows(self, c_kv, k_rope, dtype):
+        """The cached rows ``[..., row_width]`` of ``dtype``."""
+        pad = self.row_width - self.kv_rank - self.rope_dim
+        parts = [c_kv, k_rope]
+        if pad:
+            parts.append(jnp.zeros(c_kv.shape[:-1] + (pad,), c_kv.dtype))
+        return jnp.concatenate(parts, axis=-1).astype(dtype)
+
+    def _expanded(self, q_nope, q_rope, c_kv, k_rope, visible):
+        """Attention of ``q [B, Q, H, .]`` over the context ``c_kv [B, K,
+        kv_rank]``, ``k_rope [B, K, rope]`` expanded to per-head keys and
+        values; ``visible [B, Q, K]``. Returns the un-normalised
+        ``(acc [B, Q, H, v], m [B, Q, H], l [B, Q, H])`` of a softmax over
+        this context, so that a caller can go on to another tile."""
+        pol = current_policy()
+        wk, wv = self._kv_b()
+        c = pol.cast_compute(c_kv)
+        k_nope = jnp.einsum("bkc,chd->bkhd", c, wk,
+                            preferred_element_type=pol.accum_dtype)
+        v = jnp.einsum("bkc,chd->bkhd", c, wv,
+                       preferred_element_type=pol.accum_dtype)
+        cc = pol.cast_compute
+        s = (jnp.einsum("bqhd,bkhd->bqhk", cc(q_nope), cc(k_nope),
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bqhd,bkd->bqhk", cc(q_rope), cc(k_rope),
+                          preferred_element_type=jnp.float32)) * self.scale
+        s = jnp.where(visible[:, :, None, :], s, -1e30)
+        m = jnp.max(s, axis=-1)
+        p = jnp.where(visible[:, :, None, :], jnp.exp(s - m[..., None]), 0.0)
+        acc = jnp.einsum("bqhk,bkhd->bqhd", cc(p), cc(v),
+                         preferred_element_type=jnp.float32)
+        return acc, m, jnp.sum(p, axis=-1)
+
+    def _out(self, ctx):
+        """``ctx [B, T, H, v] -> [B, T, D]``."""
+        return self.o(ctx.reshape(*ctx.shape[:2], -1))
+
+    # -- entry points --------------------------------------------------------
+
+    def forward(self, x, positions=None):
+        """Causal attention over a whole sequence ``x [B, T, D]``, expanded
+        form, no cache."""
+        B, T = x.shape[:2]
+        if positions is None:
+            positions = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
+        with jax.named_scope("latent_attn"):
+            q_nope, q_rope, c_kv, k_rope = self._project(x, positions)
+            causal = jnp.broadcast_to(jnp.tril(jnp.ones((T, T), bool)),
+                                      (B, T, T))
+            acc, _, l = self._expanded(q_nope, q_rope, c_kv, k_rope, causal)
+            return self._out(acc / l[..., None])
+
+    def decode(self, x, pool, layer, tables, positions, active,
+               impl: str = "xla"):
+        """One new token a slot against the paged latent cache, absorbed
+        form. ``x [S, 1, D]``; ``pool [L, N, bs, row_width]``: every
+        layer's pages, this layer (``layer``, traced or not) written in
+        place and read by index; ``tables [S, MB]``; ``positions [S]`` the
+        new token's position; ``active [S]``. ``impl``: ``"paged"`` the
+        Pallas kernel, ``"xla"`` the gather path of the same arithmetic.
+        Returns ``(out [S, 1, D], pool)``."""
+        from ..serve.kv_cache import write_token
+        from .pallas_attention import (latent_paged_decode,
+                                       latent_paged_reference)
+        with self.scope(), jax.named_scope("latent_attn"):
+            pol = current_policy()
+            q_nope, q_rope, c_kv, k_rope = self._project(
+                x, positions[:, None])
+            pool = write_token(pool, layer,
+                               self._rows(c_kv, k_rope, pool.dtype)[:, 0],
+                               tables, positions, active)
+            wk, wv = self._kv_b()
+            q_lat = jnp.einsum("shd,chd->shc", pol.cast_compute(q_nope[:, 0]),
+                               wk, preferred_element_type=pol.accum_dtype)
+            q = self._rows(q_lat, q_rope[:, 0], pool.dtype)  # [S, H, row]
+            eff_len = jnp.where(active, positions + 1, 0)
+            if impl == "paged":
+                o_lat = latent_paged_decode(
+                    q, pool, tables, eff_len, layer,
+                    value_width=self.kv_rank, scale=self.scale)
+            else:
+                o_lat = latent_paged_reference(
+                    q, pool, tables, eff_len, layer, self.kv_rank,
+                    self.scale)
+            ctx = jnp.einsum("shc,chd->shd", pol.cast_compute(o_lat), wv,
+                             preferred_element_type=pol.accum_dtype)
+            return self._out(ctx[:, None]), pool
+
+    def decode_span(self, x, pool, layer, tables, start, n, active,
+                    write_from=None):
+        """A span of consecutive new tokens a slot (a prefill chunk, a
+        speculative tick's drafts), expanded form: the span's rows are
+        written first, then the slot's context is read back from the pool
+        a tile of ``SPAN_TILE`` rows at a time, as many tiles as the
+        longest live slot needs, each expanded to keys and values once for
+        the whole span (online softmax across tiles). ``x [S, Q, D]``;
+        token ``j`` of slot ``s`` sits at ``start[s] + j`` and sees the
+        positions up to itself; rows ``j >= n[s]`` are padding (written to
+        the null block, their output unspecified). Returns ``(out [S, Q,
+        D], pool)``. Every row read is the pool's rounding of it, the
+        span's own rows too, as a later decode will read them."""
+        from ..serve.kv_cache import write_span
+        with self.scope(), jax.named_scope("latent_attn"):
+            S, Q = x.shape[:2]
+            bs, MB = pool.shape[2], tables.shape[1]
+            pos = start[:, None] + jnp.arange(Q, dtype=jnp.int32)[None]
+            q_nope, q_rope, c_kv, k_rope = self._project(x, pos)
+            n_eff = jnp.where(active, n, 0)
+            pool = write_span(pool, layer,
+                              self._rows(c_kv, k_rope, pool.dtype), tables,
+                              start, n_eff, write_from)
+            per = max(1, min(self.SPAN_TILE // bs, MB))   # pages a tile
+            T = per * bs
+            n_tiles = (jnp.max(jnp.where(n_eff > 0, start + n_eff, 0))
+                       + T - 1) // T
+            h = self.num_heads
+
+            def tile(t, carry):
+                acc, m, l = carry
+                cols = jnp.minimum(t * per + jnp.arange(per), MB - 1)
+                rows = pool[layer, jnp.take(tables, cols, axis=1)]
+                rows = rows.reshape(S, T, -1)
+                k_pos = t * T + jnp.arange(T, dtype=jnp.int32)
+                visible = ((k_pos[None, None, :] <= pos[:, :, None])
+                           & (t * per + jnp.arange(per) < MB).repeat(bs)[
+                               None, None, :])
+                a, m_t, l_t = self._expanded(
+                    q_nope, q_rope, rows[..., :self.kv_rank],
+                    rows[..., self.kv_rank:self.kv_rank + self.rope_dim],
+                    visible)
+                m_new = jnp.maximum(m, m_t)
+                c_old, c_new = jnp.exp(m - m_new), jnp.exp(m_t - m_new)
+                return (acc * c_old[..., None] + a * c_new[..., None],
+                        m_new, l * c_old + l_t * c_new)
+
+            init = (jnp.zeros((S, Q, h, self.v_dim), jnp.float32),
+                    jnp.full((S, Q, h), -1e30, jnp.float32),
+                    jnp.zeros((S, Q, h), jnp.float32))
+            acc, _, l = jax.lax.fori_loop(0, n_tiles, tile, init)
+            ctx = acc / jnp.maximum(l, 1e-30)[..., None]
+            return self._out(ctx), pool

@@ -40,7 +40,7 @@ __all__ = [
     "ScaleSubRegion", "Power", "Scaling", "DotProd", "ConvexCombination",
     "CosSimVecMat", "BilinearInterp", "EosIdCheck", "PRelu",
     "ScalingProjection", "SliceProjection", "TransposedFullMatrixProjection",
-    "SwitchOrder", "MaxPoolWithMask",
+    "SwitchOrder", "MaxPoolWithMask", "RMSNorm", "GatedFFN",
 ]
 
 Pair = Union[int, Tuple[int, int]]
@@ -439,6 +439,38 @@ class LayerNorm(Module):
         if self.use_bias:
             y = y + self.param("bias", I.zeros, (c,))
         return y.astype(dtype)
+
+
+class RMSNorm(Module):
+    """Root-mean-square normalization with a learned scale and no bias or
+    mean: ``x / sqrt(mean(x^2) + eps) * scale``, computed in float32
+    whatever ``x`` is and handed back in ``x``'s dtype."""
+
+    def __init__(self, eps: float = 1e-5, name=None):
+        super().__init__(name=name)
+        self.eps = eps
+
+    def forward(self, x):
+        x32 = x.astype(jnp.float32)
+        ms = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+        scale = self.param("scale", I.ones, (x.shape[-1],))
+        return (x32 * lax.rsqrt(ms + self.eps) * scale).astype(x.dtype)
+
+
+class GatedFFN(Module):
+    """SiLU-gated feed-forward, no biases:
+    ``down(silu(gate(x)) * up(x))`` with ``gate``, ``up`` of width
+    ``hidden`` and ``down`` back to ``dim``."""
+
+    def __init__(self, dim: int, hidden: int, w_init=I.fan_in_uniform,
+                 name=None):
+        super().__init__(name=name)
+        self.gate = Linear(hidden, use_bias=False, w_init=w_init)
+        self.up = Linear(hidden, use_bias=False, w_init=w_init)
+        self.down = Linear(dim, use_bias=False, w_init=w_init)
+
+    def forward(self, x):
+        return self.down(jax.nn.silu(self.gate(x)) * self.up(x))
 
 
 class GroupNorm(Module):

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from paddle_tpu.core import initializers as I
 from paddle_tpu.core.module import Module
 
-__all__ = ["MoEFFN", "moe_sharding_rules"]
+__all__ = ["MoEFFN", "HeldExpertsFFN", "moe_sharding_rules"]
 
 
 class MoEFFN(Module):
@@ -128,6 +128,91 @@ class MoEFFN(Module):
         if not return_aux:
             return out, stats
         return out, aux, stats
+
+
+class HeldExpertsFFN(Module):
+    """This chip's share of an expert layer of an expert-parallel
+    deployment: ``x [N, D] -> (y [N, D], tokens_per_expert [held])``.
+
+    The router scores ALL ``num_experts`` (``sigmoid(x W_g)`` in float32),
+    takes each token's ``top_k`` and normalises their scores to gates
+    (``scaling * s_e / (sum of the k + 1e-20)``), as the whole layer would.
+    Of the ``N * top_k`` (token, expert) pairs this layer keeps those whose
+    expert is one of the ``experts_held = (first id, count)`` it holds,
+    sorts them by expert and runs each held expert's SiLU-gated
+    feed-forward over its own rows as one grouped product
+    (``jax.lax.ragged_dot`` over the sorted pairs, the kept ones first:
+    a group is as long as its expert has pairs, so there is no capacity
+    and no pair is dropped). The result is the held experts'
+    part of ``sum_e g_e Expert_e(x)``; what the absent experts would add
+    is not computed here and nothing stands in for it. A shared expert is
+    the caller's (every chip computes it alike).
+
+    ``tokens_per_expert`` (int32) counts the rows each held expert
+    received: the engine's ``expert_pairs`` / ``expert_hits`` counters."""
+
+    def __init__(self, dim: int, hidden: int, num_experts: int, top_k: int,
+                 experts_held=None, scaling: float = 1.0,
+                 w_init=I.fan_in_uniform, name=None):
+        super().__init__(name=name)
+        first, count = experts_held or (0, num_experts)
+        assert 0 <= first and first + count <= num_experts and count > 0
+        assert 1 <= top_k <= num_experts
+        self.dim, self.hidden = dim, hidden
+        self.num_experts, self.top_k = num_experts, top_k
+        self.first, self.count = int(first), int(count)
+        self.scaling = float(scaling)
+        self.w_init = w_init
+
+    def route(self, x):
+        """``(expert ids [N, k] int32, gates [N, k] float32)`` over all
+        ``num_experts``. The product is float32 at the highest precision:
+        the eighth and the ninth of 256 scores lie close together."""
+        wg = self.param("router", self.w_init, (self.dim, self.num_experts))
+        scores = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), wg.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        top, idx = jax.lax.top_k(scores, self.top_k)
+        gates = self.scaling * top / (
+            jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+        return idx.astype(jnp.int32), gates
+
+    def forward(self, x, live=None):
+        """``live [N]`` bool (optional): rows that are padding (a chunk's
+        tail, an empty slot) keep no pair and count for no expert."""
+        from paddle_tpu.core.dtypes import current_policy
+        pol = current_policy()
+        N, D = x.shape
+        K, E = self.top_k, self.count
+        w_gate = self.param("gate", self.w_init, (E, D, self.hidden))
+        w_up = self.param("up", self.w_init, (E, D, self.hidden))
+        w_down = self.param("down", self.w_init, (E, self.hidden, D))
+        with jax.named_scope("moe_route"):
+            idx, gates = self.route(x)
+            local = idx - self.first                         # [N, K]
+            held = (local >= 0) & (local < E)
+            if live is not None:
+                held = held & live[:, None]
+            # pairs by expert, the pairs of absent experts last
+            key = jnp.where(held, local, E).reshape(N * K)
+            order = jnp.argsort(key, stable=True)
+            sizes = jnp.sum(jax.nn.one_hot(key, E, dtype=jnp.int32), axis=0)
+            token = (order // K).astype(jnp.int32)
+            kept = jnp.arange(N * K) < jnp.sum(sizes)
+        with jax.named_scope("moe_experts"):
+            grouped = lambda a, w: jax.lax.ragged_dot(
+                pol.cast_compute(a), pol.cast_compute(w), sizes,
+                preferred_element_type=pol.accum_dtype)
+            # the pairs' rows in expert order, the kept ones first; rows
+            # past the kept pairs belong to no group and are not computed
+            rows = jnp.take(pol.cast_compute(x), token, axis=0)
+            h = jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
+            y = jnp.where(kept[:, None], grouped(h, w_down), 0.0)
+            g = jnp.where(kept, gates.reshape(N * K)[order], 0.0)
+            # back to token order: a token's K rows, summed
+            out = jnp.take(y * g[:, None], jnp.argsort(order),
+                           axis=0).reshape(N, K, D).sum(axis=1)
+        return out, sizes
 
 
 def moe_sharding_rules(expert_axis: str = "expert"):

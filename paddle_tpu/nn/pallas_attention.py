@@ -36,7 +36,9 @@ finite output (uniform average of the streamed v blocks) — identical to the
 convention of other public TPU flash kernels; mask padding rows downstream.
 
 Every ``pallas_call`` carries a stable ``name`` (``flash_fwd``,
-``flash_bwd_dq``, ``flash_bwd_dkv``, ``paged_decode``, ``paged_span``):
+``flash_bwd_dq``, ``flash_bwd_dkv``, ``paged_decode``, ``paged_span``,
+``latent_decode``; the last is not its wrapper's name, so that a trace's
+events of the wrapper's own small operations do not read as the kernel's):
 it names the Mosaic custom call in compiled HLO text and in a device
 trace, which is how ``chip_smoke.py`` proves the kernels were compiled.
 
@@ -59,7 +61,8 @@ from . import autotune, pallas_mode
 
 __all__ = ["flash_attention", "reference_attention",
            "paged_decode_attention", "paged_reference_attention",
-           "paged_span_attention", "paged_span_reference_attention"]
+           "paged_span_attention", "paged_span_reference_attention",
+           "latent_paged_decode", "latent_paged_reference"]
 
 _NEG = -1e30
 
@@ -883,6 +886,148 @@ def paged_span_attention(q, pages_k, pages_v, tables, start, n, layer,
     )(tables.astype(jnp.int32), start.astype(jnp.int32),
       n.astype(jnp.int32), _layer_operand(layer), *operands)
     return jnp.swapaxes(out, 1, 2)           # [S, Q, H, D]
+
+
+# ---------------------------------------------------------------------------
+# latent decode: q_len = 1 over a paged LATENT cache (one shared row a token)
+# ---------------------------------------------------------------------------
+
+def latent_paged_reference(q, pool, tables, lengths, layer, value_width,
+                           scale):
+    """The XLA gather path of :func:`latent_paged_decode`, same
+    arithmetic: the serving path where the kernels would only be
+    interpreted, and the kernel's test oracle. ``q`` ``[S, H, W]``;
+    ``pool`` ``[L, N, bs, W]``; one cached row is every head's key (all
+    ``W`` columns) and every head's value (its first ``value_width``
+    columns). Scores and softmax in float32, products on the pool's
+    dtype with float32 accumulation; a slot of length 0 gives zeros."""
+    S = q.shape[0]
+    rows = pool[layer, tables]                       # [S, MB, bs, W]
+    rows = rows.reshape(S, -1, rows.shape[-1])       # [S, Wctx, W]
+    s = jnp.einsum("shw,skw->shk", q.astype(rows.dtype), rows,
+                   preferred_element_type=jnp.float32) * scale
+    live = jnp.arange(rows.shape[1])[None] < lengths[:, None]
+    s = jnp.where(live[:, None], s, _NEG)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.where(live[:, None], jnp.exp(s - m), 0.0)
+    l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    out = jnp.einsum("shk,skc->shc", p.astype(rows.dtype),
+                     rows[..., :value_width],
+                     preferred_element_type=jnp.float32)
+    return (out / l).astype(q.dtype)
+
+
+def _latent_decode_kernel(tbl_ref, len_ref, lay_ref, q_ref, pool_ref, o_ref,
+                          buf, sem, m_s, l_s, acc_s, *, scale, bs, group,
+                          value_width):
+    """One slot's 128 heads against its cached rows. Grid ``(S,)``; inside,
+    a loop over the slot's OWN page groups (``group`` pages of ``bs`` rows,
+    as many groups as its length needs and no more), each group brought
+    from the pool in HBM by one DMA a page into one contiguous
+    ``[group * bs, W]`` buffer, double-buffered: group ``g + 1`` is in
+    flight while group ``g`` is multiplied. Every row is read ONCE for all
+    heads: the scores are ``q [H, W] . rows^T`` and the values the rows'
+    first ``value_width`` columns, so no per-head key or value exists."""
+    s_idx = pl.program_id(0)
+    length = len_ref[s_idx]
+    lay = lay_ref[0]
+    T = group * bs
+    n_groups = (length + T - 1) // T
+    last = tbl_ref.shape[1] - 1
+
+    def copies(g, slot):
+        return [pltpu.make_async_copy(
+            pool_ref.at[lay, tbl_ref[s_idx, jnp.minimum(g * group + i, last)]],
+            buf.at[slot, pl.ds(i * bs, bs)], sem.at[slot])
+            for i in range(group)]
+
+    m_s[:] = jnp.full(m_s.shape, _NEG, jnp.float32)
+    l_s[:] = jnp.zeros(l_s.shape, jnp.float32)
+    acc_s[:] = jnp.zeros(acc_s.shape, jnp.float32)
+
+    @pl.when(n_groups > 0)
+    def _():
+        for c in copies(0, 0):
+            c.start()
+
+    def body(g, carry):
+        slot = g % 2
+
+        @pl.when(g + 1 < n_groups)
+        def _():
+            for c in copies(g + 1, 1 - slot):
+                c.start()
+
+        for c in copies(g, slot):
+            c.wait()
+        rows = buf[slot]                                   # [T, W]
+        s = jax.lax.dot_general(q_ref[:], rows, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        k_idx = g * T + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+        s = jnp.where(k_idx < length, s, _NEG)
+        m = m_s[:]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        l_s[:] = l_s[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_s[:] = acc_s[:] * corr + jax.lax.dot_general(
+            p.astype(rows.dtype), rows[:, :value_width],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_s[:] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n_groups, body, 0)
+    o_ref[:] = (acc_s[:] / jnp.maximum(l_s[:], 1e-30)).astype(o_ref.dtype)
+
+
+def latent_paged_decode(q, pool, tables, lengths, layer, *, value_width: int,
+                        scale: float, group: int = 16,
+                        interpret: Optional[bool] = None):
+    """Decode-shaped (q_len = 1) attention over a paged LATENT cache: the
+    absorbed form of latent attention, where a cached token is ONE row
+    shared by every head (its key all ``W`` columns, its value the first
+    ``value_width``).
+
+    Args: ``q`` ``[S, H, W]``, the queries already carried into the
+    latent space (``q_nope W_kvb,k^T`` beside the rotated ``q_rope``);
+    ``pool`` ``[L, N, bs, W]``: every layer's pages, of which layer
+    ``layer`` (an int32 scalar, traced or not: a prefetched operand, as in
+    :func:`paged_decode_attention`) is read in place; ``tables``
+    ``[S, MB]`` int32; ``lengths`` ``[S]`` int32, the valid rows INCLUDING
+    the one just written, 0 for an inactive slot (zero output); ``scale``
+    the softmax scale (``1 / sqrt(qk_nope + qk_rope)``: the width of the
+    un-absorbed key, which the caller knows and ``W`` does not say).
+    Returns ``[S, H, value_width]`` in ``q``'s dtype. ``group`` pages are
+    multiplied at a time (the pool stays in HBM; see the kernel)."""
+    S, H, W = q.shape
+    L, N, bs, Wp = pool.shape
+    assert W == Wp, f"q width {W} != pool row width {Wp}"
+    if interpret is None:
+        interpret = pallas_mode.interpret()
+    T = group * bs
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S,),
+        in_specs=[pl.BlockSpec((None, H, W), lambda s, *_: (s, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, H, value_width),
+                               lambda s, *_: (s, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, T, W), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, value_width), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_latent_decode_kernel, scale=scale, bs=bs,
+                          group=group, value_width=value_width),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, H, value_width), q.dtype),
+        interpret=interpret, name="latent_decode",
+    )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
+      _layer_operand(layer), q.astype(pool.dtype), pool)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))

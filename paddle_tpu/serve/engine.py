@@ -90,6 +90,7 @@ import jax
 import jax.numpy as jnp
 
 from .kv_cache import PagedKVCache, scatter_prefill_pages
+from ..core.dtypes import canonicalize
 from ..nn import pallas_mode
 from ..obs.trace import live, traced, tspan
 from ..parallel.sharding import tp_constrain, tp_shard_scope
@@ -188,13 +189,43 @@ def _resolve_attention(attention: str) -> str:
 
 
 class DecodeEngine:
-    """Compiled serving runtime for a :class:`~paddle_tpu.models.
-    TransformerLM` checkpoint.
+    """Compiled serving runtime for any model that states its paged
+    cache and has the serving entry points.
+
+    What the engine asks of a model, and all it may assume:
+
+    - ``model.cache_spec()``: ``{"layers": L, "pools": {name: row shape},
+      "counters": {name: shape}}`` (``counters`` optional). A pool is the
+      paged state ONE token leaves in ONE layer; the cache allocates each
+      as ``[L, num_blocks, *row[:-1], block_size, row[-1]]``.
+      :class:`~paddle_tpu.models.TransformerLM` declares ``k`` and ``v``
+      rows of ``[heads, head size]``,
+      :class:`~paddle_tpu.models.LatentMoELM` one ``latent`` row.
+    - ``decode_step(token, kv, positions, active, attn_impl=)`` and
+      ``decode_span(tokens, kv, start, n, active, attn_impl=,
+      write_from=)`` with ``kv = (*pools in declared order, tables)``,
+      returning ``(logits, kv')`` and, where counters are declared, a
+      dict of them third. The entry points carry every layer's pool
+      WHOLE, write their rows IN PLACE and read pages by layer index
+      (``serve/kv_cache.py:write_token``): the engine donates the pools
+      and a program that copied one would not fit
+      (``tests/test_chip_lowering.py`` holds both models to it).
+    - ``model.emb.vocab`` and ``model.max_len`` (the longest sequence a
+      slot's table may cover; a position table's length, or only a bound
+      where positions are rotary).
+
+    The host side (block tables, allocator, prefix cache, copy-on-write,
+    the scheduler) never learns what a row holds. What is defined for
+    ``k`` / ``v`` rows only refuses any other declaration at build:
+    ``kv_dtype="int8"``, ``mesh=``, the one-shot prefill;
+    ``export_slot`` / ``adopt_slot`` (and with them
+    ``serve/transport.py``'s wire format) when called.
 
     Args:
-      model: a TransformerLM (homogeneous blocks; any training config —
-        the serve path restacks the per-block params at trace time, so
-        checkpoints are shape-compatible as-is).
+      model: a TransformerLM (any training config — its serve path
+        restacks the per-block params at trace time, so checkpoints are
+        shape-compatible as-is) or a LatentMoELM (bfloat16 leaves are
+        used as they are).
       variables: the model's variables dict (training checkpoint or
         ``load_inference_model`` output).
       max_slots: decode-tick batch width S — the max concurrent
@@ -236,7 +267,8 @@ class DecodeEngine:
         active slots, tokens/sec, sharing/speculation/retention
         counters, ``kv_bytes_per_token``/``quant_dtype``) and the
         scheduler adds per-request records through the same object.
-      dtype: KV pool dtype. f32 default matches the projections' f32
+      dtype: pool dtype, a dtype or its name (``"bfloat16"``: what a
+        JSON file can hold). f32 default matches the projections' f32
         accumulation under both the f32 and bf16-compute policies.
       kv_dtype: ``None``/``"f32"`` (pools at ``dtype``) or ``"int8"`` —
         quantized pools with per-row-per-head scale pages (ISSUE 14):
@@ -299,10 +331,31 @@ class DecodeEngine:
         self.speculative = int(speculative)
         self.prefill_chunk = prefill_chunk
         self.sampling = sampling
-        num_layers = len(model.blocks)
-        num_heads = model.blocks[0].attn.num_heads
-        dim = model.emb.dim
-        head_dim = model.blocks[0].attn.head_dim or dim // num_heads
+        # the model DECLARES the paged state it keeps (cache_spec):
+        # named pools, each the shape of one token's row in one layer,
+        # and the counters its entry points return beside the pools
+        spec = model.cache_spec()
+        num_layers = int(spec["layers"])
+        self.pool_names = tuple(spec["pools"])
+        self.counter_names = tuple(spec.get("counters", ()))
+        kv_pools = self.pool_names == ("k", "v")
+        if not kv_pools:
+            # what has not been carried over to other pools fails here,
+            # not in a compiled program
+            for given, what in ((kv_dtype == "int8", "kv_dtype='int8'"),
+                                (mesh is not None, "mesh="),
+                                (prefill_chunk is None,
+                                 "the one-shot prefill (prefill_chunk=None)")):
+                if given:
+                    raise NotImplementedError(
+                        f"{what} is defined for the k / v pools of "
+                        f"multi-head attention only; {type(model).__name__}"
+                        f" declares {list(self.pool_names)}: int8 rows, "
+                        f"head sharding, the prefill scatter, export_slot /"
+                        f" adopt_slot and the transport's wire format know"
+                        f" [heads, head size] rows")
+        dtype = canonicalize(dtype)
+        num_heads, head_dim = spec["pools"]["k"] if kv_pools else (1, None)
         # tensor-parallel mesh (ISSUE 15): resolve the tp degree, place
         # the params by the megatron rule, and shard the pools on the
         # head axis. All of it is PLACEMENT — the traced program bodies
@@ -351,12 +404,12 @@ class DecodeEngine:
             num_layers, num_heads, head_dim, num_blocks, block_size,
             max_slots=max_slots, max_blocks_per_seq=max_blocks_per_seq,
             dtype=dtype, share_prefix=share_prefix, kv_dtype=kv_dtype,
-            retain_prefix=retain_prefix, tp_degree=self.tp_degree)
+            retain_prefix=retain_prefix, tp_degree=self.tp_degree,
+            row_shapes=None if kv_pools else dict(spec["pools"]))
         if mesh is not None:
             self.cache.shard_pools(mesh, tp_axis)
         else:
-            self.cache.k = jax.device_put(self.cache.k, placement)
-            self.cache.v = jax.device_put(self.cache.v, placement)
+            self.cache.pools = jax.device_put(self.cache.pools, placement)
         self.max_slots = max_slots
         # host-authoritative slot state beside the cache's tables/lengths
         self.active = np.zeros((max_slots,), bool)
@@ -378,6 +431,11 @@ class DecodeEngine:
         self.prefill_chunks = 0          # cumulative chunk calls
         self.draft_proposed = 0          # cumulative drafted tokens
         self.draft_accepted = 0          # cumulative accepted drafts
+        # of a model that counts them (``expert_tokens`` among its
+        # counters): (token, expert) pairs its held experts received, and
+        # held experts with at least one token, summed over calls
+        self.expert_pairs = 0
+        self.expert_hits = 0
         # per-slot attribution for request-level telemetry
         self.slot_stats: List[Dict[str, int]] = [
             {} for _ in range(max_slots)]
@@ -395,59 +453,76 @@ class DecodeEngine:
                 return jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
             return _sample_tokens(cfg, last_logits[None], key[None])[0]
 
+        names, counted = self.pool_names, bool(self.counter_names)
+
+        def run(method, variables, pools, tables, *args, **kw):
+            """A serving entry point of the model on the declared pools:
+            ``kv`` goes in as ``(*pools in declared order, tables)`` and
+            comes back alike; a model that declares counters returns
+            them third. Returns ``(logits, pools, counters)``."""
+            out = model.apply(variables, args[0],
+                              (*(pools[k] for k in names), tables),
+                              *args[1:], method=method, **kw)
+            return (out[0], dict(zip(names, out[1][:-1])),
+                    out[2] if counted else {})
+
+        def tail(counters):
+            """What a program returns after its tokens: the model's
+            counters, where it declares any, fetched with the tokens."""
+            return (counters,) if counted else ()
+
         if prefill_chunk is None:
-            def prefill_fn(variables, pages_k, pages_v, ids, length,
+            def prefill_fn(variables, pools, ids, length,
                            start, table, key):
                 # ids [1, W] padded; length/start [1]; table [1, MB]
                 logits, (ks, vs) = model.apply(variables, ids,
                                                method="prefill")
                 scat = jax.vmap(scatter_prefill_pages,
                                 in_axes=(0, 0, None, None, None))
-                pages_k = scat(pages_k, ks, table, length, start)
-                pages_v = scat(pages_v, vs, table, length, start)
+                pages_k = scat(pools["k"], ks, table, length, start)
+                pages_v = scat(pools["v"], vs, table, length, start)
                 last = jnp.take_along_axis(
                     logits, (length - 1)[:, None, None], axis=1)[0, 0]
-                return pages_k, pages_v, first_token(last, key)
+                return {"k": pages_k, "v": pages_v}, first_token(last, key)
         else:
             C = prefill_chunk
 
-            def prefill_fn(variables, pages_k, pages_v, ids, start, n,
+            def prefill_fn(variables, pools, ids, start, n,
                            write_from, table, key):
                 # ids [1, C]: tokens at positions start..start+n-1;
                 # rows >= n are padding; scatter floored at write_from
                 # (shared-prefix rows are co-owned — never rewritten)
-                logits, (pages_k, pages_v, _) = model.apply(
-                    variables, ids, (pages_k, pages_v, table), start, n,
+                logits, pools, counters = run(
+                    "decode_span", variables, pools, table, ids, start, n,
                     jnp.ones((1,), bool), attn_impl=attn_impl,
-                    write_from=write_from, method="decode_span")
+                    write_from=write_from)
                 last = jnp.take_along_axis(
                     logits, (n - 1)[:, None, None], axis=1)[0, 0]
-                return pages_k, pages_v, first_token(last, key)
+                return (pools, first_token(last, key)) + tail(counters)
 
         if self.speculative == 0:
-            def tick_fn(variables, pages_k, pages_v, tables, lengths,
+            def tick_fn(variables, pools, tables, lengths,
                         tokens, active, keys):
-                logits, (pages_k, pages_v, _) = model.apply(
-                    variables, tokens, (pages_k, pages_v, tables), lengths,
-                    active, attn_impl=attn_impl, method="decode_step")
+                logits, pools, counters = run(
+                    "decode_step", variables, pools, tables, tokens,
+                    lengths, active, attn_impl=attn_impl)
                 if cfg is None:
                     nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 else:
                     nxt = _sample_tokens(cfg, logits, keys)
-                return pages_k, pages_v, nxt[:, None]
+                return (pools, nxt[:, None]) + tail(counters)
         elif cfg is None:
-            def tick_fn(variables, pages_k, pages_v, tables, lengths,
+            def tick_fn(variables, pools, tables, lengths,
                         tokens, n, active):
                 # tokens [S, 1+k]: pending + drafts; ONE span dispatch
                 # verifies every draft (greedy argmax per row)
-                logits, (pages_k, pages_v, _) = model.apply(
-                    variables, tokens, (pages_k, pages_v, tables),
-                    lengths, n, active, attn_impl=attn_impl,
-                    method="decode_span")
+                logits, pools, counters = run(
+                    "decode_span", variables, pools, tables, tokens,
+                    lengths, n, active, attn_impl=attn_impl)
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return pages_k, pages_v, nxt        # [S, 1+k]
+                return (pools, nxt) + tail(counters)        # [S, 1+k]
         else:
-            def tick_fn(variables, pages_k, pages_v, tables, lengths,
+            def tick_fn(variables, pools, tables, lengths,
                         tokens, n, active, keys):
                 # stochastic speculation, the [S3] rejection rule: for
                 # draft row j the proposal distribution is a point mass
@@ -456,10 +531,9 @@ class DecodeEngine:
                 # (= norm(max(p - q, 0))) — distribution-preserving by
                 # construction. All three verdict arrays are computed in
                 # ONE dispatch; the host walks the accept prefix.
-                logits, (pages_k, pages_v, _) = model.apply(
-                    variables, tokens, (pages_k, pages_v, tables),
-                    lengths, n, active, attn_impl=attn_impl,
-                    method="decode_span")
+                logits, pools, counters = run(
+                    "decode_span", variables, pools, tables, tokens,
+                    lengths, n, active, attn_impl=attn_impl)
                 x = _filter_logits(cfg, logits)     # [S, 1+k, V]
                 p = jax.nn.softmax(x, axis=-1)
                 Q = x.shape[1]
@@ -483,7 +557,7 @@ class DecodeEngine:
                     role(1)[:, :-1], res_x).astype(jnp.int32)
                 bonus = jax.vmap(jax.vmap(jax.random.categorical))(
                     role(2), x).astype(jnp.int32)   # [S, 1+k]
-                return pages_k, pages_v, accept, resample, bonus
+                return (pools, accept, resample, bonus) + tail(counters)
 
         # shard-in-scope wrapping (ISSUE 15): with a mesh, every traced
         # body runs inside tp_shard_scope (the attention layer pins
@@ -506,11 +580,11 @@ class DecodeEngine:
         # partitioner may pick a different pool layout, which both
         # breaks donation and retraces the next call on the changed
         # input sharding (the no-retrace invariant would die quietly).
-        def _pin_pools(fn, pool_outs=(0, 1)):
+        def _pin_pools(fn):
             def pinned(*args):
-                out = fn(*args)
-                return tuple(tp_constrain(o, 2) if i in pool_outs else o
-                             for i, o in enumerate(out))
+                pools, *rest = fn(*args)
+                return (jax.tree_util.tree_map(
+                    lambda o: tp_constrain(o, 2), pools), *rest)
             return pinned
 
         # donate the KV pools: the tick writes its rows into the buffers
@@ -519,9 +593,9 @@ class DecodeEngine:
         # tests/test_chip_lowering.py); the prefill's scatter still
         # copies them
         self._prefill_fn = jax.jit(_in_scope(_pin_pools(prefill_fn)),
-                                   donate_argnums=(1, 2))
+                                   donate_argnums=(1,))
         self._tick_fn = jax.jit(_in_scope(_pin_pools(tick_fn)),
-                                donate_argnums=(1, 2))
+                                donate_argnums=(1,))
         # COW block copy: [L, H, bs, hd] pages move pool-internally, one
         # tiny donated program (not an engine entry point — not counted
         # in compile_counts, traced once for the process lifetime).
@@ -601,20 +675,20 @@ class DecodeEngine:
             key = self._prefill_key()
             if self.prefill_chunk is None:
                 out = self._prefill_fn(
-                    self.variables, self.cache.k, self.cache.v,
+                    self.variables, self.cache.pools,
                     jnp.zeros((1, self._W), jnp.int32),
                     jnp.asarray([1], jnp.int32),
                     jnp.asarray([0], jnp.int32), table, key)
             else:
                 out = self._prefill_fn(
-                    self.variables, self.cache.k, self.cache.v,
+                    self.variables, self.cache.pools,
                     jnp.zeros((1, self.prefill_chunk), jnp.int32),
                     jnp.asarray([0], jnp.int32),
                     jnp.asarray([1], jnp.int32),
                     jnp.asarray([0], jnp.int32), table, key)
-            # donated pools: the engine's carry is the returned pair
-            self.cache.k, self.cache.v = out[0], out[1]
-            return out[2]
+            # donated pools: the engine's carry is the returned pools
+            self.cache.pools = out[0]
+            return out[1]
 
         def _tick_once():
             tables, lengths = self.cache.device_tables()
@@ -622,25 +696,25 @@ class DecodeEngine:
                 keys = (self._zero_keys if self.sampling is None
                         else self._tick_keys(self.ticks))
                 out = self._tick_fn(
-                    self.variables, self.cache.k, self.cache.v, tables,
+                    self.variables, self.cache.pools, tables,
                     lengths, jnp.asarray(self.tokens),
                     jnp.asarray(self.active), keys)
             elif self.sampling is not None:
                 out = self._tick_fn(
-                    self.variables, self.cache.k, self.cache.v, tables,
+                    self.variables, self.cache.pools, tables,
                     lengths, jnp.zeros((self.max_slots, self._K1),
                                        jnp.int32),
                     jnp.zeros((self.max_slots,), jnp.int32),
                     jnp.asarray(self.active), self._tick_keys(self.ticks))
             else:
                 out = self._tick_fn(
-                    self.variables, self.cache.k, self.cache.v, tables,
+                    self.variables, self.cache.pools, tables,
                     lengths, jnp.zeros((self.max_slots, self._K1),
                                        jnp.int32),
                     jnp.zeros((self.max_slots,), jnp.int32),
                     jnp.asarray(self.active))
-            self.cache.k, self.cache.v = out[0], out[1]
-            return out[2]
+            self.cache.pools = out[0]
+            return out[1]
 
         def _measured(name, fn):
             t = time.perf_counter()
@@ -661,7 +735,7 @@ class DecodeEngine:
                     #         warm this process's jit cache
             else:
                 fn()
-            jax.block_until_ready((self.cache.k, self.cache.v))
+            jax.block_until_ready(self.cache.pools)
             timings[name] = time.perf_counter() - t
 
         _measured("prefill", _prefill_once)
@@ -847,8 +921,8 @@ class DecodeEngine:
             if self.prefill_chunk is None:
                 ids = st["staged"] if st["staged"] is not None \
                     else self.stage_prompt(prompt)
-                self.cache.k, self.cache.v, tok = self._prefill_fn(
-                    self.variables, self.cache.k, self.cache.v,
+                self.cache.pools, tok = self._prefill_fn(
+                    self.variables, self.cache.pools,
                     jnp.asarray(ids), jnp.asarray([P], jnp.int32),
                     jnp.asarray([st["shared_len"]], jnp.int32),
                     jnp.asarray(self.cache.tables[slot:slot + 1]),
@@ -860,13 +934,14 @@ class DecodeEngine:
                 n = min(C, P - cur)
                 ids = np.zeros((1, C), np.int32)
                 ids[0, :n] = prompt[cur:cur + n]
-                self.cache.k, self.cache.v, tok = self._prefill_fn(
-                    self.variables, self.cache.k, self.cache.v,
+                self.cache.pools, tok, *counters = self._prefill_fn(
+                    self.variables, self.cache.pools,
                     jnp.asarray(ids), jnp.asarray([cur], jnp.int32),
                     jnp.asarray([n], jnp.int32),
                     jnp.asarray([st["shared_len"]], jnp.int32),
                     jnp.asarray(self.cache.tables[slot:slot + 1]),
                     self._prefill_key())
+                st.setdefault("counters", []).extend(counters)
                 st["cursor"] = cur + n
                 done = st["cursor"] >= P
             stats["prefill_chunks"] += 1
@@ -877,11 +952,17 @@ class DecodeEngine:
             return None
         # the drain: int(tok) waits for the device, then the slot goes
         # live and its prefix is registered
-        with tspan(tr, "prefill_drain", slot=slot):
+        with tspan(tr, "prefill_drain", slot=slot) as sp:
             del self._prefilling[slot]
             self.cache.lengths[slot] = P
             self.active[slot] = True
             tok = int(tok)
+            # every chunk's counters, fetched with the token: a chunk's
+            # dispatch does not wait for the device, so the prompt's
+            # counts are known here
+            facts = self._count_experts(*st.get("counters", ()))
+            if sp is not None and facts:
+                sp.set(**facts)
             self.tokens[slot] = tok
             self.history[slot] = []
             self._bigram_idx[slot] = {}
@@ -889,6 +970,23 @@ class DecodeEngine:
             self._history_append(slot, list(prompt) + [tok])
             self.cache.register_prefix(slot, prompt)
         return tok
+
+    def _count_experts(self, *counters) -> Dict[str, int]:
+        """Fetch the ``expert_tokens`` of one or more calls (``[expert
+        layers, experts held]`` int32 each), add them to the cumulative
+        ``expert_pairs`` and ``expert_hits`` and return the calls' facts
+        for a span: the pairs, the held experts that got a token, and the
+        busiest expert's tokens. Nothing for a model that counts none."""
+        counts = [np.asarray(c["expert_tokens"]) for c in counters
+                  if "expert_tokens" in c]
+        if not counts:
+            return {}
+        facts = {"expert_pairs": int(sum(c.sum() for c in counts)),
+                 "expert_hits": int(sum((c > 0).sum() for c in counts)),
+                 "expert_max": int(max(c.max() for c in counts))}
+        self.expert_pairs += facts["expert_pairs"]
+        self.expert_hits += facts["expert_hits"]
+        return facts
 
     def evict(self, slot: int) -> None:
         """Free ``slot``'s blocks back to the pool (shared blocks
@@ -915,6 +1013,7 @@ class DecodeEngine:
         the ADOPTING replica's first tick, so nothing transient is
         lost in flight."""
         assert self.active[slot], f"slot {slot} is not live"
+        self.cache._kv_only("export_slot")
         P = int(self.cache.lengths[slot])
         ids, kpages, vpages = self.cache.export_pages(slot)
         meta = {"length": P, "blocks": len(ids),
@@ -934,6 +1033,7 @@ class DecodeEngine:
         changed)."""
         assert not self.active[slot], f"slot {slot} is occupied"
         assert slot not in self._prefilling, f"slot {slot} is prefilling"
+        self.cache._kv_only("adopt_slot")
         P = len(prompt)
         if not 0 < P <= self._W:
             raise ValueError(f"prompt length {P} not in [1, {self._W}]")
@@ -1024,8 +1124,9 @@ class DecodeEngine:
                 src, dst = self.cache.fork_block(slot, idx)
                 src_i = jnp.asarray(src, jnp.int32)
                 dst_i = jnp.asarray(dst, jnp.int32)
-                self.cache.k = self._cow_fn(self.cache.k, src_i, dst_i)
-                self.cache.v = self._cow_fn(self.cache.v, src_i, dst_i)
+                for name in self.pool_names:
+                    self.cache.pools[name] = self._cow_fn(
+                        self.cache.pools[name], src_i, dst_i)
                 self.slot_stats[slot]["cow_forks"] = \
                     self.slot_stats[slot].get("cow_forks", 0) + 1
         return n
@@ -1067,10 +1168,9 @@ class DecodeEngine:
                         operands += (self._tick_keys(self.ticks),)
             # the enqueue: returns once XLA has the program
             with tspan(tr, "tick_dispatch"):
-                out = self._tick_fn(self.variables, self.cache.k,
-                                    self.cache.v, tables, lengths,
-                                    *operands)
-            self.cache.k, self.cache.v = out[0], out[1]
+                out = self._tick_fn(self.variables, self.cache.pools,
+                                    tables, lengths, *operands)
+            self.cache.pools = out[0]
             # the dispatch is async: host bookkeeping that doesn't need
             # the sampled tokens runs UNDER the in-flight device call (the
             # PR-3 overlap move at tick scale) — the plain tick advances
@@ -1086,9 +1186,11 @@ class DecodeEngine:
             # the drain: the host waits for the device here
             with tspan(tr, "tick_drain"):
                 if stochastic:
-                    acc_d, res_d, bon_d = (np.asarray(o) for o in out[2:])
+                    acc_d, res_d, bon_d = (np.asarray(o) for o in out[1:4])
                 else:
-                    nxt = np.asarray(out[2])         # [S, 1] or [S, 1+k]
+                    nxt = np.asarray(out[1])         # [S, 1] or [S, 1+k]
+                expert_facts = self._count_experts(
+                    out[-1] if self.counter_names else {})
             with tspan(tr, "tick_retire"):
                 self.last_accepted = {}
                 front = np.zeros((self.max_slots,), np.int32)
@@ -1142,7 +1244,7 @@ class DecodeEngine:
                 self.draft_accepted += accepted_tick
             if tick_sp is not None:
                 tick_sp.set(tokens=tokens_tick,
-                            accepted_drafts=accepted_tick)
+                            accepted_drafts=accepted_tick, **expert_facts)
         if self.telemetry is not None:
             wall = time.perf_counter() - t0
             # sharing/chunk counters are emitted as PER-TICK DELTAS
@@ -1173,7 +1275,7 @@ class DecodeEngine:
                 "kv_bytes_per_token": self.cache.kv_bytes_per_token,
                 "quant_dtype": self.cache.quant_dtype,
                 "tp_degree": self.tp_degree,
-                **delta,
+                **delta, **expert_facts,
             })
         if self.metrics is not None:
             m = self.metrics
@@ -1215,7 +1317,7 @@ class DecodeEngine:
         """The tick's operands at the engine's shapes (zeros where the
         host stages them per tick)."""
         tables, lengths = self.cache.device_tables()
-        args = (self.variables, self.cache.k, self.cache.v, tables, lengths)
+        args = (self.variables, self.cache.pools, tables, lengths)
         active = jnp.asarray(self.active)
         keys = jnp.zeros((self.max_slots, 2), jnp.uint32)
         if self.speculative == 0:

@@ -5,10 +5,13 @@ contiguous allocation at ``max_len`` wastes most of it: concurrent sequences
 have ragged lengths, so reserving the worst case per slot strands HBM
 (PagedAttention's motivating measurement — PAPERS.md [S1]). The fix is the
 OS page-table design: the cache is a single pool of fixed-size **blocks**
-(``[num_blocks, heads, block_size, head_dim]`` per layer — heads ahead of
+(for multi-head attention's K and V ``[num_blocks, heads, block_size,
+head_dim]`` per layer — heads ahead of
 the block's tokens, so one head's page is a whole ``[block_size,
 head_dim]`` tile, the shape the TPU's Pallas lowering can block and
-DMA) and each sequence
+DMA; in general whatever rows the model DECLARES, ``[num_blocks, *lead,
+block_size, width]`` for a row ``(*lead, width)``, see
+:class:`PagedKVCache`) and each sequence
 holds an ordered **block table** of pool indices; allocation is
 block-granular, so waste is bounded by one partial block per sequence and
 freed blocks are immediately reusable by any other request.
@@ -220,24 +223,27 @@ def scatter_span(pages, kv, table, start, n, write_from=None):
 # (DESIGN_DECISIONS, PR 26). A row is therefore written as one
 # ``dynamic_update_slice`` of a small slab, which XLA does in place.
 
-def _block_size(pages):
-    """``bs`` of stacked pools ``[L, N, H, bs, ...]``, plain or the
-    quantized tuple."""
-    return (pages[0] if isinstance(pages, tuple) else pages).shape[3]
+def _block_size(pages, lead=1):
+    """``bs`` of stacked pools ``[L, N, *lead, bs, ...]`` (``lead`` axes
+    of a token's row come ahead of the block's tokens: the heads of a K
+    or V pool, none of a latent pool), plain or the quantized tuple."""
+    return (pages[0] if isinstance(pages, tuple) else pages).shape[2 + lead]
 
 
-def _write_rows(pages, layer, kv, blk, off):
+def _write_rows(pages, layer, kv, blk, off, lead=1):
     """``kv [R, H, hd]`` written into layer ``layer`` of the stacked
     pools at ``(blk[r], :, off[r])``, one ``[1, 1, H, 1, hd]`` slab a
     row, in row order (the null block takes every masked row; what it
     holds is never read unmasked). Straight-line code whatever ``R``:
-    inside a loop XLA re-lays an int8 pool round the writes."""
+    inside a loop XLA re-lays an int8 pool round the writes. With
+    ``lead=0`` a row is ``[width]`` and its slab ``[1, 1, 1, width]``
+    at ``(layer, blk[r], off[r], 0)``."""
     def write(pool, rows):
-        zeros = (0,) * (rows.ndim - 2)
+        zeros = (0,) * (rows.ndim - 1 - lead)
         for r in range(rows.shape[0]):
             pool = lax.dynamic_update_slice(
-                pool, rows[r][None, None, :, None],
-                (layer, blk[r], 0, off[r]) + zeros)
+                pool, jnp.expand_dims(rows[r], lead)[None, None],
+                (layer, blk[r]) + (0,) * lead + (off[r],) + zeros)
         return pool
 
     if isinstance(pages, tuple):
@@ -252,19 +258,62 @@ def write_token(pages, layer, kv, table, position, active):
     """The stacked pools ``[L, N, H, bs, hd]`` (or the quantized tuple)
     with layer ``layer``'s new-token K (or V) ``kv [S, H, hd]`` written
     in place: :func:`scatter_token` on ``pages[layer]``, without taking
-    the layer out. ``layer`` may be traced."""
-    blk, off = _token_route(table, position, active, _block_size(pages))
-    return _write_rows(pages, layer, kv, blk, off)
+    the layer out. ``layer`` may be traced. A pool whose rows have no
+    leading axis (``[L, N, bs, width]``, ``kv [S, width]``) is written
+    the same way."""
+    lead = kv.ndim - 2           # row axes ahead of the block's tokens
+    blk, off = _token_route(table, position, active,
+                            _block_size(pages, lead))
+    return _write_rows(pages, layer, kv, blk, off, lead)
+
+
+def _write_pages(pool, layer, rows, table, start, n, write_from):
+    """A long span's rows ``[S, Q, width]`` written into layer ``layer``
+    of a pool ``[L, N, bs, width]`` a PAGE at a time: the span of slot
+    ``s`` touches at most ``ceil(Q / bs) + 1`` pages from the one that
+    holds ``start[s]`` on; each is read, its live rows (``start <= position
+    < start + n``, and not below ``write_from``) replaced, and written
+    back whole as one ``dynamic_update_slice``; a page with no live row is
+    the null block's. Straight-line code, in place like the row writes: 33
+    page writes for a 512-row chunk where the row writes were 512 (2,560
+    a five-layer chunk: 79 s of compile, PR 29)."""
+    S, Q, W = rows.shape
+    bs, MB = pool.shape[2], table.shape[1]
+    span = -(-Q // bs) + 1
+    rows = rows.astype(pool.dtype)
+    for s in range(S):
+        first, shift = start[s] // bs, start[s] % bs
+        padded = lax.dynamic_update_slice(
+            jnp.zeros((span * bs, W), pool.dtype), rows[s], (shift, 0))
+        pos = first * bs + jnp.arange(span * bs, dtype=jnp.int32)
+        live = (pos >= start[s]) & (pos < start[s] + n[s])
+        if write_from is not None:
+            live = live & (pos >= write_from[s])
+        for j in range(span):
+            rows_live = live[j * bs:(j + 1) * bs]
+            blk = jnp.where(jnp.any(rows_live),
+                            table[s, jnp.minimum(first + j, MB - 1)],
+                            NULL_BLOCK)
+            old = lax.dynamic_slice(pool, (layer, blk, 0, 0), (1, 1, bs, W))
+            page = jnp.where(rows_live[None, None, :, None],
+                             padded[j * bs:(j + 1) * bs][None, None], old)
+            pool = lax.dynamic_update_slice(pool, page, (layer, blk, 0, 0))
+    return pool
 
 
 def write_span(pages, layer, kv, table, start, n, write_from=None):
     """:func:`scatter_span` on ``pages[layer]`` in place: ``kv``
-    ``[S, Q, H, hd]``, one row write a token."""
+    ``[S, Q, H, hd]`` (or ``[S, Q, width]``), one row write a token; a
+    span of two pages and more into a pool of plain ``[width]`` rows goes
+    a page at a time (:func:`_write_pages`)."""
     S, Q = kv.shape[:2]
+    lead = kv.ndim - 3
+    if lead == 0 and Q >= 2 * _block_size(pages, lead):
+        return _write_pages(pages, layer, kv, table, start, n, write_from)
     blk, off = _span_route(table, start, n, write_from, Q,
-                           _block_size(pages))
+                           _block_size(pages, lead))
     return _write_rows(pages, layer, kv.reshape(S * Q, *kv.shape[2:]),
-                       blk.reshape(S * Q), off.reshape(S * Q))
+                       blk.reshape(S * Q), off.reshape(S * Q), lead)
 
 
 def scatter_prefill_pages(pages, kv, table, length, start=0):
@@ -594,19 +643,50 @@ class PagedKVCache:
     """Device pools + the authoritative host mirror of block tables and
     sequence lengths for up to ``max_slots`` concurrent sequences.
 
-    ``k``/``v`` are ``[L, num_blocks, H, block_size, hd]`` device arrays
-    (the leading layer axis matches the model's scan-over-layers stack, so
-    the decode scan consumes one layer's pool per iteration). The compiled
-    tick DONATES and returns them; the engine reassigns ``cache.k/.v``
-    each call. Tables/lengths live here as small host numpy arrays —
-    admission and eviction are plain host mutations between ticks."""
+    ``pools`` maps each pool's name to its device array: a model
+    DECLARES the paged state it keeps as named pools, each with the shape
+    of ONE token's row in one layer (``row_shapes``), and a row shape
+    ``(*lead, width)`` is allocated as ``[L, num_blocks, *lead,
+    block_size, width]``: the block's tokens sit ahead of the row's last
+    axis, so a page's last two dimensions are a whole ``[block_size,
+    width]`` tile. By default (``row_shapes=None``) the pools are
+    multi-head attention's ``k`` and ``v`` with rows ``(H, hd)``:
+    ``[L, num_blocks, H, block_size, hd]``, also reachable as
+    ``cache.k`` / ``cache.v``; a latent-attention model declares one
+    pool ``latent`` with rows ``(width,)``: ``[L, num_blocks, block_size,
+    width]``. The leading layer axis is the one the model's layer loop
+    indexes: it carries every layer's pool whole, writes rows in place
+    and reads pages by layer index. The compiled programs DONATE and
+    return the pools; the engine reassigns ``cache.pools`` each call.
+    Tables/lengths live here as small host numpy arrays — admission and
+    eviction are plain host mutations between ticks, and know nothing of
+    what a row holds.
 
-    def __init__(self, num_layers: int, num_heads: int, head_dim: int,
+    Int8 quantization, head sharding (``tp_degree``, ``shard_pools``) and
+    the page export / import of a handoff are defined for the ``k`` /
+    ``v`` pools only and refuse any other declaration."""
+
+    def __init__(self, num_layers: int, num_heads: Optional[int],
+                 head_dim: Optional[int],
                  num_blocks: int, block_size: int, max_slots: int,
                  max_blocks_per_seq: int, dtype=jnp.float32,
                  share_prefix: bool = False, kv_dtype: Optional[str] = None,
-                 retain_prefix: bool = True, tp_degree: int = 1):
+                 retain_prefix: bool = True, tp_degree: int = 1,
+                 row_shapes: Optional[Dict[str, Tuple[int, ...]]] = None):
         self.num_layers = num_layers
+        self.kv_pools = row_shapes is None
+        if self.kv_pools:
+            row_shapes = {"k": (num_heads, head_dim),
+                          "v": (num_heads, head_dim)}
+        else:
+            if kv_dtype == "int8" or tp_degree != 1:
+                raise ValueError(
+                    f"int8 pools and head-sharded pools are defined for "
+                    f"the k / v pools of multi-head attention only; this "
+                    f"model declares {sorted(row_shapes)}")
+            num_heads, head_dim = 1, None
+        self.row_shapes = {n: tuple(int(d) for d in r)
+                           for n, r in row_shapes.items()}
         self.num_heads = num_heads
         self.head_dim = head_dim
         # tensor-parallel degree (ISSUE 15): the pools are LOGICALLY
@@ -629,19 +709,17 @@ class PagedKVCache:
             raise ValueError(f"kv_dtype must be None|'f32'|'int8', "
                              f"got {kv_dtype!r}")
         self.quantized = kv_dtype == "int8"
-        shape = (num_layers, num_blocks, num_heads, block_size, head_dim)
-        if self.quantized:
-            # int8 value pages + per-block scale pages (one f32 per
-            # token row per head) — quantize-on-scatter writes both
-            # through the same block/offset routing
-            sshape = shape[:-1]
-            self.k = (jnp.zeros(shape, jnp.int8),
-                      jnp.zeros(sshape, jnp.float32))
-            self.v = (jnp.zeros(shape, jnp.int8),
-                      jnp.zeros(sshape, jnp.float32))
-        else:
-            self.k = jnp.zeros(shape, dtype)
-            self.v = jnp.zeros(shape, dtype)
+        self.pools: Dict[str, object] = {}
+        for name, row in self.row_shapes.items():
+            shape = (num_layers, num_blocks, *row[:-1], block_size, row[-1])
+            if self.quantized:
+                # int8 value pages + per-block scale pages (one f32 per
+                # token row per head) — quantize-on-scatter writes both
+                # through the same block/offset routing
+                self.pools[name] = (jnp.zeros(shape, jnp.int8),
+                                    jnp.zeros(shape[:-1], jnp.float32))
+            else:
+                self.pools[name] = jnp.zeros(shape, dtype)
         self.allocator = BlockAllocator(num_blocks)
         self.tables = np.zeros((max_slots, max_blocks_per_seq), np.int32)
         self.lengths = np.zeros((max_slots,), np.int32)
@@ -666,6 +744,28 @@ class PagedKVCache:
         self.retained_hits = 0
 
     # -- derived -----------------------------------------------------------
+
+    @property
+    def k(self):
+        return self.pools["k"]
+
+    @k.setter
+    def k(self, value):
+        self.pools["k"] = value
+
+    @property
+    def v(self):
+        return self.pools["v"]
+
+    @v.setter
+    def v(self, value):
+        self.pools["v"] = value
+
+    def _kv_only(self, what: str) -> None:
+        if not self.kv_pools:
+            raise NotImplementedError(
+                f"{what} is defined for the k / v pools of multi-head "
+                f"attention only; this cache holds {sorted(self.pools)}")
 
     @property
     def context_width(self) -> int:
@@ -701,6 +801,9 @@ class PagedKVCache:
         ``num_heads / tp_degree`` heads of every row (ISSUE 15), so this
         is the number a device's HBM budget divides by — capacity scales
         with the mesh."""
+        if not self.kv_pools:
+            return self.num_layers * jnp.dtype(self.dtype).itemsize * sum(
+                int(np.prod(r)) for r in self.row_shapes.values())
         if self.quantized:
             per_head = self.head_dim * 1 + 4          # int8 + f32 scale
         else:
@@ -833,6 +936,7 @@ class PagedKVCache:
         ids don't travel; the receiver re-homes the pages at its own
         allocations. Shared/adopted blocks export fine (it's a read);
         only blocks covering the length ship, not the reservation."""
+        self._kv_only("export_pages")
         nb = self.blocks_needed(int(self.lengths[slot]))
         ids = [int(b) for b in self.tables[slot, :nb]]
         sel = jnp.asarray(ids, jnp.int32)
@@ -854,6 +958,7 @@ class PagedKVCache:
         Returns False (nothing changed) when the pool can't supply the
         blocks — the decode side's backpressure; the fleet retries or
         re-routes the handoff."""
+        self._kv_only("import_pages")
         assert not self._owned[slot], "import_pages on a non-empty slot"
         target = max(int(length), int(reserve_len or 0))
         if not self.ensure_capacity(slot, target):
@@ -925,6 +1030,7 @@ class PagedKVCache:
         one logical block table while the bytes live distributed."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
+        self._kv_only("shard_pools")
         # TRIMMED spec (no trailing None): matches the normalized form
         # `tp_constrain` pins on the compiled programs' pool outputs, so
         # the carry's sharding hashes identical call to call (padded vs
@@ -942,8 +1048,16 @@ class PagedKVCache:
         self.v = put(self.v)
 
     def device_tables(self) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """The current (tables, lengths) as device operands for a tick.
-        Copies, never views: on the CPU backend ``jnp.asarray`` may alias
-        a numpy buffer zero-copy, and the engine bumps ``lengths`` in
-        place while the tick that reads them is still in flight."""
-        return jnp.array(self.tables), jnp.array(self.lengths)
+        """The current (tables, lengths) as device operands for a tick,
+        made from numpy COPIES that nobody else holds: the engine bumps
+        ``lengths`` in place while the tick that reads them is still in
+        flight, and neither ``jnp.asarray`` nor ``jnp.array`` of the live
+        array is safe against that. ``jnp.asarray`` may alias a numpy
+        buffer zero-copy; ``jnp.array`` copies, but under asynchronous
+        dispatch the copy is made when the transfer runs, not when the
+        call returns, so with a prefill chunk still in flight ahead of it
+        the tick read lengths one too long (measured on the CPU backend,
+        PR 29: 17 of 40 runs of one scenario served another token; none
+        of 40 with the copies taken here)."""
+        return (jnp.asarray(self.tables.copy()),
+                jnp.asarray(self.lengths.copy()))

@@ -28,9 +28,10 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu.core import mesh as mesh_lib
-from paddle_tpu.models import TransformerLM
+from paddle_tpu.models import LatentMoELM, TransformerLM
 from paddle_tpu.nn import MultiHeadAttention, pallas_mode
 from paddle_tpu.nn.pallas_attention import (flash_attention,
+                                            latent_paged_decode,
                                             paged_decode_attention,
                                             paged_span_attention)
 from paddle_tpu.parallel.sharding import tp_shard_scope
@@ -77,6 +78,22 @@ def test_paged_kernels_lower(kind, heads, dh):
         lower_tpu(functools.partial(paged_span_attention, interpret=False),
                   sds((SLOTS, q_len, heads, dh), jnp.float32), pages, pages,
                   tables, vec, vec, layer)
+
+
+@pytest.mark.parametrize("kind", ["float32", "bfloat16"])
+def test_latent_decode_kernel_lowers(kind):
+    """The latent decode kernel at the published widths: 128 heads
+    against pages of 16 rows of 576 values stored in 640 columns, the
+    first 512 of them the values, 16 and 32 pages a group."""
+    heads, row, values, layers = 128, 640, 512, 5
+    for group in (16, 32):
+        lower_tpu(functools.partial(latent_paged_decode, value_width=values,
+                                    scale=192 ** -0.5, group=group,
+                                    interpret=False),
+                  sds((SLOTS, heads, row), jnp.dtype(kind)),
+                  sds((layers, N, BS, row), jnp.dtype(kind)),
+                  sds((SLOTS, MB), jnp.int32), sds((SLOTS,), jnp.int32),
+                  sds((), jnp.int32))
 
 
 @pytest.mark.parametrize("segmented", [False, True])
@@ -180,12 +197,31 @@ def pool_sized_results(text, sizes):
     return found
 
 
+def toy_latent_engine(blocks, **kw):
+    """A toy of the latent-attention expert model at the lane tile's
+    widths: two layers (one dense, one of 8 experts with 4 held), a
+    latent row of 128 + 64 values stored in 256 columns, bfloat16
+    weights and pool, a chunked prefill."""
+    model = LatentMoELM(vocab=512, dim=256, num_layers=2,
+                        num_dense_layers=1, num_heads=4, q_rank=128,
+                        kv_rank=128, nope_dim=128, rope_dim=64, v_dim=128,
+                        dense_hidden=512, expert_hidden=256, num_experts=8,
+                        top_k=2, experts_held=(2, 4), max_len=256)
+    variables = jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16),
+        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    return DecodeEngine(model, variables, max_slots=4, block_size=BS,
+                        num_blocks=blocks, attention="paged",
+                        prefill_chunk=32, dtype="bfloat16", **kw)
+
+
 # pools of 160 MB: one that fits the chip's 128 MiB of fast memory is
 # prefetched there whole, and its writes with it
 @pytest.mark.parametrize("kv_dtype,blocks,speculative",
                          [(None, 2449, 0), ("int8", 9793, 0),
-                          (None, 2449, 4)],
-                         ids=["float32", "int8", "float32-speculative4"])
+                          (None, 2449, 4), ("latent", 19593, 0)],
+                         ids=["float32", "int8", "float32-speculative4",
+                              "latent-bfloat16"])
 def test_tick_leaves_the_pools_in_place(one_chip, monkeypatch, kv_dtype,
                                         blocks, speculative):
     """The decode tick (and speculation's verify tick) of a toy engine,
@@ -194,24 +230,33 @@ def test_tick_leaves_the_pools_in_place(one_chip, monkeypatch, kv_dtype,
     of a pool or of one layer's pool, (b) the program's temporaries are
     smaller than one pool. The tick that scanned the pools as ``xs`` /
     ``ys`` sliced every layer out, copied it to the scatter's layout and
-    back, collected it, and copied both pools whole at the end."""
+    back, collected it, and copied both pools whole at the end. The
+    same holds for whatever pools a model declares: the latent case is a
+    ``LatentMoELM``'s one pool of latent rows, written by its unrolled
+    layers and read by ``latent_paged_decode``."""
     monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
     layers, heads, dh = 2, 4, 128
-    model = TransformerLM(vocab=512, dim=heads * dh, num_layers=layers,
-                          num_heads=heads, ffn_hidden=1024, max_len=256)
-    variables = model.init(jax.random.PRNGKey(0),
-                           jnp.zeros((1, 8), jnp.int32))
-    engine = DecodeEngine(model, variables, max_slots=4, block_size=BS,
-                          num_blocks=blocks, attention="paged",
-                          kv_dtype=kv_dtype, speculative=speculative)
+    if kv_dtype == "latent":
+        engine = toy_latent_engine(blocks)
+    else:
+        model = TransformerLM(vocab=512, dim=heads * dh, num_layers=layers,
+                              num_heads=heads, ffn_hidden=1024, max_len=256)
+        variables = model.init(jax.random.PRNGKey(0),
+                               jnp.zeros((1, 8), jnp.int32))
+        engine = DecodeEngine(model, variables, max_slots=4, block_size=BS,
+                              num_blocks=blocks, attention="paged",
+                              kv_dtype=kv_dtype, speculative=speculative)
     args = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         engine._tick_args())
     compiled = engine._tick_fn.lower(*args).compile()
+    if kv_dtype == "latent":
+        assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
 
     # a quantized pool's values; its scale pages, a thirty-second of its
     # bytes, XLA re-lays once a tick at the program's entry
-    leaves = jax.tree_util.tree_leaves(engine.cache.k)
+    leaves = jax.tree_util.tree_leaves(
+        next(iter(engine.cache.pools.values())))
     values = leaves[0].size
     held = pool_sized_results(compiled.as_text(),
                               {values, values // layers})

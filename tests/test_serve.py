@@ -807,6 +807,36 @@ def test_speculative_composes_with_sampling_rejection_rule(model_and_vars,
 # ISSUE 12: chunked prefill
 # ---------------------------------------------------------------------------
 
+def test_every_admission_in_progress_runs_one_chunk_between_ticks(
+        model_and_vars, nprng):
+    """The scheduler's one interleaving policy: between two decode ticks
+    every admission in progress runs ONE chunk, so a running slot's
+    inter-token gap is a tick and as many chunks as admissions overlap
+    (the benchmark's closed-loop cells read their tail from this), and
+    never two chunks of one admission."""
+    model, vs = model_and_vars
+    prompts = [list(nprng.randint(0, V, n)) for n in (3, 17, 18, 15, 16)]
+    eng = DecodeEngine(model, vs, max_slots=4, block_size=BS,
+                       prefill_chunk=4)
+    calls = []
+    step, tick = eng.prefill_step, eng.decode_tick
+    eng.prefill_step = lambda slot: (calls.append(slot), step(slot))[1]
+    eng.decode_tick = lambda: (calls.append("t"), tick())[1]
+    sched = ContinuousBatchingScheduler(eng)
+    reqs = [sched.submit(list(p), 6) for p in prompts]
+    sched.run()
+    assert all(len(r.tokens) == 6 for r in reqs)
+    runs, run = [], []
+    for c in calls:
+        if c == "t":
+            runs.append(run)
+            run = []
+        else:
+            run.append(c)
+    assert max(len(r) for r in runs) > 1          # admissions overlap
+    assert all(len(set(r)) == len(r) for r in runs)
+
+
 def test_chunked_prefill_bit_equal_and_interleaves(model_and_vars, nprng):
     """Chunked prefill produces the same first token and generation as
     the monolithic prefill (bit-equal span rows), and a long admission
