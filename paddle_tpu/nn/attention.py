@@ -30,7 +30,7 @@ __all__ = ["AdditiveAttention", "DotProductAttention", "MultiHeadAttention",
 def _tp_paged_kernel(kernel, q, pages_k, pages_v, *rest, head_dim: int):
     """Run a paged Pallas kernel PER SHARD over the active tp scope's
     head groups (ISSUE 15): the kernel is head-parallel by construction
-    (its grid iterates heads independently), so a ``shard_map`` over the
+    (every head's softmax is its own), so a ``shard_map`` over the
     model axis hands each device its ``H/tp`` local heads of the query
     and of every pool block — block tables and lengths replicate. With
     no scope active the kernel runs whole, unchanged. ``head_dim`` is

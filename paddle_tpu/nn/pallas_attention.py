@@ -624,61 +624,108 @@ def _kv_blocks(k_ref, v_ref, sk_ref, sv_ref, head):
             sk_ref[row, :], sv_ref[row, :])
 
 
-def _paged_decode_kernel(tbl_ref, len_ref, lay_ref, q_ref, k_ref, v_ref,
-                         *rest, scale, bs, quant):
-    """One (slot, head) row's online softmax over its block table. Grid
-    ``(S, H, MB)``: the innermost axis streams the slot's KV blocks
-    (sequential on TPU — the m/l/acc scratch carries across it), with the
-    pool block resolved by the PREFETCHED block table in the index map,
-    so the DMA fetches exactly the pages the sequence owns. With
-    ``quant`` the K/V blocks arrive int8 with per-row scale pages and
-    are dequantized IN VMEM (never in HBM — the whole point of the int8
-    pool is HBM bytes; :func:`_kv_blocks`)."""
-    if quant:
-        sk_ref, sv_ref, o_ref, m_s, l_s, acc_s = rest
-    else:
-        sk_ref = sv_ref = None
-        o_ref, m_s, l_s, acc_s = rest
+def _walk_page_groups(n_groups, copies, multiply):
+    """The paged kernels' loop over ONE slot's page groups, as many as
+    ``n_groups`` (traced) says and no more, double-buffered: group
+    ``g + 1``'s copies (``copies(g, buffer)``: the DMAs that fill that
+    buffer) are in flight while ``multiply(g, buffer)`` works on group
+    ``g``."""
+    @pl.when(n_groups > 0)
+    def _():
+        for c in copies(0, 0):
+            c.start()
+
+    def body(g, carry):
+        slot = g % 2
+
+        @pl.when(g + 1 < n_groups)
+        def _():
+            for c in copies(g + 1, 1 - slot):
+                c.start()
+
+        for c in copies(g, slot):
+            c.wait()
+        multiply(g, slot)
+        return carry
+
+    jax.lax.fori_loop(0, n_groups, body, 0)
+
+
+# what one slot's K and V page groups may hold of VMEM, both of them
+# double-buffered: at 128 KB an all-heads page (16 heads x 16 rows x 128
+# float32) that is 4 pages a group (on the chip 4, 8 and 16 pages read
+# 67.7%, 64.8% and 58.3% of the bytes' roofline: the last group's unused
+# pages cost more than the loop's turns)
+_PAGE_GROUP_BYTES = 2 << 20
+# the page copies of a group are unrolled: no more than this many
+_PAGE_GROUP_MAX = 32
+
+
+def _pages_per_group(page_bytes, max_blocks):
+    """Pages :func:`paged_decode_attention` multiplies at a time, from the
+    bytes of one all-heads page against :data:`_PAGE_GROUP_BYTES`."""
+    return max(1, min(_PAGE_GROUP_BYTES // (4 * page_bytes),
+                      _PAGE_GROUP_MAX, max_blocks))
+
+
+def _paged_decode_kernel(tbl_ref, len_ref, lay_ref, q_ref, k_hbm, v_hbm,
+                         o_ref, kbuf, vbuf, sem, m_s, l_s, acc_s, *, scale):
+    """One slot's heads against its own pages. Grid ``(S,)``; the pools
+    stay in HBM and a loop walks the slot's page GROUPS, as many as its
+    length needs and no more (an inactive slot, length 0, copies nothing
+    and writes zeros). ``pool[layer, block]`` is every head's page in one
+    contiguous ``[H, bs, D]`` region, so one DMA a page brings all heads'
+    K and one their V, double-buffered: group ``g + 1`` is in flight
+    while group ``g`` is multiplied. A head's query is one row, which the
+    MXU would take a whole tile of K as weights to push through; both
+    products are the VPU's instead (a broadcast multiply and a sum, in
+    float32 whatever the pool holds: bfloat16 pages are widened in VMEM),
+    with a page's rows on sublanes throughout: the scores
+    ``[G, H, bs, 1]`` never change layout, and the online softmax runs
+    once a group."""
+    _, G, H, bs, D = kbuf.shape
     s_idx = pl.program_id(0)
-    head = pl.program_id(1)
-    j = pl.program_id(2)
-    nkb = pl.num_programs(2)
-
-    @pl.when(j == 0)
-    def _():
-        m_s[:] = jnp.full(m_s.shape, _NEG, jnp.float32)
-        l_s[:] = jnp.zeros(l_s.shape, jnp.float32)
-        acc_s[:] = jnp.zeros(acc_s.shape, jnp.float32)
-
     length = len_ref[s_idx]
+    lay = lay_ref[0]
+    T = G * bs
+    n_groups = (length + T - 1) // T
+    last = tbl_ref.shape[1] - 1
 
-    # blocks past the sequence length are skipped entirely (an inactive
-    # slot — length 0 — skips every block and writes zeros)
-    @pl.when(j * bs < length)
-    def _():
-        # native-dtype matmul operands + f32 accumulate (see _attn_kernel)
-        kb, vb, sk, sv = _kv_blocks(k_ref, v_ref, sk_ref, sv_ref, head)
-        s = jax.lax.dot_general(q_ref[:], kb, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if quant:
-            s = s * sk
-        k_idx = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-        s = jnp.where(k_idx < length, s, _NEG)
-        m = m_s[:]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
+    def copies(g, slot):
+        out = []
+        for i in range(G):
+            # past the slot's last page: any page it may read, masked below
+            block = tbl_ref[s_idx, jnp.minimum(g * G + i, last)]
+            out += [pltpu.make_async_copy(pool.at[lay, block],
+                                          buf.at[slot, i], sem.at[slot])
+                    for pool, buf in ((k_hbm, kbuf), (v_hbm, vbuf))]
+        return out
+
+    m_s[:] = jnp.full(m_s.shape, _NEG, jnp.float32)
+    l_s[:] = jnp.zeros(l_s.shape, jnp.float32)
+    acc_s[:] = jnp.zeros(acc_s.shape, jnp.float32)
+    q = q_ref[:].astype(jnp.float32) * scale                  # [H, 1, D]
+    # a row's position within its group
+    pos = (jax.lax.broadcasted_iota(jnp.int32, (G, H, bs, 1), 0) * bs
+           + jax.lax.broadcasted_iota(jnp.int32, (G, H, bs, 1), 2))
+
+    def multiply(g, slot):
+        k = kbuf[slot].astype(jnp.float32)                    # [G, H, bs, D]
+        s = jnp.sum(k * q[None], axis=-1, keepdims=True)      # [G, H, bs, 1]
+        s = jnp.where(g * T + pos < length, s, _NEG)
+        m = m_s[:]                                            # [H, 1, 1]
+        m_new = jnp.maximum(m, jnp.max(s, axis=(0, 2), keepdims=True)[0])
+        p = jnp.exp(s - m_new[None])
         corr = jnp.exp(m - m_new)
-        l_s[:] = l_s[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        pv = p * sv if quant else p
-        acc_s[:] = acc_s[:] * corr + jax.lax.dot_general(
-            pv.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        l_s[:] = l_s[:] * corr + jnp.sum(p, axis=(0, 2), keepdims=True)[0]
+        v = vbuf[slot].astype(jnp.float32)
+        # a page's rows are summed once, after the last group
+        acc_s[:] = acc_s[:] * corr + jnp.sum(p * v, axis=0)   # [H, bs, D]
         m_s[:] = m_new
 
-    @pl.when(j == nkb - 1)
-    def _():
-        l = jnp.maximum(l_s[:], 1e-30)
-        o_ref[:] = (acc_s[:] / l).astype(o_ref.dtype)
+    _walk_page_groups(n_groups, copies, multiply)
+    out = jnp.sum(acc_s[:], axis=1, keepdims=True)            # [H, 1, D]
+    o_ref[:] = (out / jnp.maximum(l_s[:], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, pages_k, pages_v, tables, lengths, layer,
@@ -687,86 +734,91 @@ def paged_decode_attention(q, pages_k, pages_v, tables, lengths, layer,
     """Decode-shaped (q_len = 1) flash attention over a paged KV cache.
 
     The serving hot op: each active slot attends its single new-token
-    query against the KV blocks its block table names, streaming block by
-    block with the online softmax (lse-correct across the slot's ragged
-    length; within-block tail positions masked). The block table and
-    lengths are SCALAR-PREFETCHED (``pltpu.PrefetchScalarGridSpec``) so
-    the K/V index maps resolve pool pages before each grid step's DMA —
-    the kernel never touches blocks the sequence does not own, which is
-    what makes the pool's ragged sharing free.
+    query against the KV blocks its block table names, a group of pages
+    at a time with the online softmax (lse-correct across the slot's
+    ragged length; tail positions masked). The pools stay in HBM
+    (``memory_space=pl.ANY``) and the kernel copies the pages the slot's
+    SCALAR-PREFETCHED block table names, up to its length: it never
+    touches blocks the sequence does not own, which is what makes the
+    pool's ragged sharing free, and nothing is sliced, gathered or laid
+    out anew outside it.
 
     Args: ``q`` ``[S, H, D]`` (slot-major, one token per slot);
     ``pages_k``/``pages_v`` ``[L, N, H, bs, D]``: EVERY layer's pool,
     of which the kernel reads layer ``layer`` (heads ahead of the page's
-    tokens, so each K/V block is one head's whole ``[bs, D]`` page: the
-    TPU lowering requires a block's last two dimensions to be
-    tile-aligned or whole), or the quantized ``(int8 values, scales
-    [L, N, H, bs])`` tuple — scale pages stream beside the value blocks
-    and dequantization happens in VMEM; ``tables`` ``[S, MB]`` int32;
-    ``lengths`` ``[S]`` int32 — the number of valid tokens INCLUDING the
-    one just written; 0 marks an inactive slot (zero output); ``layer``
-    an int32 scalar, traced or not: the third prefetched operand, so
-    the serving tick's layer scan hands the kernel its carried pools
-    whole and no layer is ever sliced out of them. ``interpret``
-    defaults to True off-TPU (same contract as
-    :func:`flash_attention`)."""
+    tokens, so ``pool[layer, block]`` is every head's page in one
+    contiguous region and one copy brings it), or the quantized ``(int8
+    values, scales [L, N, H, bs])`` tuple, dequantized in VMEM;
+    ``tables`` ``[S, MB]`` int32; ``lengths`` ``[S]`` int32 — the number
+    of valid tokens INCLUDING the one just written; 0 marks an inactive
+    slot (zero output); ``layer`` an int32 scalar, traced or not: the
+    third prefetched operand, so the serving tick's layer scan hands the
+    kernel its carried pools whole and no layer is ever sliced out of
+    them. How many pages make a group follows from the shapes
+    (:func:`_pages_per_group`). ``interpret`` defaults to True off-TPU
+    (same contract as :func:`flash_attention`).
+
+    Mosaic copies no window narrower than its 128 lanes out of an array
+    in HBM: not a quantized pool's ``[H, bs]`` scale page, not a page of
+    head size 64. Those pools keep the ``(slot, head, page)`` grid, whose
+    BlockSpec pipeline can: the span kernel's at ``Q = 1``, under this
+    kernel's name."""
     S, H, D = q.shape
-    pages_k, scale_k = _unpack_pages(pages_k)
-    pages_v, scale_v = _unpack_pages(pages_v)
-    quant = scale_k is not None
+    if isinstance(pages_k, tuple) or D % 128:
+        return _paged_span_call(
+            q[:, None], pages_k, pages_v, tables,
+            jnp.maximum(lengths - 1, 0), (lengths > 0).astype(jnp.int32),
+            layer, scale, interpret, name="paged_decode")[:, 0]
     L, N, Hk, bs, Dk = pages_k.shape
     assert (H, D) == (Hk, Dk), f"q heads {(H, D)} != pages {(Hk, Dk)}"
-    MB = tables.shape[1]
     scale, interpret = _resolve_defaults(q, scale, interpret)
-    q4 = q.reshape(S, H, 1, D)
+    group = _pages_per_group(H * bs * D * pages_k.dtype.itemsize,
+                             tables.shape[1])
 
-    def q_map(s, h, j, tbl, lens, lay):
-        return (s, h, 0, 0)
+    def q_map(s, tbl, lens, lay):
+        return (s, 0, 0, 0)
 
-    def kv_map(s, h, j, tbl, lens, lay):
-        return (lay[0], tbl[s, j], h, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((None, None, 1, D), q_map),
-        pl.BlockSpec((None, None, None, bs, D), kv_map),
-        pl.BlockSpec((None, None, None, bs, D), kv_map),
-    ]
-    operands = [q4, pages_k, pages_v]
-    if quant:
-        in_specs += [_scale_spec(H, bs, kv_map)] * 2
-        operands += [scale_k, scale_v]
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(S, H, MB),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, None, 1, D), q_map),
+        grid=(S,),
+        in_specs=[pl.BlockSpec((None, H, 1, D), q_map), hbm, hbm],
+        out_specs=pl.BlockSpec((None, H, 1, D), q_map),
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, D), jnp.float32),
+            pltpu.VMEM((2, group, H, bs, D), pages_k.dtype),
+            pltpu.VMEM((2, group, H, bs, D), pages_v.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((H, 1, 1), jnp.float32),
+            pltpu.VMEM((H, 1, 1), jnp.float32),
+            pltpu.VMEM((H, bs, D), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, scale=scale, bs=bs,
-                          quant=quant),
+        functools.partial(_paged_decode_kernel, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, 1, D), q.dtype),
         interpret=interpret, name="paged_decode",
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
-      _layer_operand(layer), *operands)
+      _layer_operand(layer), q.reshape(S, H, 1, D), pages_k, pages_v)
     return out.reshape(S, H, D)
 
 
 def _paged_span_kernel(tbl_ref, start_ref, n_ref, lay_ref, q_ref, k_ref,
                        v_ref, *rest, scale, bs, quant):
-    """One (slot, head) SPAN's online softmax over its block table — the
-    q_len = 1+k generalization of :func:`_paged_decode_kernel` (ISSUE
-    14). Grid ``(S, H, MB)`` with the span's ``Q`` rows resident in one
-    VMEM block and per-row online-softmax state ``[Q, 1]``/``[Q, D]``;
-    causality WITHIN the span is a per-element mask (row ``j`` sees
-    positions ``<= start + j``), so the speculative verify tick and
-    chunked prefill stream exactly the pages the slot owns instead of
-    materializing an O(W)-per-row XLA gather."""
+    """One (slot, head) SPAN's online softmax over its block table
+    (ISSUE 14). Grid ``(S, H, MB)``: the innermost axis streams the
+    slot's KV blocks (sequential on TPU — the m/l/acc scratch carries
+    across it), with the pool block resolved by the PREFETCHED block
+    table in the index map, so the DMA fetches exactly the pages the
+    sequence owns. The span's ``Q`` rows are resident in one VMEM block
+    with per-row online-softmax state ``[Q, 1]``/``[Q, D]``; causality
+    WITHIN the span is a per-element mask (row ``j`` sees positions
+    ``<= start + j``), so the speculative verify tick and chunked
+    prefill stream exactly the pages the slot owns instead of
+    materializing an O(W)-per-row XLA gather. With ``quant`` the K/V
+    blocks arrive int8 with per-row scale pages and are dequantized IN
+    VMEM (never in HBM — the whole point of the int8 pool is HBM bytes;
+    :func:`_kv_blocks`)."""
     if quant:
         sk_ref, sv_ref, o_ref, m_s, l_s, acc_s = rest
     else:
@@ -837,10 +889,17 @@ def paged_span_attention(q, pages_k, pages_v, tables, start, n, layer,
     :func:`paged_decode_attention`; ``tables`` ``[S, MB]``;
     ``start``/``n`` ``[S]`` int32 — rows
     ``>= n[s]`` are padding (finite garbage output the host ignores),
-    ``n == 0`` marks an inactive slot (zero output). At ``Q = 1`` the
-    kernel runs the exact op sequence of
-    :func:`paged_decode_attention` (bit-equal — the greedy-path
-    contract). ``interpret`` defaults to True off-TPU."""
+    ``n == 0`` marks an inactive slot (zero output). At ``Q = 1`` it
+    agrees with :func:`paged_decode_attention` to rounding, as both do
+    with their oracles, and no closer: that kernel sums a group of pages
+    at a time. ``interpret`` defaults to True off-TPU."""
+    return _paged_span_call(q, pages_k, pages_v, tables, start, n, layer,
+                            scale, interpret, name="paged_span")
+
+
+def _paged_span_call(q, pages_k, pages_v, tables, start, n, layer, scale,
+                     interpret, name):
+    """:func:`paged_span_attention` as the Mosaic kernel ``name``."""
     S, Q, H, D = q.shape
     pages_k, scale_k = _unpack_pages(pages_k)
     pages_v, scale_v = _unpack_pages(pages_v)
@@ -882,7 +941,7 @@ def paged_span_attention(q, pages_k, pages_v, tables, start, n, layer,
                           quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, Q, D), q.dtype),
-        interpret=interpret, name="paged_span",
+        interpret=interpret, name=name,
     )(tables.astype(jnp.int32), start.astype(jnp.int32),
       n.astype(jnp.int32), _layer_operand(layer), *operands)
     return jnp.swapaxes(out, 1, 2)           # [S, Q, H, D]
@@ -945,21 +1004,7 @@ def _latent_decode_kernel(tbl_ref, len_ref, lay_ref, q_ref, pool_ref, o_ref,
     l_s[:] = jnp.zeros(l_s.shape, jnp.float32)
     acc_s[:] = jnp.zeros(acc_s.shape, jnp.float32)
 
-    @pl.when(n_groups > 0)
-    def _():
-        for c in copies(0, 0):
-            c.start()
-
-    def body(g, carry):
-        slot = g % 2
-
-        @pl.when(g + 1 < n_groups)
-        def _():
-            for c in copies(g + 1, 1 - slot):
-                c.start()
-
-        for c in copies(g, slot):
-            c.wait()
+    def multiply(g, slot):
         rows = buf[slot]                                   # [T, W]
         s = jax.lax.dot_general(q_ref[:], rows, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
@@ -974,9 +1019,8 @@ def _latent_decode_kernel(tbl_ref, len_ref, lay_ref, q_ref, pool_ref, o_ref,
             p.astype(rows.dtype), rows[:, :value_width],
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         m_s[:] = m_new
-        return carry
 
-    jax.lax.fori_loop(0, n_groups, body, 0)
+    _walk_page_groups(n_groups, copies, multiply)
     o_ref[:] = (acc_s[:] / jnp.maximum(l_s[:], 1e-30)).astype(o_ref.dtype)
 
 

@@ -63,10 +63,12 @@ def pool(kind, heads, dh, layers=2):
 
 @pytest.mark.parametrize("kind", ["float32", "bfloat16", "int8"])
 @pytest.mark.parametrize("heads,dh", [(HEADS, DH), (HEADS // 4, DH),
-                                      (HEADS, 64)])
+                                      (HEADS, 64), (16, DH)])
 def test_paged_kernels_lower(kind, heads, dh):
     """Decode (Q=1), speculative verify (Q=5) and prefill chunk (Q=256)
-    over f32 / bf16 / int8 pools; ``heads // 4`` is one tp=4 shard."""
+    over f32 / bf16 / int8 pools; ``heads // 4`` is one tp=4 shard, 16
+    heads of 128 the ``serve-1p3b-closed8`` cell's own shape (8 slots of
+    128 blocks, float32 there)."""
     pages = pool(kind, heads, dh)
     tables = sds((SLOTS, MB), jnp.int32)
     vec = sds((SLOTS,), jnp.int32)
@@ -158,6 +160,34 @@ def one_chip():
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("kind", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("heads,dh", [(16, DH), (HEADS // 4, DH),
+                                      (HEADS, 64)])
+def test_paged_decode_compiles(one_chip, kind, heads, dh):
+    """Mosaic's own compile of the decode kernel, which the lowering
+    above does not run: the kernel copies whole pages out of a pool in
+    HBM, and Mosaic refuses such a copy where the page is narrower than
+    its 128 lanes (head size 64, an int8 pool's scale pages): those
+    pools take the grid. One Mosaic kernel named ``paged_decode`` and no
+    temporary the size of a layer's pool either way, head size 64 apart:
+    XLA re-lays a pool of half-filled lanes for any Mosaic kernel."""
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    pages = jax.tree_util.tree_map(on_chip, pool(kind, heads, dh))
+    compiled = jax.jit(
+        functools.partial(paged_decode_attention, interpret=False)).lower(
+            on_chip(sds((SLOTS, heads, dh), jnp.float32)), pages, pages,
+            on_chip(sds((SLOTS, MB), jnp.int32)),
+            on_chip(sds((SLOTS,), jnp.int32)),
+            on_chip(sds((), jnp.int32))).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and "paged_decode" in calls[0], calls
+    if dh % 128 == 0:
+        layer_bytes = N * heads * BS * dh * jnp.dtype(kind).itemsize
+        assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
 
 
 # an instruction: its name, whether its result is a tuple, the (first)
