@@ -241,8 +241,8 @@ def serve_leg(sizes: Sizes, model, variables, name: str, n_requests: int,
 
 def serve_legs(sizes: Sizes, model, variables, **engine_kwargs) -> None:
     """The plain tick under admission and eviction (more requests than
-    slots), then the three other tick variants of ``bench.DEFAULT_PLAN``
-    that run different kernel code, each on the same weights."""
+    slots), then the three other tick variants that run different kernel
+    code, each on the same weights."""
     assert sizes.requests > sizes.slots
     serve_leg(sizes, model, variables, "plain", sizes.requests,
               "paged_decode", **engine_kwargs)

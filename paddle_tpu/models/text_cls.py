@@ -2,9 +2,8 @@
 
 Reference: ``/root/reference/benchmark/paddle/rnn/rnn.py`` (embedding ->
 2 x lstm -> fc over the last step; the published anchor is 184 ms/batch at
-bs64 h512 seq100 vocab30k on 1xK40m, BASELINE.md). Library model so the
-benchmark (``bench.py --metric lstm``) measures the same code users train —
-benchmark-only model definitions are how perf regressions hide.
+bs64 h512 seq100 vocab30k on 1xK40m, BASELINE.md). A library model, so
+that whatever measures it measures the code users train.
 """
 
 from __future__ import annotations

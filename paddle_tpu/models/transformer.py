@@ -57,8 +57,7 @@ class TransformerBlock(Module):
         # Constraining residuals to a seq-sharded spec (e.g.
         # P("data", "model", None)) turns Megatron tensor-parallel's
         # activation all-reduces into reduce-scatter/all-gather pairs —
-        # sequence-parallel residuals, halving tp wire bytes
-        # (experiments/scaling_projection.py quantifies it).
+        # sequence-parallel residuals, halving tp wire bytes.
         self.residual_sharding = residual_sharding
         self.ln1 = LayerNorm()
         self.attn = MultiHeadAttention(num_heads, use_flash=use_flash,

@@ -250,7 +250,7 @@ class MultiHeadAttention(Module):
                     f"flash path needs seq len divisible by 8; pad T={T}")
             # block sizes auto-select in the kernel (large blocks: the
             # per-grid-step overhead dominated at the old fixed 128 —
-            # measured 5x per-layer, experiments/profile_transformer.py).
+            # measured 5x per-layer on v5e, see _auto_block).
             # The kernel takes its operands in the policy's compute
             # dtype (bf16 pairs multiply into f32 on the MXU at full
             # rate) and saves q/k/v/out for its backward in that dtype:

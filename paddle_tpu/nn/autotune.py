@@ -33,7 +33,7 @@ Contract (the zero-overhead pin, PR-2/4 style):
 Trial timing goes through :func:`time_kernel`, which fences with
 ``jax.block_until_ready`` and discards the first (compile) iteration —
 timing the enqueue or the compile instead of the kernel was the bug the
-shared util exists to delete (bench.py's steady-state loops use it too).
+shared util exists to delete.
 """
 
 from __future__ import annotations

@@ -122,7 +122,7 @@ class Embedding(Module):
 # How 1x1 convs lower: "conv" = lax.conv_general_dilated; "matmul" =
 # reshape + dot (XLA's matmul path — different tiling than its conv path);
 # "pallas" = matmul forward + Pallas dW reduction kernel
-# (nn/pallas_conv.py). Measured per-shape in experiments/conv1x1_backward.py.
+# (nn/pallas_conv.py).
 _CONV1X1_IMPL = "conv"
 
 
@@ -131,8 +131,7 @@ def set_conv1x1_impl(impl: str) -> str:
 
     TRACE-TIME semantics: the global is read when a step is traced, and jit
     caches do NOT key on it — any function already jitted keeps the lowering
-    it was traced with. Call this BEFORE building/jitting the step (the bench
-    children set it via ``BENCH_CONV1X1_IMPL`` at process start); toggling
+    it was traced with. Call this BEFORE building/jitting the step; toggling
     after compilation silently has no effect on cached executables."""
     global _CONV1X1_IMPL
     assert impl in ("conv", "matmul", "pallas"), impl
@@ -386,7 +385,7 @@ class BatchNorm(Module):
         # E[x^2]-E[x]^2 form: sum and sum-of-squares are independent
         # reductions XLA multi-output-fuses into a single read of x, where
         # mean-then-var would read the activation twice (measured ~2x BN
-        # stat cost on the ResNet-50 step, experiments/profile_resnet50.py).
+        # stat cost on the ResNet-50 step, v5e).
         xf = x.astype(jnp.float32)
         if train:
             n = x.size // c

@@ -14,9 +14,8 @@ BN-backward reductions run at 5-11%. A 1x1 stride-1 conv IS a matmul
 - :func:`conv1x1_strided` — the stride-s variant (the bottleneck shortcut):
   slice then matmul; the slice VJP is a scatter XLA handles well.
 
-``experiments/conv1x1_backward.py`` measures this form against
-``lax.conv_general_dilated`` per bottleneck shape; ``nn.layers.Conv2D``
-routes 1x1 convs here when ``set_conv1x1_impl`` selects it.
+``nn.layers.Conv2D`` routes 1x1 convs here when ``set_conv1x1_impl``
+selects it.
 
 Reference lineage: the reference's 1x1 convs run as cuDNN GEMMs
 (``gserver/layers/ExpandConvLayer.cpp`` im2col+GEMM path) — the GEMM view

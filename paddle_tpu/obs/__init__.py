@@ -38,13 +38,6 @@ The layers:
   retrace bursts, drain stalls, memory high-water, the NaN sentinel);
   on trigger, a one-shot forensics bundle (telemetry ring + trace tail +
   config/env/mesh snapshot + verdict) lands on disk.
-- :mod:`~paddle_tpu.obs.hloprof` + :mod:`~paddle_tpu.obs.attribution`
-  (ISSUE 6) — the device-side attribution layer: a structured parser
-  over the compiled step's optimized HLO (per-op FLOPs/bytes, named-
-  scope paths, loop trip counts, collective inventory) feeding a
-  per-scope roofline / MFU-gap report with an exposed-vs-overlappable
-  communication estimate, joined with measured ``jax.profiler``
-  device lanes when a capture exists (``Trainer.attribution_report``).
 - :mod:`~paddle_tpu.obs.report` — ``python -m paddle_tpu.obs.report
   run.jsonl``: run-summary table (throughput, MFU, retraces, overlap,
   anomalies) from a telemetry JSONL.
@@ -72,14 +65,11 @@ tracer=Tracer(), anomaly=AnomalyDetector(out_dir))``. With none attached
 same dispatch count, same donation, zero extra device fetches.
 """
 
-from . import attribution, hloprof, xla_cache, xla_flags
+from . import xla_cache, xla_flags
 from .anomaly import (ANOMALY_KINDS, SERVING_ANOMALY_KINDS,
                       AnomalyDetector, ServingAnomalyDetector, Verdict)
-from .attribution import build_report, format_report, parse_profile_trace
 from .fleet_trace import (flow_connected, flow_summary, lane_monotonic,
                           merge_fleet_trace, save_fleet_trace)
-from .hloprof import (DCN_BYTES_PER_S, HBM_BANDWIDTH, ICI_BANDWIDTH,
-                      collective_inventory, parse_collectives, parse_module)
 from .health import (HEALTH_KEYS, health_scalars, tree_l2_norm,
                      tree_nonfinite_count)
 from .metrics import (Counter, Gauge, Histogram, MetricsHub,
@@ -102,10 +92,7 @@ __all__ = [
     "Tracer", "tspan", "jax_profile", "live", "session_tracer", "self_times",
     "AnomalyDetector", "ServingAnomalyDetector", "Verdict",
     "ANOMALY_KINDS", "SERVING_ANOMALY_KINDS",
-    "hloprof", "attribution", "xla_flags",
-    "parse_module", "collective_inventory", "parse_collectives",
-    "build_report", "format_report", "parse_profile_trace",
-    "ICI_BANDWIDTH", "DCN_BYTES_PER_S", "HBM_BANDWIDTH",
+    "xla_flags",
     "percentile", "P2Quantile", "summarize_requests", "summarize_scale",
     "summarize_handoffs", "GOODPUT_REASONS",
     "SLOMonitor", "SLOTargets",

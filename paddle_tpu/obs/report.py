@@ -71,7 +71,6 @@ def summarize(records: List[Dict[str, Any]]) -> Dict[str, Any]:
     steps = [r for r in records if r.get("kind") == "step"]
     compiles = [r for r in records if r.get("kind") == "compile"]
     anomalies = [r for r in records if r.get("kind") == "anomaly"]
-    attributions = [r for r in records if r.get("kind") == "attribution"]
     summary_rec = next((r for r in reversed(records)
                         if r.get("kind") == "summary"), None)
 
@@ -87,7 +86,6 @@ def summarize(records: List[Dict[str, Any]]) -> Dict[str, Any]:
         # to anomaly_kind (the record "kind" slot holds "anomaly")
         "anomaly_kinds": sorted({a.get("anomaly_kind") or "?"
                                  for a in anomalies}),
-        "attribution_reports": len(attributions),
         "from_summary_record": summary_rec is not None,
     }
 
@@ -128,11 +126,6 @@ def summarize(records: List[Dict[str, Any]]) -> Dict[str, Any]:
                 continue
             if val is not None:
                 out[key] = val
-    if attributions:
-        att = attributions[-1]
-        out["attribution_est_mfu_pct"] = att.get("est_mfu_pct")
-        comm = att.get("comm") or {}
-        out["attribution_exposed_comm_ms"] = comm.get("exposed_ms")
     # serving SLO percentiles (ISSUE 11): p50/p95/p99 TTFT/TPOT +
     # goodput-under-deadline over kind="request" records, when present
     serving = summarize_requests(records)
@@ -233,8 +226,6 @@ _ROWS = (
     ("pipelined steps/sec", "pipelined_steps_per_sec"),
     ("tokens/sec", "tokens_per_sec"),
     ("est MFU %", "est_mfu_pct"),
-    ("static-attribution MFU %", "attribution_est_mfu_pct"),
-    ("exposed comm ms (static)", "attribution_exposed_comm_ms"),
     ("compiles / retraces", None),                # composite
     ("compile wall s", "compile_wall_s"),
     ("mean dispatch ms", "mean_dispatch_ms"),

@@ -43,7 +43,7 @@ __all__ = ["Telemetry", "PEAK_FLOPS", "device_peak_flops",
 _log = logging.getLogger("paddle_tpu.telemetry")
 
 # Peak dense bf16 FLOP/s per chip by device kind (public spec sheets) — the
-# MFU denominator. bench.py consumes this table too.
+# MFU denominator.
 PEAK_FLOPS = {
     "TPU v6 lite": 918e12,   # v6e (Trillium)
     "TPU v6e": 918e12,
@@ -336,8 +336,7 @@ class Telemetry:
 
     def emit_event(self, record: Dict[str, Any]) -> Dict[str, Any]:
         """Emit one non-step record to every sink — the carrier for
-        ``kind="attribution"`` reports (``Trainer.attribution_report``)
-        and ``kind="anomaly"`` verdicts, so a run's JSONL holds the whole
+        ``kind="anomaly"`` verdicts, so a run's JSONL holds the whole
         story (``obs.report`` reads these back). Stamps ``ts`` and a
         ``kind`` (default ``"event"``) when absent."""
         rec = {"kind": record.get("kind", "event"), "ts": time.time()}
@@ -386,7 +385,7 @@ class Telemetry:
     # -- summaries -----------------------------------------------------------
 
     def summary(self) -> Dict[str, Any]:
-        """Aggregate view (bench.py wires this into its output JSON)."""
+        """Aggregate view (the close-time ``summary`` record)."""
         mem = self.peak_bytes_run
         out = {"steps_emitted": self._steps_emitted,
                "compile_count": self.compile_count,

@@ -2,8 +2,8 @@
 
 One helper owns where compiled executables are kept. :func:`setup` is
 called before the first compile by every entry point that compiles
-(``chip_smoke.py``, ``bench.py``, ``train/cli.py``,
-``serve.replica_proc.main``):
+(``chip_smoke.py``, ``benchmarks/run.py``, ``train/cli.py``,
+``serve.replica_proc.main``, ``tests/drills.py``):
 
 - where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX itself reads it and
   the program sets no directory in code: whoever runs the program

@@ -25,7 +25,7 @@ CAVEATS (read before enabling):
   no-op unless ``force=True``.
 
 Provenance of the set (public sources, same pattern as the
-``PEAK_FLOPS``/``ICI_BANDWIDTH`` tables):
+``PEAK_FLOPS`` table):
 
 - ``xla_tpu_enable_async_collective_fusion*`` and
   ``xla_tpu_overlap_compute_collective_tc`` — the async-collective +

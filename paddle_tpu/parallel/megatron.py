@@ -11,7 +11,7 @@ Two ways to run Megatron tp in this framework:
    (``TransformerLM(residual_sharding=...)``) does not reliably lower to
    reduce-scatter (measured on the CPU backend: the reshard splits into
    all-reduce + all-gather, WORSE than plain tp — 3.1 vs 2.0 GB/device
-   per d512 step, experiments/scaling_projection.py r5 notes).
+   per d512 step, counted in the compiled HLO, r5).
 
 2. **Explicit** (this module): shard_map the whole LM and write the
    Megatron-SP collectives by hand — ``all_gather`` the LayerNorm'd
@@ -95,7 +95,7 @@ def make_megatron_sp_lm_apply(model, mesh: Mesh, data_axis: str = "data",
     keeps every [*, vocab] tensor seq-sharded — emitting global logits
     from the shard_map makes XLA assemble them with a [B, T, vocab]
     all-gather, which at d512/V32k is 2.1 GB/step of pure waste
-    (measured, experiments/scaling_projection.py r5).
+    (counted in the compiled HLO, r5).
 
     ``comm_dtype`` (e.g. ``jnp.bfloat16``) casts the tensors crossing the
     AG/RS collectives, halving tp activation wire vs the f32 the policy's

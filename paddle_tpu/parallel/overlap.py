@@ -7,9 +7,8 @@ produced it (``pserver/ParameterServer2.h:73``, SURVEY §3.3). The
 XLA-era default collapses all of that into GSPMD: the partitioner
 inserts one all-reduce per gradient tensor wherever the backward
 computes it, then the backend's combiner/scheduler typically merges and
-sinks them into one monolithic sync after the full backward — the
-exposed-communication gap ``Trainer.attribution_report()`` measures
-(``comm.grad_allreduce.exposed_ms_today`` vs ``exposed_ms_if_overlapped``).
+sinks them into one monolithic sync after the full backward, all of it
+exposed.
 
 This module makes the sync OURS again, pserver-style but on-device:
 
@@ -78,10 +77,8 @@ __all__ = [
 ]
 
 # Every explicit-sync psum is traced under this jax.named_scope, so the
-# compiled HLO's collectives carry scope=('grad_sync', '<tag>') metadata —
-# obs.attribution classifies them as gradient all-reduces by this name
-# (robust even where transform-wrapper metadata would hide the backward
-# flag) and the bench gate counts them per mode.
+# program's all-reduces carry a grad_sync/<tag> location: a trace names
+# them by it, and tests/test_overlap.py counts them per mode.
 GRAD_SYNC_SCOPE = "grad_sync"
 
 GRAD_SYNC_MODES = (None, "bucketed", "fused")
@@ -184,7 +181,7 @@ def _flat_psum(gs: Tuple[Any, ...], axis_name, tag: str) -> Tuple[Any, ...]:
     ravel + concatenate, a single ``lax.psum`` (one HLO all-reduce — the
     per-leaf form would emit one op per leaf and hand the backend the
     same fragmented schedule we are replacing), then slice/reshape back.
-    Traced under ``named_scope(grad_sync/<tag>)`` for attribution."""
+    Traced under ``named_scope(grad_sync/<tag>)``."""
     with jax.named_scope(f"{GRAD_SYNC_SCOPE}/{tag}"):
         if len(gs) == 1:
             g = gs[0]

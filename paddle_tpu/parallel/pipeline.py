@@ -129,8 +129,8 @@ def pipeline_loss_apply(stage_fn: Callable, stage_params, x,
 
     Same wavefront as :func:`pipeline_apply`, but instead of broadcasting
     the completed [M, mb, ...] output stack to every stage (a full-tensor
-    ``psum`` over the pipe axis — measured 1.07 GB/step of wire for a
-    d1024 LM, experiments/scaling_projection.py r5), the loss closes on
+    ``psum`` over the pipe axis — 1.07 GB/step of wire for a d1024 LM,
+    counted in the compiled HLO, r5), the loss closes on
     the last stage: ``final_fn(final_params, outbuf, *extras)`` maps the
     stack to a scalar, non-last stages contribute zero, and only the
     SCALAR crosses the wire. Every device traces ``final_fn`` (bubble

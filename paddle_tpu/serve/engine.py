@@ -1329,34 +1329,6 @@ class DecodeEngine:
 
     def lower_tick(self):
         """The decode tick lowered at the engine's shapes, not run
-        (``jax.stages.Lowered``): what :meth:`attribution_report` parses
-        and how a caller reads the tick's compiled text
+        (``jax.stages.Lowered``): how a caller reads the tick's compiled text
         (``lower_tick().compile().as_text()``). The pools are untouched."""
         return self._tick_fn.lower(*self._tick_args())
-
-    def attribution_report(self, emit: bool = True) -> Dict[str, Any]:
-        """MFU-gap attribution of the compiled decode tick (the
-        ``Trainer.attribution_report`` recipe: one AOT
-        ``lower().compile()``, zero executions). Decode is memory-bound —
-        every tick streams the full parameter set and the active KV for
-        one token of compute — and the report's ``decode`` block says so
-        on the spec-sheet HBM tables (``bound="memory"``)."""
-        from ..obs import attribution as attr_lib
-        from ..obs import hloprof
-        from ..obs.telemetry import lowered_hlo_flops
-        compiled = self.lower_tick().compile()
-        analysis = hloprof.parse_module(compiled.as_text())
-        report = attr_lib.build_report(
-            analysis,
-            device_kind=getattr(jax.devices()[0], "device_kind", ""),
-            n_devices=self.tp_degree,
-            cost_analysis_flops=lowered_hlo_flops(compiled),
-            meta={"program": "decode_tick", "max_slots": self.max_slots,
-                  "context_width": self._W,
-                  "block_size": self.cache.block_size,
-                  "attention": self.attention,
-                  "speculative": self.speculative,
-                  "tp_degree": self.tp_degree})
-        if emit and self.telemetry is not None:
-            self.telemetry.emit_event(report)
-        return report

@@ -46,7 +46,7 @@ distributed deployment could actually know:
 Fault injection rides the PR-10 :class:`~paddle_tpu.train.faults.
 FaultSchedule` (``kill_replica_at_tick``, ``stall_replica_at_tick``,
 ``drop_submit_at``, ``duplicate_submit_at``), so the whole fleet path is
-deterministically drilled in CI (``bench.py --fleet-child``) the same
+deterministically drilled in CI (``tests/drills.py fleet``) the same
 way ``run_resilient`` is.
 
 **Process isolation (ISSUE 13).** ``ServingFleet(replica_mode=

@@ -1557,27 +1557,6 @@ class Trainer:
 
         return jax.tree_util.tree_map(stack, *hosts)
 
-    def compile_fused(self, host_batches):
-        """Public harness hook: stack ``steps_per_call * grad_accum`` host
-        batches into the fused [K, M, batch, ...] group, build the compiled
-        fused step if needed, and return ``(fused_step, device_batches)``.
-
-        ``fused_step(params, state, opt_state, step, device_batches, rng)``
-        returns ``(params, state, opt_state, step, losses[K], stats)`` —
-        plus a trailing health pytree when health telemetry is attached —
-        the stable surface benchmarks drive for repeated dispatch of one
-        resident group (bench.py's ``transformer_fused`` metric) without
-        depending on the Trainer's private stacking/sharding layout."""
-        K, M = self.steps_per_call, self.grad_accum
-        if len(host_batches) != K * M:
-            raise ValueError(
-                f"compile_fused needs steps_per_call*grad_accum = {K * M} "
-                f"host batches, got {len(host_batches)}")
-        stacked = self._stack_group(host_batches, K, M)
-        if self._fused_step is None:
-            self._build_fused_step(stacked)
-        return self._fused_step, self._shard_fused(stacked)
-
     def _fused_leaf_sharding(self, x):
         """The ONE per-leaf layout rule for stacked [K, M, batch, ...] group
         leaves — shared by the compiled step's in_shardings and the host
@@ -1934,12 +1913,12 @@ class Trainer:
     def lower_step(self, sample_batches, rng: Optional[Any] = None):
         """Lower the training step for ``sample_batches``' shapes without
         running it: ``(jax.stages.Lowered, step fingerprint)``. What
-        :meth:`warmup` compiles, what :meth:`attribution_report` parses,
-        and how a caller reads the step's compiled text
+        :meth:`warmup` compiles, and how a caller reads the step's text
+        (``lowered.as_text()``) or its compiled text
         (``lowered.compile().as_text()``); ``train_state`` is untouched.
 
         ``sample_batches``: ``steps_per_call * grad_accum`` host batches
-        in fused mode (``compile_fused``'s contract), one batch (or a
+        in fused mode (one group of the fused step), one batch (or a
         one-element list) in plain mode. ``rng``: PRNGKey for the
         lowering (default PRNGKey(0))."""
         assert self.train_state is not None, "call init() first"
@@ -1951,7 +1930,7 @@ class Trainer:
                     or len(sample_batches) != K * M:
                 raise ValueError(
                     f"fused mode needs steps_per_call*grad_accum = "
-                    f"{K * M} host batches (compile_fused's contract)")
+                    f"{K * M} host batches")
             stacked = self._stack_group(list(sample_batches), K, M)
             if self._fused_step is None:
                 self._build_fused_step(stacked)
@@ -1993,9 +1972,8 @@ class Trainer:
 
         Args:
           sample_batches: host batches fixing the step's input shapes —
-            ``steps_per_call * grad_accum`` batches in fused mode
-            (``compile_fused``'s contract), one batch (or a one-element
-            list) in plain mode.
+            ``steps_per_call * grad_accum`` batches in fused mode, one
+            batch (or a one-element list) in plain mode.
           rng: PRNGKey for the lowering (default PRNGKey(0)).
         """
         from ..nn import autotune
@@ -2017,70 +1995,6 @@ class Trainer:
         return {"fingerprint": fp, "wall_s": round(wall, 6),
                 "cache_hit": cache_hit, "autotune_trials": trials,
                 "xla_cache_entries_added": added}
-
-    # -- device-side attribution (ISSUE 6) -----------------------------------
-
-    def attribution_report(self, sample_batches, rng: Optional[Any] = None,
-                           profile_dir: Optional[str] = None,
-                           emit: bool = True) -> Dict[str, Any]:
-        """MFU-gap attribution of the compiled train step: parse the
-        optimized (post-SPMD) HLO into per-``jax.named_scope`` FLOPs/bytes
-        rooflines, a structured collective inventory, and an
-        exposed-vs-overlappable communication estimate
-        (:mod:`paddle_tpu.obs.hloprof` / :mod:`~paddle_tpu.obs.attribution`).
-
-        PULL-BASED, OFF THE HOT LOOP: nothing here runs unless this method
-        is called — a Trainer that never calls it is byte-identical to the
-        pre-attribution build (same traced step, dispatch count, donation,
-        zero fences; pinned by tests/test_hloprof.py in the PR-2/4 style).
-        The report costs one AOT ``lower().compile()`` of the step (the
-        live jit executable's text is not exposed) and zero executions:
-        ``train_state`` and the host step mirror are untouched.
-
-        Args:
-          sample_batches: host batches fixing the step's input shapes —
-            a list of ``steps_per_call * grad_accum`` batches in fused
-            mode (``compile_fused``'s contract), one batch (or a
-            one-element list) in plain mode.
-          rng: PRNGKey for the lowering (default PRNGKey(0)).
-          profile_dir: a ``Tracer.profile_window()`` / ``jax.profiler``
-            capture directory — when it holds a device-lane Chrome trace
-            the MEASURED compute-vs-communication split joins the static
-            report under ``report["measured"]`` (absent on CPU captures:
-            static-only, degrading gracefully).
-          emit: emit the report as a ``kind="attribution"`` telemetry
-            record to every sink (no-op with ``telemetry=None``).
-        """
-        from ..obs import attribution as attr_lib
-        from ..obs import hloprof
-        from ..obs.telemetry import lowered_hlo_flops
-        fused = self.steps_per_call > 1 or self.grad_accum > 1
-        lowered, _ = self.lower_step(sample_batches, rng)
-        compiled = lowered.compile()
-        # the agreement check must compare against the SAME optimized
-        # module we parse (lowered_hlo_flops accepts anything with
-        # cost_analysis(): here the Compiled, not the Lowered)
-        cost_flops = lowered_hlo_flops(compiled)
-        analysis = hloprof.parse_module(compiled.as_text())
-        mesh = self.mesh
-        report = attr_lib.build_report(
-            analysis,
-            device_kind=getattr(jax.devices()[0], "device_kind", ""),
-            n_devices=int(mesh.devices.size),
-            cost_analysis_flops=cost_flops,
-            meta={
-                "mesh_axes": dict(zip(mesh.axis_names, mesh.devices.shape)),
-                "steps_per_call": self.steps_per_call,
-                "grad_accum": self.grad_accum,
-                "fused": fused,
-            })
-        if profile_dir is not None:
-            measured = attr_lib.parse_profile_trace(profile_dir)
-            if measured is not None:
-                report["measured"] = measured
-        if emit and self.telemetry is not None:
-            self.telemetry.emit_event(report)
-        return report
 
     # -- checkpoint ----------------------------------------------------------
 

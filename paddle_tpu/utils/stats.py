@@ -70,7 +70,7 @@ class StatSet:
 
     def to_dict(self) -> Dict[str, object]:
         """JSON-ready export: name + the per-key summary (telemetry sinks
-        and bench.py consume this)."""
+        consume this)."""
         return {"name": self.name, "stats": self.summary()}
 
     def report(self, top_n: Optional[int] = None) -> str:

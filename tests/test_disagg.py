@@ -9,8 +9,8 @@ router, the hostile-scale loadgen, the router_ms host-cost meter and
 the M/M/c Erlang-C term each hold their contracts.
 
 Everything in-process on a :class:`SimClock` except where noted — the
-socket path is exercised end-to-end by tests/test_transport.py and the
-bench disagg leg."""
+socket path is exercised end-to-end by tests/test_transport.py and
+``tests/test_drills.py::test_drill_leg[fleet-disagg]``."""
 
 import collections
 import tempfile

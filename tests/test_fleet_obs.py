@@ -5,7 +5,7 @@ error-budget burn rate), serving anomaly forensics, and the satellites
 serving transport/SLO blocks, and the ``obs.top`` dashboard.
 
 All fleet drills here are in-process on a :class:`SimClock` (the
-process-mode twin runs in ``bench.py --fleet-child`` leg 4), so the
+process-mode twin is ``tests/test_drills.py::test_drill_leg[fleet-tracing]``), so the
 determinism assertions are exact: the same drill must produce the same
 merged trace, byte for byte."""
 

@@ -7,8 +7,8 @@ transport totals, the ``obs.top`` sparkline dashboard block, and the
 P² quantile adversarial streams (satellite 4).
 
 Fleet drills are in-process on a :class:`SimClock` — the process/socket
-twin with real piggybacked deltas runs in ``bench.py --fleet-child``
-leg 4."""
+twin with real piggybacked deltas is
+``tests/test_drills.py::test_drill_leg[fleet-tracing]``."""
 
 import json
 import os

@@ -70,7 +70,8 @@ def test_jsonl_sink_schema_roundtrip(tmp_path):
     for r in steps:
         for key in ("ts", "pass", "step", "k_steps", "m", "loss",
                     "host_stack_ms", "shard_ms", "dispatch_ms", "device_ms",
-                    "replay_ms", "compile_count", "retrace_count",
+                    "replay_ms", "stage_ms", "drain_wait_ms", "overlap_frac",
+                    "compile_count", "retrace_count", "bytes_in_use",
                     "peak_bytes", "fenced") + HEALTH_KEYS:
             assert key in r, f"missing {key}"
         assert r["fenced"] is True and r["device_ms"] is not None

@@ -6,9 +6,10 @@ per-bucket dp grad all-reduces anchored inside the backward — reproduces
 f32 on a 2-device dp mesh: params and per-step losses, composing with
 ``grad_accum > 1``, ``steps_per_call > 1``, ``param_sharding``, the
 remat'd scan-over-layers stack (per-layer in-scan sync), and the
-pipelined host loop. Plus: the HLO gate (bucketed >= 2 gradient
-all-reduces where fused yields exactly 1), the bucket partitioner's
-invariants, and the graceful no-dp fallback.
+pipelined host loop. Plus: the count of all-reduces each mode asks of
+the compiler (one a bucket where fused asks exactly one, the per-layer
+one inside the layer scan), the bucket partitioner's invariants, and the
+graceful no-dp fallback.
 """
 
 import logging
@@ -26,6 +27,8 @@ from paddle_tpu.core.module import Module
 from paddle_tpu.nn import costs
 from paddle_tpu.parallel import overlap
 from paddle_tpu.train import Trainer, events as ev
+
+from hlo_counts import compiled_all_reduces, lowered_all_reduces
 
 
 class MLP(Module):
@@ -93,11 +96,13 @@ def _assert_trees_equal(a, b):
         np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
 
 
-def _grad_sync_rows(tr, batches):
-    """Per-bucket grad all-reduce rows of the trainer's compiled step."""
-    rep = tr.attribution_report(batches, emit=False)
-    gar = (rep["comm"] or {}).get("grad_allreduce") or {}
-    return gar.get("buckets") or []
+def _step_all_reduces(tr, batches):
+    """``(whiles, tag)`` of every all-reduce the trainer's step ASKS for:
+    read in the lowered StableHLO, because what a compiler's combiner
+    makes of them (the CPU's folds the buckets into one variadic
+    instruction) is that compiler's business, not the program's."""
+    return lowered_all_reduces(
+        tr.lower_step(batches)[0].as_text(debug_info=True))
 
 
 # ---------------------------------------------------------------------------
@@ -218,36 +223,44 @@ def test_hlo_bucketed_vs_fused_allreduce_counts():
     batches = _batches(4)
     tr_b = _make_trainer(batches, "bucketed")
     tr_f = _make_trainer(batches, "fused")
-    rows_b = _grad_sync_rows(tr_b, batches[:2])
-    rows_f = _grad_sync_rows(tr_f, batches[:2])
-    assert len(rows_b) >= 2, rows_b
-    assert len(rows_f) == 1, rows_f
-    # every row carries the sched_distance field (None on CPU's
-    # synchronous all-reduces; an int for async start/done pairs)
-    for r in rows_b + rows_f:
-        assert "sched_distance" in r
-    # the markers' psums are traced in the backward: transpose metadata
-    # must mark the rows backward=True in the full collective table
-    rep = tr_b.attribution_report(batches[:2], emit=False)
-    gs = [c for c in rep["collectives"]
-          if c["scope"].startswith("grad_sync")]
-    assert gs and all(c["overlappable"] for c in gs)
-    assert any(c["backward"] for c in gs)
+    ars_b = _step_all_reduces(tr_b, batches[:2])
+    ars_f = _step_all_reduces(tr_f, batches[:2])
+    buckets = overlap.partition_buckets(tr_b.train_state.params,
+                                        tr_b.bucket_mb)
+    assert len(buckets) >= 2
+    # one scoped all-reduce a bucket
+    assert sorted(tag for _, tag in ars_b if tag) == \
+        sorted(b.tag for b in buckets)
+    assert [tag for _, tag in ars_f if tag] == ["bucket0"]
+    # beside them both modes ask the same unscoped one (the loss's mean)
+    assert len(ars_b) - len(buckets) == len(ars_f) - 1 == 1
+    # and the default mode asks none: its reductions are the partitioner's
+    assert _step_all_reduces(_make_trainer(batches, None), batches[:2]) == []
 
 
 def test_hlo_default_mode_has_no_grad_sync_scopes():
-    """grad_sync=None is the pre-overlap program: no grad_sync scopes in
-    the collective table; the implicit (transpose-metadata) grad
-    all-reduces of the scoped transformer are still classified, with an
-    empty per-bucket row list."""
+    """grad_sync=None is the pre-overlap program: no ``grad_sync/`` scope
+    anywhere in the scoped transformer's lowered step."""
     batches = _lm_batches()
     tr = _make_lm_trainer(batches, None)
-    rep = tr.attribution_report(batches[:2], emit=False)
-    assert not [c for c in rep["collectives"]
-                if c["scope"].startswith("grad_sync")]
-    gar = (rep["comm"] or {}).get("grad_allreduce")
-    assert gar is not None and gar["ops"] >= 1
-    assert gar["buckets"] == []
+    text = tr.lower_step(batches[:2])[0].as_text(debug_info=True)
+    assert overlap.GRAD_SYNC_SCOPE + "/" not in text
+    assert lowered_all_reduces(text) == []
+
+
+def test_compiled_all_reduces_reads_variadic_and_async_forms():
+    """The compiled-text counter on the forms XLA prints: a plain result,
+    a tuple result long enough to carry ``/*index=5*/`` marks (the CPU
+    combiner's variadic all-reduce), an async start (its done is not a
+    second one), and an operand that merely NAMES an all-reduce."""
+    hlo = """
+  %psum.80 = f32[8416]{0} all-reduce(%concatenate.210), channel_id=1
+  %all-reduce.1 = (f32[4]{0}, f32[4]{0}, f32[4]{0}, f32[4]{0}, f32[4]{0}, /*index=5*/f32[]) all-reduce(%a, %b, %c, %d, %e, %f), channel_id=2
+  %ars = f32[8]{0} all-reduce-start(%x), channel_id=3
+  ROOT %ard = f32[8]{0} all-reduce-done(%ars)
+  %gte = f32[4]{0} get-tuple-element(%all-reduce.1), index=0
+"""
+    assert compiled_all_reduces(hlo) == 3
 
 
 # ---------------------------------------------------------------------------
@@ -282,14 +295,13 @@ def test_transformer_in_scan_sync_bitexact_and_in_loop():
     pf, lf = _run(tr_f, batches)
     assert lb == lf
     _assert_trees_equal(pb, pf)
-    rows = _grad_sync_rows(tr_b, batches[:2])
-    # the per-layer in-scan sync executes K * L times per dispatch — a
-    # multiplier above K proves the all-reduce sits INSIDE the backward
-    # layer scan, not after it
-    scan_rows = [r for r in rows if r["scope"] == "grad_sync/scan_layer"]
-    assert scan_rows and scan_rows[0]["multiplier"] > 2
-    # embed/pos/head leaves still sync via top-level buckets
-    assert [r for r in rows if r["scope"].startswith("grad_sync/bucket")]
+    whiles = {tag: n for n, tag in _step_all_reduces(tr_b, batches[:2])}
+    # embed/pos/head leaves still sync via top-level buckets, inside the
+    # K-step scan alone; the per-layer sync sits one while region deeper,
+    # INSIDE the backward layer scan and not after it
+    assert whiles["bucket0"] == 1
+    assert whiles["scan_layer"] == 2
+    assert all(n == 1 for n, tag in _step_all_reduces(tr_f, batches[:2]))
 
 
 def test_transformer_scan_claim_protocol():

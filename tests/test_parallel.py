@@ -451,8 +451,7 @@ def test_seq_parallel_residuals_match_and_use_reduce_scatter(nprng, rng):
     stream to a seq-sharded spec must (a) leave the logits numerically
     identical to the unsharded model and (b) make XLA lower the tp
     activation sync as reduce-scatter/all-gather pairs instead of
-    all-reduces — the halved-wire-bytes recipe
-    ``experiments/scaling_projection.py`` projects at scale."""
+    all-reduces — the halved-wire-bytes recipe."""
     from jax.sharding import NamedSharding
 
     from paddle_tpu.models import TransformerLM
@@ -487,8 +486,7 @@ def test_seq_parallel_residuals_match_and_use_reduce_scatter(nprng, rng):
     # The constraint must change the lowering: the tp-only forward syncs its
     # partial sums with per-sublayer all-reduces; seq-sharding the residuals
     # re-expresses those syncs in scattered form (reduce-scatter, or
-    # all-gather pairs — the exact mix is XLA's cost-model choice; the wire
-    # accounting lives in experiments/scaling_projection.py).
+    # all-gather pairs — the exact mix is XLA's cost-model choice).
     def n_allreduce(fn):
         hlo = fn.lower(params, inp).compile().as_text()
         return hlo.count(" all-reduce(") + hlo.count(" all-reduce-start(")

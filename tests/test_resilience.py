@@ -91,8 +91,8 @@ SWEEP = [
     ("crash_during_save", dict(fail_save_at=1), {}),
     # the latest landed checkpoint (pass-0 end, save idx 4) is corrupted,
     # then a crash early in pass 1: resume quarantines the poisoned pass
-    # and falls back (here: to scratch — bench's --faults-child covers
-    # the fall-back-one-PASS case with 3 passes)
+    # and falls back (here: to scratch — test_drills.py's faults-corrupt
+    # leg covers the fall-back-one-PASS case with 3 passes)
     ("corrupt_latest_pass",
      dict(corrupt_checkpoint_file=4, crash_at_step=18), {}),
     # preemption notice mid-pass: graceful stop -> quiesced checkpoint ->

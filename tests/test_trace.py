@@ -92,7 +92,7 @@ def test_tracer_spans_flows_and_chrome_format(tmp_path):
              and e["name"] == "thread_name"]
     assert len(names) == 2
     assert any(e["ph"] == "i" and e["name"] == "marker" for e in evs)
-    # serialized traceEvents are timestamp-sorted (the bench gate's rule)
+    # serialized traceEvents are timestamp-sorted
     ts = [e.get("ts", -1.0) for e in evs]
     assert ts == sorted(ts)
 
@@ -161,6 +161,36 @@ def test_traced_pipelined_run_two_threads_flows_pair(tmp_path):
     # the whole document serializes as valid Chrome trace JSON
     tracer.save(str(tmp_path / "t.json"))
     json.load(open(str(tmp_path / "t.json")))
+
+
+def test_traced_pipelined_stage_span_concurrent_with_main_thread():
+    """The overlap the trace exists to make auditable: in a pipelined run
+    some stager-thread ``stage`` span INTERSECTS IN TIME one main-thread
+    span (a union-window check would pass for fully serialized staging
+    too), and every span sits on the tracer's clock (``ts >= 0``,
+    ``dur > 0``). Which pass shows it is the host's scheduling: a fast
+    stager can finish between two main-thread spans, so any of six
+    post-compile passes may."""
+    tr = make_trainer(K=4, tracer=Tracer(), pipeline_depth=2)
+    batches = make_batches(4 * 2 * 4)
+    tr.init(jax.random.PRNGKey(0), batches[0])
+    tr.train(lambda: iter(batches), num_passes=1, log_period=0)   # compiles
+    concurrent = False
+    for _ in range(6):
+        tr.tracer = Tracer()
+        tr.train(lambda: iter(batches), num_passes=1, log_period=0)
+        xs = [e for e in tr.tracer.events() if e["ph"] == "X"]
+        assert all(e["ts"] >= 0 and e["dur"] > 0 for e in xs)
+        stage = [e for e in xs if e["name"] == "stage"]
+        stage_tids = {e["tid"] for e in stage}
+        main = [e for e in xs if e["tid"] not in stage_tids]
+        assert stage and main
+        concurrent = any(
+            s["ts"] < m["ts"] + m["dur"] and s["ts"] + s["dur"] > m["ts"]
+            for s in stage for m in main)
+        if concurrent:
+            break
+    assert concurrent
 
 
 def test_tracer_off_is_byte_identical_params_and_dispatches():

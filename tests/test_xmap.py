@@ -1,8 +1,7 @@
 """Multi-process mapper (VERDICT r4 #8) — the xmap_readers analog
 (reference: ``v2/reader/decorator.py:233-292``; image loader
-``utils/image_multiproc.py``). Correctness is asserted everywhere; the
-speedup assertion only runs on multi-core hosts (the bench host has one
-core, where process parallelism cannot win)."""
+``utils/image_multiproc.py``). Correctness and where the work ran are
+asserted; how fast it ran is the host's, and is not."""
 
 import multiprocessing as mp
 import os
@@ -90,17 +89,14 @@ def test_xmap_train_augment_pickles_and_is_worker_independent():
     assert any(not np.array_equal(s, e) for s, e in zip(serial, epoch1))
 
 
-@pytest.mark.skipif((os.cpu_count() or 1) < 4,
-                    reason="speedup needs a multi-core host; the bench "
-                           "host has one core (correctness is asserted "
-                           "in the other tests)")
-def test_xmap_beats_thread_map_on_cpu_bound_mapper():
-    n = 48
-    t0 = time.perf_counter()
+def test_xmap_runs_cpu_bound_mapper_in_worker_processes():
+    """What ``processes=`` is for: the CPU-bound work leaves the parent
+    (and its GIL) and spreads over the workers. How much faster that is
+    depends on the host's free cores, so it is not asserted."""
+    n = 24
     serial = [H.burn(x) for x in range(n)]
-    t_serial = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    par = list(data.xmap(H.burn, _ints(n), processes=4, buffer=16)())
-    t_par = time.perf_counter() - t0
-    assert par == serial
-    assert t_par < t_serial, (t_par, t_serial)
+    out = list(data.xmap(H.burn_with_pid, _ints(n), processes=4,
+                         buffer=16)())
+    assert [v for v, _ in out] == serial
+    pids = {pid for _, pid in out}
+    assert len(pids) > 1 and os.getpid() not in pids

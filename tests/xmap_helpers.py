@@ -1,6 +1,6 @@
 """Top-level picklable mappers for test_xmap: spawn workers unpickle these
 by importing THIS module, which deliberately avoids jax so worker startup
-stays cheap on the 1-core bench host."""
+stays cheap."""
 
 import time
 
@@ -24,14 +24,19 @@ def boom_on_3(x):
 
 
 def burn(x):
-    """CPU-bound mapper (~100 ms/call) for the multi-core-only speedup
-    check — heavy enough that 48 calls (~5 s serial) amortize the
-    spawn-context worker startup."""
+    """CPU-bound mapper (~100 ms/call): heavy enough that every worker
+    of a pool is up before the calls run out."""
     a = np.random.RandomState(x).rand(600, 600)
     for _ in range(20):
         a = a @ a.T
         a /= np.abs(a).max()
     return float(a[0, 0])
+
+
+def burn_with_pid(x):
+    """``burn`` and the process that ran it."""
+    import os
+    return burn(x), os.getpid()
 
 
 def die_hard(x):
