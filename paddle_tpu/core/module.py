@@ -211,11 +211,15 @@ class Module:
         PARENT's forward, without entering the submodule's scope. The scan/
         remat paths use it to stack homogeneous sibling submodules' params
         ([L, ...] leading layer axis) and re-apply one submodule over the
-        stack (``models/transformer.py``)."""
+        stack (``models/transformer.py``). From inside this module's own
+        scope (a method run by ``apply(method=)``) it is the module's own
+        subtree: like :meth:`scope`, an active instance adds no segment."""
         fr = _frame()
-        name = self._ensure_name(fr)
-        return _get_node(fr.variables.get(collection, {}),
-                         list(fr.path) + [name], create=False)
+        path = list(fr.path)
+        if not (fr.active and fr.active[-1] == id(self)):
+            path.append(self._ensure_name(fr))
+        return _get_node(fr.variables.get(collection, {}), path,
+                         create=False)
 
     def update_state(self, name: str, value: jax.Array) -> None:
         """Write a state variable. No-op outside init unless 'state' is mutable."""

@@ -155,6 +155,14 @@ class LatentMoELM(Module):
                 "expert_tokens": (len(moe), moe[0].experts.count)}
         return spec
 
+    def serving_variables(self, variables):
+        """What the serving engine asks a model beside ``cache_spec()``:
+        the tree the entry points run on. This one: its leaves are stored
+        in the type the products take and the layers are unrolled, so
+        nothing is cast, sliced or stacked, at build or inside a
+        program."""
+        return variables
+
     def _counters(self, counts):
         counts = [c for c in counts if c is not None]
         return {"expert_tokens": jnp.stack(counts)} if counts else {}

@@ -15,13 +15,20 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from jax.sharding import NamedSharding, PartitionSpec
+
 from paddle_tpu.core import initializers as I
+from paddle_tpu.core.dtypes import current_policy
 from paddle_tpu.core.module import Module, is_initializing
 from paddle_tpu.nn.attention import MultiHeadAttention
 from paddle_tpu.nn.layers import Dropout, Embedding, LayerNorm, Linear
 from paddle_tpu.nn.moe import MoEFFN
 
 __all__ = ["TransformerBlock", "TransformerLM", "remat_policy"]
+
+# the node of a prepared tree that holds the blocks' stack
+# (``TransformerLM.serving_variables``); a training tree has ``block<i>``
+_STACK = "blocks"
 
 
 def remat_policy(name):
@@ -45,6 +52,16 @@ class TransformerBlock(Module):
     """Pre-LN block: ``x + MHA(LN(x))`` then ``x + FFN(LN(x))``; the FFN is
     a dense two-layer gelu MLP or an :class:`MoEFFN` when
     ``moe_experts > 0``."""
+
+    # The leaves of a block's subtree that the block itself passes through
+    # ``cast_compute`` as a product's operand: the four projections
+    # (``nn/attention.py``) and the dense MLP's two matrices (``Linear``).
+    # ``TransformerLM.serving_variables`` holds exactly these in the
+    # policy's compute type; biases and LayerNorm leaves are never cast,
+    # and ``MoEFFN`` multiplies its leaves (``ffn/wg``, ``w1``, ``w2``) as
+    # they are stored.
+    compute_operands = ("attn/wq", "attn/wk", "attn/wv", "attn/wo",
+                        "ffn1/w", "ffn2/w")
 
     def __init__(self, dim: int, num_heads: int, ffn_hidden: int,
                  use_flash: bool = False, moe_experts: int = 0,
@@ -257,9 +274,15 @@ class TransformerLM(Module):
     # -- serving entry points (paddle_tpu.serve) ---------------------------
     #
     # All three run the block stack as ONE lax.scan over the per-block
-    # param subtrees STACKED AT TRACE TIME (the _scan_blocks recipe, minus
-    # checkpoint — no gradients flow here), so the variables tree is the
-    # training tree unchanged: any training checkpoint serves as-is.
+    # param subtrees stacked on a leading layer axis (the _scan_blocks
+    # recipe, minus checkpoint — no gradients flow here). The stack is
+    # a function of the weights alone, so whoever calls them every tick
+    # makes it ONCE: `serving_variables` turns the training tree into the
+    # tree whose `blocks` node IS the stack (the products' operands already
+    # in the policy's compute type), and the engine holds that. Handed the
+    # training tree itself (a checkpoint, `InferenceModel.decode_step`, a
+    # test) the entry points stack at trace time, inside the program, every
+    # call: `_stacked_blocks` tells the two apart by the tree's structure.
     #
     # Shard-in-scope (ISSUE 15): the bodies are mesh-oblivious, but when
     # the engine traces them inside `parallel.tp_shard_scope` the
@@ -271,11 +294,63 @@ class TransformerLM(Module):
     # row-parallel out/ffn2 projections all-reduce back to the replicated
     # residual, and the tied readout runs replicated on every shard.
 
+    def _stack(self, subs):
+        """The blocks' subtrees -> one subtree on a leading ``[L, ...]``
+        layer axis, :attr:`TransformerBlock.compute_operands` in the
+        CURRENT policy's compute type (the cast the block would make in
+        every product: bfloat16 of a float32 value is one value whenever
+        it is computed; under the float32 policy it is the identity)."""
+        dtype = current_policy().compute_dtype
+        operands = self.blocks[0].compute_operands
+
+        def stack(path, *leaves):
+            out = jnp.stack(leaves)
+            name = "/".join(str(k.key) for k in path)
+            return out.astype(dtype) if name in operands else out
+
+        return jax.tree_util.tree_map_with_path(stack, *subs)
+
+    def serving_variables(self, variables):
+        """What the serving engine asks a model beside ``cache_spec()``:
+        the variables tree :meth:`prefill`, :meth:`decode_step` and
+        :meth:`decode_span` run on, made ONCE from the training tree. The
+        ``block<i>`` subtrees become one ``blocks`` subtree
+        (:meth:`_stack`); both embeddings, the final LayerNorm and every
+        other collection come back as the objects they were (the tied
+        readout casts nothing). The result is right for the policy it was
+        made under and for no other.
+
+        One jitted program over the blocks' leaves alone (a jit copies
+        what it passes through). Leaves that a mesh holds keep their
+        layout behind an unsharded layer axis, pinned on the program's
+        outputs: ``P(None, *spec)``."""
+        root = variables["params"]
+        name = self._name if self._name in root else next(iter(root))
+        own = dict(root[name])
+        subs = [own.pop(blk._name) for blk in self.blocks]
+
+        def behind_layer_axis(leaf):
+            at = getattr(leaf, "sharding", None)
+            return (NamedSharding(at.mesh, PartitionSpec(None, *at.spec))
+                    if isinstance(at, NamedSharding) else None)
+
+        # a new function every call: jit keys its cache on the function
+        # and the arguments, and the policy is neither
+        own[_STACK] = jax.jit(
+            lambda subs: self._stack(subs),
+            out_shardings=jax.tree_util.tree_map(behind_layer_axis,
+                                                 subs[0]))(subs)
+        return {**variables, "params": {**root, name: own}}
+
     def _stacked_blocks(self):
-        block0 = self.blocks[0]
-        subs = [blk.subtree() for blk in self.blocks]
-        return block0, jax.tree_util.tree_map(lambda *ls: jnp.stack(ls),
-                                              *subs)
+        """``(block0, stack)`` for the layer scan: the stack the tree
+        holds (:meth:`serving_variables`), else the training tree's
+        blocks stacked here, at trace time."""
+        own = self.subtree()
+        if _STACK in own:
+            return self.blocks[0], own[_STACK]
+        return self.blocks[0], self._stack(
+            [blk.subtree() for blk in self.blocks])
 
     def prefill(self, ids, positions=None):
         """Serving prefill: ``ids [B, W] -> (logits [B, W, vocab],

@@ -90,7 +90,7 @@ import jax
 import jax.numpy as jnp
 
 from .kv_cache import PagedKVCache, scatter_prefill_pages
-from ..core.dtypes import canonicalize
+from ..core.dtypes import canonicalize, current_policy
 from ..nn import pallas_mode
 from ..obs.trace import live, traced, tspan
 from ..parallel.sharding import tp_constrain, tp_shard_scope
@@ -210,6 +210,12 @@ class DecodeEngine:
       (``serve/kv_cache.py:write_token``): the engine donates the pools
       and a program that copied one would not fit
       (``tests/test_chip_lowering.py`` holds both models to it).
+    - ``model.serving_variables(variables)``: the tree the entry points
+      run on, from the placed training tree. Whatever a program would
+      derive from the weights alone, every call, the model derives here,
+      once (``TransformerLM``: the blocks' stack and the compute-type
+      cast); the leaves it does not change come back as the objects they
+      were, and a model with nothing to derive returns its argument.
     - ``model.emb.vocab`` and ``model.max_len`` (the longest sequence a
       slot's table may cover; a position table's length, or only a bound
       where positions are rotary).
@@ -222,12 +228,18 @@ class DecodeEngine:
     ``serve/transport.py``'s wire format) when called.
 
     Args:
-      model: a TransformerLM (any training config — its serve path
-        restacks the per-block params at trace time, so checkpoints are
-        shape-compatible as-is) or a LatentMoELM (bfloat16 leaves are
-        used as they are).
+      model: a TransformerLM (any training config: a training
+        checkpoint serves as it is) or a LatentMoELM.
       variables: the model's variables dict (training checkpoint or
-        ``load_inference_model`` output).
+        ``load_inference_model`` output). It stays the caller's. What the
+        engine holds as ``self.variables``, and hands to both programs,
+        is ``model.serving_variables(...)`` of it, made once at build
+        after placement: a TransformerLM's blocks stacked for the layer
+        scan with the products' operands in the compute type of the
+        policy the engine is BUILT under (``self.policy``; a program
+        traced under another raises); a LatentMoELM's bfloat16 leaves as
+        they are. The engine keeps no other copy of the weights, so a
+        caller that drops its tree frees the float32 matrices.
       max_slots: decode-tick batch width S — the max concurrent
         sequences. Fixed at compile time; empty slots are masked lanes.
       block_size: KV tokens per pool block. Small blocks waste less on
@@ -307,7 +319,6 @@ class DecodeEngine:
                  kv_dtype: Optional[str] = None,
                  mesh=None, param_sharding=None, tp_axis: str = "model"):
         self.model = model
-        self.variables = variables
         self.telemetry = telemetry
         # optional Tracer (ISSUE 17): assigned by the fleet/replica when
         # request tracing is on. With None the engine's spans are live
@@ -379,7 +390,7 @@ class DecodeEngine:
                 # thread tp_axis through: a mesh whose tp axis is not
                 # named "model" must get matching default specs
                 param_sharding = megatron_sp_rules(model_axis=tp_axis)
-            self.variables = shard_tree(mesh, variables, param_sharding)
+            placed = shard_tree(mesh, variables, param_sharding)
         else:
             self.tp_degree = 1
             # one device, one placement: params and pools committed
@@ -391,7 +402,20 @@ class DecodeEngine:
             dev = (next(iter(leaf.devices())) if isinstance(leaf, jax.Array)
                    else jax.devices()[0])
             placement = jax.sharding.SingleDeviceSharding(dev)
-            self.variables = jax.device_put(variables, placement)
+            placed = jax.device_put(variables, placement)
+        # What the programs derive from the weights alone is derived
+        # HERE, once: the model turns the placed tree into the tree its
+        # entry points run on (a TransformerLM: the blocks stacked for the
+        # layer scan, the products' operands in the policy's compute
+        # type; one jitted program of the model's, not an entry point).
+        # Before the pools exist, so that the caller's tree, the result
+        # and the pools never stand beside that program's temporaries.
+        # The engine holds the RESULT and no other copy of the weights;
+        # it is right for this policy only, which the traced bodies check.
+        self.policy = current_policy()
+        with tspan(self.tracer, "engine_prepare"):
+            self.variables = model.serving_variables(placed)
+        del placed
         if max_blocks_per_seq is None:
             max_blocks_per_seq = max(1, model.max_len // block_size)
         if max_blocks_per_seq * block_size > model.max_len:
@@ -587,15 +611,33 @@ class DecodeEngine:
                     lambda o: tp_constrain(o, 2), pools), *rest)
             return pinned
 
+        # The weights were prepared under the build's policy: a body traced
+        # under another would multiply operands cast for the first by
+        # activations cast for the second, quietly. (A policy is trace-time
+        # state and no part of jit's cache key: once traced, a program runs
+        # as traced whatever the caller's policy.)
+        def _same_policy(fn):
+            def checked(*args):
+                if current_policy() != self.policy:
+                    raise RuntimeError(
+                        f"this engine prepared its weights under "
+                        f"{self.policy} and is being traced under "
+                        f"{current_policy()}: build it, warm it up and "
+                        f"make its first calls inside one use_policy")
+                return fn(*args)
+            return checked
+
         # donate the KV pools: the tick writes its rows into the buffers
         # it was handed and returns them (its layer scan carries the
         # pools and nothing in it has a pool-sized result,
         # tests/test_chip_lowering.py); the prefill's scatter still
         # copies them
-        self._prefill_fn = jax.jit(_in_scope(_pin_pools(prefill_fn)),
-                                   donate_argnums=(1,))
-        self._tick_fn = jax.jit(_in_scope(_pin_pools(tick_fn)),
-                                donate_argnums=(1,))
+        self._prefill_fn = jax.jit(
+            _same_policy(_in_scope(_pin_pools(prefill_fn))),
+            donate_argnums=(1,))
+        self._tick_fn = jax.jit(
+            _same_policy(_in_scope(_pin_pools(tick_fn))),
+            donate_argnums=(1,))
         # COW block copy: [L, H, bs, hd] pages move pool-internally, one
         # tiny donated program (not an engine entry point — not counted
         # in compile_counts, traced once for the process lifetime).
@@ -671,21 +713,7 @@ class DecodeEngine:
         timings: Dict[str, float] = {}
 
         def _prefill_once():
-            table = jnp.asarray(self.cache.tables[0:1])
-            key = self._prefill_key()
-            if self.prefill_chunk is None:
-                out = self._prefill_fn(
-                    self.variables, self.cache.pools,
-                    jnp.zeros((1, self._W), jnp.int32),
-                    jnp.asarray([1], jnp.int32),
-                    jnp.asarray([0], jnp.int32), table, key)
-            else:
-                out = self._prefill_fn(
-                    self.variables, self.cache.pools,
-                    jnp.zeros((1, self.prefill_chunk), jnp.int32),
-                    jnp.asarray([0], jnp.int32),
-                    jnp.asarray([1], jnp.int32),
-                    jnp.asarray([0], jnp.int32), table, key)
+            out = self._prefill_fn(*self._prefill_args())
             # donated pools: the engine's carry is the returned pools
             self.cache.pools = out[0]
             return out[1]
@@ -755,6 +783,10 @@ class DecodeEngine:
             "xla_cache_entries_added": added,
             "xla_cache_hit": xla_hit,
             "compile_counts": self.compile_counts(),
+            # the tree both programs take, as engine build prepared it
+            "prepared_bytes": sum(
+                leaf.nbytes
+                for leaf in jax.tree_util.tree_leaves(self.variables)),
         }
         if self.telemetry is not None:
             self.telemetry.record_compile(
@@ -1326,6 +1358,19 @@ class DecodeEngine:
                  jnp.ones((self.max_slots,), jnp.int32), active)
         # the stochastic verify tick takes keys, the greedy one does not
         return args + (keys,) if self.sampling is not None else args
+
+    def _prefill_args(self):
+        """The prefill's operands at the engine's shapes: slot 0's table,
+        one live token of zeros (``warmup()`` runs the program on them)."""
+        table = jnp.asarray(self.cache.tables[0:1])
+        one, zero = jnp.asarray([1], jnp.int32), jnp.asarray([0], jnp.int32)
+        args = (self.variables, self.cache.pools)
+        if self.prefill_chunk is None:      # ids, length, start
+            args += (jnp.zeros((1, self._W), jnp.int32), one, zero)
+        else:                               # ids, start, n, write_from
+            args += (jnp.zeros((1, self.prefill_chunk), jnp.int32),
+                     zero, one, zero)
+        return args + (table, self._prefill_key())
 
     def lower_tick(self):
         """The decode tick lowered at the engine's shapes, not run

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from paddle_tpu.core import mesh as mesh_lib
+from paddle_tpu.core import bfloat16_compute, mesh as mesh_lib, use_policy
 from paddle_tpu.models import LatentMoELM, TransformerLM
 from paddle_tpu.nn import MultiHeadAttention, pallas_mode
 from paddle_tpu.nn.pallas_attention import (flash_attention,
@@ -201,14 +201,14 @@ _MAY_HOLD_A_POOL = {"parameter", "get-tuple-element", "bitcast",
                     "dynamic-update-slice"}
 
 
-def pool_sized_results(text, sizes):
-    """``[(operation, shape)]`` of every instruction of the compiled
-    module, fused computations' insides left out, whose result has as
-    many elements as a pool or as one layer of one. What the compiler
-    prefetches into the chip's fast memory (``S(1)`` in a layout: a toy
-    pool fits there, a deployment's does not) is left out, with the
-    ``copy-done`` that brings it back."""
-    found, fused, prefetches = [], False, set()
+def results(text):
+    """``(operation, shape, dims)`` of every instruction of the compiled
+    module whose result is one array, fused computations' insides left
+    out. What the compiler prefetches into the chip's fast memory
+    (``S(1)`` in a layout: a toy pool or weight fits there, a
+    deployment's does not) is left out, with the ``copy-done`` that
+    brings it back."""
+    fused, prefetches = False, set()
     for line in text.splitlines():
         if line.endswith("{") and not line.startswith(" "):
             fused = "fused_computation" in line.split("(")[0]
@@ -220,11 +220,18 @@ def pool_sized_results(text, sizes):
         if "S(1)" in line.split(f" {op}(")[0]:
             prefetches.add(name)
             continue
-        dims = [int(d) for d in re.findall(r"[0-9]+", shape.split("[")[1])]
-        if (int(np.prod(dims)) in sizes and not is_tuple
-                and not (op == "copy-done" and operand in prefetches)):
-            found.append((op, shape))
-    return found
+        dims = tuple(int(d) for d in
+                     re.findall(r"[0-9]+", shape.split("[")[1]))
+        if not is_tuple and not (op == "copy-done"
+                                 and operand in prefetches):
+            yield op, shape, dims
+
+
+def pool_sized_results(text, sizes):
+    """``[(operation, shape)]`` of the :func:`results` with as many
+    elements as a pool or as one layer of one."""
+    return [(op, shape) for op, shape, dims in results(text)
+            if int(np.prod(dims)) in sizes]
 
 
 def toy_latent_engine(blocks, **kw):
@@ -299,3 +306,55 @@ def test_tick_leaves_the_pools_in_place(one_chip, monkeypatch, kv_dtype,
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < pool_bytes, \
         f"temporaries {temp} B, one pool {pool_bytes} B"
+
+
+# what may have a result the shape of a stacked block leaf: the program's
+# arguments on their way into the layer scan
+_MAY_HOLD_THE_STACK = {"parameter", "get-tuple-element", "bitcast"}
+
+
+@pytest.mark.parametrize("kw", [{}, {"speculative": 4},
+                                {"prefill_chunk": 32}],
+                         ids=["plain", "speculative4", "chunk32"])
+def test_programs_take_the_weights_as_prepared(one_chip, monkeypatch, kw):
+    """Both programs of a toy engine built under ``bfloat16_compute``,
+    compiled for the TPU: (a) no instruction but the arguments' plumbing
+    has a result in the shape of a stacked block leaf (``[L, D, F]``,
+    ``[L, F, D]``, ``[L, D, D]``), (b) the temporaries are smaller than
+    one stacked MLP matrix. Engine build stacks the blocks and casts the
+    products' operands once (``TransformerLM.serving_variables``); the
+    programs that did it themselves, every call, held the whole stack
+    as temporaries: a ``concatenate`` or an update loop a leaf, and a
+    ``convert`` where a leaf was left out of the cast."""
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+    L, heads, dh, F = 24, 4, 128, 2048
+    D = heads * dh
+    model = TransformerLM(vocab=512, dim=D, num_layers=L, num_heads=heads,
+                          ffn_hidden=F, max_len=128)
+    variables = model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))
+    with use_policy(bfloat16_compute):
+        engine = DecodeEngine(model, variables, max_slots=4, block_size=BS,
+                              attention="paged", **kw)
+        stack = engine.variables["params"]["transformer_lm"]["blocks"]
+        assert stack["ffn1"]["w"].shape == (L, D, F)
+        assert stack["ffn1"]["w"].dtype == jnp.bfloat16
+        on_chip = lambda tree: jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+        programs = {
+            "tick": engine._tick_fn.lower(*on_chip(engine._tick_args())),
+            "prefill": engine._prefill_fn.lower(
+                *on_chip(engine._prefill_args()))}
+    for name, lowered in programs.items():
+        compiled = lowered.compile()
+        shaped = [(op, shape) for op, shape, dims in
+                  results(compiled.as_text())
+                  if dims in {(L, D, F), (L, F, D), (L, D, D)}]
+        assert shaped, f"{name}: the stack is not in the compiled text"
+        made = [r for r in shaped if r[0] not in _MAY_HOLD_THE_STACK]
+        assert not made, f"{name} makes stack-shaped results: {made}"
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < stack["ffn1"]["w"].nbytes, \
+            f"{name}: temporaries {temp} B, one stacked MLP matrix " \
+            f"{stack['ffn1']['w'].nbytes} B"

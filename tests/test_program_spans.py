@@ -33,7 +33,8 @@ TRAINER_SPANS = {"trainer_init", "train_step", "reader_wait", "device_put",
                  "dispatch", "loss_fetch", "events"}
 SCHEDULER_SPANS = {"sched_step", "expire", "admit", "queue_wait",
                    "prefill_chunk", "decode_tick", "finish"}
-ENGINE_SPANS = {"engine_init", "engine_warmup", "begin_prefill",
+ENGINE_SPANS = {"engine_init", "engine_prepare", "engine_warmup",
+                "begin_prefill",
                 "prefill_dispatch", "prefill_drain", "engine_tick",
                 "tick_stage", "tick_dispatch", "tick_drain", "tick_retire"}
 # child -> the span it must lie inside, on the same thread
@@ -44,7 +45,8 @@ PARENT = {"device_put": "train_step", "dispatch": "train_step",
           "prefill_chunk": "admit", "prefill_dispatch": "prefill_chunk",
           "prefill_drain": "prefill_chunk", "engine_tick": "decode_tick",
           "tick_stage": "engine_tick", "tick_dispatch": "engine_tick",
-          "tick_drain": "engine_tick", "tick_retire": "engine_tick"}
+          "tick_drain": "engine_tick", "tick_retire": "engine_tick",
+          "engine_prepare": "engine_init"}
 
 
 def make_batches(n, bs=16, dim=12, seed=0):

@@ -1774,3 +1774,223 @@ def test_proc_spec_ships_mesh_and_single_device_roundtrip(
     # PR-15 schema-stability rule extends to the ISSUE-16 fields)
     for k in ("warmup", "autotune_cache_dir"):
         assert k not in plain
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 32: the engine prepares its weights once
+# ---------------------------------------------------------------------------
+
+def _policy(name):
+    from paddle_tpu.core import dtypes
+    return getattr(dtypes, name)
+
+
+def _leaf_paths(tree):
+    return {"/".join(str(k.key) for k in path): leaf for path, leaf
+            in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+@pytest.mark.parametrize("experts", [0, 4], ids=["dense", "moe"])
+@pytest.mark.parametrize("policy", ["float32", "bfloat16_compute"])
+def test_prepared_tree_stacks_blocks_and_casts_only_operands(policy,
+                                                             experts):
+    """``serving_variables``: the ``block<i>`` subtrees become ONE
+    ``blocks`` subtree on a leading layer axis; exactly the leaves the
+    block passes through ``cast_compute`` are in the policy's compute
+    type, every other leaf in its stored type (an expert layer's leaves
+    too: ``MoEFFN`` multiplies them as stored); embeddings, the final
+    LayerNorm and the other collections are the objects they were."""
+    from paddle_tpu.core import use_policy
+    from paddle_tpu.models.transformer import TransformerBlock
+    model = TransformerLM(vocab=V, dim=DIM, num_layers=3, num_heads=HEADS,
+                          ffn_hidden=FFN, max_len=W, moe_experts=experts)
+    vs = model.init(jax.random.PRNGKey(0), jnp.zeros((1, W), jnp.int32))
+    pol = _policy(policy)
+    with use_policy(pol):
+        got = model.serving_variables(vs)
+    own, was = got["params"]["transformer_lm"], vs["params"]["transformer_lm"]
+    assert set(own) == {"emb", "pos", "ln_f", "blocks"}
+    for name in ("emb", "pos", "ln_f"):
+        for a, b in zip(jax.tree_util.tree_leaves(own[name]),
+                        jax.tree_util.tree_leaves(was[name])):
+            assert a is b
+    assert got["state"] is vs["state"]
+    stack = _leaf_paths(own["blocks"])
+    assert set(stack) == set(_leaf_paths(was["block0"]))
+    cast = [n for n in TransformerBlock.compute_operands if n in stack]
+    assert len(cast) == (4 if experts else 6)
+    for name, leaf in stack.items():
+        per_block = [_leaf_paths(was[f"block{i}"])[name] for i in range(3)]
+        assert leaf.shape == (3,) + per_block[0].shape, name
+        want = pol.compute_dtype if name in cast else per_block[0].dtype
+        assert leaf.dtype == want, (name, leaf.dtype)
+        np.testing.assert_array_equal(
+            np.asarray(leaf, np.float32),
+            np.asarray(jnp.stack(per_block).astype(want), np.float32))
+
+
+@pytest.fixture(scope="module")
+def prepared_logits():
+    """Logits of ``prefill``, ``decode_span`` and ``decode_step`` under
+    ``bfloat16_compute`` on three trees of one jittered model: the
+    training tree (stacked at trace time, every call), the prepared tree,
+    and the prepared tree with ONE leaf too many cast (``ln1/scale`` in
+    bfloat16): what a wrong list of cast leaves would cost."""
+    from paddle_tpu.core import bfloat16_compute, use_policy
+    model = TransformerLM(vocab=V, dim=DIM, num_layers=LAYERS,
+                          num_heads=HEADS, ffn_hidden=FFN, max_len=W)
+    vs = model.init(jax.random.PRNGKey(0), jnp.zeros((1, W), jnp.int32))
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 64))
+    # LayerNorm scales start at 1 and biases at 0, which every type holds
+    vs = jax.tree_util.tree_map(
+        lambda x: x + 0.1 * jax.random.normal(next(keys), x.shape), vs)
+    rng = np.random.RandomState(0)
+    B, P, Q = 2, 7, 5
+    ids = rng.randint(0, V, (B, W)).astype(np.int32)
+
+    def run(tree):
+        cache = PagedKVCache(LAYERS, HEADS, DIM // HEADS, B * MB + 1, BS,
+                             max_slots=B, max_blocks_per_seq=MB)
+        for b in range(B):
+            assert cache.ensure_capacity(b, W)
+        tbl = jnp.asarray(cache.tables)
+        out = {}
+        out["prefill"], (ks, vv) = jax.jit(lambda v, i: model.apply(
+            v, i, method="prefill"))(tree, jnp.asarray(ids))
+        scat = jax.vmap(kvc.scatter_prefill, in_axes=(0, 0, None, None))
+        plen = jnp.full((B,), P, jnp.int32)
+        k, v = scat(cache.k, ks, tbl, plen), scat(cache.v, vv, tbl, plen)
+        every = jnp.ones((B,), bool)
+        out["decode_span"], (k, v, _) = jax.jit(
+            lambda t, c, kv: model.apply(
+                t, c, kv, plen, jnp.full((B,), Q, jnp.int32), every,
+                method="decode_span"))(
+                    tree, jnp.asarray(ids[:, P:P + Q]), (k, v, tbl))
+        out["decode_step"], _ = jax.jit(
+            lambda t, c, kv: model.apply(
+                t, c, kv, plen + Q, every, method="decode_step"))(
+                    tree, jnp.asarray(ids[:, P + Q]), (k, v, tbl))
+        return {name: np.asarray(x) for name, x in out.items()}
+
+    with use_policy(bfloat16_compute):
+        prepared = model.serving_variables(vs)
+        own = prepared["params"]["transformer_lm"]
+        ln1 = own["blocks"]["ln1"]
+        wrong = {**prepared, "params": {"transformer_lm": {
+            **own, "blocks": {**own["blocks"], "ln1": {
+                **ln1, "scale": ln1["scale"].astype(jnp.bfloat16)}}}}}
+        return {"training": run(vs), "prepared": run(prepared),
+                "wrong": run(wrong)}
+
+
+@pytest.mark.parametrize("method", ["prefill", "decode_span", "decode_step"])
+def test_prepared_tree_logits_equal_training_tree(prepared_logits, method):
+    """The same work, not less of it: on the prepared tree every entry
+    point gives the logits it gives on the training tree, to the bit
+    where the backend compiles the two products alike, and otherwise
+    inside a hundredth of what ONE wrongly cast leaf moves them by."""
+    training, prepared, wrong = (prepared_logits[k][method]
+                                 for k in ("training", "prepared", "wrong"))
+    assert training.shape == prepared.shape and training.dtype == np.float32
+    miscast = float(np.max(np.abs(wrong - training)))
+    assert miscast > 1e-3, miscast      # the control does show
+    gap = float(np.max(np.abs(prepared - training)))
+    assert gap <= miscast / 100, (gap, miscast)
+
+
+@pytest.mark.parametrize("kw", [{}, {"speculative": 3},
+                                {"prefill_chunk": 4}],
+                         ids=["plain", "speculative3", "chunk4"])
+def test_prepared_engine_tokens_equal_training_tree_engine(
+        model_and_vars, monkeypatch, kw):
+    """Greedy tokens over two waves of admit/evict churn under
+    ``bfloat16_compute``: the engine on its prepared tree against an
+    engine whose model hands the training tree back (the programs then
+    stack and cast inside, every call, as they did before). Both trace
+    each entry point once, the preparation not among them."""
+    from paddle_tpu.core import bfloat16_compute, use_policy
+    model, vs = model_and_vars
+    with use_policy(bfloat16_compute):
+        got, eng = _churn_run(model, vs, None, waves=2, **kw)
+        assert "blocks" in eng.variables["params"]["transformer_lm"]
+        monkeypatch.setattr(model, "serving_variables", lambda tree: tree)
+        want, old = _churn_run(model, vs, None, waves=2, **kw)
+        assert "block0" in old.variables["params"]["transformer_lm"]
+    assert got == want
+    assert eng.compile_counts() == {"prefill": 1, "tick": 1}
+    assert old.compile_counts() == {"prefill": 1, "tick": 1}
+
+
+def test_engine_holds_no_copy_of_the_blocks_float32_matrices(model_and_vars):
+    """What the engine keeps is the prepared tree alone: under
+    ``bfloat16_compute`` no float32 leaf of it has three axes (a stacked
+    matrix), the caller's tree is untouched, and ``warmup()`` reports the
+    bytes both programs take."""
+    from paddle_tpu.core import bfloat16_compute, use_policy
+    model, vs = model_and_vars
+    before = jax.tree_util.tree_structure(vs)
+    with use_policy(bfloat16_compute):
+        eng = DecodeEngine(model, vs, max_slots=2, block_size=BS)
+        report = eng.warmup()
+    assert jax.tree_util.tree_structure(vs) == before
+    leaves = jax.tree_util.tree_leaves(eng.variables)
+    assert not [x.shape for x in leaves
+                if x.ndim == 3 and x.dtype == jnp.float32]
+    assert report["prepared_bytes"] == sum(x.nbytes for x in leaves)
+    assert report["compile_counts"] == {"prefill": 1, "tick": 1}
+    matrices = LAYERS * (4 * DIM * DIM + 2 * DIM * FFN)
+    whole = sum(x.nbytes for x in jax.tree_util.tree_leaves(vs))
+    assert report["prepared_bytes"] == whole - 2 * matrices
+
+
+@pytest.mark.parametrize("build,trace", [("float32", "bfloat16_compute"),
+                                         ("bfloat16_compute", "float32")])
+def test_engine_traced_under_another_policy_raises(model_and_vars, build,
+                                                   trace):
+    """The prepared operands are right for the policy of the build only:
+    a first call (the trace) under another policy fails loudly. Once
+    traced, a program runs as traced whatever the caller's policy (a
+    policy is no part of jit's cache key), so a warmed engine serves from
+    any context."""
+    from paddle_tpu.core import use_policy
+    model, vs = model_and_vars
+    with use_policy(_policy(build)):
+        cold = DecodeEngine(model, vs, max_slots=2, block_size=BS)
+        warm = DecodeEngine(model, vs, max_slots=2, block_size=BS)
+        warm.warmup()
+        want = warm.admit(0, [3, 1, 4], reserve_len=6)
+        warm.evict(0)
+    with use_policy(_policy(trace)):
+        with pytest.raises(RuntimeError, match="prepared its weights"):
+            cold.admit(0, [3, 1, 4], reserve_len=6)
+        assert warm.admit(0, [3, 1, 4], reserve_len=6) == want
+        warm.decode_tick()
+    assert warm.compile_counts() == {"prefill": 1, "tick": 1}
+
+
+@pytest.mark.parametrize("policy", ["float32", "bfloat16_compute"])
+def test_tp_prepared_stack_keeps_the_rule_behind_the_layer_axis(
+        model_and_vars, policy):
+    """With ``mesh=``: the per-block leaves are placed by the rule, then
+    stacked, and a stacked leaf carries its rule's spec behind an
+    unsharded layer axis, ``P(None, *rule)``, pinned on the preparing
+    program's outputs: the tick traces once over two waves of churn."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from paddle_tpu.core import use_policy
+    from paddle_tpu.parallel.megatron import megatron_sp_rules
+    model, vs = model_and_vars
+    with use_policy(_policy(policy)):
+        tp, eng = _churn_run(model, vs, _tp_mesh(), waves=2)
+        base, _ = _churn_run(model, vs, None, waves=2)
+    rules = megatron_sp_rules()
+    stack = _leaf_paths(eng.variables["params"]["transformer_lm"]["blocks"])
+    sharded = 0
+    for name, leaf in stack.items():
+        rule = rules.spec_for("transformer_lm/block0/" + name)
+        assert isinstance(leaf.sharding, NamedSharding), name
+        assert leaf.sharding.spec == P(None, *rule), (name, leaf.sharding)
+        sharded += "model" in rule
+    assert sharded == 7                 # the rule's seven sharded leaves
+    assert eng.compile_counts() == {"prefill": 1, "tick": 1}
+    if policy == "float32":
+        assert tp == base
