@@ -3,7 +3,7 @@ ResNet, seq2seq attention NMT, sequence tagging, CTR) built on paddle_tpu.nn."""
 
 from .ctr import CTR_SHARDING_RULES, SparseLR, WideDeepCTR
 from .gan import Discriminator, Generator, gan_step_fn
-from .latent_moe import LatentMoEBlock, LatentMoELM
+from .latent_moe import LatentMoEBlock, LatentMoELM, ShortcutMoEBlock
 from .image_zoo import AlexNet, GoogLeNet, VGG, vgg16, vgg19
 from .mnist import LeNet, MnistMLP
 from .resnet import (ResNet, resnet18, resnet34, resnet50, resnet101,

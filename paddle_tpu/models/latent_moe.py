@@ -22,10 +22,20 @@ What differs from :class:`~paddle_tpu.models.TransformerLM`, by layer:
   inside a compiled program, so weights held in bfloat16 stay where they
   are.
 
+A second block, :class:`ShortcutMoEBlock`, is LongCat-Flash's
+shortcut-connected DOUBLE layer: two latent attentions and two dense
+feed-forwards in sequence, and one expert layer (a softmax router whose
+last outputs are identity experts) that reads the first feed-forward's
+input and joins the stream after the second. It leaves TWO latent rows a
+token in the cache. :class:`LatentMoELM` is built from either (``block``),
+and :meth:`LatentMoELM.cache_spec` counts the rows.
+
 Serving entry points keep :class:`TransformerLM`'s signatures; ``kv`` is
 ``(latent pool, tables)`` and each returns, as a third result, the
 counters the spec declares: ``expert_tokens [expert layers, experts
-held]``, the rows each held expert received in this call.
+held]``, the rows each held expert received in this call; with identity
+experts also ``zero_pairs [expert layers]``
+(:class:`~paddle_tpu.nn.moe.HeldExpertsFFN`).
 """
 
 from __future__ import annotations
@@ -41,13 +51,15 @@ from paddle_tpu.nn.attention import LatentAttention
 from paddle_tpu.nn.layers import Embedding, GatedFFN, Linear, RMSNorm
 from paddle_tpu.nn.moe import HeldExpertsFFN
 
-__all__ = ["LatentMoEBlock", "LatentMoELM"]
+__all__ = ["LatentMoEBlock", "ShortcutMoEBlock", "LatentMoELM"]
 
 
 class LatentMoEBlock(Module):
     """One sandwich-norm block; ``moe`` (the :class:`HeldExpertsFFN`
     arguments and ``shared_hidden``) makes its feed-forward an expert
     layer, ``dense_hidden`` a dense one."""
+
+    cache_rows = 1       # latent rows a token leaves in the paged cache
 
     def __init__(self, dim: int, attn: dict, dense_hidden: Optional[int],
                  moe: Optional[dict], eps: float, w_init, name=None):
@@ -64,7 +76,7 @@ class LatentMoEBlock(Module):
             self.ffn = GatedFFN(dim, dense_hidden, w_init)
 
     def _ffn(self, h, live=None):
-        """``h [B, T, D] -> (y, rows each held expert received | None)``;
+        """``h [B, T, D] -> (y, the expert layer's counters | None)``;
         ``live [B, T]`` marks the rows that are not padding."""
         z = self.norm_pre_mlp(h)
         if not self.is_moe:
@@ -104,14 +116,105 @@ class LatentMoEBlock(Module):
             return h + y, pool, counts
 
 
+class ShortcutMoEBlock(Module):
+    """LongCat-Flash's shortcut-connected double layer, four norms (none
+    on a sublayer's output):
+
+    ``h0 = x + Attn_0(RMS(x))``; ``u = RMS(h0)``; ``m = Experts(u)``;
+    ``h1 = h0 + FFN_0(u)``; ``h2 = h1 + Attn_1(RMS(h1))``;
+    ``h3 = h2 + FFN_1(RMS(h2))``; ``y = h3 + m``.
+
+    The expert layer reads the first feed-forward's input and joins the
+    stream after the second: the second attention and both dense
+    feed-forwards do not depend on it (in a deployment its exchange hides
+    behind them). ``moe`` is the :class:`HeldExpertsFFN` arguments; there
+    is no shared expert. Two latent rows a token: pool layers ``layer``
+    and ``layer + 1``."""
+
+    cache_rows = 2
+    is_moe = True
+
+    def __init__(self, dim: int, attn: dict, dense_hidden: int, moe: dict,
+                 eps: float, w_init, name=None):
+        super().__init__(name=name)
+        assert moe is not None, \
+            "every double layer has an expert layer: num_dense_layers == 0"
+        moe = dict(moe)
+        assert not moe.pop("shared_hidden", 0), "no shared expert here"
+        self.attn0 = LatentAttention(dim, eps=eps, w_init=w_init, **attn)
+        self.attn1 = LatentAttention(dim, eps=eps, w_init=w_init, **attn)
+        self.norm_attn0, self.norm_ffn0 = RMSNorm(eps), RMSNorm(eps)
+        self.norm_attn1, self.norm_ffn1 = RMSNorm(eps), RMSNorm(eps)
+        self.ffn0 = GatedFFN(dim, dense_hidden, w_init)
+        self.ffn1 = GatedFFN(dim, dense_hidden, w_init)
+        self.experts = HeldExpertsFFN(dim, w_init=w_init, **moe)
+
+    @property
+    def attn(self):
+        return self.attn0
+
+    def _layer(self, x, attend, live=None):
+        """The double layer on ``x [B, T, D]``; ``attend(j, attn, z)`` is
+        attention ``j`` of the two on its normalised input."""
+        with jax.named_scope("attn0"):
+            h = x + attend(0, self.attn0, self.norm_attn0(x))
+        u = self.norm_ffn0(h)
+        with jax.named_scope("moe_shortcut"):
+            m, counts = self.experts(
+                u.reshape(-1, u.shape[-1]),
+                None if live is None else live.reshape(-1))
+        with jax.named_scope("ffn0"):
+            h = h + self.ffn0(u)
+        with jax.named_scope("attn1"):
+            h = h + attend(1, self.attn1, self.norm_attn1(h))
+        with jax.named_scope("ffn1"):
+            h = h + self.ffn1(self.norm_ffn1(h))
+        return h + m.reshape(h.shape), counts
+
+    def forward(self, x, positions=None):
+        return self._layer(x, lambda j, attn, z: attn(z, positions))
+
+    def _paged(self, x, pool, layer, live, method, *args, **kw):
+        """The double layer against the paged cache: both attentions go
+        through ``LatentAttention.<method>`` on pool layers ``layer`` and
+        ``layer + 1``, each handing the pool on."""
+        with self.scope():
+            pools = [pool]
+
+            def attend(j, attn, z):
+                a, pools[0] = getattr(attn, method)(z, pools[0], layer + j,
+                                                    *args, **kw)
+                return a
+
+            y, counts = self._layer(x, attend, live)
+            return y, pools[0], counts
+
+    def decode_step(self, x, pool, layer, tables, positions, active,
+                    attn_impl: str = "xla"):
+        return self._paged(x, pool, layer, active[:, None], "decode",
+                           tables, positions, active, impl=attn_impl)
+
+    def decode_span(self, x, pool, layer, tables, start, n, active,
+                    write_from=None):
+        live = active[:, None] & (jnp.arange(x.shape[1])[None] < n[:, None])
+        return self._paged(x, pool, layer, live, "decode_span", tables,
+                           start, n, active, write_from=write_from)
+
+
 class LatentMoELM(Module):
     """``ids [B, T] -> logits [B, T, vocab]``.
 
     ``experts_held = (first id, count)`` is this chip's share of every
     expert layer's ``num_experts`` (default: all of them, the uncut
     layer); ``vocab`` is the slice of the vocabulary held here (embedding
-    and head alike). ``forward(ids, return_aux=True)`` also returns
-    ``expert_tokens``."""
+    and head alike). ``forward(ids, return_aux=True)`` also returns the
+    counters. ``block`` is the class the stack is made of
+    (:class:`LatentMoEBlock`, or :class:`ShortcutMoEBlock` with no leading
+    dense layers and no shared expert); ``scoring``, ``select_bias`` and
+    ``num_zero_experts`` are the router's
+    (:class:`~paddle_tpu.nn.moe.HeldExpertsFFN`), ``q_scale`` and
+    ``kv_scale`` the latents' constant factors
+    (:class:`~paddle_tpu.nn.attention.LatentAttention`)."""
 
     def __init__(self, vocab: int, dim: int, num_layers: int,
                  num_dense_layers: int, num_heads: int, q_rank: int,
@@ -120,7 +223,10 @@ class LatentMoELM(Module):
                  top_k: int, experts_held: Optional[Tuple[int, int]] = None,
                  num_shared: int = 1, routed_scaling: float = 1.0,
                  rope_base: float = 10000.0, eps: float = 1e-5,
-                 max_len: int = 131072, w_init=I.fan_in_uniform,
+                 max_len: int = 131072, block=LatentMoEBlock,
+                 scoring: str = "sigmoid", select_bias: bool = False,
+                 num_zero_experts: int = 0, q_scale: float = 1.0,
+                 kv_scale: float = 1.0, w_init=I.fan_in_uniform,
                  name="latent_moe_lm"):
         super().__init__(name=name)
         assert 0 <= num_dense_layers <= num_layers
@@ -128,31 +234,39 @@ class LatentMoELM(Module):
         self.emb = Embedding(vocab, dim)
         attn = dict(num_heads=num_heads, q_rank=q_rank, kv_rank=kv_rank,
                     nope_dim=nope_dim, rope_dim=rope_dim, v_dim=v_dim,
-                    rope_base=rope_base)
+                    rope_base=rope_base, q_scale=q_scale, kv_scale=kv_scale)
         moe = dict(hidden=expert_hidden, num_experts=num_experts,
                    top_k=top_k, experts_held=experts_held,
-                   scaling=routed_scaling,
+                   scaling=routed_scaling, scoring=scoring,
+                   select_bias=select_bias, num_zero=num_zero_experts,
                    shared_hidden=expert_hidden * num_shared)
         self.blocks = [
-            LatentMoEBlock(dim, attn, dense_hidden,
-                           None if i < num_dense_layers else moe, eps,
-                           w_init, name=f"block{i}")
+            block(dim, attn, dense_hidden,
+                  None if i < num_dense_layers else moe, eps, w_init,
+                  name=f"block{i}")
             for i in range(num_layers)]
+        # the pool layer of each block's first latent row
+        self.first_row = [sum(b.cache_rows for b in self.blocks[:i])
+                          for i in range(num_layers)]
         self.norm_f = RMSNorm(eps)
         self.head = Linear(vocab, use_bias=False, w_init=w_init)
 
     def cache_spec(self):
         """What the serving engine asks a model (``serve/engine.py``):
-        ``layers``; ``pools``, the paged state a token leaves in one
-        layer, as named rows (here ONE latent row, ``[c_kv | k_rope]``
-        padded to the lane tile); ``counters``, what ``decode_step`` and
-        ``decode_span`` return beside logits and pools."""
+        ``layers``, the cache layers: the latent rows a token leaves, one
+        a block or two (``cache_rows``); ``pools``, the paged state a
+        token leaves in one cache layer, as named rows (here ONE latent
+        row, ``[c_kv | k_rope]`` padded to the lane tile); ``counters``,
+        what ``decode_step`` and ``decode_span`` return beside logits and
+        pools."""
         moe = [b for b in self.blocks if b.is_moe]
-        spec = {"layers": len(self.blocks),
+        spec = {"layers": sum(b.cache_rows for b in self.blocks),
                 "pools": {"latent": (self.blocks[0].attn.row_width,)}}
         if moe:
             spec["counters"] = {
                 "expert_tokens": (len(moe), moe[0].experts.count)}
+            if moe[0].experts.num_zero:
+                spec["counters"]["zero_pairs"] = (len(moe),)
         return spec
 
     def serving_variables(self, variables):
@@ -165,7 +279,8 @@ class LatentMoELM(Module):
 
     def _counters(self, counts):
         counts = [c for c in counts if c is not None]
-        return {"expert_tokens": jnp.stack(counts)} if counts else {}
+        return {k: jnp.stack([c[k] for c in counts])
+                for k in (counts[0] if counts else ())}
 
     def _embed(self, ids):
         with jax.named_scope("embed"):
@@ -201,9 +316,9 @@ class LatentMoELM(Module):
             counts = []
             for i, blk in enumerate(self.blocks):
                 with jax.named_scope(blk._name):
-                    x, pool, c = blk.decode_step(x, pool, i, tables,
-                                                 positions, active,
-                                                 attn_impl=attn_impl)
+                    x, pool, c = blk.decode_step(
+                        x, pool, self.first_row[i], tables, positions,
+                        active, attn_impl=attn_impl)
                 counts.append(c)
             logits = self._logits(x)
         return logits[:, 0], (pool, tables), self._counters(counts)
@@ -224,9 +339,9 @@ class LatentMoELM(Module):
             counts = []
             for i, blk in enumerate(self.blocks):
                 with jax.named_scope(blk._name):
-                    x, pool, c = blk.decode_span(x, pool, i, tables, start,
-                                                 n, active,
-                                                 write_from=write_from)
+                    x, pool, c = blk.decode_span(
+                        x, pool, self.first_row[i], tables, start, n,
+                        active, write_from=write_from)
                 counts.append(c)
             logits = self._logits(x)
         return logits, (pool, tables), self._counters(counts)

@@ -518,9 +518,11 @@ class LatentAttention(Module):
     position (``nope_dim``, from the latents) beside a rotated part
     (``rope_dim``); its value has ``v_dim``. No biases; both latents are
     RMS-normalised; scores are scaled by ``1 / sqrt(nope_dim + rope_dim)``.
+    ``q_scale`` and ``kv_scale`` are constant factors on the normalised
+    latents (LongCat-Flash's ``sqrt(dim / rank)``; 1.0 multiplies nothing).
 
     What a token leaves in the cache is ONE row, ``[c_kv | k_rope]``
-    (after the norm and after the rotation), ``kv_rank + rope_dim`` values
+    (after the norm, ``kv_scale`` and the rotation), ``kv_rank + rope_dim`` values
     padded with zeros to ``row_width``, a multiple of ``ROW_ALIGN``: the
     chip stores a ``[.., bs, 576]`` bfloat16 array as 640 columns anyway,
     and the decode kernel's page copies want the array's shape to say so.
@@ -549,10 +551,12 @@ class LatentAttention(Module):
     def __init__(self, dim: int, num_heads: int, q_rank: int, kv_rank: int,
                  nope_dim: int, rope_dim: int, v_dim: int,
                  rope_base: float = 10000.0, eps: float = 1e-5,
+                 q_scale: float = 1.0, kv_scale: float = 1.0,
                  w_init=I.fan_in_uniform, name=None):
         super().__init__(name=name)
         from .layers import RMSNorm
         self.dim, self.num_heads = dim, num_heads
+        self.q_scale, self.kv_scale = float(q_scale), float(kv_scale)
         self.q_rank, self.kv_rank = q_rank, kv_rank
         self.nope_dim, self.rope_dim, self.v_dim = nope_dim, rope_dim, v_dim
         self.rope_base = float(rope_base)
@@ -579,11 +583,12 @@ class LatentAttention(Module):
     def _project(self, x, positions):
         """``x [B, T, D]``, ``positions [B, T]`` -> ``(q_nope [B, T, H,
         nope], q_rope [B, T, H, rope], c_kv [B, T, kv_rank], k_rope [B, T,
-        rope])``, latents normalised, rotary parts rotated."""
+        rope])``, latents normalised and scaled, rotary parts rotated."""
         from .rotary import apply_rotary, rotary_angles
         B, T = x.shape[:2]
         h = self.num_heads
-        q = self.q_b(self.q_norm(self.q_a(x))).reshape(
+        scaled = lambda c, f: c if f == 1.0 else c * f
+        q = self.q_b(scaled(self.q_norm(self.q_a(x)), self.q_scale)).reshape(
             B, T, h, self.nope_dim + self.rope_dim)
         kv = self.kv_a(x)
         cos, sin = rotary_angles(positions, self.rope_dim, self.rope_base)
@@ -591,7 +596,8 @@ class LatentAttention(Module):
                               sin[:, :, None])
         k_rope = apply_rotary(kv[..., self.kv_rank:], cos, sin)
         return (q[..., :self.nope_dim], q_rope,
-                self.kv_norm(kv[..., :self.kv_rank]), k_rope)
+                scaled(self.kv_norm(kv[..., :self.kv_rank]), self.kv_scale),
+                k_rope)
 
     def _rows(self, c_kv, k_rope, dtype):
         """The cached rows ``[..., row_width]`` of ``dtype``."""

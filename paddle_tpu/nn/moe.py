@@ -132,11 +132,28 @@ class MoEFFN(Module):
 
 class HeldExpertsFFN(Module):
     """This chip's share of an expert layer of an expert-parallel
-    deployment: ``x [N, D] -> (y [N, D], tokens_per_expert [held])``.
+    deployment: ``x [N, D] -> (y [N, D], counters)``, the counters a dict
+    of int32 arrays (``expert_tokens [held]``, and with identity experts
+    ``zero_pairs``).
 
-    The router scores ALL ``num_experts`` (``sigmoid(x W_g)`` in float32),
-    takes each token's ``top_k`` and normalises their scores to gates
-    (``scaling * s_e / (sum of the k + 1e-20)``), as the whole layer would.
+    The router scores ALL its outputs in float32 and takes each token's
+    ``top_k``, as the whole layer would. ``scoring``:
+
+    - ``"sigmoid"``: ``s = sigmoid(x W_g)``, gates normalised over the k
+      (``scaling * s_e / (sum of the k + 1e-20)``);
+    - ``"softmax"``: ``s = softmax(x W_g)``, gates the raw scores
+      (``scaling * s_e``, not renormalised).
+
+    ``select_bias`` adds a per-output bias (parameter ``select_bias``) to
+    the scores FOR THE CHOICE ONLY: it moves which outputs are taken and
+    never a gate. ``num_zero`` identity ("zero-computation") experts
+    follow the ``num_experts`` real ones in the router's outputs (width
+    ``num_experts + num_zero``): a chosen identity expert adds ``g_e * x``
+    and computes nothing, so its pairs never enter the grouped product
+    (in the sort of the ``N * top_k`` pairs they are absent pairs, like
+    those of experts held elsewhere); their gates are summed a token and
+    multiply the input, on every chip for its own tokens.
+
     Of the ``N * top_k`` (token, expert) pairs this layer keeps those whose
     expert is one of the ``experts_held = (first id, count)`` it holds,
     sorts them by expert and runs each held expert's SiLU-gated
@@ -148,33 +165,51 @@ class HeldExpertsFFN(Module):
     is not computed here and nothing stands in for it. A shared expert is
     the caller's (every chip computes it alike).
 
-    ``tokens_per_expert`` (int32) counts the rows each held expert
-    received: the engine's ``expert_pairs`` / ``expert_hits`` counters."""
+    ``expert_tokens`` counts the rows each held expert received: the
+    engine's ``expert_pairs`` / ``expert_hits`` counters. ``zero_pairs``
+    (a scalar, only with ``num_zero``) counts the live rows' choices that
+    went to identity experts."""
 
     def __init__(self, dim: int, hidden: int, num_experts: int, top_k: int,
                  experts_held=None, scaling: float = 1.0,
-                 w_init=I.fan_in_uniform, name=None):
+                 scoring: str = "sigmoid", select_bias: bool = False,
+                 num_zero: int = 0, w_init=I.fan_in_uniform, name=None):
         super().__init__(name=name)
         first, count = experts_held or (0, num_experts)
         assert 0 <= first and first + count <= num_experts and count > 0
-        assert 1 <= top_k <= num_experts
+        assert 1 <= top_k <= num_experts + num_zero and num_zero >= 0
+        assert scoring in ("sigmoid", "softmax"), scoring
         self.dim, self.hidden = dim, hidden
         self.num_experts, self.top_k = num_experts, top_k
         self.first, self.count = int(first), int(count)
         self.scaling = float(scaling)
+        self.scoring, self.select_bias = scoring, bool(select_bias)
+        self.num_zero = int(num_zero)
         self.w_init = w_init
 
     def route(self, x):
         """``(expert ids [N, k] int32, gates [N, k] float32)`` over all
-        ``num_experts``. The product is float32 at the highest precision:
-        the eighth and the ninth of 256 scores lie close together."""
-        wg = self.param("router", self.w_init, (self.dim, self.num_experts))
-        scores = jax.nn.sigmoid(jnp.dot(
-            x.astype(jnp.float32), wg.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))
-        top, idx = jax.lax.top_k(scores, self.top_k)
-        gates = self.scaling * top / (
-            jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+        the router's outputs. The product is float32 at the highest
+        precision: the eighth and the ninth of 256 scores lie close
+        together."""
+        width = self.num_experts + self.num_zero
+        wg = self.param("router", self.w_init, (self.dim, width))
+        logits = jnp.dot(x.astype(jnp.float32), wg.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        scores = (jax.nn.sigmoid(logits) if self.scoring == "sigmoid"
+                  else jax.nn.softmax(logits, axis=-1))
+        if self.select_bias:
+            bias = self.param("select_bias", I.zeros, (width,))
+            _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32),
+                                   self.top_k)
+            top = jnp.take_along_axis(scores, idx, axis=-1)
+        else:
+            top, idx = jax.lax.top_k(scores, self.top_k)
+        if self.scoring == "sigmoid":
+            gates = self.scaling * top / (
+                jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+        else:
+            gates = self.scaling * top
         return idx.astype(jnp.int32), gates
 
     def forward(self, x, live=None):
@@ -212,7 +247,16 @@ class HeldExpertsFFN(Module):
             # back to token order: a token's K rows, summed
             out = jnp.take(y * g[:, None], jnp.argsort(order),
                            axis=0).reshape(N, K, D).sum(axis=1)
-        return out, sizes
+        counters = {"expert_tokens": sizes}
+        if self.num_zero:
+            with jax.named_scope("moe_zero"):
+                zero = idx >= self.num_experts               # [N, K]
+                if live is not None:
+                    zero = zero & live[:, None]
+                out = out + jnp.sum(jnp.where(zero, gates, 0.0), axis=-1)[
+                    :, None] * x.astype(out.dtype)
+                counters["zero_pairs"] = jnp.sum(zero.astype(jnp.int32))
+        return out, counters
 
 
 def moe_sharding_rules(expert_axis: str = "expert"):

@@ -1004,20 +1004,28 @@ class DecodeEngine:
         return tok
 
     def _count_experts(self, *counters) -> Dict[str, int]:
-        """Fetch the ``expert_tokens`` of one or more calls (``[expert
-        layers, experts held]`` int32 each), add them to the cumulative
-        ``expert_pairs`` and ``expert_hits`` and return the calls' facts
-        for a span: the pairs, the held experts that got a token, and the
-        busiest expert's tokens. Nothing for a model that counts none."""
-        counts = [np.asarray(c["expert_tokens"]) for c in counters
-                  if "expert_tokens" in c]
-        if not counts:
+        """Fetch the counters of one or more calls in one go, add the
+        ``expert_tokens`` (``[expert layers, experts held]`` int32 each) to
+        the cumulative ``expert_pairs`` and ``expert_hits`` and return the
+        calls' facts for a span: the pairs, the held experts that got a
+        token, and the busiest expert's tokens; every other counter the
+        model declares under its own name, summed over layers and calls.
+        Nothing for a model that counts none."""
+        counters = jax.device_get([c for c in counters if c])
+        if not counters:
             return {}
-        facts = {"expert_pairs": int(sum(c.sum() for c in counts)),
-                 "expert_hits": int(sum((c > 0).sum() for c in counts)),
-                 "expert_max": int(max(c.max() for c in counts))}
-        self.expert_pairs += facts["expert_pairs"]
-        self.expert_hits += facts["expert_hits"]
+        facts: Dict[str, int] = {}
+        for name in counters[0]:
+            values = [c[name] for c in counters]
+            if name == "expert_tokens":
+                facts.update(
+                    expert_pairs=int(sum(c.sum() for c in values)),
+                    expert_hits=int(sum((c > 0).sum() for c in values)),
+                    expert_max=int(max(c.max() for c in values)))
+            else:
+                facts[name] = int(sum(c.sum() for c in values))
+        self.expert_pairs += facts.get("expert_pairs", 0)
+        self.expert_hits += facts.get("expert_hits", 0)
         return facts
 
     def evict(self, slot: int) -> None:

@@ -28,7 +28,8 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu.core import bfloat16_compute, mesh as mesh_lib, use_policy
-from paddle_tpu.models import LatentMoELM, TransformerLM
+from paddle_tpu.models import (LatentMoELM, ShortcutMoEBlock,
+                               TransformerLM)
 from paddle_tpu.nn import MultiHeadAttention, pallas_mode
 from paddle_tpu.nn.pallas_attention import (flash_attention,
                                             latent_paged_decode,
@@ -82,12 +83,14 @@ def test_paged_kernels_lower(kind, heads, dh):
                   tables, vec, vec, layer)
 
 
+@pytest.mark.parametrize("heads,layers", [(128, 5), (64, 8)])
 @pytest.mark.parametrize("kind", ["float32", "bfloat16"])
-def test_latent_decode_kernel_lowers(kind):
-    """The latent decode kernel at the published widths: 128 heads
-    against pages of 16 rows of 576 values stored in 640 columns, the
-    first 512 of them the values, 16 and 32 pages a group."""
-    heads, row, values, layers = 128, 640, 512, 5
+def test_latent_decode_kernel_lowers(kind, heads, layers):
+    """The latent decode kernel at the published widths: 128 heads (five
+    cache layers) or 64 (eight: two a double layer) against pages of 16
+    rows of 576 values stored in 640 columns, the first 512 of them the
+    values, 16 and 32 pages a group."""
+    row, values = 640, 512
     for group in (16, 32):
         lower_tpu(functools.partial(latent_paged_decode, value_width=values,
                                     scale=192 ** -0.5, group=group,
@@ -234,16 +237,25 @@ def pool_sized_results(text, sizes):
             if int(np.prod(dims)) in sizes]
 
 
-def toy_latent_engine(blocks, **kw):
+def toy_latent_engine(blocks, shortcut=False, **kw):
     """A toy of the latent-attention expert model at the lane tile's
     widths: two layers (one dense, one of 8 experts with 4 held), a
     latent row of 128 + 64 values stored in 256 columns, bfloat16
-    weights and pool, a chunked prefill."""
-    model = LatentMoELM(vocab=512, dim=256, num_layers=2,
-                        num_dense_layers=1, num_heads=4, q_rank=128,
-                        kv_rank=128, nope_dim=128, rope_dim=64, v_dim=128,
-                        dense_hidden=512, expert_hidden=256, num_experts=8,
-                        top_k=2, experts_held=(2, 4), max_len=256)
+    weights and pool, a chunked prefill. ``shortcut``: ONE
+    shortcut-connected double layer in their place (two cache layers
+    too), a softmax router with a bias and 4 identity experts, both
+    latent factors."""
+    sizes = dict(vocab=512, dim=256, num_heads=4, q_rank=128, kv_rank=128,
+                 nope_dim=128, rope_dim=64, v_dim=128, dense_hidden=512,
+                 expert_hidden=256, num_experts=8, top_k=2,
+                 experts_held=(2, 4), max_len=256)
+    if shortcut:
+        model = LatentMoELM(num_layers=1, num_dense_layers=0, num_shared=0,
+                            block=ShortcutMoEBlock, scoring="softmax",
+                            select_bias=True, num_zero_experts=4,
+                            q_scale=2 ** 0.5, kv_scale=2 ** 0.5, **sizes)
+    else:
+        model = LatentMoELM(num_layers=2, num_dense_layers=1, **sizes)
     variables = jax.tree_util.tree_map(
         lambda a: a.astype(jnp.bfloat16),
         model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
@@ -256,9 +268,10 @@ def toy_latent_engine(blocks, **kw):
 # prefetched there whole, and its writes with it
 @pytest.mark.parametrize("kv_dtype,blocks,speculative",
                          [(None, 2449, 0), ("int8", 9793, 0),
-                          (None, 2449, 4), ("latent", 19593, 0)],
+                          (None, 2449, 4), ("latent", 19593, 0),
+                          ("shortcut", 19593, 0)],
                          ids=["float32", "int8", "float32-speculative4",
-                              "latent-bfloat16"])
+                              "latent-bfloat16", "shortcut-bfloat16"])
 def test_tick_leaves_the_pools_in_place(one_chip, monkeypatch, kv_dtype,
                                         blocks, speculative):
     """The decode tick (and speculation's verify tick) of a toy engine,
@@ -270,11 +283,12 @@ def test_tick_leaves_the_pools_in_place(one_chip, monkeypatch, kv_dtype,
     back, collected it, and copied both pools whole at the end. The
     same holds for whatever pools a model declares: the latent case is a
     ``LatentMoELM``'s one pool of latent rows, written by its unrolled
-    layers and read by ``latent_paged_decode``."""
+    layers and read by ``latent_paged_decode``, the shortcut case one
+    double layer's two cache layers."""
     monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
     layers, heads, dh = 2, 4, 128
-    if kv_dtype == "latent":
-        engine = toy_latent_engine(blocks)
+    if kv_dtype in ("latent", "shortcut"):
+        engine = toy_latent_engine(blocks, shortcut=kv_dtype == "shortcut")
     else:
         model = TransformerLM(vocab=512, dim=heads * dh, num_layers=layers,
                               num_heads=heads, ffn_hidden=1024, max_len=256)
@@ -287,7 +301,7 @@ def test_tick_leaves_the_pools_in_place(one_chip, monkeypatch, kv_dtype,
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         engine._tick_args())
     compiled = engine._tick_fn.lower(*args).compile()
-    if kv_dtype == "latent":
+    if kv_dtype in ("latent", "shortcut"):
         assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
 
     # a quantized pool's values; its scale pages, a thirty-second of its

@@ -260,7 +260,8 @@ def share(zf, w, first, count, x, live=None):
                           "gate": w["e_gate"][first:first + count],
                           "up": w["e_up"][first:first + count],
                           "down": w["e_down"][first:first + count]}}
-    return layer.apply({"params": params, "state": {}}, x, live)
+    y, counters = layer.apply({"params": params, "state": {}}, x, live)
+    return y, counters["expert_tokens"]
 
 
 def test_all_sixteen_shares_add_up_to_the_uncut_layer():
