@@ -33,8 +33,9 @@ and :meth:`LatentMoELM.cache_spec` counts the rows.
 Serving entry points keep :class:`TransformerLM`'s signatures; ``kv`` is
 ``(latent pool, tables)`` and each returns, as a third result, the
 counters the spec declares: ``expert_tokens [expert layers, experts
-held]``, the rows each held expert received in this call; with identity
-experts also ``zero_pairs [expert layers]``
+held]``, the rows each held expert received in this call, and
+``expert_rows [expert layers]``, the rows its grouped product was handed;
+with identity experts also ``zero_pairs [expert layers]``
 (:class:`~paddle_tpu.nn.moe.HeldExpertsFFN`).
 """
 
@@ -264,7 +265,8 @@ class LatentMoELM(Module):
                 "pools": {"latent": (self.blocks[0].attn.row_width,)}}
         if moe:
             spec["counters"] = {
-                "expert_tokens": (len(moe), moe[0].experts.count)}
+                "expert_tokens": (len(moe), moe[0].experts.count),
+                "expert_rows": (len(moe),)}
             if moe[0].experts.num_zero:
                 spec["counters"]["zero_pairs"] = (len(moe),)
         return spec

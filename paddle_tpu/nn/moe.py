@@ -31,6 +31,14 @@ from paddle_tpu.core.module import Module
 
 __all__ = ["MoEFFN", "HeldExpertsFFN", "moe_sharding_rules"]
 
+# Sorted rows a window of ``HeldExpertsFFN``'s grouped product. A constant
+# of the chip and not an option: a visit of an expert streams its matrix
+# (``D * F * 2`` bytes) whatever the rows, and multiplies a whole window
+# of them; on a v5e (197 TFLOP/s, 819 GB/s) the two take the same time at
+# about 240 rows, so under that a visit is bound by the stream it cannot
+# avoid, and 128 is the MXU's row count (``DESIGN_DECISIONS.md``).
+ROW_WINDOW = 128
+
 
 class MoEFFN(Module):
     """Top-k routed expert FFN: ``x [B, T, D] -> [B, T, D]``.
@@ -133,8 +141,8 @@ class MoEFFN(Module):
 class HeldExpertsFFN(Module):
     """This chip's share of an expert layer of an expert-parallel
     deployment: ``x [N, D] -> (y [N, D], counters)``, the counters a dict
-    of int32 arrays (``expert_tokens [held]``, and with identity experts
-    ``zero_pairs``).
+    of int32 arrays (``expert_tokens [held]``, ``expert_rows``, and with
+    identity experts ``zero_pairs``).
 
     The router scores ALL its outputs in float32 and takes each token's
     ``top_k``, as the whole layer would. ``scoring``:
@@ -157,18 +165,27 @@ class HeldExpertsFFN(Module):
     Of the ``N * top_k`` (token, expert) pairs this layer keeps those whose
     expert is one of the ``experts_held = (first id, count)`` it holds,
     sorts them by expert and runs each held expert's SiLU-gated
-    feed-forward over its own rows as one grouped product
+    feed-forward over its own rows as a grouped product
     (``jax.lax.ragged_dot`` over the sorted pairs, the kept ones first:
     a group is as long as its expert has pairs, so there is no capacity
-    and no pair is dropped). The result is the held experts'
-    part of ``sum_e g_e Expert_e(x)``; what the absent experts would add
-    is not computed here and nothing stands in for it. A shared expert is
-    the caller's (every chip computes it alike).
+    and no pair is dropped). The product, the gather that feeds it and
+    the gates that weigh it run over the KEPT pairs' rows only,
+    ``ROW_WINDOW`` sorted rows a window in one ``jax.lax.while_loop``
+    whose trip count is the kept pairs' (a device scalar): XLA's grouped
+    product multiplies a whole tile of the rows it is handed for every
+    expert with a row in it, so the pairs of absent experts, 15 of 16 in
+    a deployment's share, are never handed to it. The result is the held
+    experts' part of ``sum_e g_e Expert_e(x)``; what the absent experts
+    would add is not computed here and nothing stands in for it. A shared
+    expert is the caller's (every chip computes it alike).
 
     ``expert_tokens`` counts the rows each held expert received: the
-    engine's ``expert_pairs`` / ``expert_hits`` counters. ``zero_pairs``
-    (a scalar, only with ``num_zero``) counts the live rows' choices that
-    went to identity experts."""
+    engine's ``expert_pairs`` / ``expert_hits`` counters. ``expert_rows``
+    (a scalar) counts the rows handed to the grouped product, windows
+    times ``min(N * top_k, ROW_WINDOW)``: over the pairs it says how much
+    of the product is padding. ``zero_pairs`` (a scalar, only with
+    ``num_zero``) counts the live rows' choices that went to identity
+    experts."""
 
     def __init__(self, dim: int, hidden: int, num_experts: int, top_k: int,
                  experts_held=None, scaling: float = 1.0,
@@ -233,21 +250,54 @@ class HeldExpertsFFN(Module):
             order = jnp.argsort(key, stable=True)
             sizes = jnp.sum(jax.nn.one_hot(key, E, dtype=jnp.int32), axis=0)
             token = (order // K).astype(jnp.int32)
-            kept = jnp.arange(N * K) < jnp.sum(sizes)
         with jax.named_scope("moe_experts"):
-            grouped = lambda a, w: jax.lax.ragged_dot(
-                pol.cast_compute(a), pol.cast_compute(w), sizes,
-                preferred_element_type=pol.accum_dtype)
-            # the pairs' rows in expert order, the kept ones first; rows
-            # past the kept pairs belong to no group and are not computed
-            rows = jnp.take(pol.cast_compute(x), token, axis=0)
-            h = jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
-            y = jnp.where(kept[:, None], grouped(h, w_down), 0.0)
-            g = jnp.where(kept, gates.reshape(N * K)[order], 0.0)
+            # the kept pairs' rows, ``M`` sorted rows a window, as many
+            # windows as the kept pairs need: the trip count is a device
+            # scalar, so a tick of 32 kept pairs and a chunk of 256 run
+            # one program each and multiply no tile of absent pairs
+            M = min(N * K, ROW_WINDOW)
+            pad = -(N * K) % M
+            n_kept = jnp.sum(sizes)
+            windows = (n_kept + M - 1) // M
+            ends = jnp.cumsum(sizes)
+            starts = ends - sizes
+            xc = pol.cast_compute(x)
+            w_gate, w_up, w_down = (pol.cast_compute(w)
+                                    for w in (w_gate, w_up, w_down))
+            token = jnp.pad(token, (0, pad))
+            gate = jnp.pad(gates.reshape(N * K)[order], (0, pad))
+
+            def window(carry):
+                i, y = carry
+                lo = i * M
+                # every group's [start, end) clipped to the window; an
+                # expert whose rows straddle an edge is visited in both
+                size = (jnp.clip(ends, lo, lo + M)
+                        - jnp.clip(starts, lo, lo + M))
+                grouped = lambda a, w: jax.lax.ragged_dot(
+                    pol.cast_compute(a), w, size,
+                    preferred_element_type=pol.accum_dtype)
+                rows = jnp.take(
+                    xc, jax.lax.dynamic_slice(token, (lo,), (M,)), axis=0)
+                h = jax.nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
+                g = jax.lax.dynamic_slice(gate, (lo,), (M,))
+                # rows of the window past the kept pairs belong to no
+                # group: zero
+                kept = lo + jnp.arange(M) < n_kept
+                gated = jnp.where(kept[:, None],
+                                  grouped(h, w_down) * g[:, None], 0.0)
+                return i + 1, jax.lax.dynamic_update_slice(y, gated, (lo, 0))
+
+            _, y = jax.lax.while_loop(
+                lambda carry: carry[0] < windows, window,
+                (jnp.int32(0), jnp.zeros(
+                    (N * K + pad, D),
+                    jnp.result_type(pol.accum_dtype, gates.dtype))))
             # back to token order: a token's K rows, summed
-            out = jnp.take(y * g[:, None], jnp.argsort(order),
+            out = jnp.take(y, jnp.argsort(order),
                            axis=0).reshape(N, K, D).sum(axis=1)
-        counters = {"expert_tokens": sizes}
+        counters = {"expert_tokens": sizes,
+                    "expert_rows": windows * M}
         if self.num_zero:
             with jax.named_scope("moe_zero"):
                 zero = idx >= self.num_experts               # [N, K]

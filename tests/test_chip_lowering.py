@@ -31,6 +31,7 @@ from paddle_tpu.core import bfloat16_compute, mesh as mesh_lib, use_policy
 from paddle_tpu.models import (LatentMoELM, ShortcutMoEBlock,
                                TransformerLM)
 from paddle_tpu.nn import MultiHeadAttention, pallas_mode
+from paddle_tpu.nn.moe import ROW_WINDOW, HeldExpertsFFN
 from paddle_tpu.nn.pallas_attention import (flash_attention,
                                             latent_paged_decode,
                                             paged_decode_attention,
@@ -372,3 +373,35 @@ def test_programs_take_the_weights_as_prepared(one_chip, monkeypatch, kw):
         assert temp < stack["ffn1"]["w"].nbytes, \
             f"{name}: temporaries {temp} B, one stacked MLP matrix " \
             f"{stack['ffn1']['w'].nbytes} B"
+
+
+def test_grouped_product_is_handed_a_window_of_rows(one_chip):
+    """``HeldExpertsFFN`` at ``serve-pangu718b-closed64``'s tick shape
+    (64 tokens, 8 of 256 experts each, 16 held, 7,680 x 2,048, bfloat16)
+    compiled for the TPU: each of the three grouped products is XLA's
+    ``ragged-dot-none`` custom call (the name the benchmark's
+    ``moe_ffn_roofline_pct`` reads) over ``ROW_WINDOW`` rows, inside the
+    loop over the kept pairs' windows, and none over the tick's 512
+    sorted pairs: the compiler multiplies a whole tile of the rows it is
+    handed for every expert with a row in it."""
+    tokens, K, E, held, D, F = 64, 8, 256, 16, 7680, 2048
+    layer = HeldExpertsFFN(D, F, E, K, (0, held), name="experts")
+
+    def on_chip(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {"experts": {
+        "router": on_chip((D, E)), "gate": on_chip((held, D, F)),
+        "up": on_chip((held, D, F)), "down": on_chip((held, F, D))}}
+
+    def share(params, x):
+        with use_policy(bfloat16_compute):
+            return layer.apply({"params": params, "state": {}}, x)
+
+    text = jax.jit(share).lower(
+        params, on_chip((tokens, D), jnp.float32)).compile().as_text()
+    rows = [int(m.group(1)) for m in re.finditer(
+        r"%ragged-dot-none[.\d]* = [a-z0-9]+\[(\d+),\d+\]", text)]
+    assert len(rows) == 3 and set(rows) == {ROW_WINDOW}, rows
+    assert ROW_WINDOW < tokens * K
+    assert re.search(r" while\(", text), "no loop over the windows"

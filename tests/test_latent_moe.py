@@ -33,6 +33,7 @@ from paddle_tpu.nn import pallas_attention as pa       # noqa: E402
 from paddle_tpu.serve import (ContinuousBatchingScheduler,  # noqa: E402
                               DecodeEngine)
 from paddle_tpu.serve.kv_cache import PagedKVCache     # noqa: E402
+from steered_router import CASES, M, TOKENS, steered   # noqa: E402
 
 ATOL = 2e-4
 TOY = {
@@ -172,6 +173,7 @@ def test_chunked_prefill_then_decode_agrees_with_reference(prog):
     out, fed, counters = serve_logits(model, vs, prompts, new=6, chunk=8)
     check_served(out, fed, prompts)
     assert counters["expert_tokens"].shape == (Z.L - Z.L_dense, Z.held)
+    assert counters["expert_rows"].shape == (Z.L - Z.L_dense,)
 
 
 def test_shared_prefix_is_read_and_not_rewritten(prog):
@@ -314,36 +316,48 @@ def test_no_pair_is_dropped_when_every_token_picks_one_expert():
     assert np.abs(np.asarray(y2[:20] - want[:20])).max() < 1e-5
 
 
-@pytest.mark.parametrize("forced", [False, True])
-def test_grouped_product_keeps_few_pairs_or_all_of_them(forced):
-    """200 tokens, 2 experts each, 4 of 16 held: about 100 of the 400
-    pairs are kept, or all of them (``forced``: every token picks the
-    held experts 5 and 6). Neither drops a pair: both agree with every
-    token through every held expert."""
+@pytest.mark.parametrize("case", ["as_the_router_falls", *CASES])
+def test_grouped_product_keeps_few_pairs_or_all_of_them(case):
+    """200 tokens, 2 experts each, 4 of 16 held (ids 4 to 7): the kept
+    pairs against the grouped product's window of ``M`` sorted rows. As
+    the seeded router falls about 100 of the 400 pairs are kept; steered
+    (``steered_router.py``), none, fewer than a window, exactly one, one
+    more, three windows with experts 5 and 6 across their edges, every
+    pair, and a ``live`` mask on top. No case drops a pair: each agrees
+    with every token through every held expert, counts the rows each
+    expert received, and hands the product whole windows and no more of
+    them than the kept pairs need."""
     zf, w = full_layer_weights(Z)
     w = dict(w)
-    x = jnp.asarray(np.random.RandomState(10).randn(200, zf.D), jnp.float32)
-    if forced:
-        push = jnp.zeros((zf.D, zf.E)).at[:, 5].set(1.0).at[:, 6].set(0.9)
-        w["router"] = 0.01 * w["router"] + 50.0 * push * jnp.sign(x).mean(0)[
-            :, None]
-        x = jnp.abs(x) * jnp.sign(jnp.sign(x).mean(0) + 1e-9)
-    y, counts = share(zf, w, 4, 4, x)
+    x = np.random.RandomState(10).randn(TOKENS, zf.D)
+    live = kept = None
+    if case in CASES:
+        w["router"], x, live, kept = steered(
+            case, x, zf.E, yes=(5, 6), no=(0, 1))
+    x = jnp.asarray(x, jnp.float32)
     layer = HeldExpertsFFN(zf.D, zf.F_e, zf.E, zf.K, (4, 4),
                            scaling=zf.scaling, name="experts")
-    idx, gates = layer.apply(
-        {"params": {"experts": {"router": w["router"]}}, "state": {}}, x,
-        method="route")
-    want = jnp.zeros_like(x)
+    params = {"experts": {"router": w["router"], "gate": w["e_gate"][4:8],
+                          "up": w["e_up"][4:8], "down": w["e_down"][4:8]}}
+    y, counters = layer.apply({"params": params, "state": {}}, x,
+                              None if live is None else jnp.asarray(live))
+    idx, gates = layer.apply({"params": params, "state": {}}, x,
+                             method="route")
+    if live is not None:
+        gates = jnp.where(jnp.asarray(live)[:, None], gates, 0.0)
+    want, received = jnp.zeros_like(x), []
     with jax.default_matmul_precision("highest"):
         for e in range(4, 8):
             g = jnp.sum(jnp.where(idx == e, gates, 0.0), -1)
             want += g[:, None] * reference._gated(
                 x, w["e_gate"][e], w["e_up"][e], w["e_down"][e], None)
-    kept = int(((np.asarray(idx) >= 4) & (np.asarray(idx) < 8)).sum())
-    assert int(counts.sum()) == kept
-    assert (kept == 400) if forced else (0 < kept <= 256)
+            received.append(int(((idx == e) & (gates > 0)).sum()))
     assert np.abs(np.asarray(y - want)).max() < 1e-5
+    assert (sum(received) == kept) if case in CASES \
+        else (0 < sum(received) <= 256)
+    assert set(counters) == {"expert_tokens", "expert_rows"}
+    assert np.asarray(counters["expert_tokens"]).tolist() == received
+    assert int(counters["expert_rows"]) == M * -(-sum(received) // M)
 
 
 # -- the engine and the scheduler, as the serve driver builds them ------------

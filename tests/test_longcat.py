@@ -43,6 +43,7 @@ from paddle_tpu.obs.trace import Tracer                  # noqa: E402
 from paddle_tpu.serve import (ContinuousBatchingScheduler,  # noqa: E402
                               DecodeEngine)
 from paddle_tpu.serve.kv_cache import PagedKVCache       # noqa: E402
+from steered_router import CASES, M, TOKENS, steered     # noqa: E402
 
 ATOL = 5e-4
 TOY = {
@@ -186,6 +187,7 @@ def test_chunked_prefill_then_decode_agrees_with_reference(prog):
     out, fed, counters = serve_logits(model, vs, prompts, new=6, chunk=8)
     check_served(out, fed, prompts)
     assert counters["expert_tokens"].shape == (Z.L, Z.held)
+    assert counters["expert_rows"].shape == (Z.L,)
     assert counters["zero_pairs"].shape == (Z.L,)
     # three live tokens, four choices each, an expert layer
     z = np.asarray(counters["zero_pairs"])
@@ -412,6 +414,44 @@ def test_a_token_of_real_choices_only_counts_all_of_them():
     assert np.abs(np.asarray(y - want)).max() < 1e-5
 
 
+@pytest.mark.parametrize("case", list(CASES))
+def test_grouped_product_runs_over_the_kept_pairs_windows(case):
+    """``test_latent_moe.py``'s cases on this router: 200 tokens, 4
+    choices each of 32 real and 16 identity outputs, 4 held (ids 8 to
+    11); a token's other choices are absent experts (0, 30) and
+    identities, whose pairs never enter the sort. Each case agrees with
+    every token through every held expert plus its identity terms, and
+    the product is handed whole windows of the kept pairs' rows only."""
+    zf, w = full_layer()
+    x = np.random.RandomState(12).randn(TOKENS, zf.D)
+    router, x, live, kept = steered(
+        case, x, zf.E + zf.Z, yes=(8, 9, 10, 11),
+        no=(0, zf.E + 1, 30, zf.E + 7))
+    w = dict(w, router=jnp.asarray(router), bias=jnp.zeros_like(w["bias"]))
+    x = jnp.asarray(x)
+    y, counts, stats = share(zf, w, 8, 4, x,
+                             None if live is None else jnp.asarray(live))
+    ids, gates = layer_of(zf, 8, 4).apply(
+        {"params": share_params(w, 8, 4), "state": {}}, x, method="route")
+    if live is not None:
+        gates = jnp.where(jnp.asarray(live)[:, None], gates, 0.0)
+    want = jnp.sum(jnp.where(ids >= zf.E, gates, 0.0), -1)[:, None] * x
+    received = []
+    with jax.default_matmul_precision("highest"):
+        for e in range(8, 12):
+            g = jnp.sum(jnp.where(ids == e, gates, 0.0), -1)
+            want += g[:, None] * reference._gated(
+                x, w["e_gate"][e], w["e_up"][e], w["e_down"][e], None)
+            received.append(int(((ids == e) & (gates > 0)).sum()))
+    assert np.abs(np.asarray(y - want)).max() < 1e-5
+    assert sum(received) == kept
+    assert set(stats) == {"expert_tokens", "expert_rows", "zero_pairs"}
+    assert np.asarray(counts).tolist() == received
+    assert int(stats["expert_rows"]) == M * -(-kept // M)
+    assert int(stats["zero_pairs"]) == int(
+        ((ids >= zf.E) & (gates > 0)).sum())
+
+
 def test_selection_bias_moves_a_choice_and_never_a_gate():
     zf, w = full_layer()
     x = jnp.asarray(np.random.RandomState(10).randn(50, zf.D), jnp.float32)
@@ -440,13 +480,14 @@ def test_selection_bias_moves_a_choice_and_never_a_gate():
 
 def test_sigmoid_scoring_is_what_it_was():
     """The other latent configuration's router: no bias, no identity
-    experts, sigmoid scores normalised over the k; one counter."""
+    experts, sigmoid scores normalised over the k; its two
+    counters."""
     layer = HeldExpertsFFN(16, 8, 8, 2, (2, 2), scaling=2.5, name="experts")
     x = jnp.asarray(np.random.RandomState(3).randn(6, 16), jnp.float32)
     vs = layer.init(jax.random.PRNGKey(1), x)
     assert set(vs["params"]["experts"]) == {"router", "gate", "up", "down"}
     _, counters = layer.apply(vs, x)
-    assert set(counters) == {"expert_tokens"}
+    assert set(counters) == {"expert_tokens", "expert_rows"}
     _, gates = layer.apply(vs, x, method="route")
     assert np.allclose(np.asarray(gates.sum(-1)), 2.5, rtol=1e-6)
 
@@ -461,12 +502,14 @@ def test_cache_spec_declares_two_rows_a_block(prog):
     assert model.first_row == [0, 2]
     assert spec["layers"] == 2 * Z.L and spec["pools"] == {"latent": (128,)}
     assert spec["counters"] == {"expert_tokens": (Z.L, Z.held),
+                                "expert_rows": (Z.L,),
                                 "zero_pairs": (Z.L,)}
     engine = DecodeEngine(model, vs, max_slots=2, block_size=BS,
                           num_blocks=16, prefill_chunk=8,
                           max_blocks_per_seq=4)
     assert engine.cache.pools["latent"].shape == (2 * Z.L, 16, BS, 128)
-    assert engine.counter_names == ("expert_tokens", "zero_pairs")
+    assert engine.counter_names == ("expert_tokens", "expert_rows",
+                                    "zero_pairs")
     # the sandwich-norm stack: one row a block, the counters it had
     other = LatentMoELM(vocab=32, dim=16, num_layers=3, num_dense_layers=1,
                         num_heads=2, q_rank=8, kv_rank=8, nope_dim=4,
@@ -476,7 +519,8 @@ def test_cache_spec_declares_two_rows_a_block(prog):
     assert [type(b) for b in other.blocks] == [LatentMoEBlock] * 3
     assert other.first_row == [0, 1, 2]
     assert other.cache_spec() == {"layers": 3, "pools": {"latent": (128,)},
-                                  "counters": {"expert_tokens": (2, 4)}}
+                                  "counters": {"expert_tokens": (2, 4),
+                                               "expert_rows": (2,)}}
 
 
 def test_engine_serves_tokens_the_reference_ranks_first(prog):
@@ -517,12 +561,15 @@ def test_engine_serves_tokens_the_reference_ranks_first(prog):
         choices = a["tokens"] * Z.K * Z.L
         assert 0 <= a["zero_pairs"] <= choices
         assert a["expert_pairs"] <= choices - a["zero_pairs"]
+        # whole windows: a tick's is its 3 slots' 12 sorted rows
+        assert a["expert_pairs"] <= a["expert_rows"] <= 3 * Z.K * Z.L
+        assert a["expert_rows"] % (3 * Z.K) == 0
     drains = [a for a in spans["prefill_drain"] if "zero_pairs" in a]
     assert len(drains) == len(prompts)
     assert sum(a["zero_pairs"] for a in drains) > 0
     tick_records = records.by_kind("decode_tick")
     assert tick_records and all(
-        {"zero_pairs", "expert_pairs"} <= set(r)
+        {"zero_pairs", "expert_pairs", "expert_rows"} <= set(r)
         for r in tick_records)
     assert sum(r["zero_pairs"] for r in tick_records) \
         == sum(a["zero_pairs"] for a in ticks)
