@@ -352,6 +352,36 @@ def kernel_leg(sizes: Sizes, seed: int = 0) -> None:
             assert not got[n == 0].any(), \
                 "an inactive slot must read zeros"
 
+    # grouped KV heads and a window: ``G`` query heads read each KV head
+    # of the same pools (48 and 72 on 8 at the full size), the second case
+    # over a slot's last ``window`` positions only: a window that starts
+    # inside a page, shorter than some lengths and longer than others
+    Q = 1 + sizes.speculative
+    for G, window in ((6, None), (9, 4 * bs + 3)):
+        qg, qs = normal(S, G * H, D), normal(S, Q, G * H, D)
+        start = np.minimum(lengths, W - Q).astype(np.int32)
+        n = np.full((S,), Q, np.int32)
+        start[1], n[1] = 7, 0
+        live = np.arange(Q)[None, :] < n[:, None]
+        for kind, (pk, pv) in pools.items():
+            lk, lv = jax.tree_util.tree_map(lambda p: p[layer], (pk, pv))
+            rest = (tables, jnp.asarray(lengths))
+            got = jax.jit(lambda *a: paged_decode_attention(
+                *a, window=window))(qg, pk, pv, *rest, layer)
+            want = oracle(lambda *a: paged_reference_attention(
+                *a, window=window), qg, lk, lv, *rest)
+            check(f"paged_decode/G{G}/w{window}/{kind}", kind, got, want)
+            assert not np.asarray(got)[lengths == 0].any(), \
+                "an inactive slot must read zeros"
+            rest = (tables, jnp.asarray(start), jnp.asarray(n))
+            got = np.asarray(jax.jit(lambda *a: paged_span_attention(
+                *a, window=window))(qs, pk, pv, *rest, layer))
+            want = np.asarray(oracle(
+                lambda *a: paged_span_reference_attention(
+                    *a, window=window), qs, lk, lv, *rest))
+            check(f"paged_span/G{G}/w{window}/{kind}", kind, got[live],
+                  want[live])
+
     # flash forward + gradients, bf16 operands as in the train leg
     B, T = 2, sizes.max_len
     q, k, v, w = (normal(B, H, T, D) for _ in range(4))

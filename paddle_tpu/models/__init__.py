@@ -15,3 +15,4 @@ from .tagging import LinearCrfTagger, RnnCrfTagger
 from .text_cls import LSTMTextClassifier
 from .traffic import TrafficPredictor
 from .transformer import TransformerBlock, TransformerLM
+from .window_moe import WindowMoEBlock, WindowMoELM

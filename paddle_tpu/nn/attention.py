@@ -24,7 +24,8 @@ from ..core.module import Module
 from .layers import Linear
 
 __all__ = ["AdditiveAttention", "DotProductAttention", "MultiHeadAttention",
-           "LatentAttention", "dot_product_attention_weights"]
+           "LatentAttention", "GroupedQueryAttention",
+           "dot_product_attention_weights"]
 
 
 def _tp_paged_kernel(kernel, q, pages_k, pages_v, *rest, head_dim: int):
@@ -740,3 +741,237 @@ class LatentAttention(Module):
             acc, _, l = jax.lax.fori_loop(0, n_tiles, tile, init)
             ctx = acc / jnp.maximum(l, 1e-30)[..., None]
             return self._out(ctx), pool
+
+
+class GroupedQueryAttention(Module):
+    """Causal self-attention with GROUPED KV heads, rotary positions, an
+    optional WINDOW and an optional per-head output gate; no biases.
+
+    ``num_heads`` query heads read ``num_kv_heads`` KV heads of size
+    ``head_dim`` (query head ``h`` reads KV head ``h // G``, ``G =
+    num_heads / num_kv_heads``): a token leaves ``num_kv_heads`` keys and
+    values in the cache however many heads query them. Queries and keys
+    are rotated (``nn/rotary.py``): the first ``rope_dim`` values of a
+    head (default all: ``rope_dim < head_dim`` is a partial rotation), at
+    ``rope_base``'s frequencies or, with ``yarn = {"factor",
+    "original_len", "beta_fast", "beta_slow", "attention_factor"}``,
+    YaRN's, cosines and sines times its attention factor. Scores are
+    ``q . k / sqrt(head_dim)``, softmax in float32. With ``window`` query
+    ``i`` sees keys ``i - window < j <= i``. With ``head_gate`` head
+    ``h``'s output is multiplied by ``sigmoid(x W_g)_h`` (``W_g [dim,
+    num_heads]``, from the layer's input) before the output projection.
+
+    Against the paged cache (``pages_k`` / ``pages_v`` ``[L, N, H_kv, bs,
+    head_dim]``, this layer number ``layer``, written in place):
+
+    - :meth:`decode`, ``impl="paged"``: the token's row is written, then
+      :func:`~paddle_tpu.nn.pallas_attention.paged_decode_attention`
+      walks the slot's pages (from the window's first page on);
+    - :meth:`decode_span` and ``decode(impl="xla")``: the new rows attend
+      to THEMSELVES as computed (rounded as the pool will hold them) and
+      to the older context READ FROM THE POOL a tile of pages at a time
+      (online softmax), and are written afterwards. Reading before
+      writing is what a window group's ring needs
+      (``serve/kv_cache.py``): a chunk's writes wrap onto the rows its
+      own first queries still see.
+    """
+
+    SPAN_TILE = 512      # older context rows read at a time
+
+    def __init__(self, dim: int, num_heads: int, num_kv_heads: int,
+                 head_dim: int, rope_base: float = 10000.0,
+                 rope_dim: Optional[int] = None, yarn: Optional[dict] = None,
+                 window: Optional[int] = None, head_gate: bool = False,
+                 w_init=I.fan_in_uniform, name=None):
+        super().__init__(name=name)
+        from .rotary import yarn_frequencies
+        assert num_heads % num_kv_heads == 0, (num_heads, num_kv_heads)
+        self.dim, self.head_dim = dim, head_dim
+        self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
+        self.window = None if window is None else int(window)
+        self.rope_base = float(rope_base)
+        self.rope_dim = head_dim if rope_dim is None else int(rope_dim)
+        self.inv_freq, self.rope_factor = None, 1.0
+        if yarn:
+            yarn = dict(yarn)
+            self.rope_factor = float(yarn.pop("attention_factor", 1.0))
+            self.inv_freq = yarn_frequencies(self.rope_dim, self.rope_base,
+                                             **yarn)
+        self.scale = 1.0 / float(np.sqrt(head_dim))
+        self.w_init = w_init
+        lin = lambda n: Linear(n, use_bias=False, w_init=w_init)
+        self.gate = lin(num_heads) if head_gate else None
+        self.o = lin(dim)
+
+    # -- the parts every entry point shares -------------------------------
+
+    def _heads(self, name, x, heads):
+        """``x [B, T, D] -> [B, T, heads, hd]`` through the matrix ``name``,
+        held ``[heads * hd, D]``: the axis the product sums over is the
+        minor one, which is how both compiled programs want the q, k and v
+        matrices (held ``[D, heads * hd]`` XLA re-laid all three in every
+        tick and chunk: 0.52 GB copied a tick at Laguna-S-2.1's widths)."""
+        pol = current_policy()
+        w = self.param(
+            name, lambda rng, shape, dtype: self.w_init(
+                rng, shape[::-1], dtype).T, (heads * self.head_dim, self.dim))
+        y = jnp.einsum("btd,fd->btf", pol.cast_compute(x),
+                       pol.cast_compute(w),
+                       preferred_element_type=pol.accum_dtype)
+        return y.reshape(*x.shape[:2], heads, self.head_dim)
+
+    def _project(self, x, positions):
+        """``x [B, T, D]``, ``positions [B, T]`` -> ``(q [B, T, H, hd],
+        k, v [B, T, H_kv, hd], gate [B, T, H] | None)``, q and k
+        rotated."""
+        from .rotary import apply_rotary, rotary_angles
+        cos, sin = rotary_angles(positions, self.rope_dim, self.rope_base,
+                                 self.inv_freq, self.rope_factor)
+        cos, sin = cos[:, :, None], sin[:, :, None]
+        q = apply_rotary(self._heads("wq", x, self.num_heads), cos, sin)
+        k = apply_rotary(self._heads("wk", x, self.num_kv_heads), cos, sin)
+        gate = None
+        if self.gate is not None:
+            with jax.named_scope("head_gate"):
+                gate = jax.nn.sigmoid(self.gate(x).astype(jnp.float32))
+        return q, k, self._heads("wv", x, self.num_kv_heads), gate
+
+    def _attend(self, q, k, v, q_pos, k_pos, k_live=None):
+        """Queries ``q [B, Q, H, hd]`` at ``q_pos [B, Q]`` over the keys
+        ``k``, ``v`` ``[B, K, H_kv, hd]`` at ``k_pos [B, K]`` (``k_live
+        [B, K]``: the rows that hold one), causal and windowed. Returns
+        the un-normalised ``(acc [B, Q, H, hd], m [B, Q, H], l)`` of a
+        softmax over these keys, so that a caller can go on to others."""
+        cc = current_policy().cast_compute
+        B, Q = q.shape[:2]
+        grouped = q.reshape(B, Q, self.num_kv_heads, -1, self.head_dim)
+        s = jnp.einsum("bqhgd,bkhd->bqhgk", cc(grouped), cc(k),
+                       preferred_element_type=jnp.float32) * self.scale
+        visible = k_pos[:, None, :] <= q_pos[:, :, None]
+        if self.window is not None:
+            visible &= k_pos[:, None, :] > q_pos[:, :, None] - self.window
+        if k_live is not None:
+            visible &= k_live[:, None, :]
+        visible = visible[:, :, None, None, :]
+        s = jnp.where(visible, s, -1e30)
+        m = jnp.max(s, axis=-1)
+        p = jnp.where(visible, jnp.exp(s - m[..., None]), 0.0)
+        acc = jnp.einsum("bqhgk,bkhd->bqhgd", cc(p), cc(v),
+                         preferred_element_type=jnp.float32)
+        flat = lambda t: t.reshape(B, Q, self.num_heads, *t.shape[4:])
+        return flat(acc), flat(m), flat(jnp.sum(p, axis=-1))
+
+    def _out(self, acc, l, gate):
+        """``acc / l`` gated a head, through the output projection."""
+        ctx = acc / jnp.maximum(l, 1e-30)[..., None]
+        if gate is not None:
+            with jax.named_scope("head_gate"):
+                ctx = ctx * gate[..., None]
+        return self.o(ctx.reshape(*ctx.shape[:2], -1))
+
+    def _cached(self, q, k, v, pages_k, pages_v, layer, table, start, n):
+        """The span's queries ``q [S, Q, H, hd]`` (row ``j`` at ``start[s]
+        + j``, ``n[s]`` of them live) over their own rows ``k``, ``v``
+        (as the pool will round them) and over the slot's OLDER context,
+        positions below ``start``, read from the pool by ``table`` a tile
+        of pages at a time from the window's first page on."""
+        S, Q = q.shape[:2]
+        bs, MB = pages_k.shape[3], table.shape[1]
+        pos = start[:, None] + jnp.arange(Q, dtype=jnp.int32)[None]
+        state = self._attend(q, k.astype(pages_k.dtype),
+                             v.astype(pages_v.dtype), pos, pos,
+                             jnp.arange(Q)[None] < n[:, None])
+        if self.window is None:
+            first = jnp.zeros((S,), jnp.int32)
+            per = self.SPAN_TILE // bs
+        else:
+            first = jnp.maximum(start - self.window + 1, 0) // bs
+            per = -(-self.window // bs) + 1
+        per = max(1, min(per, MB))                      # pages a tile
+        T = per * bs
+
+        def tile(t, carry):
+            acc, m, l = carry
+            cols = first[:, None] + t * per + jnp.arange(per)[None]
+            blocks = jnp.take_along_axis(table, jnp.minimum(cols, MB - 1),
+                                         axis=1)        # [S, per]
+            rows = lambda pages: jnp.swapaxes(
+                pages[layer, blocks], 2, 3).reshape(S, T, *pages.shape[2:3],
+                                                    pages.shape[-1])
+            k_pos = (cols[:, :, None] * bs
+                     + jnp.arange(bs)[None, None]).reshape(S, T)
+            a, m_t, l_t = self._attend(q, rows(pages_k), rows(pages_v), pos,
+                                       k_pos, k_pos < start[:, None])
+            m_new = jnp.maximum(m, m_t)
+            c_old, c_new = jnp.exp(m - m_new), jnp.exp(m_t - m_new)
+            return (acc * c_old[..., None] + a * c_new[..., None], m_new,
+                    l * c_old + l_t * c_new)
+
+        if self.window is not None and per * bs >= self.window + bs - 1:
+            # a window's older rows lie in ONE tile: no loop (a loop that
+            # reads the pool ahead of the span's in-place writes makes
+            # XLA copy the whole pool round it)
+            acc, _, l = tile(0, state)
+        else:
+            n_tiles = jnp.max(jnp.where(n > 0, start - first * bs + T - 1,
+                                        0)) // T
+            acc, _, l = jax.lax.fori_loop(0, n_tiles, tile, state)
+        return acc, l
+
+    # -- entry points -------------------------------------------------------
+
+    def forward(self, x, positions=None):
+        """Causal (windowed) attention over a whole sequence ``x [B, T,
+        D]``, no cache."""
+        B, T = x.shape[:2]
+        if positions is None:
+            positions = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
+        q, k, v, gate = self._project(x, positions)
+        acc, _, l = self._attend(q, k, v, positions, positions)
+        return self._out(acc, l, gate)
+
+    def decode(self, x, pages_k, pages_v, layer, table, positions, active,
+               impl: str = "xla"):
+        """One new token a slot. ``x [S, 1, D]``; ``table [S, MB]`` this
+        layer's group's; ``positions [S]``; ``active [S]``. Returns ``(out
+        [S, 1, D], pages_k, pages_v)``."""
+        from ..serve.kv_cache import write_token
+        with self.scope():
+            q, k, v, gate = self._project(x, positions[:, None])
+            if impl != "paged":
+                acc, l = self._cached(q, k, v, pages_k, pages_v, layer,
+                                      table, positions,
+                                      active.astype(jnp.int32))
+            pages_k = write_token(pages_k, layer, k[:, 0], table, positions,
+                                  active)
+            pages_v = write_token(pages_v, layer, v[:, 0], table, positions,
+                                  active)
+            if impl == "paged":
+                from .pallas_attention import paged_decode_attention
+                acc = paged_decode_attention(
+                    q[:, 0], pages_k, pages_v, table,
+                    jnp.where(active, positions + 1, 0), layer,
+                    scale=self.scale, window=self.window)[:, None]
+                l = jnp.ones(acc.shape[:-1], acc.dtype)
+            return self._out(acc, l, gate), pages_k, pages_v
+
+    def decode_span(self, x, pages_k, pages_v, layer, table, start, n,
+                    active, write_from=None):
+        """A span of consecutive new tokens a slot (a prefill chunk):
+        ``x [S, Q, D]``, token ``j`` of slot ``s`` at ``start[s] + j``,
+        ``n[s]`` of them live (the others are padding: written to the
+        null block, their output unspecified). Returns ``(out [S, Q, D],
+        pages_k, pages_v)``."""
+        from ..serve.kv_cache import write_span
+        with self.scope():
+            Q = x.shape[1]
+            pos = start[:, None] + jnp.arange(Q, dtype=jnp.int32)[None]
+            q, k, v, gate = self._project(x, pos)
+            n_eff = jnp.where(active, n, 0)
+            acc, l = self._cached(q, k, v, pages_k, pages_v, layer, table,
+                                  start, n_eff)
+            pages_k = write_span(pages_k, layer, k, table, start, n_eff,
+                                 write_from, by_page=True)
+            pages_v = write_span(pages_v, layer, v, table, start, n_eff,
+                                 write_from, by_page=True)
+            return self._out(acc, l, gate), pages_k, pages_v

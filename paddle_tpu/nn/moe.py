@@ -150,7 +150,11 @@ class HeldExpertsFFN(Module):
     - ``"sigmoid"``: ``s = sigmoid(x W_g)``, gates normalised over the k
       (``scaling * s_e / (sum of the k + 1e-20)``);
     - ``"softmax"``: ``s = softmax(x W_g)``, gates the raw scores
-      (``scaling * s_e``, not renormalised).
+      (``scaling * s_e``, not renormalised);
+
+    ``normalise`` overrides which of the two a scoring does with its k
+    scores (``"softmax"`` with ``normalise=True``: the softmax's scores
+    renormalised over the k, Qwen2-MoE's ``norm_topk_prob``).
 
     ``select_bias`` adds a per-output bias (parameter ``select_bias``) to
     the scores FOR THE CHOICE ONLY: it moves which outputs are taken and
@@ -190,7 +194,8 @@ class HeldExpertsFFN(Module):
     def __init__(self, dim: int, hidden: int, num_experts: int, top_k: int,
                  experts_held=None, scaling: float = 1.0,
                  scoring: str = "sigmoid", select_bias: bool = False,
-                 num_zero: int = 0, w_init=I.fan_in_uniform, name=None):
+                 num_zero: int = 0, normalise=None,
+                 w_init=I.fan_in_uniform, name=None):
         super().__init__(name=name)
         first, count = experts_held or (0, num_experts)
         assert 0 <= first and first + count <= num_experts and count > 0
@@ -201,6 +206,8 @@ class HeldExpertsFFN(Module):
         self.first, self.count = int(first), int(count)
         self.scaling = float(scaling)
         self.scoring, self.select_bias = scoring, bool(select_bias)
+        self.normalise = (scoring == "sigmoid" if normalise is None
+                          else bool(normalise))
         self.num_zero = int(num_zero)
         self.w_init = w_init
 
@@ -222,7 +229,7 @@ class HeldExpertsFFN(Module):
             top = jnp.take_along_axis(scores, idx, axis=-1)
         else:
             top, idx = jax.lax.top_k(scores, self.top_k)
-        if self.scoring == "sigmoid":
+        if self.normalise:
             gates = self.scaling * top / (
                 jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
         else:

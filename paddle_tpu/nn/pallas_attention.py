@@ -560,22 +560,33 @@ def _gathered(pages, tables):
     return gather_pages(pages, tables)
 
 
+def _gathered_for(q_heads, pages, tables):
+    """:func:`_gathered` with every KV head repeated for the query heads
+    that read it (query head ``h`` reads KV head ``h // G``)."""
+    kv = _gathered(pages, tables)
+    return jnp.repeat(kv, q_heads // kv.shape[2], axis=2)
+
+
 def paged_reference_attention(q, pages_k, pages_v, tables, lengths,
-                              scale: Optional[float] = None):
+                              scale: Optional[float] = None,
+                              window: Optional[int] = None):
     """Numeric oracle for :func:`paged_decode_attention` — gather the
     block-table pages into position order (dequantized for int8 pools)
     and run masked softmax attention for the single query token. ``q``
-    ``[S, H, D]``; pages ``[N, H, bs, D]`` or the quantized
-    ``(int8, scales)`` tuple; ``tables`` ``[S, MB]``; ``lengths``
-    ``[S]`` (0 = inactive slot -> zero output)."""
+    ``[S, H, D]``; pages ``[N, H_kv, bs, D]`` (``H % H_kv == 0``) or the
+    quantized ``(int8, scales)`` tuple; ``tables`` ``[S, MB]``;
+    ``lengths`` ``[S]`` (0 = inactive slot -> zero output); ``window``:
+    the last ``window`` positions only."""
     S, H, D = q.shape
     if scale is None:
         scale = D ** -0.5
-    k = _gathered(pages_k, tables)
-    v = _gathered(pages_v, tables)
+    k = _gathered_for(H, pages_k, tables)
+    v = _gathered_for(H, pages_v, tables)
     W = k.shape[1]
     s = jnp.einsum("shd,skhd->shk", q, k) * scale
     mask = jnp.arange(W)[None] < lengths[:, None]
+    if window is not None:
+        mask = mask & (jnp.arange(W)[None] >= lengths[:, None] - window)
     s = jnp.where(mask[:, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     p = jnp.where(jnp.isnan(p), 0.0, p)       # length-0 (inactive) rows
@@ -583,24 +594,28 @@ def paged_reference_attention(q, pages_k, pages_v, tables, lengths,
 
 
 def paged_span_reference_attention(q, pages_k, pages_v, tables, start, n,
-                                   scale: Optional[float] = None):
+                                   scale: Optional[float] = None,
+                                   window: Optional[int] = None):
     """Numeric oracle for :func:`paged_span_attention` — per-row masked
     softmax over the gathered (dequantized) context. ``q``
     ``[S, Q, H, D]`` (row ``j`` of slot ``s`` sits at position
     ``start[s] + j``); rows ``>= n[s]`` are padding whose output is
     unspecified (compare live rows only); ``n == 0`` marks an inactive
-    slot (zero output on every row)."""
+    slot (zero output on every row). Grouped KV heads and ``window`` as
+    in :func:`paged_reference_attention`."""
     S, Q, H, D = q.shape
     if scale is None:
         scale = D ** -0.5
-    k = _gathered(pages_k, tables)            # [S, W, H, D]
-    v = _gathered(pages_v, tables)
+    k = _gathered_for(H, pages_k, tables)     # [S, W, H, D]
+    v = _gathered_for(H, pages_v, tables)
     W = k.shape[1]
     s = jnp.einsum("sqhd,skhd->sqhk", q, k) * scale
     k_idx = jnp.arange(W)[None, None, :]
     # causal within the span: row j sees positions <= start + j
-    vis = (k_idx <= (start[:, None] + jnp.arange(Q)[None, :])[..., None]) \
-        & (n[:, None, None] > 0)
+    q_pos = (start[:, None] + jnp.arange(Q)[None, :])[..., None]
+    vis = (k_idx <= q_pos) & (n[:, None, None] > 0)
+    if window is not None:
+        vis = vis & (k_idx > q_pos - window)
     s = jnp.where(vis[:, :, None, :], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     p = jnp.where(jnp.isnan(p), 0.0, p)       # inactive slots
@@ -728,9 +743,128 @@ def _paged_decode_kernel(tbl_ref, len_ref, lay_ref, q_ref, k_hbm, v_hbm,
     o_ref[:] = (out / jnp.maximum(l_s[:], 1e-30)).astype(o_ref.dtype)
 
 
+def _grouped_decode_kernel(tbl_ref, len_ref, lay_ref, q_ref, k_hbm, v_hbm,
+                           o_ref, kbuf, vbuf, sem, m_s, l_s, acc_s, *, scale,
+                           window):
+    """:func:`_paged_decode_kernel` for GROUPED KV heads and for a window:
+    one slot's ``H_kv`` KV heads, each read by ``G`` query heads (padded
+    to the ``Gp`` rows of a sublane tile), against the slot's own pages.
+    Same grid ``(S,)``, same page groups, same double buffer; what differs
+    is the product: a KV head's ``G`` queries are ``[Gp, D] x [D, T]``
+    against the group's ``T`` rows and ``[Gp, T] x [T, D]`` against their
+    values, both the MXU's (a page's bytes are read once for ``G`` query
+    heads, and ``G`` rows on the VPU would cost ``G`` times the one-row
+    kernel's multiplies), so the buffers hold a group as ``[H_kv, pages,
+    bs, D]``: one KV head's rows are one contiguous ``[T, D]`` tile.
+    With ``window`` the walk starts at the page that holds position
+    ``length - window`` and rows before that position are masked: a slot
+    reads ``ceil(window / bs) + 1`` pages at most, whatever its length."""
+    _, Hk, P, bs, D = kbuf.shape
+    s_idx = pl.program_id(0)
+    length = len_ref[s_idx]
+    lay = lay_ref[0]
+    T = P * bs
+    oldest = 0 if window is None else jnp.maximum(length - window, 0)
+    first = oldest // bs                        # the walk's first page
+    n_groups = ((length + bs - 1) // bs - first + P - 1) // P
+    last = tbl_ref.shape[1] - 1
+
+    def copies(g, slot):
+        out = []
+        for i in range(P):
+            # past the slot's last page: any page it may read, masked below
+            block = tbl_ref[s_idx, jnp.minimum(first + g * P + i, last)]
+            out += [pltpu.make_async_copy(pool.at[lay, block],
+                                          buf.at[slot, :, i], sem.at[slot])
+                    for pool, buf in ((k_hbm, kbuf), (v_hbm, vbuf))]
+        return out
+
+    m_s[:] = jnp.full(m_s.shape, _NEG, jnp.float32)
+    l_s[:] = jnp.zeros(l_s.shape, jnp.float32)
+    acc_s[:] = jnp.zeros(acc_s.shape, jnp.float32)
+    row = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+
+    def multiply(g, slot):
+        # every group that runs holds a live row (the first holds position
+        # ``oldest``), so a masked score's exp is nought against a real max
+        pos = (first + g * P) * bs + row
+        live = pos < length
+        if window is not None:
+            live &= pos >= oldest
+        for h in range(Hk):
+            k = kbuf[slot, h].reshape(T, D)
+            s = jax.lax.dot_general(
+                q_ref[h], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale     # [Gp, T]
+            s = jnp.where(live, s, _NEG)
+            m = m_s[h]                                          # [Gp, 1]
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m - m_new)
+            l_s[h] = l_s[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            v = vbuf[slot, h].reshape(T, D)
+            acc_s[h] = acc_s[h] * corr + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_s[h] = m_new
+
+    _walk_page_groups(n_groups, copies, multiply)
+    o_ref[:] = (acc_s[:] / jnp.maximum(l_s[:], 1e-30)).astype(o_ref.dtype)
+
+
+def _grouped_decode_call(q, pages_k, pages_v, tables, lengths, layer, scale,
+                         interpret, window):
+    """:func:`paged_decode_attention` for ``H_q = G * H_kv`` query heads
+    or a window, as the Mosaic kernel ``paged_decode``."""
+    S, H, D = q.shape
+    L, N, Hk, bs, _ = pages_k.shape
+    G = H // Hk
+    sub = 32 // pages_k.dtype.itemsize          # rows of a sublane tile
+    Gp = -(-G // sub) * sub
+    scale, interpret = _resolve_defaults(q, scale, interpret)
+    group = _pages_per_group(Hk * bs * D * pages_k.dtype.itemsize,
+                             tables.shape[1])
+    if window is not None:
+        # the pages a window spans, in as many groups as they need and no
+        # page more: 33 pages walk as 3 x 11, not as 3 x 16
+        span = -(-window // bs) + 1
+        group = min(group, -(-span // -(-span // group)))
+    qg = jnp.pad(q.reshape(S, Hk, G, D).astype(pages_k.dtype),
+                 ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
+
+    def q_map(s, tbl, lens, lay):
+        return (s, 0, 0, 0)
+
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S,),
+        in_specs=[pl.BlockSpec((None, Hk, Gp, D), q_map), hbm, hbm],
+        out_specs=pl.BlockSpec((None, Hk, Gp, D), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((2, Hk, group, bs, D), pages_k.dtype),
+            pltpu.VMEM((2, Hk, group, bs, D), pages_v.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((Hk, Gp, 1), jnp.float32),
+            pltpu.VMEM((Hk, Gp, 1), jnp.float32),
+            pltpu.VMEM((Hk, Gp, D), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_grouped_decode_kernel, scale=scale,
+                          window=window),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, Hk, Gp, D), q.dtype),
+        interpret=interpret, name="paged_decode",
+    )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
+      _layer_operand(layer), qg, pages_k, pages_v)
+    return out[:, :, :G].reshape(S, H, D)
+
+
 def paged_decode_attention(q, pages_k, pages_v, tables, lengths, layer,
                            scale: Optional[float] = None,
-                           interpret: Optional[bool] = None):
+                           interpret: Optional[bool] = None,
+                           window: Optional[int] = None):
     """Decode-shaped (q_len = 1) flash attention over a paged KV cache.
 
     The serving hot op: each active slot attends its single new-token
@@ -758,6 +892,14 @@ def paged_decode_attention(q, pages_k, pages_v, tables, lengths, layer,
     (:func:`_pages_per_group`). ``interpret`` defaults to True off-TPU
     (same contract as :func:`flash_attention`).
 
+    GROUPED KV heads: ``q`` may carry ``H = G * H_kv`` heads against
+    pools of ``H_kv`` (query head ``h`` reads KV head ``h // G``), and
+    ``window`` keeps a slot to its last ``window`` positions: the walk
+    covers pages ``max(0, length - window) // bs`` onward and masks
+    inside the first one. Either goes to :func:`_grouped_decode_kernel`
+    (the same grid, walk and name, the products on the MXU); ``G == 1``
+    with no window is the one-row kernel below, as it was.
+
     Mosaic copies no window narrower than its 128 lanes out of an array
     in HBM: not a quantized pool's ``[H, bs]`` scale page, not a page of
     head size 64. Those pools keep the ``(slot, head, page)`` grid, whose
@@ -768,9 +910,13 @@ def paged_decode_attention(q, pages_k, pages_v, tables, lengths, layer,
         return _paged_span_call(
             q[:, None], pages_k, pages_v, tables,
             jnp.maximum(lengths - 1, 0), (lengths > 0).astype(jnp.int32),
-            layer, scale, interpret, name="paged_decode")[:, 0]
+            layer, scale, interpret, name="paged_decode",
+            window=window)[:, 0]
     L, N, Hk, bs, Dk = pages_k.shape
-    assert (H, D) == (Hk, Dk), f"q heads {(H, D)} != pages {(Hk, Dk)}"
+    assert D == Dk and H % Hk == 0, f"q heads {(H, D)} != pages {(Hk, Dk)}"
+    if H != Hk or window is not None:
+        return _grouped_decode_call(q, pages_k, pages_v, tables, lengths,
+                                    layer, scale, interpret, window)
     scale, interpret = _resolve_defaults(q, scale, interpret)
     group = _pages_per_group(H * bs * D * pages_k.dtype.itemsize,
                              tables.shape[1])
@@ -804,7 +950,8 @@ def paged_decode_attention(q, pages_k, pages_v, tables, lengths, layer,
 
 
 def _paged_span_kernel(tbl_ref, start_ref, n_ref, lay_ref, q_ref, k_ref,
-                       v_ref, *rest, scale, bs, quant):
+                       v_ref, *rest, scale, bs, quant, window=None,
+                       groups=1):
     """One (slot, head) SPAN's online softmax over its block table
     (ISSUE 14). Grid ``(S, H, MB)``: the innermost axis streams the
     slot's KV blocks (sequential on TPU — the m/l/acc scratch carries
@@ -818,7 +965,12 @@ def _paged_span_kernel(tbl_ref, start_ref, n_ref, lay_ref, q_ref, k_ref,
     materializing an O(W)-per-row XLA gather. With ``quant`` the K/V
     blocks arrive int8 with per-row scale pages and are dequantized IN
     VMEM (never in HBM — the whole point of the int8 pool is HBM bytes;
-    :func:`_kv_blocks`)."""
+    :func:`_kv_blocks`). With ``window`` row ``j`` sees the positions
+    ``start + j - window < p <= start + j`` only, and the blocks wholly
+    behind the FIRST row's window are skipped like those past the last
+    (a later row whose window has not begun in a block that runs sums
+    masked scores there, which its own position's block then scales to
+    nought)."""
     if quant:
         sk_ref, sv_ref, o_ref, m_s, l_s, acc_s = rest
     else:
@@ -845,16 +997,24 @@ def _paged_span_kernel(tbl_ref, start_ref, n_ref, lay_ref, q_ref, k_ref,
     # always runs for a live slot, so every live row's softmax state
     # lifts off the _NEG floor there (row j's own position
     # start+j >= 0 is always visible)
-    @pl.when((n > 0) & (j * bs < start + n))
+    runs = (n > 0) & (j * bs < start + n)
+    if window is not None:
+        runs = runs & ((j + 1) * bs > start - window + 1)
+
+    @pl.when(runs)
     def _():
-        kb, vb, sk, sv = _kv_blocks(k_ref, v_ref, sk_ref, sv_ref, head)
+        kb, vb, sk, sv = _kv_blocks(k_ref, v_ref, sk_ref, sv_ref,
+                                    head // groups)
         s = jax.lax.dot_general(q_ref[:], kb, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if quant:
             s = s * sk
         k_idx = j * bs + jax.lax.broadcasted_iota(jnp.int32, (Q, bs), 1)
         q_idx = jax.lax.broadcasted_iota(jnp.int32, (Q, bs), 0)
-        s = jnp.where(k_idx <= start + q_idx, s, _NEG)
+        visible = k_idx <= start + q_idx
+        if window is not None:
+            visible = visible & (k_idx > start + q_idx - window)
+        s = jnp.where(visible, s, _NEG)
         m = m_s[:]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -874,7 +1034,8 @@ def _paged_span_kernel(tbl_ref, start_ref, n_ref, lay_ref, q_ref, k_ref,
 
 def paged_span_attention(q, pages_k, pages_v, tables, start, n, layer,
                          scale: Optional[float] = None,
-                         interpret: Optional[bool] = None):
+                         interpret: Optional[bool] = None,
+                         window: Optional[int] = None):
     """Multi-query (q_len = 1+k) flash attention over a paged KV cache —
     the span-tick hot op (ISSUE 14). Each slot's span of ``Q``
     consecutive new-token queries attends its block-table pages with one
@@ -892,20 +1053,29 @@ def paged_span_attention(q, pages_k, pages_v, tables, start, n, layer,
     ``n == 0`` marks an inactive slot (zero output). At ``Q = 1`` it
     agrees with :func:`paged_decode_attention` to rounding, as both do
     with their oracles, and no closer: that kernel sums a group of pages
-    at a time. ``interpret`` defaults to True off-TPU."""
+    at a time. ``interpret`` defaults to True off-TPU. Grouped KV heads
+    (``H = G * H_kv``) and ``window`` as in
+    :func:`paged_decode_attention`: a query head's grid steps read KV
+    head ``h // G``'s pages, and a row sees its last ``window``
+    positions. The pages have to be where the TABLE says for every
+    position a row sees: a ring that the span's own writes have wrapped
+    is not (``nn/attention.py:GroupedQueryAttention.decode_span`` reads
+    before it writes)."""
     return _paged_span_call(q, pages_k, pages_v, tables, start, n, layer,
-                            scale, interpret, name="paged_span")
+                            scale, interpret, name="paged_span",
+                            window=window)
 
 
 def _paged_span_call(q, pages_k, pages_v, tables, start, n, layer, scale,
-                     interpret, name):
+                     interpret, name, window=None):
     """:func:`paged_span_attention` as the Mosaic kernel ``name``."""
     S, Q, H, D = q.shape
     pages_k, scale_k = _unpack_pages(pages_k)
     pages_v, scale_v = _unpack_pages(pages_v)
     quant = scale_k is not None
     L, N, Hk, bs, Dk = pages_k.shape
-    assert (H, D) == (Hk, Dk), f"q heads {(H, D)} != pages {(Hk, Dk)}"
+    assert D == Dk and H % Hk == 0, f"q heads {(H, D)} != pages {(Hk, Dk)}"
+    G = H // Hk
     MB = tables.shape[1]
     scale, interpret = _resolve_defaults(q, scale, interpret)
     qt = jnp.swapaxes(q, 1, 2)               # [S, H, Q, D]
@@ -914,7 +1084,7 @@ def _paged_span_call(q, pages_k, pages_v, tables, start, n, layer, scale,
         return (s, h, 0, 0)
 
     def kv_map(s, h, j, tbl, st, nn, lay):
-        return (lay[0], tbl[s, j], h, 0, 0)
+        return (lay[0], tbl[s, j], h // G, 0, 0)
 
     in_specs = [
         pl.BlockSpec((None, None, Q, D), q_map),
@@ -923,7 +1093,7 @@ def _paged_span_call(q, pages_k, pages_v, tables, start, n, layer, scale,
     ]
     operands = [qt, pages_k, pages_v]
     if quant:
-        in_specs += [_scale_spec(H, bs, kv_map)] * 2
+        in_specs += [_scale_spec(Hk, bs, kv_map)] * 2
         operands += [scale_k, scale_v]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -938,7 +1108,7 @@ def _paged_span_call(q, pages_k, pages_v, tables, start, n, layer, scale,
     )
     out = pl.pallas_call(
         functools.partial(_paged_span_kernel, scale=scale, bs=bs,
-                          quant=quant),
+                          quant=quant, window=window, groups=G),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, Q, D), q.dtype),
         interpret=interpret, name=name,

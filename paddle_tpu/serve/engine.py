@@ -201,6 +201,14 @@ class DecodeEngine:
       :class:`~paddle_tpu.models.TransformerLM` declares ``k`` and ``v``
       rows of ``[heads, head size]``,
       :class:`~paddle_tpu.models.LatentMoELM` one ``latent`` row.
+      A model whose layers are of several KINDS declares ``{"groups":
+      {group: {"layers": n, "pools": {...}, "window": w}}}`` instead
+      (``window`` optional): the pools are then named ``<group>/<name>``,
+      each group is sized apart, a window group holds ``ceil(w /
+      block_size) + 1`` blocks a slot whatever the context
+      (``serve/kv_cache.py``), and ``tables`` below is a dict, a table a
+      group (:class:`~paddle_tpu.models.WindowMoELM`: ``full`` and
+      ``window`` groups of ``k`` / ``v`` rows).
     - ``decode_step(token, kv, positions, active, attn_impl=)`` and
       ``decode_span(tokens, kv, start, n, active, attn_impl=,
       write_from=)`` with ``kv = (*pools in declared order, tables)``,
@@ -225,7 +233,9 @@ class DecodeEngine:
     ``k`` / ``v`` rows only refuses any other declaration at build:
     ``kv_dtype="int8"``, ``mesh=``, the one-shot prefill;
     ``export_slot`` / ``adopt_slot`` (and with them
-    ``serve/transport.py``'s wire format) when called.
+    ``serve/transport.py``'s wire format) when called. A WINDOW group
+    refuses besides, at build: prefix sharing with its copy-on-write,
+    speculation, and a prefill chunk longer than the window.
 
     Args:
       model: a TransformerLM (any training config: a training
@@ -259,7 +269,9 @@ class DecodeEngine:
         (ISSUE 14).
       share_prefix: copy-on-write physical block sharing between
         resident sequences with a common prompt prefix (default ON —
-        the PagedAttention production win, ISSUE 12).
+        the PagedAttention production win, ISSUE 12 — wherever the
+        cache can share: a model with a window group gets it OFF by
+        default and refuses True).
       retain_prefix: RadixAttention-style retention (ISSUE 14, needs
         ``share_prefix``): evicted registered blocks park in a
         retained LRU (lazily reclaimed under pool pressure) so
@@ -310,7 +322,8 @@ class DecodeEngine:
     def __init__(self, model, variables, *, max_slots: int = 4,
                  block_size: int = 16, num_blocks: Optional[int] = None,
                  max_blocks_per_seq: Optional[int] = None,
-                 attention: str = "auto", share_prefix: bool = True,
+                 attention: str = "auto",
+                 share_prefix: Optional[bool] = None,
                  retain_prefix: bool = True,
                  speculative: int = 0,
                  prefill_chunk: Optional[int] = None,
@@ -346,25 +359,50 @@ class DecodeEngine:
         # named pools, each the shape of one token's row in one layer,
         # and the counters its entry points return beside the pools
         spec = model.cache_spec()
-        num_layers = int(spec["layers"])
-        self.pool_names = tuple(spec["pools"])
+        groups = spec.get("groups")
+        if groups:
+            self.pool_names = tuple(f"{g}/{n}" for g, decl in groups.items()
+                                    for n in decl["pools"])
+            num_layers = None
+        else:
+            self.pool_names = tuple(spec["pools"])
+            num_layers = int(spec["layers"])
         self.counter_names = tuple(spec.get("counters", ()))
         kv_pools = self.pool_names == ("k", "v")
+        windows = [int(g["window"]) for g in (groups or {}).values()
+                   if g.get("window")]
+        if share_prefix is None:
+            share_prefix = not windows
+        # what has not been carried over to other pools, or to a window
+        # group's ring, fails here and not in a compiled program
+        refused = []
         if not kv_pools:
-            # what has not been carried over to other pools fails here,
-            # not in a compiled program
-            for given, what in ((kv_dtype == "int8", "kv_dtype='int8'"),
-                                (mesh is not None, "mesh="),
-                                (prefill_chunk is None,
-                                 "the one-shot prefill (prefill_chunk=None)")):
-                if given:
-                    raise NotImplementedError(
-                        f"{what} is defined for the k / v pools of "
-                        f"multi-head attention only; {type(model).__name__}"
-                        f" declares {list(self.pool_names)}: int8 rows, "
-                        f"head sharding, the prefill scatter, export_slot /"
-                        f" adopt_slot and the transport's wire format know"
-                        f" [heads, head size] rows")
+            refused += [
+                (kv_dtype == "int8", "kv_dtype='int8'"),
+                (mesh is not None, "mesh="),
+                (prefill_chunk is None,
+                 "the one-shot prefill (prefill_chunk=None)")]
+        if windows:
+            refused += [
+                (share_prefix, "share_prefix=True (prefix sharing and "
+                 "copy-on-write)"),
+                (speculative > 0, "speculative > 0"),
+                (prefill_chunk is not None and prefill_chunk > min(windows),
+                 f"a prefill chunk longer than the window {min(windows)}")]
+        for given, what in refused:
+            if given:
+                raise NotImplementedError(
+                    f"{what} is defined for the k / v pools of multi-head "
+                    f"attention only; {type(model).__name__} declares "
+                    f"{list(self.pool_names)}"
+                    + (f" with a window group of {min(windows)}: a ring a "
+                       f"slot, which no other slot shares, a rejected draft "
+                       f"cannot be taken out of and a longer chunk wraps"
+                       if windows else "")
+                    + ": int8 rows, head sharding, the prefill scatter, "
+                    "export_slot / adopt_slot and the transport's wire "
+                    "format know [heads, head size] rows in ONE pool "
+                    "group")
         dtype = canonicalize(dtype)
         num_heads, head_dim = spec["pools"]["k"] if kv_pools else (1, None)
         # tensor-parallel mesh (ISSUE 15): resolve the tp degree, place
@@ -429,7 +467,8 @@ class DecodeEngine:
             max_slots=max_slots, max_blocks_per_seq=max_blocks_per_seq,
             dtype=dtype, share_prefix=share_prefix, kv_dtype=kv_dtype,
             retain_prefix=retain_prefix, tp_degree=self.tp_degree,
-            row_shapes=None if kv_pools else dict(spec["pools"]))
+            row_shapes=None if kv_pools or groups else dict(spec["pools"]),
+            groups=groups)
         if mesh is not None:
             self.cache.shard_pools(mesh, tp_axis)
         else:
@@ -971,8 +1010,7 @@ class DecodeEngine:
                     jnp.asarray(ids), jnp.asarray([cur], jnp.int32),
                     jnp.asarray([n], jnp.int32),
                     jnp.asarray([st["shared_len"]], jnp.int32),
-                    jnp.asarray(self.cache.tables[slot:slot + 1]),
-                    self._prefill_key())
+                    self.cache.slot_tables(slot), self._prefill_key())
                 st.setdefault("counters", []).extend(counters)
                 st["cursor"] = cur + n
                 done = st["cursor"] >= P
@@ -1219,8 +1257,13 @@ class DecodeEngine:
             # wait.
             n_active = int(self.active.sum())
             if tick_sp is not None:
-                tick_sp.set(active=n_active, live_tokens=int(
-                    self.cache.lengths[self.active].sum()) + n_active)
+                seen = self.cache.lengths[self.active] + 1
+                tick_sp.set(active=n_active, live_tokens=int(seen.sum()), **{
+                    # the keys a window group's layers read: its window of
+                    # every slot's and no more
+                    f"live_tokens_{g.name}": int(
+                        np.minimum(seen, g.window).sum())
+                    for g in self.cache.groups.values() if g.window})
             if self.speculative == 0:
                 self.cache.lengths[self.active] += 1
             # the drain: the host waits for the device here
@@ -1370,7 +1413,7 @@ class DecodeEngine:
     def _prefill_args(self):
         """The prefill's operands at the engine's shapes: slot 0's table,
         one live token of zeros (``warmup()`` runs the program on them)."""
-        table = jnp.asarray(self.cache.tables[0:1])
+        table = self.cache.slot_tables(0)
         one, zero = jnp.asarray([1], jnp.int32), jnp.asarray([0], jnp.int32)
         args = (self.variables, self.cache.pools)
         if self.prefill_chunk is None:      # ids, length, start

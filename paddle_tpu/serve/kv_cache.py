@@ -85,7 +85,8 @@ import numpy as np
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["BlockAllocator", "PagedKVCache", "PrefixCache", "PrefixMatch",
+__all__ = ["BlockAllocator", "PagedKVCache", "PoolGroup", "PrefixCache",
+           "PrefixMatch",
            "gather_pages", "scatter_prefill", "scatter_token",
            "scatter_span", "scatter_prefill_pages", "write_token",
            "write_span", "quantize_rows", "dequantize_rows",
@@ -301,15 +302,76 @@ def _write_pages(pool, layer, rows, table, start, n, write_from):
     return pool
 
 
-def write_span(pages, layer, kv, table, start, n, write_from=None):
+def _write_fresh_pages(pool, layer, rows, table, start, n, write_from):
+    """A long span's rows ``[S, Q, H, hd]`` written into layer ``layer``
+    of a pool ``[L, N, H, bs, hd]`` WITHOUT reading it: the page that
+    holds the first written position (``lo``: ``start``, or ``write_from``
+    where that is later) takes its rows one at a time, as a tick's do, and
+    every later page is written WHOLE, rows past the span's end as zeros.
+    What such a row held is never read again: its position lies at or past
+    the sequence's end, where every reader masks and the row that comes to
+    lie there is written first (in a window group's ring it stands for a
+    position ``ring * bs`` older, behind every window). ``bs`` row writes
+    and ``ceil(Q / bs)`` page writes, all plain ``dynamic_update_slice``s
+    that no compiler has a reason to move a pool for: the page writes of
+    :func:`_write_pages` read each old page, and fused pairwise over a
+    ``k`` and a ``v`` pool XLA copied one of them whole."""
+    S, Q = rows.shape[:2]
+    bs, MB = pool.shape[3], table.shape[1]
+    pages = -(-Q // bs)
+    zeros = (0,) * (rows.ndim - 2)
+    for s in range(S):
+        lo = start[s] if write_from is None \
+            else jnp.maximum(start[s], write_from[s])
+        hi = start[s] + n[s]
+        own = jnp.pad(rows[s].astype(pool.dtype),
+                      ((0, bs),) + ((0, 0),) * len(zeros))
+
+        def rows_at(position):
+            """``bs`` of the span's rows from ``position`` on."""
+            return lax.dynamic_slice(
+                own, (jnp.clip(position - start[s], 0, Q),) + zeros,
+                (bs,) + own.shape[1:])
+
+        def block_of(position, live):
+            return jnp.where(live, table[s, jnp.minimum(position // bs,
+                                                        MB - 1)], NULL_BLOCK)
+
+        head = rows_at(lo)
+        for i in range(bs):
+            pos = lo + i
+            live = (pos < hi) & (pos // bs == lo // bs)
+            pool = lax.dynamic_update_slice(
+                pool, head[i][None, None, :, None],
+                (layer, block_of(pos, live), 0, pos % bs, 0))
+        for j in range(pages):
+            first = (lo // bs + 1 + j) * bs
+            live = first + jnp.arange(bs, dtype=jnp.int32) < hi
+            page = jnp.where(live[:, None, None], rows_at(first), 0)
+            pool = lax.dynamic_update_slice(
+                pool, jnp.moveaxis(page, 0, 1)[None, None],
+                (layer, block_of(first, live[0]), 0, 0, 0))
+    return pool
+
+
+def write_span(pages, layer, kv, table, start, n, write_from=None,
+               by_page: bool = False):
     """:func:`scatter_span` on ``pages[layer]`` in place: ``kv``
     ``[S, Q, H, hd]`` (or ``[S, Q, width]``), one row write a token; a
     span of two pages and more into a pool of plain ``[width]`` rows goes
-    a page at a time (:func:`_write_pages`)."""
+    a page at a time (:func:`_write_pages`), and so does one into a plain
+    (not quantized) pool of ``[H, hd]`` rows where the caller asks
+    (``by_page``: :func:`_write_fresh_pages`, which leaves zeros in the
+    rows of its last page that lie past the span's end)."""
     S, Q = kv.shape[:2]
     lead = kv.ndim - 3
-    if lead == 0 and Q >= 2 * _block_size(pages, lead):
-        return _write_pages(pages, layer, kv, table, start, n, write_from)
+    if Q >= 2 * _block_size(pages, lead):
+        if lead == 0:
+            return _write_pages(pages, layer, kv, table, start, n,
+                                write_from)
+        if by_page and not isinstance(pages, tuple):
+            return _write_fresh_pages(pages, layer, kv, table, start, n,
+                                      write_from)
     blk, off = _span_route(table, start, n, write_from, Q,
                            _block_size(pages, lead))
     return _write_rows(pages, layer, kv.reshape(S * Q, *kv.shape[2:]),
@@ -639,6 +701,26 @@ class PrefixCache:
                 del table[key]
 
 
+@dataclasses.dataclass
+class PoolGroup:
+    """One declared group of pools (:class:`PagedKVCache`): the layers of
+    one KIND. ``ring`` is the blocks a slot owns of a window group
+    (``ceil(window / block_size) + 1``: a window of positions touches at
+    most that many pages), None for a group that grows with the context;
+    ``rows`` the shape of one token's row in one layer, a pool."""
+    name: str
+    layers: int
+    window: Optional[int]
+    ring: Optional[int]
+    num_blocks: int
+    rows: Dict[str, Tuple[int, ...]]
+
+    def row_bytes(self, dtype) -> int:
+        """Bytes one token leaves in ONE layer of the group."""
+        return jnp.dtype(dtype).itemsize * sum(
+            int(np.prod(r)) for r in self.rows.values())
+
+
 class PagedKVCache:
     """Device pools + the authoritative host mirror of block tables and
     sequence lengths for up to ``max_slots`` concurrent sequences.
@@ -664,15 +746,53 @@ class PagedKVCache:
 
     Int8 quantization, head sharding (``tp_degree``, ``shard_pools``) and
     the page export / import of a handoff are defined for the ``k`` /
-    ``v`` pools only and refuse any other declaration."""
+    ``v`` pools only and refuse any other declaration.
 
-    def __init__(self, num_layers: int, num_heads: Optional[int],
+    Pool GROUPS (``groups``): a model whose layers are of several kinds
+    declares ``{group: {"layers": n, "pools": {name: row}, "window":
+    w}}`` (``window`` optional) in place of ``num_layers`` and
+    ``row_shapes``; the pools are then named ``<group>/<name>`` and each
+    group is sized apart. A group with no window grows with the context:
+    ``num_blocks`` blocks, and the tables, the allocator and the free
+    count above are ITS account (several such groups grow alike and
+    share it). A WINDOW group is a ring every slot owns outright:
+    ``ceil(window / block_size) + 1`` blocks a slot
+    (:attr:`PoolGroup.ring`), whatever the context, in a table that never
+    changes: position ``p`` lives in the slot's ring block ``(p //
+    block_size) % ring``, so the entry points and the kernels address it
+    through an ordinary ``[max_slots, max_blocks_per_seq]`` table whose
+    entries repeat with period ``ring``, and read no position older than
+    ``window`` (what lies there has been overwritten). It has no
+    allocator and nothing to free, and it never refuses an admission.
+    Prefix sharing and copy-on-write do not reach a ring (the engine
+    refuses them at build)."""
+
+    def __init__(self, num_layers: Optional[int], num_heads: Optional[int],
                  head_dim: Optional[int],
                  num_blocks: int, block_size: int, max_slots: int,
                  max_blocks_per_seq: int, dtype=jnp.float32,
                  share_prefix: bool = False, kv_dtype: Optional[str] = None,
                  retain_prefix: bool = True, tp_degree: int = 1,
-                 row_shapes: Optional[Dict[str, Tuple[int, ...]]] = None):
+                 row_shapes: Optional[Dict[str, Tuple[int, ...]]] = None,
+                 groups: Optional[Dict[str, Dict]] = None):
+        self.groups: Dict[str, PoolGroup] = {}
+        if groups:
+            assert row_shapes is None, "declare pools or groups, not both"
+            for g, spec in groups.items():
+                window = spec.get("window")
+                ring = None if window is None \
+                    else -(-int(window) // block_size) + 1
+                self.groups[g] = PoolGroup(
+                    g, int(spec["layers"]), window, ring,
+                    num_blocks if ring is None else max_slots * ring + 1,
+                    {n: tuple(int(d) for d in r)
+                     for n, r in spec["pools"].items()})
+            num_layers = sum(g.layers for g in self.groups.values())
+            row_shapes = {f"{g.name}/{n}": r for g in self.groups.values()
+                          for n, r in g.rows.items()}
+            if share_prefix and any(g.ring for g in self.groups.values()):
+                raise ValueError("prefix sharing does not reach a window "
+                                 "group's ring")
         self.num_layers = num_layers
         self.kv_pools = row_shapes is None
         if self.kv_pools:
@@ -711,7 +831,10 @@ class PagedKVCache:
         self.quantized = kv_dtype == "int8"
         self.pools: Dict[str, object] = {}
         for name, row in self.row_shapes.items():
-            shape = (num_layers, num_blocks, *row[:-1], block_size, row[-1])
+            group = self.groups.get(name.split("/")[0])
+            layers, blocks = ((num_layers, num_blocks) if group is None
+                              else (group.layers, group.num_blocks))
+            shape = (layers, blocks, *row[:-1], block_size, row[-1])
             if self.quantized:
                 # int8 value pages + per-block scale pages (one f32 per
                 # token row per head) — quantize-on-scatter writes both
@@ -723,6 +846,17 @@ class PagedKVCache:
         self.allocator = BlockAllocator(num_blocks)
         self.tables = np.zeros((max_slots, max_blocks_per_seq), np.int32)
         self.lengths = np.zeros((max_slots,), np.int32)
+        # a window group's table: slot s owns blocks 1 + s * ring ..,
+        # page j of any context is ring block j % ring; made once, on the
+        # host (a chunk takes one row of it) and on the device (a tick
+        # takes it whole, the same array every time)
+        self._ring_tables = {
+            g.name: (1 + np.arange(max_slots)[:, None] * g.ring
+                     + np.arange(max_blocks_per_seq)[None, :] % g.ring
+                     ).astype(np.int32)
+            for g in self.groups.values() if g.ring}
+        self._ring_device = {n: jnp.asarray(t)
+                             for n, t in self._ring_tables.items()}
         self._owned: List[List[int]] = [[] for _ in range(max_slots)]
         # COW bookkeeping: which table indices this slot ADOPTED (vs
         # allocated), and the admission-reserved fork target
@@ -801,6 +935,10 @@ class PagedKVCache:
         ``num_heads / tp_degree`` heads of every row (ISSUE 15), so this
         is the number a device's HBM budget divides by — capacity scales
         with the mesh."""
+        if self.groups:
+            # what GROWS with the context: the groups without a window
+            return sum(g.layers * g.row_bytes(self.dtype)
+                       for g in self.groups.values() if not g.ring)
         if not self.kv_pools:
             return self.num_layers * jnp.dtype(self.dtype).itemsize * sum(
                 int(np.prod(r)) for r in self.row_shapes.values())
@@ -820,6 +958,30 @@ class PagedKVCache:
 
     def blocks_needed(self, length: int) -> int:
         return -(-length // self.block_size)          # ceil
+
+    def group_facts(self) -> Dict[str, Dict[str, int]]:
+        """Each declared group's account: its layers and window, the bytes
+        of one of its blocks (all its pools, all its layers), the blocks
+        it has, those IN USE now (allocated, of a group that grows; of a
+        ring the blocks the slots' lengths reach, ``ring`` a slot at
+        most) and those LIVE (the blocks the slots' lengths reach, in
+        either: what holds a position, without the reservation for
+        positions to come), beside the positions themselves. Nothing for
+        a cache without groups."""
+        reach = -(-self.lengths.astype(np.int64) // self.block_size)
+        facts = {}
+        for g in self.groups.values():
+            live = int(np.minimum(reach, g.ring).sum() if g.ring
+                       else reach.sum())
+            facts[g.name] = {
+                "layers": g.layers, "window": g.window or 0,
+                "block_bytes": g.layers * g.row_bytes(self.dtype)
+                * self.block_size,
+                "num_blocks": g.num_blocks, "blocks_live": live,
+                "blocks_in_use": live if g.ring else
+                self.num_blocks - 1 - self.allocator.reclaimable,
+                "live_tokens": int(self.lengths.sum())}
+        return facts
 
     def owned_count(self, slot: int) -> int:
         """How many pool blocks ``slot`` currently holds — the size of
@@ -1059,5 +1221,22 @@ class PagedKVCache:
         the tick read lengths one too long (measured on the CPU backend,
         PR 29: 17 of 40 runs of one scenario served another token; none
         of 40 with the copies taken here)."""
-        return (jnp.asarray(self.tables.copy()),
+        return (self._tables(jnp.asarray(self.tables.copy()),
+                             self._ring_device),
                 jnp.asarray(self.lengths.copy()))
+
+    def _tables(self, grown, rings):
+        """``grown``, the table of the account that grows, as the entry
+        points take it: itself, or with declared groups ``{group: table}``
+        (a window group's is its ring's, which never changes)."""
+        if not self.groups:
+            return grown
+        return {g.name: rings[g.name] if g.ring else grown
+                for g in self.groups.values()}
+
+    def slot_tables(self, slot: int):
+        """One slot's ``[1, MB]`` table(s), a prefill chunk's operand."""
+        rows = slice(slot, slot + 1)
+        return self._tables(
+            jnp.asarray(self.tables[rows]),
+            {n: jnp.asarray(t[rows]) for n, t in self._ring_tables.items()})

@@ -29,7 +29,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu.core import bfloat16_compute, mesh as mesh_lib, use_policy
 from paddle_tpu.models import (LatentMoELM, ShortcutMoEBlock,
-                               TransformerLM)
+                               TransformerLM, WindowMoELM)
+from paddle_tpu.nn import pallas_attention
 from paddle_tpu.nn import MultiHeadAttention, pallas_mode
 from paddle_tpu.nn.moe import ROW_WINDOW, HeldExpertsFFN
 from paddle_tpu.nn.pallas_attention import (flash_attention,
@@ -82,6 +83,27 @@ def test_paged_kernels_lower(kind, heads, dh):
         lower_tpu(functools.partial(paged_span_attention, interpret=False),
                   sds((SLOTS, q_len, heads, dh), jnp.float32), pages, pages,
                   tables, vec, vec, layer)
+
+
+@pytest.mark.parametrize("kind", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("q_heads,window", [(48, None), (72, 512)])
+def test_grouped_paged_kernels_lower(kind, q_heads, window):
+    """The same kernels for GROUPED KV heads at the published shape of
+    ``serve-laguna118b-closed64``'s two layer kinds: 48 query heads on 8
+    KV heads over the whole context, 72 on 8 over a window of 512 (an
+    int8 pool takes the grid, with a KV head's scale row)."""
+    pages = pool(kind, 8, DH)
+    tables = sds((SLOTS, MB), jnp.int32)
+    vec = sds((SLOTS,), jnp.int32)
+    layer = sds((), jnp.int32)
+    lower_tpu(functools.partial(paged_decode_attention, interpret=False,
+                                window=window),
+              sds((SLOTS, q_heads, DH), jnp.float32), pages, pages, tables,
+              vec, layer)
+    lower_tpu(functools.partial(paged_span_attention, interpret=False,
+                                window=window),
+              sds((SLOTS, 5, q_heads, DH), jnp.float32), pages, pages,
+              tables, vec, vec, layer)
 
 
 @pytest.mark.parametrize("heads,layers", [(128, 5), (64, 8)])
@@ -192,6 +214,35 @@ def test_paged_decode_compiles(one_chip, kind, heads, dh):
     if dh % 128 == 0:
         layer_bytes = N * heads * BS * dh * jnp.dtype(kind).itemsize
         assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+
+
+@pytest.mark.parametrize("kind", ["float32", "bfloat16"])
+@pytest.mark.parametrize("q_heads,window,slots,blocks", [
+    (48, None, 64, 896), (72, 512, 64, 896), (16, 128, SLOTS, MB)])
+def test_grouped_paged_decode_compiles(one_chip, kind, q_heads, window,
+                                       slots, blocks):
+    """Mosaic's own compile of the GROUPED decode kernel at
+    ``serve-laguna118b-closed64``'s shapes (64 slots of 896 pages; 48
+    query heads on 8 KV heads over everything, 72 on 8 over a window of
+    512, whose 33 pages walk as 3 groups of 11) and of one row a KV head
+    under a window: one Mosaic kernel named ``paged_decode`` (the name the
+    benchmark's readers look for) and no temporary the size of a layer's
+    pool."""
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    kv_heads = 8 if q_heads > 16 else 16
+    pages = on_chip(sds((2, N, kv_heads, BS, DH), jnp.dtype(kind)))
+    compiled = jax.jit(functools.partial(
+        paged_decode_attention, interpret=False, window=window)).lower(
+            on_chip(sds((slots, q_heads, DH), jnp.float32)), pages, pages,
+            on_chip(sds((slots, blocks), jnp.int32)),
+            on_chip(sds((slots,), jnp.int32)),
+            on_chip(sds((), jnp.int32))).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and "paged_decode" in calls[0], calls
+    layer_bytes = N * kv_heads * BS * DH * jnp.dtype(kind).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
 
 
 # an instruction: its name, whether its result is a tuple, the (first)
@@ -321,6 +372,106 @@ def test_tick_leaves_the_pools_in_place(one_chip, monkeypatch, kv_dtype,
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < pool_bytes, \
         f"temporaries {temp} B, one pool {pool_bytes} B"
+
+
+def toy_window_engine(blocks):
+    """A toy of the two-kinds-of-attention expert model at the lane
+    tile's widths: ``[full, sliding, sliding]`` layers of 4 and 6 query
+    heads on 2 KV heads of 128, a window of 64, one dense layer and two
+    of 8 experts with 4 held, bfloat16 weights and pools, a chunked
+    prefill."""
+    model = WindowMoELM(
+        vocab=512, dim=256, layer_windows=[None, 64, 64],
+        layer_heads=[4, 6, 6], num_kv_heads=2, head_dim=128,
+        rotary={"full": dict(rope_base=5e5, rope_dim=64, yarn=dict(
+            factor=8.0, original_len=64, attention_factor=1.2)),
+            "window": dict(rope_base=1e4)},
+        dense_hidden=512, expert_hidden=256, shared_hidden=256,
+        num_experts=8, top_k=2, experts_held=(2, 4), routed_scaling=2.5,
+        max_len=1024)
+    variables = jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16),
+        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    return DecodeEngine(model, variables, max_slots=4, block_size=BS,
+                        num_blocks=blocks, attention="paged",
+                        max_blocks_per_seq=64, prefill_chunk=32,
+                        dtype="bfloat16")
+
+
+def test_window_groups_programs_leave_every_pool_in_place(one_chip,
+                                                          monkeypatch):
+    """The tick AND the prefill chunk of a toy engine with two pool
+    groups, compiled for the TPU: every pool of both groups is an
+    argument aliased to a result (written in place: the chunk's page
+    writes too), nothing but the carry's plumbing and the in-place writes
+    has a result the size of a pool or of one layer of one, and the
+    temporaries are smaller than one pool that grows. The window group's
+    pools are ``slots * ring + 1`` blocks whatever ``num_blocks`` is."""
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+    engine = toy_window_engine(blocks=40001)
+    pools = engine.cache.pools
+    ring = engine.cache.groups["window"].ring
+    assert ring == 64 // BS + 1
+    assert pools["window/k"].shape == (2, 4 * ring + 1, 2, BS, 128)
+    assert pools["full/k"].shape == (1, 40001, 2, BS, 128)
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip), tree)
+    sizes = {p.size // div for p in pools.values()
+             for div in (1, p.shape[0])}
+    pool_bytes = sum(p.nbytes for p in pools.values())
+    for name, fn, args in (
+            ("tick", engine._tick_fn, engine._tick_args()),
+            ("prefill", engine._prefill_fn, engine._prefill_args())):
+        compiled = fn.lower(*on_chip(args)).compile()
+        text = compiled.as_text()
+        assert 'custom_call_target="tpu_custom_call"' in text or \
+            name == "prefill"
+        held = pool_sized_results(text, sizes)
+        moved = [h for h in held if h[0] not in _MAY_HOLD_A_POOL]
+        assert not moved, f"{name}: pool-sized results: {moved}"
+        # the chunk's page writes of the four pools fuse into in-place
+        # updates with several results; the tick's row writes stand alone
+        assert " dynamic-update-slice(" in text, name
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes == pool_bytes, name
+        assert memory.temp_size_in_bytes < pools["full/k"].nbytes, name
+
+
+@pytest.mark.parametrize("kind", ["transformer", "latent"])
+def test_models_without_groups_keep_their_pools_and_their_kernel(
+        kind, monkeypatch):
+    """A model that declares no pool groups gets the pools, the tables and
+    the decode kernel it got before groups existed: one pool a name with
+    the one leading layer axis, one table an array, prefix sharing on by
+    default, and (``TransformerLM``: as many KV heads as query heads, no
+    window) the one-row ``paged_decode`` kernel, never the grouped one."""
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+
+    def refuse(*a, **k):
+        raise AssertionError("the grouped kernel on an ungrouped model")
+    monkeypatch.setattr(pallas_attention, "_grouped_decode_call", refuse)
+    if kind == "latent":
+        engine = toy_latent_engine(blocks=257)
+        want = {"latent": (2, 257, BS, 256)}
+    else:
+        model = TransformerLM(vocab=512, dim=512, num_layers=2, num_heads=4,
+                              ffn_hidden=1024, max_len=256)
+        engine = DecodeEngine(
+            model, model.init(jax.random.PRNGKey(0),
+                              jnp.zeros((1, 8), jnp.int32)),
+            max_slots=4, block_size=BS, num_blocks=257, attention="paged")
+        want = {"k": (2, 257, 4, BS, 128), "v": (2, 257, 4, BS, 128)}
+    cache = engine.cache
+    assert {n: p.shape for n, p in cache.pools.items()} == want
+    assert engine.pool_names == tuple(want) and cache.groups == {}
+    assert cache.share_prefix is True and cache.group_facts() == {}
+    tables, lengths = cache.device_tables()
+    assert tables.shape == (4, 16) and lengths.shape == (4,)
+    assert cache.slot_tables(1).shape == (1, 16)
+    text = jax.jit(engine._tick_fn.__wrapped__).trace(
+        *engine._tick_args()).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
 
 
 # what may have a result the shape of a stacked block leaf: the program's
