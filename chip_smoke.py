@@ -5,7 +5,8 @@ to finish: ``Trainer.init`` / ``Trainer.train`` on a reader, then the
 trained parameters as they are into ``DecodeEngine`` under
 ``ContinuousBatchingScheduler``, at the full width of the widest model
 the repo has measured (``transformer_big``: d1024, dh=128, 8 layers,
-seq 2048), then every Pallas kernel against its float32 oracle. When four
+seq 2048), then the one-shot prefill's pages against the scatter's and
+every Pallas kernel against its float32 oracle. When four
 devices are visible the same path runs on four (dp=4 training, tp=4
 serving) in the same process. Seeded synthetic data only; no network, no
 child that needs a device.
@@ -69,6 +70,13 @@ TOLERANCE = {"float32": 1e-2, "bfloat16": 2e-2, "int8": 2e-2}
 
 def log(msg: str) -> None:
     print(msg, flush=True)
+
+
+def relative_error(got, want) -> float:
+    """The largest difference over the oracle's largest value."""
+    import numpy as np
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-6))
 
 
 def mosaic_kernels(hlo_text: str) -> List[str]:
@@ -262,6 +270,67 @@ def serve_legs(sizes: Sizes, model, variables, **engine_kwargs) -> None:
     assert engine.prefill_chunks > n, "no prompt took two chunks"
 
 
+def prefill_leg(sizes: Sizes, model, variables, **engine_kwargs) -> None:
+    """The one-shot prefill's in-place page writes on the device, outside
+    the benchmark: an engine with prefix sharing admits a prompt, a
+    duplicate of it and a prompt that extends its first blocks (``start``
+    at the prompt's end, and on a block edge), float32 and bfloat16 pools;
+    every slot's pages then gather to the rows the oracle scatters from
+    the same projections (``model.prefill`` + ``scatter_prefill`` on pools
+    of zeros, another program: the kernel leg's tolerances), up to the
+    slot's length."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.core.dtypes import bfloat16_compute, use_policy
+    from paddle_tpu.serve import DecodeEngine
+    from paddle_tpu.serve.kv_cache import gather_pages, scatter_prefill
+
+    t0 = time.perf_counter()
+    rng = np.random.RandomState(0)
+    bs = sizes.block_size
+    first = list(rng.randint(0, sizes.vocab, 2 * bs + bs // 2))
+    prompts = [first, list(first),
+               first[:2 * bs] + list(rng.randint(0, sizes.vocab, bs + 1))]
+    errs: Dict[str, float] = {}
+    scat = jax.jit(jax.vmap(scatter_prefill, in_axes=(0, 0, None, None)))
+    for kind in ("float32", "bfloat16"):
+        with use_policy(bfloat16_compute):
+            engine = DecodeEngine(model, variables, max_slots=len(prompts),
+                                  block_size=bs, dtype=kind,
+                                  share_prefix=True, **engine_kwargs)
+            assert engine.prefill_chunk is None
+            W = engine.context_width
+            ids = np.zeros((len(prompts), W), np.int32)
+            for slot, prompt in enumerate(prompts):
+                engine.admit(slot, prompt)
+                ids[slot, :len(prompt)] = prompt
+            _, stacked = jax.jit(lambda v, i: model.apply(
+                v, i, method="prefill"))(engine.variables, jnp.asarray(ids))
+        assert engine.cache.prefix_hit_blocks >= 4, "nothing was shared"
+        tables, lengths = engine.cache.device_tables()
+        for name, kv in zip(("k", "v"), stacked):
+            pool = engine.cache.pools[name]
+            want = scat(jnp.zeros_like(pool), kv.astype(pool.dtype),
+                        tables, lengths)
+            for layer in range(pool.shape[0]):
+                got_rows, want_rows = (
+                    np.asarray(gather_pages(p, tables, layer), np.float32)
+                    for p in (pool, want))
+                for slot, n in enumerate(np.asarray(lengths)):
+                    g, w = got_rows[slot, :n], want_rows[slot, :n]
+                    assert np.isfinite(g).all()
+                    err = relative_error(g, w)
+                    assert err <= TOLERANCE[kind], \
+                        f"prefill pages {kind}/{name} layer {layer} slot " \
+                        f"{slot}: {err:.2e} exceeds {TOLERANCE[kind]:.0e}"
+                    errs[kind] = max(errs.get(kind, 0.0), err)
+        assert engine.compile_counts()["prefill"] == 1
+    log("  one-shot prefill's pages vs the scatter, max relative error: "
+        + ", ".join(f"{k} {v:.1e}" for k, v in errs.items())
+        + f" ({time.perf_counter() - t0:.1f}s)")
+
+
 # ---------------------------------------------------------------------------
 # kernels against their oracles
 # ---------------------------------------------------------------------------
@@ -314,11 +383,9 @@ def kernel_leg(sizes: Sizes, seed: int = 0) -> None:
             return jax.jit(fn)(*args)
 
     def check(name, kind, got, want):
-        got = np.asarray(got, np.float32)
-        want = np.asarray(want, np.float32)
-        assert np.isfinite(got).all(), f"{name}: output is not finite"
-        err = float(np.abs(got - want).max()
-                    / max(np.abs(want).max(), 1e-6))
+        assert np.isfinite(np.asarray(got, np.float32)).all(), \
+            f"{name}: output is not finite"
+        err = relative_error(got, want)
         assert err <= TOLERANCE[kind], \
             f"{name}: {err:.2e} exceeds {TOLERANCE[kind]:.0e}"
         errs[name] = err
@@ -497,6 +564,8 @@ def main() -> int:
     trained = train_leg(sizes, mesh=mesh_lib.single_device_mesh(dev))
     log("[serve]")
     serve_legs(sizes, trained["model"], trained["variables"])
+    log("[prefill]")
+    prefill_leg(sizes, trained["model"], trained["variables"])
     log("[kernels]")
     kernel_leg(sizes)
     losses = trained["losses"]

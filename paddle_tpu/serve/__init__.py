@@ -78,8 +78,8 @@ from .kv_cache import (BlockAllocator, PagedKVCache, PrefixCache,
                        PrefixMatch, gather_pages, scatter_prefill,
                        scatter_token, scatter_span,
                        scatter_prefill_pages, write_token, write_span,
-                       quantize_rows, dequantize_rows, pages_to_blobs,
-                       blobs_to_pages)
+                       write_prefill, quantize_rows, dequantize_rows,
+                       pages_to_blobs, blobs_to_pages)
 from .engine import AdmitProbe, DecodeEngine, SamplingConfig
 from .scheduler import ContinuousBatchingScheduler, Request
 from .router import FleetRouter, RouteDecision
@@ -102,7 +102,7 @@ __all__ = ["BlockAllocator", "PagedKVCache", "PrefixCache", "PrefixMatch",
            "ContinuousBatchingScheduler", "Request", "gather_pages",
            "scatter_prefill", "scatter_token", "scatter_span",
            "scatter_prefill_pages", "write_token", "write_span",
-           "quantize_rows", "dequantize_rows",
+           "write_prefill", "quantize_rows", "dequantize_rows",
            "FleetRouter", "RouteDecision", "ServingFleet",
            "ReplicaWorker", "ProcReplicaWorker", "FleetRequest",
            "build_proc_spec",

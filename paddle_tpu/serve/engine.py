@@ -89,7 +89,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .kv_cache import PagedKVCache, scatter_prefill_pages
+from .kv_cache import PagedKVCache, write_prefill
 from ..core.dtypes import canonicalize, current_policy
 from ..nn import pallas_mode
 from ..obs.trace import live, traced, tspan
@@ -540,10 +540,8 @@ class DecodeEngine:
                 # ids [1, W] padded; length/start [1]; table [1, MB]
                 logits, (ks, vs) = model.apply(variables, ids,
                                                method="prefill")
-                scat = jax.vmap(scatter_prefill_pages,
-                                in_axes=(0, 0, None, None, None))
-                pages_k = scat(pools["k"], ks, table, length, start)
-                pages_v = scat(pools["v"], vs, table, length, start)
+                pages_k = write_prefill(pools["k"], ks, table, length, start)
+                pages_v = write_prefill(pools["v"], vs, table, length, start)
                 last = jnp.take_along_axis(
                     logits, (length - 1)[:, None, None], axis=1)[0, 0]
                 return {"k": pages_k, "v": pages_v}, first_token(last, key)
@@ -666,11 +664,13 @@ class DecodeEngine:
                 return fn(*args)
             return checked
 
-        # donate the KV pools: the tick writes its rows into the buffers
-        # it was handed and returns them (its layer scan carries the
-        # pools and nothing in it has a pool-sized result,
-        # tests/test_chip_lowering.py); the prefill's scatter still
-        # copies them
+        # donate the KV pools: both programs write into the buffers they
+        # were handed and return them. The tick's layer scan carries the
+        # pools and writes rows; the one-shot prefill's layer loop carries
+        # each and writes whole pages (kv_cache.write_prefill); nothing in
+        # either has a pool-sized result but those writes
+        # (tests/test_chip_lowering.py). A quantized pool's one-shot
+        # prefill still scatters, and XLA copies the pool round it
         self._prefill_fn = jax.jit(
             _same_policy(_in_scope(_pin_pools(prefill_fn))),
             donate_argnums=(1,))

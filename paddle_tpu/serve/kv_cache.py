@@ -22,10 +22,13 @@ Split of responsibilities (the framework's static-shapes contract):
   and :class:`PagedKVCache` (device pools + the authoritative host mirror
   of block tables and lengths). Admission/eviction mutate ONLY these small
   host arrays between decode ticks — nothing here is traced.
-- **Device side, pure**: :func:`gather_pages`, :func:`scatter_prefill`,
-  :func:`write_token`, :func:`write_span` — ``jnp``-pure gather/scatter
-  and in-place row writes the compiled prefill/decode programs call with
-  fixed shapes. Block tables enter the
+- **Device side, pure**: :func:`gather_pages`, :func:`write_prefill`,
+  :func:`write_token`, :func:`write_span` — a ``jnp``-pure gather and
+  in-place row and page writes the compiled prefill/decode programs call
+  with fixed shapes (:func:`scatter_prefill`, :func:`scatter_token` and
+  :func:`scatter_span` are the XLA scatters they replaced: the tests'
+  oracles, and what a quantized pool's one-shot prefill still runs).
+  Block tables enter the
   compiled step as ordinary int32 operands, so the program never retraces
   as sequences come and go.
 
@@ -82,6 +85,7 @@ import dataclasses
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -89,7 +93,7 @@ __all__ = ["BlockAllocator", "PagedKVCache", "PoolGroup", "PrefixCache",
            "PrefixMatch",
            "gather_pages", "scatter_prefill", "scatter_token",
            "scatter_span", "scatter_prefill_pages", "write_token",
-           "write_span", "quantize_rows", "dequantize_rows",
+           "write_span", "write_prefill", "quantize_rows", "dequantize_rows",
            "pages_to_blobs", "blobs_to_pages", "NULL_BLOCK"]
 
 # block 0 never holds live data: it is the scatter target for padding rows
@@ -217,12 +221,14 @@ def scatter_span(pages, kv, table, start, n, write_from=None):
     return pages.at[blk, :, off].set(kv)
 
 
-# The compiled tick's writes. The pools ``[L, N, H, bs, hd]`` are the
-# layer scan's CARRY, so a write has to leave them where they are: an XLA
-# scatter over the carried pool makes the compiler give the whole carry a
-# scatter-friendly layout and copy both pools to it and back every layer
-# (DESIGN_DECISIONS, PR 26). A row is therefore written as one
-# ``dynamic_update_slice`` of a small slab, which XLA does in place.
+# The compiled programs' writes. The pools ``[L, N, H, bs, hd]`` are the
+# CARRY of the tick's layer scan (and of the one-shot prefill's layer loop,
+# :func:`write_prefill`), so a write has to leave them where they are: an
+# XLA scatter over the carried pool makes the compiler give the whole carry
+# a scatter-friendly layout and copy both pools to it and back every layer
+# (DESIGN_DECISIONS, PR 26; once a prefill for the scatter under ``vmap``,
+# PR 36). A row is therefore written as one ``dynamic_update_slice`` of a
+# small slab, and a page as one of a whole page, which XLA does in place.
 
 def _block_size(pages, lead=1):
     """``bs`` of stacked pools ``[L, N, *lead, bs, ...]`` (``lead`` axes
@@ -390,6 +396,79 @@ def scatter_prefill_pages(pages, kv, table, length, start=0):
                 scatter_prefill(scales, s, table, length, start))
     return scatter_prefill(pages, kv.astype(pages.dtype), table, length,
                            start)
+
+
+# pages a trip of the one-shot prefill's page loop writes (write_prefill)
+_PAGES_A_TRIP = 8
+
+
+def write_prefill(pages, kv, table, length, start):
+    """:func:`scatter_prefill_pages` on every layer of the stacked pools
+    ``[L, N, H, bs, hd]``, IN PLACE: ``kv [L, 1, W, H, hd]`` is a one-shot
+    prefill's projections for positions ``0..W-1`` of ONE sequence,
+    ``table [1, MB]``, ``length`` / ``start`` ``[1]``; rows in
+    ``[start, length)`` are written and no row below ``start`` (a shared
+    prefix's rows are co-owned: the copy-on-write discipline).
+
+    A plain pool is the carry of a loop over the layers, and every write
+    in it is one WHOLE page ``[H, bs, hd]`` put down by a plain
+    ``dynamic_update_slice``. The page that holds ``start`` may be partly
+    someone else's (an exact duplicate's boundary block ends inside it),
+    so it alone is read first and only its live rows replaced; every later
+    page is written unread, rows past ``length`` as zeros (every reader
+    masks them and a tick writes a row before it is read), and a page with
+    no live row is the null block's. XLA leaves such a pool where it is,
+    float32 or bfloat16, where the scatter under ``vmap`` had both pools
+    copied whole to its layout and back; ROW writes in the loop (what
+    :func:`write_span` makes of the first page) would have a bfloat16 pool
+    re-laid round it (DESIGN_DECISIONS, PR 36). A quantized ``(values,
+    scales)`` pool keeps the scatter: no cell runs one."""
+    if isinstance(pages, tuple):
+        return jax.vmap(scatter_prefill_pages,
+                        in_axes=(0, 0, None, None, None))(
+                            pages, kv, table, length, start)
+    L, B, W, H, hd = kv.shape
+    bs, MB = pages.shape[3], table.shape[1]
+    assert B == 1 and W % bs == 0, "one sequence, whole pages"
+    lo, hi = start[0], length[0]
+    slots = jnp.arange(bs, dtype=jnp.int32)
+
+    def block_of(first, live):
+        return jnp.where(live, table[0, jnp.minimum(first // bs, MB - 1)],
+                         NULL_BLOCK)
+
+    first = lo // bs * bs
+    live_first = (first + slots >= lo) & (first + slots < hi)
+    block_first = block_of(first, jnp.any(live_first))
+
+    def layer(i, pool):
+        own = kv[i, 0].astype(pool.dtype)
+
+        def page_at(position):
+            """The page ``[H, bs, hd]`` of rows from ``position`` on (past
+            ``W``: the last page's, where no row is live)."""
+            rows = lax.dynamic_slice(own, (position, 0, 0), (bs, H, hd))
+            return jnp.moveaxis(rows, 0, 1)
+
+        old = lax.dynamic_slice(pool, (i, block_first, 0, 0, 0),
+                                (1, 1, H, bs, hd))
+        pool = lax.dynamic_update_slice(
+            pool, jnp.where(live_first[:, None], page_at(first), old),
+            (i, block_first, 0, 0, 0))
+
+        def later(j, pool):
+            at = first + (1 + j) * bs
+            live = at + slots < hi
+            return lax.dynamic_update_slice(
+                pool, jnp.where(live[:, None], page_at(at), 0)[None, None],
+                (i, block_of(at, live[0]), 0, 0, 0))
+
+        # page 0 is the first at the earliest: W / bs - 1 later ones reach W
+        trips = W // bs - 1
+        return lax.fori_loop(0, trips, later, pool,
+                             unroll=min(_PAGES_A_TRIP, max(trips, 1)))
+
+    return lax.fori_loop(0, L, layer, pages)
 
 
 # ---------------------------------------------------------------------------

@@ -289,6 +289,43 @@ def pool_sized_results(text, sizes):
             if int(np.prod(dims)) in sizes]
 
 
+def fusions_updating_in_place(text):
+    """The result shapes of the compiled module's fusions that ARE an
+    in-place write: the fused computation's root is a
+    ``dynamic-update-slice`` of the computation's first parameter, and
+    the result has that parameter's shape (XLA aliases the two: the
+    update's rows are all that is written). A shape is in the set only if
+    EVERY fusion with that result shape is such a write."""
+    roots, body = {}, None
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            body = line.split()[0].lstrip("%")
+        elif body and "ROOT " in line:
+            m = re.search(r" dynamic-update-slice\(%?(param_0[\w.\-]*)", line)
+            roots[body] = bool(m)
+    verdicts = {}
+    for line in text.splitlines():
+        m = _RESULT.match(line)
+        if not m or " fusion(" not in line:
+            continue
+        called = re.search(r"calls=%?([\w.\-]+)", line).group(1)
+        shape = m.group(3)
+        verdicts[shape] = verdicts.get(shape, True) and roots.get(called,
+                                                                  False)
+    return {shape for shape, ok in verdicts.items() if ok}
+
+
+def toy_engine(blocks, **kw):
+    """A toy ``TransformerLM`` engine at the lane tile's widths: two
+    layers of 4 heads of 128, float32 weights, the one-shot prefill."""
+    model = TransformerLM(vocab=512, dim=512, num_layers=2, num_heads=4,
+                          ffn_hidden=1024, max_len=256)
+    variables = model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))
+    return DecodeEngine(model, variables, max_slots=4, block_size=BS,
+                        num_blocks=blocks, attention="paged", **kw)
+
+
 def toy_latent_engine(blocks, shortcut=False, **kw):
     """A toy of the latent-attention expert model at the lane tile's
     widths: two layers (one dense, one of 8 experts with 4 held), a
@@ -338,17 +375,12 @@ def test_tick_leaves_the_pools_in_place(one_chip, monkeypatch, kv_dtype,
     layers and read by ``latent_paged_decode``, the shortcut case one
     double layer's two cache layers."""
     monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
-    layers, heads, dh = 2, 4, 128
+    layers = 2
     if kv_dtype in ("latent", "shortcut"):
         engine = toy_latent_engine(blocks, shortcut=kv_dtype == "shortcut")
     else:
-        model = TransformerLM(vocab=512, dim=heads * dh, num_layers=layers,
-                              num_heads=heads, ffn_hidden=1024, max_len=256)
-        variables = model.init(jax.random.PRNGKey(0),
-                               jnp.zeros((1, 8), jnp.int32))
-        engine = DecodeEngine(model, variables, max_slots=4, block_size=BS,
-                              num_blocks=blocks, attention="paged",
-                              kv_dtype=kv_dtype, speculative=speculative)
+        engine = toy_engine(blocks, kv_dtype=kv_dtype,
+                            speculative=speculative)
     args = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         engine._tick_args())
@@ -372,6 +404,57 @@ def test_tick_leaves_the_pools_in_place(one_chip, monkeypatch, kv_dtype,
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < pool_bytes, \
         f"temporaries {temp} B, one pool {pool_bytes} B"
+
+
+@pytest.mark.parametrize("kind,blocks", [("float32", 2449),
+                                         ("bfloat16", 4897),
+                                         ("int8", 9793)])
+def test_one_shot_prefill_leaves_the_pools_in_place(one_chip, monkeypatch,
+                                                    kind, blocks):
+    """The one-shot prefill (``prefill_chunk=None``, prefix sharing on)
+    of a toy engine, compiled for the TPU. A plain pool, float32 or
+    bfloat16: nothing but the carry's plumbing and the in-place
+    ``dynamic-update-slice`` row and page writes has a result the size of
+    a pool or of one layer of one, at least one such write exists, both
+    pools are aliased to their results and the temporaries are smaller
+    than one pool: the scatter under ``vmap`` copied both pools whole to
+    its layout and back (3.43 GB of temporaries at
+    ``serve-1p3b-closed8``'s shapes). An int8 pool KEEPS that scatter (no
+    cell runs one; ``DESIGN_DECISIONS.md`` PR-36): the write adapts to the
+    pool's type, and its program still compiles and still scatters."""
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+    layers, quantized = 2, kind == "int8"
+    engine = toy_engine(blocks, share_prefix=True, **(
+        {"kv_dtype": "int8"} if quantized else {"dtype": kind}))
+    assert engine.prefill_chunk is None
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        engine._prefill_args())
+    compiled = engine._prefill_fn.lower(*args).compile()
+    text = compiled.as_text()
+
+    leaves = jax.tree_util.tree_leaves(engine.cache.pools["k"])
+    assert leaves[0].dtype == jnp.dtype(kind)
+    values = leaves[0].size
+    held = pool_sized_results(text, {values, values // layers})
+    assert held, "the pools are not in the compiled text"
+    if quantized:
+        assert " scatter(" in text, "an int8 pool no longer scatters"
+        return
+    assert " scatter(" not in text
+    # a page write fuses with its page's select into ONE in-place update:
+    # a fusion whose root is the dynamic-update-slice of its first operand
+    in_place = fusions_updating_in_place(text)
+    moved = [h for h in held if h[0] not in _MAY_HOLD_A_POOL
+             and not (h[0] == "fusion" and h[1] in in_place)]
+    assert not moved, f"pool-sized results besides the writes: {moved}"
+    writes = sum(op in ("fusion", "dynamic-update-slice") for op, _ in held)
+    assert writes >= 2 * 2, "no in-place page write in the compiled prefill"
+    pool_bytes = sum(leaf.nbytes for leaf in leaves)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 2 * pool_bytes
+    assert memory.temp_size_in_bytes < pool_bytes, \
+        f"temporaries {memory.temp_size_in_bytes} B, one pool {pool_bytes} B"
 
 
 def toy_window_engine(blocks):

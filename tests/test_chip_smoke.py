@@ -2,7 +2,8 @@
 
 The chip script's legs are functions of its ``Sizes``; this runs the same
 control flow (train through the Trainer, serve through the scheduler with
-admission and eviction, the three tick variants, every kernel against
+admission and eviction, the three tick variants, the one-shot prefill's
+pages against the scatter, every kernel against
 its oracle, then dp=4 and tp=4 on four of the forced CPU devices) without
 a chip, so that a refactor cannot break the script unseen. What only the
 chip can show (Mosaic compiles, the device itself) the legs skip off-TPU,
@@ -31,6 +32,8 @@ def test_legs_at_toy_size_on_cpu():
         TOY, mesh=mesh_lib.single_device_mesh(devices[0]))
     chip_smoke.serve_legs(TOY, trained["model"], trained["variables"],
                           attention="paged")
+    chip_smoke.prefill_leg(TOY, trained["model"], trained["variables"],
+                           attention="paged")
     chip_smoke.kernel_leg(TOY)
     chip_smoke.four_device_leg(TOY, trained["losses"], devices[:4],
                                attention="paged")

@@ -189,6 +189,61 @@ def test_write_span_equals_scatter_span_on_every_layer(nprng, kind):
             _assert_only_layer_written(got, pool, want, layer)
 
 
+@pytest.mark.parametrize("kind", ["float32", "bfloat16"])
+@pytest.mark.parametrize("start", [0, BS, BS + 2],
+                         ids=["from_0", "from_a_block_edge",
+                              "from_inside_a_block"])
+@pytest.mark.parametrize("length",
+                         [1, BS - 1, BS, BS + 1, 2 * BS + 3, W])
+def test_write_prefill_equals_scatter_prefill(nprng, kind, start, length):
+    """The one-shot prefill's in-place write against its oracle, the
+    scatter a layer: every position in ``[start, length)`` reads back
+    through ``gather_pages`` what ``scatter_prefill`` put there; rows
+    below ``start`` (a shared prefix, which may end INSIDE a block: an
+    exact duplicate's partial boundary), the table's blocks wholly past
+    ``length`` and every block the table does not name are byte for byte
+    what they were. ``start >= length`` writes nothing a reader sees."""
+    L, N, H, hd = 2, 16, 2, 8
+    pool = _stacked_pool(nprng, kind, L, N, H, hd).astype(kind)
+    named = [3, 1, 5, 7, 2, 6]
+    table = jnp.asarray([named], jnp.int32)
+    kv = jnp.asarray(nprng.randn(L, 1, W, H, hd).astype(np.float32))
+    args = (table, jnp.asarray([length], jnp.int32),
+            jnp.asarray([start], jnp.int32))
+    got = jax.jit(kvc.write_prefill)(pool, kv, *args)
+    assert got.dtype == pool.dtype and got.shape == pool.shape
+    before, after = np.asarray(pool), np.asarray(got)
+    for layer in range(L):
+        want = kvc.scatter_prefill(pool[layer], kv[layer].astype(pool.dtype),
+                                   *args)
+        rows, oracle, old = (np.asarray(kvc.gather_pages(p, table))[0]
+                             for p in (got[layer], want, pool[layer]))
+        np.testing.assert_array_equal(rows[start:length],
+                                      oracle[start:length])
+        np.testing.assert_array_equal(rows[:start], old[:start])
+    reached = -(-length // BS)
+    untouched = [b for b in range(1, N) if b not in named[:reached]]
+    np.testing.assert_array_equal(after[:, untouched], before[:, untouched])
+
+
+def test_write_prefill_scatters_into_a_quantized_pool(nprng):
+    """A ``(values, scales)`` pool takes the scatter it took: the write
+    adapts to the pool's type, with no option."""
+    L, N, H, hd = 2, 16, 2, 8
+    pool = _stacked_pool(nprng, "int8", L, N, H, hd)
+    table = jnp.asarray([[3, 1, 5, 7, 2, 6]], jnp.int32)
+    kv = jnp.asarray(nprng.randn(L, 1, W, H, hd).astype(np.float32))
+    args = (table, jnp.asarray([2 * BS + 3], jnp.int32),
+            jnp.asarray([BS], jnp.int32))
+    got = jax.jit(kvc.write_prefill)(pool, kv, *args)
+    for layer in range(L):
+        want = kvc.scatter_prefill_pages(layer_of(pool, layer), kv[layer],
+                                         *args)
+        for g, w in zip(layer_of(got, layer), want):
+            np.testing.assert_array_equal(np.asarray(g)[1:],
+                                          np.asarray(w)[1:])
+
+
 # ---------------------------------------------------------------------------
 # the decode-shaped Pallas kernel vs its oracle
 # ---------------------------------------------------------------------------
@@ -724,6 +779,53 @@ def test_cow_fork_on_duplicate_prompts(model_and_vars, nprng):
     pool = (list(eng.cache.allocator._free)
             + list(eng.cache.allocator._retained))
     assert len(pool) == len(set(pool)) == eng.cache.num_blocks - 1
+
+
+def test_one_shot_prefill_serves_what_the_scatter_served(
+        model_and_vars, nprng, monkeypatch):
+    """A one-shot engine with sharing on, whose prefill writes its pages
+    in place, against the same engine with the scatter under ``vmap`` put
+    back in the write's place: an exact duplicate prompt (``start ==
+    length``, the partial boundary block shared and forked), a prompt
+    that extends a shared prefix (``start`` on a block edge) and one that
+    shares nothing are served the same tokens, and the fresh blocks a
+    prefill wrote gather to the same rows up to each slot's length."""
+    from paddle_tpu.serve import engine as engine_mod
+    model, vs = model_and_vars
+    pre = list(nprng.randint(0, V, 2 * BS))
+    first = pre + list(nprng.randint(0, V, 2))       # partial boundary
+    prompts = [first, list(first), pre + list(nprng.randint(0, V, 3)),
+               list(nprng.randint(0, V, BS + 1))]
+
+    def serve():
+        eng = DecodeEngine(model, vs, max_slots=4, block_size=BS,
+                           share_prefix=True)
+        assert eng.prefill_chunk is None
+        sched = ContinuousBatchingScheduler(eng)
+        reqs = [sched.submit(list(p), 5) for p in prompts]
+        sched.step()                     # every prompt prefilled, one tick
+        tables, lengths = eng.cache.device_tables()
+        rows = [np.asarray(kvc.gather_pages(eng.cache.pools[name], tables,
+                                            layer))
+                for name in ("k", "v") for layer in range(LAYERS)]
+        lengths = np.asarray(lengths)
+        sched.run()
+        return eng, [r.tokens for r in reqs], rows, lengths
+
+    eng, tokens, rows, lengths = serve()
+    scatter = jax.vmap(kvc.scatter_prefill_pages,
+                       in_axes=(0, 0, None, None, None))
+    monkeypatch.setattr(engine_mod, "write_prefill", scatter)
+    oracle, want_tokens, want_rows, want_lengths = serve()
+    assert tokens == want_tokens and tokens[0] == tokens[1]
+    np.testing.assert_array_equal(lengths, want_lengths)
+    assert lengths.min() > 0
+    for got, want in zip(rows, want_rows):
+        for slot, n in enumerate(lengths):
+            np.testing.assert_array_equal(got[slot, :n], want[slot, :n])
+    for e in (eng, oracle):
+        assert e.cache.prefix_hit_blocks >= 4 and e.cache.cow_forks >= 1
+        assert e.compile_counts() == {"prefill": 1, "tick": 1}
 
 
 def test_sharing_eviction_churn_bit_identity(model_and_vars, nprng):
