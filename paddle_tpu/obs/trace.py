@@ -54,11 +54,6 @@ Usage::
     trainer = Trainer(..., telemetry=tel, tracer=tracer)
     trainer.train(...)
     tracer.save("trace.json")      # open in ui.perfetto.dev
-
-Programmatic device-profiler windows ride the same API:
-``tracer.profile_window(log_dir)`` wraps a code region in
-``jax.profiler.trace`` (TensorBoard/XProf capture) *and* a host span, so
-the device capture is findable from the host timeline.
 """
 
 from __future__ import annotations
@@ -69,15 +64,17 @@ import functools
 import itertools
 import json
 import logging
+import math
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from jax.profiler import TraceAnnotation
 
 __all__ = ["Tracer", "tspan", "live", "session_tracer", "self_times",
-           "traced", "jax_profile", "ANNOTATION_PREFIX", "NULL_SPAN"]
+           "starved_by_span", "starved_in_window", "traced", "jax_profile",
+           "ANNOTATION_PREFIX", "NULL_SPAN", "RETROACTIVE"]
 
 _log = logging.getLogger("paddle_tpu.trace")
 
@@ -89,6 +86,8 @@ NULL_SPAN = contextlib.nullcontext()
 
 # what a span is called in the profiler's trace: ``paddle_tpu:<name>``
 ANNOTATION_PREFIX = "paddle_tpu:"
+# the category of a span stamped after the fact (``Tracer.complete``)
+RETROACTIVE = "paddle_tpu.retroactive"
 
 # True while a ``jax.profiler`` session is active in this process
 _session_active = TraceAnnotation.is_enabled
@@ -184,12 +183,14 @@ class _Span:
     returns. ``t0_ns`` / ``t1_ns`` are its ``perf_counter_ns`` stamps,
     readable after entry / exit, so a caller that needs the duration
     for its own books (the trainer's ``StatSet`` and telemetry) reads
-    this one clock pair instead of taking another. :meth:`set` adds
-    facts known only once the body ran (tokens retired, admissions
+    this one clock pair instead of taking another; ``t0_us`` / ``t1_us``
+    are the same instants on the tracer's time base (what a retroactive
+    span that starts or ends with this one is stamped with). :meth:`set`
+    adds facts known only once the body ran (tokens retired, admissions
     made)."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_flows", "_tid", "_ts",
-                 "_ann", "t0_ns", "t1_ns")
+    __slots__ = ("_tracer", "_name", "_args", "_flows", "_tid", "_ann",
+                 "t0_ns", "t1_ns", "t0_us", "t1_us")
 
     def __init__(self, tracer, name, flows, args):
         self._tracer = tracer
@@ -212,8 +213,8 @@ class _Span:
                                         **self._args)
             self._ann.__enter__()
         self.t0_ns = t0 = time.perf_counter_ns()
-        self._ts = (tr._now_us() if tr._clock is not None
-                    else (t0 - tr.epoch_ns) / 1e3)
+        self.t0_us = (tr._now_us() if tr._clock is not None
+                      else (t0 - tr.epoch_ns) / 1e3)
         return self
 
     def __exit__(self, *exc):
@@ -221,9 +222,9 @@ class _Span:
         self.t1_ns = t1 = time.perf_counter_ns()
         if self._ann is not None:
             self._ann.__exit__(*exc)
-        end = (tr._now_us() if tr._clock is not None
-               else (t1 - tr.epoch_ns) / 1e3)
-        tr._emit_span(self._name, self._tid, self._ts, end, *self._flows,
+        self.t1_us = end = (tr._now_us() if tr._clock is not None
+                            else (t1 - tr.epoch_ns) / 1e3)
+        tr._emit_span(self._name, self._tid, self.t0_us, end, *self._flows,
                       self._args)
         return False
 
@@ -347,17 +348,19 @@ class Tracer:
         timestamps (the tracer's time base, see :meth:`at_us`). This is
         how retroactive spans are stamped: a scheduler records a
         request's queue wait only at admit time, from the request's own
-        submit timestamp (ISSUE 17)."""
+        submit timestamp (ISSUE 17). Their category is
+        :data:`RETROACTIVE`: they cover no code of their own."""
         tid = threading.get_ident()
         self._note_thread(tid)
         self._emit_span(name, tid, float(t0_us),
                         float(t0_us if t1_us is None else t1_us),
-                        flow_start, flow_step, flow_end, args)
+                        flow_start, flow_step, flow_end, args,
+                        cat=RETROACTIVE)
 
     def _emit_span(self, name, tid, t0, t1, flow_start, flow_step,
-                   flow_end, args) -> None:
+                   flow_end, args, cat="paddle_tpu") -> None:
         ev: Dict[str, Any] = {
-            "ph": "X", "name": name, "cat": "paddle_tpu",
+            "ph": "X", "name": name, "cat": cat,
             "pid": self.pid, "tid": tid,
             "ts": t0, "dur": max(t1 - t0, 0.001)}
         if args:
@@ -384,16 +387,6 @@ class Tracer:
         if args:
             ev["args"] = {k: _json_safe(v) for k, v in args.items()}
         self._append(ev)
-
-    @contextlib.contextmanager
-    def profile_window(self, log_dir: str, name: str = "jax_profile"):
-        """A ``jax.profiler.trace`` capture window recorded as a host span
-        too, so the device capture is findable from the host timeline.
-        Lazy like any context manager: nothing starts until ``with``
-        entry (an unused return value must not leave the device profiler
-        running)."""
-        with self.span(name, log_dir=log_dir), jax_profile(log_dir):
-            yield
 
     # -- output --------------------------------------------------------------
 
@@ -493,3 +486,69 @@ def self_times(events: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
         out.append(row)
     out.sort(key=lambda e: e["ts"])
     return out
+
+
+def starved_by_span(events: List[Dict[str, Any]],
+                    window: Optional[Tuple[float, float]] = None
+                    ) -> Dict[str, float]:
+    """Where the host was while the chip had nothing queued:
+    ``{span name: seconds}`` over every ``starved`` stretch of ``events``
+    (``serve/engine.py``: from a drain's fetch to the next compiled
+    call's return), clipped to ``window`` (``(lo, hi)`` in the events'
+    microseconds) where one is given. Each piece of a stretch goes to the
+    INNERMOST span around code (not :data:`RETROACTIVE`) that covers it
+    on the stretch's own ``(pid, tid)``; ``""`` is a piece no span covers:
+    the caller's code between two steps. The values add up to the
+    stretches' seconds."""
+    lo, hi = window if window is not None else (-math.inf, math.inf)
+    lanes: Dict[Tuple[int, int], List[Dict[str, Any]]] = {}
+    for e in events:
+        if (e.get("ph") == "X" and e["ts"] < hi
+                and e["ts"] + e["dur"] > lo):
+            lanes.setdefault((e["pid"], e["tid"]), []).append(e)
+    out: Dict[str, float] = {}
+    for lane in lanes.values():
+        stretches = sorted((max(e["ts"], lo), min(e["ts"] + e["dur"], hi))
+                           for e in lane if e["name"] == "starved")
+        if not stretches:
+            continue
+        code = [e for e in lane if e.get("cat") != RETROACTIVE]
+        starts = sorted(code, key=lambda e: e["ts"])
+        ends = sorted(code, key=lambda e: e["ts"] + e["dur"])
+        cuts = sorted({t for e in code for t in (e["ts"], e["ts"] + e["dur"])}
+                      | {t for s in stretches for t in s})
+        active: Dict[int, Dict[str, Any]] = {}
+        i = j = k = 0
+        # the open spans between two neighbouring cuts never change, and
+        # the innermost of them is the one that began last
+        for x, y in zip(cuts, cuts[1:]):
+            while i < len(starts) and starts[i]["ts"] <= x:
+                active[id(starts[i])] = starts[i]
+                i += 1
+            while j < len(ends) and ends[j]["ts"] + ends[j]["dur"] <= x:
+                active.pop(id(ends[j]), None)
+                j += 1
+            while k < len(stretches) and stretches[k][1] <= x:
+                k += 1
+            if k == len(stretches):
+                break
+            if stretches[k][0] > x:
+                continue                    # between two stretches
+            inner = max(active.values(), key=lambda e: (e["ts"], -e["dur"]),
+                        default=None)
+            name = inner["name"] if inner is not None else ""
+            out[name] = out.get(name, 0.0) + (y - x) / 1e6
+    return out
+
+
+def starved_in_window(tracer: "Tracer", lo_s: float, hi_s: float
+                      ) -> Tuple[Dict[str, float], int]:
+    """:func:`starved_by_span` over ``tracer``'s events clipped to two
+    readings of its clock, and the number of ``engine_tick`` spans that
+    lie between them: what the benchmark's ``starved`` readers
+    (``benchmarks/layer_metrics/``) sum and divide by."""
+    by = starved_by_span(tracer.events(), (tracer.at_us(lo_s),
+                                           tracer.at_us(hi_s)))
+    ticks = sum(e["name"] == "engine_tick"
+                for e in tracer.between(lo_s, hi_s))
+    return by, ticks

@@ -237,6 +237,32 @@ class DecodeEngine:
     refuses besides, at build: prefix sharing with its copy-on-write,
     speculation, and a prefill chunk longer than the window.
 
+    **Starved time** (``starved_s``, cumulative seconds beside ``ticks``
+    and ``tokens_generated``): each compiled call takes the pools the call
+    before it wrote, so from the moment a drain's wait returns (a tick's,
+    or a prompt's last prefill call's) none of the engine's programs is in
+    flight until the next compiled call (``_tick_fn``, ``_prefill_fn``, or
+    ``_cow_fn`` in the copy-on-write guard) returns; ``adopt_slot``, whose
+    page import writes the pools, ends a stretch before it and opens none.
+    Every such stretch adds to ``starved_s``: always on, two clock reads a
+    program. An operator reads it as a share of wall time,
+    ``d(starved_s) / d(wall)``: the host-bound share of the chip, with no
+    profiler attached. It cannot tell a slow host from a scheduler with no
+    work: an open-loop lull with nothing queued is starved time too. It
+    counts as starved what is small and is not the engine's programs: the
+    eager copies and key that ``prefill_dispatch`` makes before its
+    compiled call, and work another user of the process puts on the chip.
+    A stretch ends when the compiled call RETURNS, and the chip may start
+    on the program a little before that. Where spans are live
+    (``obs/trace.py:live``) each stretch is also a retroactive ``starved``
+    span with facts ``after`` (``tick`` or ``prefill``) and ``by``
+    (``tick``, ``prefill``, ``cow`` or ``import``), from the start of the
+    drain's ``tick_fetch`` / ``prefill_fetch`` to the end of the next
+    ``tick_dispatch`` / ``prefill_dispatch`` (a ``cow`` one ends inside
+    ``tick_stage``), so a device trace finds every stretch from those
+    annotations; ``obs/trace.py:starved_by_span`` names the host work
+    inside them.
+
     Args:
       model: a TransformerLM (any training config: a training
         checkpoint serves as it is) or a LatentMoELM.
@@ -491,6 +517,10 @@ class DecodeEngine:
         self._prefilling: Dict[int, Dict[str, Any]] = {}
         self.ticks = 0
         self.tokens_generated = 0
+        # seconds the chip had nothing queued (class docstring); the open
+        # stretch: what drained last and when its wait returned (ns)
+        self.starved_s = 0.0
+        self._starved_since: Optional[tuple] = None
         self.prefill_chunks = 0          # cumulative chunk calls
         self.draft_proposed = 0          # cumulative drafted tokens
         self.draft_accepted = 0          # cumulative accepted drafts
@@ -1018,28 +1048,59 @@ class DecodeEngine:
             self.prefill_chunks += 1
             if sp is not None:
                 sp.set(done=done)
+        self._fed("prefill", sp, tr)
         if not done:
             return None
-        # the drain: int(tok) waits for the device, then the slot goes
-        # live and its prefix is registered
+        # the drain: the wait for the device, the fetch of the token, then
+        # the slot goes live and its prefix is registered
         with tspan(tr, "prefill_drain", slot=slot) as sp:
             del self._prefilling[slot]
             self.cache.lengths[slot] = P
             self.active[slot] = True
-            tok = int(tok)
-            # every chunk's counters, fetched with the token: a chunk's
-            # dispatch does not wait for the device, so the prompt's
-            # counts are known here
-            facts = self._count_experts(*st.get("counters", ()))
+            counters = st.get("counters", ())
+            jax.block_until_ready((tok, counters))
+            with tspan(tr, "prefill_fetch") as fetch:
+                self._starve("prefill", fetch)
+                tok = int(tok)
+                # every chunk's counters, fetched with the token: a
+                # chunk's dispatch does not wait for the device, so the
+                # prompt's counts are known here
+                facts = self._count_experts(*counters)
             if sp is not None and facts:
                 sp.set(**facts)
-            self.tokens[slot] = tok
-            self.history[slot] = []
-            self._bigram_idx[slot] = {}
-            self._unigram_idx[slot] = {}
-            self._history_append(slot, list(prompt) + [tok])
-            self.cache.register_prefix(slot, prompt)
+            with tspan(tr, "prefill_retire"):
+                self.tokens[slot] = tok
+                self.history[slot] = []
+                self._bigram_idx[slot] = {}
+                self._unigram_idx[slot] = {}
+                self._history_append(slot, list(prompt) + [tok])
+                self.cache.register_prefix(slot, prompt)
         return tok
+
+    def _starve(self, after: str, span) -> None:
+        """A drain's wait has returned: nothing is in flight from here
+        until the next compiled call returns. The stretch starts where
+        ``span`` (the drain's fetch, or None) starts."""
+        self._starved_since = (
+            after, time.perf_counter_ns() if span is None else span.t0_ns,
+            None if span is None else span.t0_us)
+
+    def _fed(self, by: str, span, tr) -> None:
+        """A compiled call has returned (``span``, its dispatch, has
+        ended, or is None): the chip has work again. Closes the open
+        stretch, if any, into ``starved_s`` (host seconds) and, where
+        ``tr`` is live, a retroactive ``starved`` span on ``tr``'s time
+        base."""
+        if self._starved_since is None:
+            return
+        (after, t0, t0_us), self._starved_since = self._starved_since, None
+        t1 = time.perf_counter_ns() if span is None else span.t1_ns
+        self.starved_s += (t1 - t0) / 1e9
+        if tr is not None:
+            tr.complete("starved",
+                        tr.at_us(t0 / 1e9) if t0_us is None else t0_us,
+                        tr.now_us() if span is None else span.t1_us,
+                        after=after, by=by)
 
     def _count_experts(self, *counters) -> Dict[str, int]:
         """Fetch the counters of one or more calls in one go, add the
@@ -1115,6 +1176,9 @@ class DecodeEngine:
         P = len(prompt)
         if not 0 < P <= self._W:
             raise ValueError(f"prompt length {P} not in [1, {self._W}]")
+        # the import writes the device's pools: like a chunk's dispatch
+        # it ends a starved stretch and opens none
+        self._fed("import", None, live(self.tracer))
         if not self.cache.import_pages(slot, kpages, vpages, P,
                                        reserve_len=reserve_len):
             return False
@@ -1205,6 +1269,7 @@ class DecodeEngine:
                 for name in self.pool_names:
                     self.cache.pools[name] = self._cow_fn(
                         self.cache.pools[name], src_i, dst_i)
+                    self._fed("cow", None, live(self.tracer))
                 self.slot_stats[slot]["cow_forks"] = \
                     self.slot_stats[slot].get("cow_forks", 0) + 1
         return n
@@ -1245,9 +1310,10 @@ class DecodeEngine:
                     if stochastic:
                         operands += (self._tick_keys(self.ticks),)
             # the enqueue: returns once XLA has the program
-            with tspan(tr, "tick_dispatch"):
+            with tspan(tr, "tick_dispatch") as sp:
                 out = self._tick_fn(self.variables, self.cache.pools,
                                     tables, lengths, *operands)
+            self._fed("tick", sp, tr)
             self.cache.pools = out[0]
             # the dispatch is async: host bookkeeping that doesn't need
             # the sampled tokens runs UNDER the in-flight device call (the
@@ -1266,14 +1332,18 @@ class DecodeEngine:
                     for g in self.cache.groups.values() if g.window})
             if self.speculative == 0:
                 self.cache.lengths[self.active] += 1
-            # the drain: the host waits for the device here
+            # the drain: the host waits for the device here, then fetches
             with tspan(tr, "tick_drain"):
-                if stochastic:
-                    acc_d, res_d, bon_d = (np.asarray(o) for o in out[1:4])
-                else:
-                    nxt = np.asarray(out[1])         # [S, 1] or [S, 1+k]
-                expert_facts = self._count_experts(
-                    out[-1] if self.counter_names else {})
+                jax.block_until_ready(out[1:])
+                with tspan(tr, "tick_fetch") as sp:
+                    self._starve("tick", sp)
+                    if stochastic:
+                        acc_d, res_d, bon_d = (np.asarray(o)
+                                               for o in out[1:4])
+                    else:
+                        nxt = np.asarray(out[1])     # [S, 1] or [S, 1+k]
+                    expert_facts = self._count_experts(
+                        out[-1] if self.counter_names else {})
             with tspan(tr, "tick_retire"):
                 self.last_accepted = {}
                 front = np.zeros((self.max_slots,), np.int32)

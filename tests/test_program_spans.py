@@ -2,9 +2,10 @@
 OR a ``jax.profiler`` session is active, and then on the profiler's clock
 as ``paddle_tpu:<name>`` annotations. Every span of the trainer's plain
 loop, the scheduler and the engine, their nesting and facts; the compile
-log; ``self_times``; one clock pair per region of the trainer; and the six
-per-layer readers of ``benchmarks/layer_metrics/`` that read them, on the
-benchmark's toy cells."""
+log; ``self_times``; one clock pair per region of the trainer; the
+engine's ``starved`` stretches (PR 37); and the per-layer readers of
+``benchmarks/layer_metrics/`` that read them, on the benchmark's toy
+cells."""
 
 import glob
 import importlib.util
@@ -36,7 +37,8 @@ SCHEDULER_SPANS = {"sched_step", "expire", "admit", "queue_wait",
 ENGINE_SPANS = {"engine_init", "engine_prepare", "engine_warmup",
                 "begin_prefill",
                 "prefill_dispatch", "prefill_drain", "engine_tick",
-                "tick_stage", "tick_dispatch", "tick_drain", "tick_retire"}
+                "tick_stage", "tick_dispatch", "tick_drain", "tick_retire",
+                "tick_fetch", "prefill_fetch", "prefill_retire"}
 # child -> the span it must lie inside, on the same thread
 PARENT = {"device_put": "train_step", "dispatch": "train_step",
           "loss_fetch": "train_step", "events": "train_step",
@@ -46,7 +48,9 @@ PARENT = {"device_put": "train_step", "dispatch": "train_step",
           "prefill_drain": "prefill_chunk", "engine_tick": "decode_tick",
           "tick_stage": "engine_tick", "tick_dispatch": "engine_tick",
           "tick_drain": "engine_tick", "tick_retire": "engine_tick",
-          "engine_prepare": "engine_init"}
+          "engine_prepare": "engine_init", "tick_fetch": "tick_drain",
+          "prefill_fetch": "prefill_drain",
+          "prefill_retire": "prefill_drain"}
 
 
 def make_batches(n, bs=16, dim=12, seed=0):
@@ -252,6 +256,133 @@ def test_attached_tracer_records_without_a_session(lm):
     assert len(session.events()) == before
 
 
+# -- starved stretches: from a drain's fetch to the next compiled call ------------
+
+@pytest.fixture(scope="module", params=[None, 2], ids=["one_shot", "chunk2"])
+def starved_run(lm, request):
+    """The toy scheduler run with one tracer on the scheduler AND the
+    engine: the one-shot prefill, and chunks of two tokens (prompts of 3
+    to 5 take two or three calls)."""
+    model, variables = lm
+    engine = DecodeEngine(model, variables, max_slots=2, block_size=4,
+                          prefill_chunk=request.param)
+    engine.warmup()
+    assert engine._starved_since is None      # warmup leaves none open
+    own = Tracer()
+    engine.tracer = own
+    sched = ContinuousBatchingScheduler(engine, tracer=own)
+    rng = np.random.RandomState(1)
+    for i in range(3):
+        sched.submit(list(rng.randint(1, V, size=3 + i)), 3 + i)
+    before = engine.starved_s
+    sched.run()
+    spans = spans_of(own.events())
+    return {"spans": spans, "grown": engine.starved_s - before,
+            "stretches": [e for e in spans if e["name"] == "starved"],
+            "chunked": request.param is not None}
+
+
+def _end(e):
+    return e["ts"] + e["dur"]
+
+
+def test_a_stretch_runs_from_a_fetch_start_to_a_dispatch_end(starved_run):
+    spans, stretches = starved_run["spans"], starved_run["stretches"]
+    fetches = [e for e in spans if e["name"] in ("tick_fetch",
+                                                 "prefill_fetch")]
+    dispatches = [e for e in spans if e["name"] in ("tick_dispatch",
+                                                    "prefill_dispatch")]
+    # every fetch opens one; the run's last stays open (no call after it)
+    assert stretches and len(stretches) == len(fetches) - 1
+    for st in stretches:
+        start = next(f for f in fetches
+                     if f["ts"] == pytest.approx(st["ts"], abs=0.01))
+        end = next(d for d in dispatches
+                   if _end(d) == pytest.approx(_end(st), abs=0.01))
+        assert st["args"]["after"] == start["name"].split("_")[0]
+        assert st["args"]["by"] == end["name"].split("_")[0]
+        # the first dispatch after its fetch closes it
+        assert end is min((d for d in dispatches if d["ts"] > st["ts"]),
+                          key=lambda d: d["ts"])
+
+
+def test_no_stretch_overlaps_a_wait_or_another(starved_run):
+    spans, stretches = starved_run["spans"], starved_run["stretches"]
+    waits = [(d["ts"], f["ts"]) for d in spans
+             if d["name"] in ("tick_drain", "prefill_drain")
+             for f in spans if f["name"] in ("tick_fetch", "prefill_fetch")
+             and inside(f, d)]
+    assert len(waits) == len(stretches) + 1
+    for st in stretches:
+        assert all(_end(st) <= lo or st["ts"] >= hi - 0.01
+                   for lo, hi in waits)
+    ordered = sorted(stretches, key=lambda e: e["ts"])
+    assert all(_end(a) <= b["ts"] for a, b in zip(ordered, ordered[1:]))
+
+
+def test_a_chunk_dispatch_ends_a_stretch_and_opens_none(starved_run):
+    spans, stretches = starved_run["spans"], starved_run["stretches"]
+    chunks = [e for e in spans if e["name"] == "prefill_dispatch"
+              and e["args"]["done"] is False]
+    assert bool(chunks) == starved_run["chunked"]
+    fetches = [e["ts"] for e in spans if e["name"].endswith("_fetch")]
+    assert not chunks or any(
+        _end(st) == pytest.approx(_end(c), abs=0.01)
+        for c in chunks for st in stretches)
+    for c in chunks:
+        # the next stretch starts at the next fetch, not at the chunk
+        later = [st["ts"] for st in stretches if st["ts"] > c["ts"]]
+        if later:
+            assert min(later) == pytest.approx(
+                min(f for f in fetches if f > _end(c)), abs=0.01)
+
+
+def test_stretches_add_up_to_the_counter(starved_run):
+    total = sum(e["dur"] for e in starved_run["stretches"]) / 1e6
+    assert starved_run["grown"] > 0
+    assert total == pytest.approx(starved_run["grown"], rel=0.01)
+
+
+def test_the_counter_grows_with_no_tracer_and_no_session(lm):
+    session = session_tracer()
+    before = len(session.events())
+    sched, _ = toy_serve(lm)
+    assert sched.engine.tracer is None and live(None) is None
+    assert sched.engine.starved_s > 0
+    assert len(session.events()) == before
+
+
+def test_a_page_import_ends_a_stretch_and_opens_none(lm):
+    model, variables = lm
+    engine = DecodeEngine(model, variables, max_slots=2, block_size=4)
+    engine.warmup()
+    own = Tracer()
+    engine.tracer = own
+    prompt = [3, 1, 4, 1, 5]
+    engine.begin_prefill(0, prompt)
+    tok = engine.prefill_step(0)            # drained: a stretch is open
+    assert engine._starved_since is not None
+    _, kpages, vpages = engine.cache.export_pages(0)
+    assert engine.adopt_slot(1, prompt, tok, kpages, vpages)
+    assert engine._starved_since is None
+    last = [e for e in spans_of(own.events()) if e["name"] == "starved"][-1]
+    assert last["args"] == {"after": "prefill", "by": "import"}
+    assert engine.starved_s == pytest.approx(last["dur"] / 1e6, rel=0.01)
+
+
+def test_starved_time_by_innermost_span_on_the_toy_run(starved_run):
+    by = trace_lib.starved_by_span(starved_run["spans"])
+    assert sum(by.values()) == pytest.approx(starved_run["grown"], rel=0.01)
+    leaves = {"tick_stage", "tick_dispatch", "tick_fetch", "tick_retire",
+              "begin_prefill", "prefill_dispatch", "prefill_fetch",
+              "prefill_retire", "expire", "decode_tick"}
+    assert {"tick_stage", "tick_dispatch", "tick_fetch", "tick_retire",
+            "prefill_fetch", "decode_tick"} <= set(by)
+    # a stretch is never its own innermost span
+    assert "starved" not in by and all(by[n] > 0 for n in by)
+    assert sum(by[n] for n in leaves & set(by)) > 0.5 * sum(by.values())
+
+
 # -- one clock pair per region ---------------------------------------------------
 
 def test_statset_telemetry_and_span_read_one_clock_pair():
@@ -282,12 +413,13 @@ def test_trainer_wraps_no_region_twice():
 def test_only_retroactive_spans_use_complete():
     for mod, allowed in (("scheduler", {"queue_wait", "finish",
                                         "handoff_out", "adopt"}),
-                         ("engine", set())):
+                         ("engine", {"starved"})):
         src = open(os.path.join(ROOT, "paddle_tpu", "serve",
                                 mod + ".py")).read()
         assert set(re.findall(r'\.complete\(\s*"(\w+)"', src)) == allowed
-        # queue_wait alone ends "now"; nothing else reads the tracer's clock
-        assert src.count("now_us()") == (1 if mod == "scheduler" else 0)
+        # queue_wait and a stretch a copy-on-write fork ends are stamped
+        # "now"; nothing else reads the tracer's clock
+        assert src.count("now_us()") == 1
 
 
 # -- the tracer's own additions ----------------------------------------------------
@@ -380,7 +512,7 @@ def test_compile_is_an_instant_on_the_live_tracer(tmp_path):
         <= {e["args"]["phase"] for e in marks}
 
 
-# -- the six readers, on the benchmark's toy cells -------------------------------------
+# -- the readers, on the benchmark's toy cells -----------------------------------------
 
 def _bench():
     spec = importlib.util.spec_from_file_location(
@@ -394,7 +526,9 @@ def _bench():
 READERS = {"toy-train": ["trainer_host_ms_p50", "trainer_dispatch_ms_p50",
                          "setup_compile_s"],
            "toy-serve": ["sched_self_ms_p50", "tick_host_ms_p50",
-                         "prefill_host_ms_p50", "setup_compile_s"]}
+                         "prefill_host_ms_p50", "setup_compile_s",
+                         "host_starved_pct.serve", "starved_feed_ms",
+                         "starved_fetch_ms", "starved_retire_ms"]}
 
 
 @pytest.fixture(scope="module")
@@ -430,8 +564,8 @@ def test_readers_report_on_a_traced_toy_cell(toy_lines, cell):
     for name in READERS[cell]:
         assert name in metrics, (name, sorted(metrics))
         assert metrics[name]["value"] > 0
-        assert metrics[name]["unit"] == ("s" if name.endswith("_s")
-                                         else "ms")
+        assert metrics[name]["unit"] == (
+            "%" if "_pct" in name else "s" if name.endswith("_s") else "ms")
     other = [n for c, names in READERS.items() if c != cell for n in names
              if n not in READERS[cell]]
     assert not set(other) & set(metrics)
@@ -446,6 +580,20 @@ def test_readers_report_on_a_traced_toy_cell(toy_lines, cell):
             < metrics["train_step_ms_p50"]["value"]
     assert metrics["setup_compile_s"]["value"] \
         < kept[cell].window[0] - kept[cell].t_start
+
+
+def test_starved_parts_are_no_more_than_the_whole_a_tick(toy_lines):
+    lines, kept, _ = toy_lines
+    metrics = {k: v["value"] for k, v in lines["toy-serve"]["metrics"].items()}
+    lo, hi = kept["toy-serve"].rec.spans["window"][0][:2]
+    ticks = sum(e["name"] == "engine_tick"
+                for e in session_tracer().between(lo, hi))
+    whole = metrics["host_starved_pct.serve"] / 100 * (hi - lo) * 1e3 / ticks
+    parts = sum(metrics[f"starved_{p}_ms"] for p in ("feed", "fetch",
+                                                      "retire"))
+    assert 0 < parts <= whole * (1 + 1e-9)
+    # under the device's idle: a stretch is the host's, and no wait
+    assert metrics["host_starved_pct.serve"] < 100
 
 
 @pytest.mark.parametrize("metric", sorted({n for names in READERS.values()

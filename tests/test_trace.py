@@ -20,6 +20,7 @@ from paddle_tpu.models import MnistMLP
 from paddle_tpu.nn import costs
 from paddle_tpu.obs import (AnomalyDetector, InMemorySink, Telemetry,
                             Tracer, tspan)
+from paddle_tpu.obs import trace as trace_lib
 from paddle_tpu.obs.anomaly import Verdict
 from paddle_tpu.train import Trainer
 from paddle_tpu.train.host_pipeline import GroupStager
@@ -342,20 +343,55 @@ def test_anomaly_profiled_record_skipped(tmp_path):
     assert _bundle_dirs(str(tmp_path)) == []
 
 
-def test_tracer_tail_zero_and_profile_window_lazy(tmp_path):
+def test_tracer_tail_zero(tmp_path):
     tracer = Tracer()
     with tracer.span("a"):
         pass
     assert [e for e in tracer.tail(0) if e["ph"] == "X"] == []
     assert len([e for e in tracer.tail(5) if e["ph"] == "X"]) == 1
-    # profile_window is lazy: constructing it must record nothing (and
-    # must not start the device profiler) until `with` entry
-    cm = tracer.profile_window(str(tmp_path / "prof"))
-    assert len([e for e in tracer.events() if e["ph"] == "X"]) == 1
-    with cm:
+
+
+def _x(name, ts, dur, tid=1, **kw):
+    return dict({"ph": "X", "name": name, "pid": 1, "tid": tid, "ts": ts,
+                 "dur": dur, "cat": "paddle_tpu"}, **kw)
+
+
+def test_starved_by_span_gives_each_piece_to_the_innermost_span():
+    events = [
+        _x("step", 0, 100), _x("tick", 10, 60), _x("stage", 20, 10),
+        _x("dispatch", 30, 10), _x("retire", 50, 10),
+        _x("step", 120, 50), _x("stage", 130, 20),
+        # one stretch: 15..35 (stage's 10 of it split with tick's own 5
+        # and dispatch's 5); another: 55..140 (retire 5, tick 10, step
+        # 30, nothing 20, step 10, stage 10)
+        _x("starved", 15, 20, cat=trace_lib.RETROACTIVE),
+        _x("starved", 55, 85, cat=trace_lib.RETROACTIVE),
+        # retroactive and on another thread: never innermost
+        _x("queue_wait", 0, 200, cat=trace_lib.RETROACTIVE),
+        _x("other", 0, 200, tid=2),
+        {"ph": "i", "name": "mark", "pid": 1, "tid": 1, "ts": 60}]
+    by = trace_lib.starved_by_span(events)
+    assert by == pytest.approx({"tick": 15e-6, "stage": 20e-6,
+                                "dispatch": 5e-6, "retire": 5e-6,
+                                "step": 40e-6, "": 20e-6})
+    assert sum(by.values()) == pytest.approx(105e-6)
+    # clipped to the window 30..135: dispatch 5, retire 5, tick 10, step
+    # 30 + 10, nothing 20, stage 5
+    clipped = trace_lib.starved_by_span(events, (30, 135))
+    assert clipped == pytest.approx({"dispatch": 5e-6, "retire": 5e-6,
+                                     "tick": 10e-6, "step": 40e-6,
+                                     "": 20e-6, "stage": 5e-6})
+    assert trace_lib.starved_by_span(
+        [e for e in events if e["name"] != "starved"]) == {}
+
+
+def test_complete_marks_its_spans_retroactive():
+    tracer = Tracer()
+    with tracer.span("code"):
         pass
-    spans = [e for e in tracer.events() if e["ph"] == "X"]
-    assert [e["name"] for e in spans].count("jax_profile") == 1
+    tracer.complete("after", tracer.now_us() - 5, tracer.now_us())
+    cats = {e["name"]: e["cat"] for e in tracer.events() if e["ph"] == "X"}
+    assert cats == {"code": "paddle_tpu", "after": trace_lib.RETROACTIVE}
 
 
 def test_anomaly_profiler_arming(tmp_path):
