@@ -54,10 +54,9 @@ The layers:
   flight recorder with per-replica serving kinds (tick stall, accept/
   prefix-hit collapse, retransmit burst, queue divergence).
 
-:mod:`~paddle_tpu.obs.xla_flags` (ISSUE 8) assembles the opt-in TPU
-async-collective / latency-hiding XLA flag set (documented provenance +
-caveats) that lets the backend actually float the bucketed gradient
-all-reduces under the backward pass.
+:mod:`~paddle_tpu.obs.xla_flags` holds the compile options that make a
+data-parallel step's gradient all-reduces asynchronous on the TPU, and
+the rule by which ``Trainer`` gives them to its step programs.
 
 Attach with ``Trainer(..., telemetry=Telemetry(sinks=[JsonlSink(path)]),
 tracer=Tracer(), anomaly=AnomalyDetector(out_dir))``. With none attached

@@ -31,6 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core import mesh as mesh_lib
 from ..core.module import Module
+from ..obs import xla_flags
 from ..obs.trace import live, tspan
 from ..optim.optimizers import Optimizer, apply_updates
 from ..utils.stats import StatSet
@@ -905,6 +906,13 @@ class Trainer:
                 return fn(*args)
         return traced
 
+    def _step_compiler_options(self) -> Optional[Dict[str, bool]]:
+        """The step programs' compile options, from the mesh alone: the
+        gradient all-reduces asynchronous where TPUs split the batch over
+        ``data`` (``obs/xla_flags.py:step_compiler_options``)."""
+        return xla_flags.step_compiler_options(
+            self.mesh.devices.flat[0].platform, dict(self.mesh.shape))
+
     def _build_train_step(self):
         step_fn = self._make_step_fn(accum_axis=False)
         # Shardings: batch sharded over the data axis, params replicated
@@ -914,15 +922,17 @@ class Trainer:
         # all-reduce over ICI — the entire pserver tier collapses here.
         mesh = self.mesh
         donate = (0, 1, 2) if self._donate else ()
+        options = self._step_compiler_options()
         if self._param_sharding is None:
             repl = NamedSharding(mesh, P())
             data = NamedSharding(mesh, P(mesh_lib.DATA_AXIS))
             self._train_step = jax.jit(
                 step_fn,
                 in_shardings=(repl, repl, repl, repl, data, repl),
-                donate_argnums=donate)
+                donate_argnums=donate, compiler_options=options)
         else:
-            self._train_step = jax.jit(step_fn, donate_argnums=donate)
+            self._train_step = jax.jit(step_fn, donate_argnums=donate,
+                                       compiler_options=options)
 
     def _build_fused_step(self, sample_batches):
         """The fused hot loop: ONE jit-compiled dispatch = a donated
@@ -949,6 +959,7 @@ class Trainer:
             return (params, state, opt_state, step) + tuple(ys)
 
         donate = (0, 1, 2) if self._donate else ()
+        options = self._step_compiler_options()
         if self._param_sharding is None:
             repl = NamedSharding(mesh, P())
             bshard = jax.tree_util.tree_map(self._fused_leaf_sharding,
@@ -956,9 +967,10 @@ class Trainer:
             self._fused_step = jax.jit(
                 fused_fn,
                 in_shardings=(repl, repl, repl, repl, bshard, repl),
-                donate_argnums=donate)
+                donate_argnums=donate, compiler_options=options)
         else:
-            self._fused_step = jax.jit(fused_fn, donate_argnums=donate)
+            self._fused_step = jax.jit(fused_fn, donate_argnums=donate,
+                                       compiler_options=options)
 
     def _build_eval_step(self):
         model = self.model

@@ -639,3 +639,98 @@ def test_grouped_product_is_handed_a_window_of_rows(one_chip):
     assert len(rows) == 3 and set(rows) == {ROW_WINDOW}, rows
     assert ROW_WINDOW < tokens * K
     assert re.search(r" while\(", text), "no loop over the windows"
+
+
+# ---------------------------------------------------------------------------
+# the data-parallel step: its gradient all-reduces asynchronous on the TPU
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def v5e_host():
+    """The four devices of a described, not attached, v5e:2x2 host (asked
+    for here only, as ``one_chip`` says why)."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return list(topo.devices)
+
+
+def compiled_step_text(devices, rule=True):
+    """The Trainer's plain step of a small ``TransformerLM`` over a
+    ``data`` mesh of ``devices``, compiled for them; ``rule=False`` takes
+    the step's compile options away (the program before the rule)."""
+    from paddle_tpu import optim
+    from paddle_tpu.nn import costs
+    from paddle_tpu.train import Trainer
+    from paddle_tpu.train.trainer import TrainState
+    V, T, B = 256, 64, 8
+    mesh = mesh_lib.make_mesh({"data": len(devices)}, devices=devices)
+    tr = Trainer(TransformerLM(vocab=V, dim=128, num_layers=2, num_heads=1,
+                               ffn_hidden=256, max_len=T),
+                 loss_fn=lambda out, b: costs.softmax_cross_entropy(
+                     out.reshape(-1, V), b["y"].reshape(-1)),
+                 optimizer=optim.adam(1e-3), mesh=mesh)
+    if not rule:
+        tr._step_compiler_options = lambda: None
+    repl = NamedSharding(mesh, P())
+    data = NamedSharding(mesh, P("data"))
+    on = lambda tree, sh: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh), tree)
+    variables = jax.eval_shape(lambda: tr.model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, T), jnp.int32), train=True))
+    tr.train_state = TrainState(
+        on(variables["params"], repl), on(variables.get("state", {}), repl),
+        on(jax.eval_shape(tr.optimizer.init, variables["params"]), repl),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=repl))
+    tr._build_train_step()
+    ts = tr.train_state
+    batch = {k: jax.ShapeDtypeStruct((B, T), jnp.int32, sharding=data)
+             for k in ("x", "y")}
+    return tr._train_step.lower(
+        ts.params, ts.state, ts.opt_state, ts.step, batch,
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=repl)
+    ).compile().as_text()
+
+
+def synchronous_all_reduces(text):
+    """The all-reduces of a compiled program that run synchronously: not
+    marked ``async_collective_name`` (an all-reduce split into a start
+    and a done) and not inside an async collective fusion (the compiler's
+    other asynchronous form: a computation named ``async_collective_fusion``
+    or one that an ``async-collective-start`` or ``-done`` calls)."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        if m := re.match(r"^(?:ENTRY )?%?([\w.-]+) ", line):
+            name = m[1]
+        elif line.startswith(" ") and name:
+            bodies.setdefault(name, []).append(line)
+    started = {m[1] for line in text.splitlines()
+               if re.search(r"%async-collective-(start|done)[.\d]* = ", line)
+               for m in re.finditer(r"calls=%([\w.-]+)", line)}
+    return [line.split(" = ")[0].strip() for comp, lines in bodies.items()
+            for line in lines if re.search(r" all-reduce\(", line)
+            and "async_collective_name" not in line
+            and not comp.startswith("async_collective_fusion")
+            and comp not in started]
+
+
+def test_data_parallel_step_reduces_its_gradients_asynchronously(v5e_host):
+    """Over ``data=4`` on the TPU every gradient all-reduce of the
+    Trainer's step is compiled asynchronous (XLA's scheduler may run it
+    beside the backward pass and the update); without the step's compile
+    options none is. On one device the rule gives nothing: the step
+    compiles to the text it had without it."""
+    from hlo_counts import compiled_all_reduces
+    with use_policy(bfloat16_compute):
+        # one call site: the texts carry their caller's source lines
+        text, before, one, one_before = (
+            compiled_step_text(devices, rule) for devices in
+            (v5e_host, v5e_host[:1]) for rule in (True, False))
+    assert compiled_all_reduces(text) > 0
+    assert synchronous_all_reduces(text) == []
+    assert len(synchronous_all_reduces(before)) \
+        == compiled_all_reduces(before) > 0
+    assert compiled_all_reduces(one) == 0 and one == one_before
