@@ -1,6 +1,7 @@
 """Model zoo — the acceptance workloads from BASELINE.json (MNIST LeNet,
 ResNet, seq2seq attention NMT, sequence tagging, CTR) built on paddle_tpu.nn."""
 
+from .conv_moe import ConvMoEBlock, ConvMoELM
 from .ctr import CTR_SHARDING_RULES, SparseLR, WideDeepCTR
 from .gan import Discriminator, Generator, gan_step_fn
 from .latent_moe import LatentMoEBlock, LatentMoELM, ShortcutMoEBlock

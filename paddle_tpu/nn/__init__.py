@@ -13,11 +13,12 @@ from .detection import (DetectionOutput, MultiBoxLoss, ROIPool,
 from .ctc import ctc_greedy_decode, ctc_loss
 from .layers import *  # noqa: F401,F403
 from .layers import __all__ as _layers_all
-from .recurrent import (RNN, BiRNN, GRUCell, HierarchicalRNN,
-                        LSTMCell, MDLstm, SimpleRNNCell)
+from .recurrent import (RNN, BiRNN, GatedShortConv, GRUCell,
+                        HierarchicalRNN, LSTMCell, MDLstm, SimpleRNNCell)
 
 __all__ = list(_layers_all) + [
-    "activations", "costs", "sequence_ops", "RNN", "BiRNN", "GRUCell",
+    "activations", "costs", "sequence_ops", "RNN", "BiRNN", "GatedShortConv",
+    "GRUCell",
     "HierarchicalRNN", "LSTMCell", "MDLstm", "SimpleRNNCell", "CRF", "crf_decode", "crf_log_likelihood",
     "ctc_loss", "ctc_greedy_decode", "AdditiveAttention", "DotProductAttention",
     "MultiHeadAttention", "detection", "DetectionOutput", "MultiBoxLoss",

@@ -11,7 +11,7 @@ long-context axis; the sequence-parallel ring variant lives in
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Tuple
 
 import numpy as np
 import jax
@@ -21,11 +21,16 @@ from jax.sharding import PartitionSpec as P
 from ..core import initializers as I
 from ..core.dtypes import current_policy
 from ..core.module import Module
-from .layers import Linear
+from .layers import Linear, RMSNorm
 
 __all__ = ["AdditiveAttention", "DotProductAttention", "MultiHeadAttention",
            "LatentAttention", "GroupedQueryAttention",
            "dot_product_attention_weights"]
+
+
+# the TPU's lanes: a pool row narrower than this is packed
+# (GroupedQueryAttention.pool_row)
+_LANES = 128
 
 
 def _tp_paged_kernel(kernel, q, pages_k, pages_v, *rest, head_dim: int):
@@ -750,9 +755,12 @@ class GroupedQueryAttention(Module):
     ``num_heads`` query heads read ``num_kv_heads`` KV heads of size
     ``head_dim`` (query head ``h`` reads KV head ``h // G``, ``G =
     num_heads / num_kv_heads``): a token leaves ``num_kv_heads`` keys and
-    values in the cache however many heads query them. Queries and keys
-    are rotated (``nn/rotary.py``): the first ``rope_dim`` values of a
-    head (default all: ``rope_dim < head_dim`` is a partial rotation), at
+    values in the cache however many heads query them. With ``qk_norm``
+    each head of q and of k is RMS-normalised over its ``head_dim`` values
+    (a learned scale a norm, epsilon ``norm_eps``) before the rotation.
+    Queries and keys are rotated (``nn/rotary.py``): the first
+    ``rope_dim`` values of a head (default all: ``rope_dim < head_dim``
+    is a partial rotation), at
     ``rope_base``'s frequencies or, with ``yarn = {"factor",
     "original_len", "beta_fast", "beta_slow", "attention_factor"}``,
     YaRN's, cosines and sines times its attention factor. Scores are
@@ -761,12 +769,19 @@ class GroupedQueryAttention(Module):
     ``h``'s output is multiplied by ``sigmoid(x W_g)_h`` (``W_g [dim,
     num_heads]``, from the layer's input) before the output projection.
 
-    Against the paged cache (``pages_k`` / ``pages_v`` ``[L, N, H_kv, bs,
-    head_dim]``, this layer number ``layer``, written in place):
+    Against the paged cache (``pages_k`` / ``pages_v`` ``[L, N, H_kv /
+    pack, bs, head_dim * pack]``, this layer number ``layer``, written in
+    place). A head narrower than the TPU's 128 lanes is PACKED: ``pack =
+    128 / head_dim`` KV heads side by side in one row (KV head ``p * pack
+    + j`` in lanes ``j * head_dim`` on of pool row ``p``), where the KV
+    heads divide so (:attr:`pool_row`); a row is then the KV heads'
+    values in their own order, so packing is a reshape and never a copy:
 
     - :meth:`decode`, ``impl="paged"``: the token's row is written, then
       :func:`~paddle_tpu.nn.pallas_attention.paged_decode_attention`
-      walks the slot's pages (from the window's first page on);
+      walks the slot's pages (from the window's first page on); packed
+      rows through :func:`~paddle_tpu.nn.pallas_attention.
+      lane_packed_decode_attention`, the same grouped kernel at 128 lanes;
     - :meth:`decode_span` and ``decode(impl="xla")``: the new rows attend
       to THEMSELVES as computed (rounded as the pool will hold them) and
       to the older context READ FROM THE POOL a tile of pages at a time
@@ -782,11 +797,14 @@ class GroupedQueryAttention(Module):
                  head_dim: int, rope_base: float = 10000.0,
                  rope_dim: Optional[int] = None, yarn: Optional[dict] = None,
                  window: Optional[int] = None, head_gate: bool = False,
+                 qk_norm: bool = False, norm_eps: float = 1e-6,
                  w_init=I.fan_in_uniform, name=None):
         super().__init__(name=name)
         from .rotary import yarn_frequencies
         assert num_heads % num_kv_heads == 0, (num_heads, num_kv_heads)
         self.dim, self.head_dim = dim, head_dim
+        pack = _LANES // head_dim if _LANES % head_dim == 0 else 1
+        self.pack = pack if pack > 1 and num_kv_heads % pack == 0 else 1
         self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
         self.window = None if window is None else int(window)
         self.rope_base = float(rope_base)
@@ -802,6 +820,20 @@ class GroupedQueryAttention(Module):
         lin = lambda n: Linear(n, use_bias=False, w_init=w_init)
         self.gate = lin(num_heads) if head_gate else None
         self.o = lin(dim)
+        self.q_norm = RMSNorm(norm_eps) if qk_norm else None
+        self.k_norm = RMSNorm(norm_eps) if qk_norm else None
+
+    @property
+    def pool_row(self) -> Tuple[int, int]:
+        """One token's K (or V) row in one layer of the paged pools:
+        ``(num_kv_heads / pack, head_dim * pack)``."""
+        return self.num_kv_heads // self.pack, self.head_dim * self.pack
+
+    def _rows(self, kv):
+        """K or V heads ``[..., H_kv, hd]`` as the pool's rows."""
+        if self.pack == 1:
+            return kv
+        return kv.reshape(*kv.shape[:-2], *self.pool_row)
 
     # -- the parts every entry point shares -------------------------------
 
@@ -828,8 +860,12 @@ class GroupedQueryAttention(Module):
         cos, sin = rotary_angles(positions, self.rope_dim, self.rope_base,
                                  self.inv_freq, self.rope_factor)
         cos, sin = cos[:, :, None], sin[:, :, None]
-        q = apply_rotary(self._heads("wq", x, self.num_heads), cos, sin)
-        k = apply_rotary(self._heads("wk", x, self.num_kv_heads), cos, sin)
+        q = self._heads("wq", x, self.num_heads)
+        k = self._heads("wk", x, self.num_kv_heads)
+        if self.q_norm is not None:
+            with jax.named_scope("qk_norm"):
+                q, k = self.q_norm(q), self.k_norm(k)
+        q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
         gate = None
         if self.gate is not None:
             with jax.named_scope("head_gate"):
@@ -896,8 +932,8 @@ class GroupedQueryAttention(Module):
             blocks = jnp.take_along_axis(table, jnp.minimum(cols, MB - 1),
                                          axis=1)        # [S, per]
             rows = lambda pages: jnp.swapaxes(
-                pages[layer, blocks], 2, 3).reshape(S, T, *pages.shape[2:3],
-                                                    pages.shape[-1])
+                pages[layer, blocks], 2, 3).reshape(
+                    S, T, self.num_kv_heads, self.head_dim)
             k_pos = (cols[:, :, None] * bs
                      + jnp.arange(bs)[None, None]).reshape(S, T)
             a, m_t, l_t = self._attend(q, rows(pages_k), rows(pages_v), pos,
@@ -942,16 +978,23 @@ class GroupedQueryAttention(Module):
                 acc, l = self._cached(q, k, v, pages_k, pages_v, layer,
                                       table, positions,
                                       active.astype(jnp.int32))
-            pages_k = write_token(pages_k, layer, k[:, 0], table, positions,
-                                  active)
-            pages_v = write_token(pages_v, layer, v[:, 0], table, positions,
-                                  active)
+            pages_k = write_token(pages_k, layer, self._rows(k[:, 0]), table,
+                                  positions, active)
+            pages_v = write_token(pages_v, layer, self._rows(v[:, 0]), table,
+                                  positions, active)
             if impl == "paged":
-                from .pallas_attention import paged_decode_attention
-                acc = paged_decode_attention(
-                    q[:, 0], pages_k, pages_v, table,
-                    jnp.where(active, positions + 1, 0), layer,
-                    scale=self.scale, window=self.window)[:, None]
+                from .pallas_attention import (lane_packed_decode_attention,
+                                               paged_decode_attention)
+                lengths = jnp.where(active, positions + 1, 0)
+                if self.pack == 1:
+                    acc = paged_decode_attention(
+                        q[:, 0], pages_k, pages_v, table, lengths, layer,
+                        scale=self.scale, window=self.window)
+                else:
+                    acc = lane_packed_decode_attention(
+                        q[:, 0], pages_k, pages_v, table, lengths, layer,
+                        pack=self.pack, scale=self.scale, window=self.window)
+                acc = acc[:, None]
                 l = jnp.ones(acc.shape[:-1], acc.dtype)
             return self._out(acc, l, gate), pages_k, pages_v
 
@@ -970,8 +1013,8 @@ class GroupedQueryAttention(Module):
             n_eff = jnp.where(active, n, 0)
             acc, l = self._cached(q, k, v, pages_k, pages_v, layer, table,
                                   start, n_eff)
-            pages_k = write_span(pages_k, layer, k, table, start, n_eff,
-                                 write_from, by_page=True)
-            pages_v = write_span(pages_v, layer, v, table, start, n_eff,
-                                 write_from, by_page=True)
+            pages_k = write_span(pages_k, layer, self._rows(k), table, start,
+                                 n_eff, write_from, by_page=True)
+            pages_v = write_span(pages_v, layer, self._rows(v), table, start,
+                                 n_eff, write_from, by_page=True)
             return self._out(acc, l, gate), pages_k, pages_v

@@ -148,7 +148,8 @@ class HeldExpertsFFN(Module):
     ``top_k``, as the whole layer would. ``scoring``:
 
     - ``"sigmoid"``: ``s = sigmoid(x W_g)``, gates normalised over the k
-      (``scaling * s_e / (sum of the k + 1e-20)``);
+      (``scaling * s_e / (sum of the k + norm_eps)``, ``norm_eps``
+      default 1e-20);
     - ``"softmax"``: ``s = softmax(x W_g)``, gates the raw scores
       (``scaling * s_e``, not renormalised);
 
@@ -194,7 +195,7 @@ class HeldExpertsFFN(Module):
     def __init__(self, dim: int, hidden: int, num_experts: int, top_k: int,
                  experts_held=None, scaling: float = 1.0,
                  scoring: str = "sigmoid", select_bias: bool = False,
-                 num_zero: int = 0, normalise=None,
+                 num_zero: int = 0, normalise=None, norm_eps: float = 1e-20,
                  w_init=I.fan_in_uniform, name=None):
         super().__init__(name=name)
         first, count = experts_held or (0, num_experts)
@@ -209,6 +210,7 @@ class HeldExpertsFFN(Module):
         self.normalise = (scoring == "sigmoid" if normalise is None
                           else bool(normalise))
         self.num_zero = int(num_zero)
+        self.norm_eps = float(norm_eps)
         self.w_init = w_init
 
     def route(self, x):
@@ -231,7 +233,7 @@ class HeldExpertsFFN(Module):
             top, idx = jax.lax.top_k(scores, self.top_k)
         if self.normalise:
             gates = self.scaling * top / (
-                jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+                jnp.sum(top, axis=-1, keepdims=True) + self.norm_eps)
         else:
             gates = self.scaling * top
         return idx.astype(jnp.int32), gates

@@ -61,6 +61,7 @@ from . import autotune, pallas_mode
 
 __all__ = ["flash_attention", "reference_attention",
            "paged_decode_attention", "paged_reference_attention",
+           "lane_packed_decode_attention",
            "paged_span_attention", "paged_span_reference_attention",
            "latent_paged_decode", "latent_paged_reference"]
 
@@ -947,6 +948,40 @@ def paged_decode_attention(q, pages_k, pages_v, tables, lengths, layer,
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32),
       _layer_operand(layer), q.reshape(S, H, 1, D), pages_k, pages_v)
     return out.reshape(S, H, D)
+
+
+def lane_packed_decode_attention(q, pages_k, pages_v, tables, lengths,
+                                 layer, *, pack: int,
+                                 scale: Optional[float] = None,
+                                 interpret: Optional[bool] = None,
+                                 window: Optional[int] = None):
+    """:func:`paged_decode_attention` over pools whose rows hold ``pack``
+    KV heads of ``d = D / pack`` side by side: ``pages_k`` / ``pages_v``
+    ``[L, N, H_kv / pack, bs, D]``, KV head ``p * pack + j`` in lanes
+    ``j * d`` to ``(j + 1) * d`` of row ``p`` (what
+    ``GroupedQueryAttention`` keeps for a head narrower than 128 lanes).
+    ``q [S, H, d]``; query head ``h`` reads KV head ``h // G``.
+
+    The query heads of row ``p``'s KV heads are consecutive, ``pack * G``
+    of them, so the pools are a grouped pool of ``H_kv / pack`` heads of
+    size ``D``: each query goes in zero-padded to ``D`` lanes, its own
+    KV head's lanes holding it and the others nought, so its score
+    against a row is its score against its own head. The grouped kernel
+    runs as it is at head size ``D`` (``scale`` defaults to ``d ** -0.5``,
+    that of the heads), and of each query's ``D`` output lanes its own head's ``d``
+    are kept: the probabilities times the row's values, in those lanes,
+    are its head's. Returns ``[S, H, d]``."""
+    S, H, d = q.shape
+    G = H // (pages_k.shape[2] * pack)
+    scale = d ** -0.5 if scale is None else scale
+    # the lanes of its own KV head, a query head's
+    own = jax.nn.one_hot((jnp.arange(H) // G) % pack, pack, dtype=q.dtype)
+    wide = (q[:, :, None, :] * own[None, :, :, None]).reshape(S, H, pack * d)
+    out = paged_decode_attention(wide, pages_k, pages_v, tables, lengths,
+                                 layer, scale=scale, interpret=interpret,
+                                 window=window)
+    return jnp.sum(out.reshape(S, H, pack, d) * own[None, :, :, None],
+                   axis=2)
 
 
 def _paged_span_kernel(tbl_ref, start_ref, n_ref, lay_ref, q_ref, k_ref,

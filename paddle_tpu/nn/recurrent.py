@@ -25,8 +25,10 @@ from jax import lax
 from ..core import initializers as I
 from ..core.module import Module
 from . import activations
+from .layers import Linear
 
-__all__ = ["LSTMCell", "GRUCell", "SimpleRNNCell", "RNN", "BiRNN"]
+__all__ = ["LSTMCell", "GRUCell", "SimpleRNNCell", "RNN", "BiRNN",
+           "GatedShortConv"]
 
 
 class LSTMCell(Module):
@@ -432,3 +434,114 @@ class HierarchicalRNN(Module):
         outer_out, _ = self.outer(summaries,
                                   mask=length_mask(num_subseqs, S))
         return inner_out, outer_out
+
+
+class GatedShortConv(Module):
+    """The gated short convolution of Liquid AI's LFM2 blocks, a token
+    mixer with a state of ``kernel - 1`` rows (``conv_L_cache``), no
+    attention and no biases: ``x [B, T, D] -> [B, T, D]``,
+
+    - ``B, C, x~ = split3(x W_in)``, ``W_in [D, 3D]``;
+    - ``u = B * x~``;
+    - ``v_t = sum_j w_j * u_{t - kernel + 1 + j}``, a depthwise causal
+      convolution (``w [kernel, D]``, parameter ``conv``; rows before the
+      first are zeros);
+    - ``(C * v) W_out``.
+
+    Three forms, one per entry point of a served model: :meth:`forward`
+    over whole sequences; :meth:`decode`, one token a slot against the
+    slot's state; :meth:`decode_span`, ``Q`` consecutive tokens a slot
+    that start from the state and leave it as it is after their last LIVE
+    row. The state is a STATE group's pool (``serve/kv_cache.py``):
+    ``[L, max_slots, (kernel - 1) * D]``, a slot's ``kernel - 1`` rows
+    side by side in one row (a layer's state is then a ``[slots, 2D]``
+    array the TPU tiles whole, and a chunk's write is one row of it; a
+    ``[.., kernel - 1, D]`` pool puts its 2 rows where the tile wants 8
+    and XLA re-lays it round a program), this layer ``layer``, the call's
+    ``S`` rows the consecutive slots ``first .. first + S - 1`` (``first``
+    a scalar), read and written in place with one ``dynamic_slice`` and
+    one ``dynamic_update_slice``. Against
+    a state, ``u`` enters the convolution rounded as the state holds it,
+    in the span as in the tick, so a prompt gives the same outputs however
+    it is cut into chunks; a span that starts at position 0 starts from
+    zeros whatever the pool holds (an admission's reset, inside the
+    program that writes the state), and an inactive slot's state is left
+    as it was."""
+
+    def __init__(self, dim: int, kernel: int = 3, w_init=I.fan_in_uniform,
+                 name=None):
+        super().__init__(name=name)
+        assert kernel >= 2, kernel
+        self.dim, self.kernel = dim, kernel
+        self.w_init = w_init
+        self.in_proj = Linear(3 * dim, use_bias=False, w_init=w_init)
+        self.out_proj = Linear(dim, use_bias=False, w_init=w_init)
+
+    @property
+    def state_row(self) -> Tuple[int]:
+        """One slot's state in one layer: ``((kernel - 1) * dim,)``."""
+        return ((self.kernel - 1) * self.dim,)
+
+    def _gated_input(self, x):
+        """``(u, C)`` of ``x``, float32."""
+        b, c, xt = jnp.split(self.in_proj(x).astype(jnp.float32), 3, -1)
+        return b * xt, c
+
+    def _mix(self, rows, c):
+        """``rows [B, kernel - 1 + T, D]``: the ``kernel - 1`` rows before
+        the first output and the ``T`` outputs' own ``u``. Returns the
+        block's output ``[B, T, D]``."""
+        T = rows.shape[1] - self.kernel + 1
+        w = self.param("conv", self.w_init, (self.kernel, self.dim))
+        rows, w = rows.astype(jnp.float32), w.astype(jnp.float32)
+        v = sum(w[j] * rows[:, j:j + T] for j in range(self.kernel))
+        return self.out_proj(c * v)
+
+    def forward(self, x):
+        with self.scope():
+            u, c = self._gated_input(x)
+            return self._mix(jnp.pad(u, ((0, 0), (self.kernel - 1, 0),
+                                         (0, 0))), c)
+
+    def _state_rows(self, state, layer, first, S):
+        """Slots ``first .. first + S - 1``'s rows ``[S, kernel - 1, D]``."""
+        rows = lax.dynamic_slice(state, (layer, first, 0),
+                                 (1, S, state.shape[-1]))
+        return rows.reshape(S, self.kernel - 1, self.dim)
+
+    def _put(self, state, layer, first, rows):
+        rows = rows.astype(state.dtype).reshape(1, rows.shape[0], -1)
+        return lax.dynamic_update_slice(state, rows, (layer, first, 0))
+
+    def decode(self, x, state, layer, first, active):
+        """One new token a slot: ``x [S, 1, D]`` for the slots ``first ..
+        first + S - 1``, ``active [S]``. Returns ``(out [S, 1, D],
+        state)``."""
+        with self.scope():
+            S = x.shape[0]
+            u, c = self._gated_input(x)
+            old = self._state_rows(state, layer, first, S)
+            rows = jnp.concatenate([old, u.astype(state.dtype)], axis=1)
+            out = self._mix(rows, c)
+            new = jnp.where(active[:, None, None], rows[:, 1:], old)
+            return out, self._put(state, layer, first, new)
+
+    def decode_span(self, x, state, layer, first, start, n, active):
+        """``Q`` consecutive new tokens a slot (a prefill chunk): ``x [S,
+        Q, D]`` for the slots ``first .. first + S - 1``, at positions
+        ``start[s] + j``, ``n[s]`` of them live.
+        Returns ``(out [S, Q, D], state)``: the state after the slot's
+        last live row (unchanged where ``n`` is 0 or the slot inactive;
+        padding rows never enter it)."""
+        with self.scope():
+            S = x.shape[0]
+            u, c = self._gated_input(x)
+            held = self._state_rows(state, layer, first, S)
+            old = jnp.where((start > 0)[:, None, None], held, 0)
+            rows = jnp.concatenate([old, u.astype(state.dtype)], axis=1)
+            out = self._mix(rows, c)
+            # rows n .. n + kernel - 2 of ``rows`` end at the last live u
+            new = jax.vmap(lambda r, m: lax.dynamic_slice_in_dim(
+                r, m, self.kernel - 1, axis=0))(rows, n)
+            new = jnp.where((active & (n > 0))[:, None, None], new, held)
+            return out, self._put(state, layer, first, new)

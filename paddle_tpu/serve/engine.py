@@ -208,7 +208,11 @@ class DecodeEngine:
       block_size) + 1`` blocks a slot whatever the context
       (``serve/kv_cache.py``), and ``tables`` below is a dict, a table a
       group (:class:`~paddle_tpu.models.WindowMoELM`: ``full`` and
-      ``window`` groups of ``k`` / ``v`` rows).
+      ``window`` groups of ``k`` / ``v`` rows). A group declared with
+      ``"state": True`` is a STATE group: a pool ``[n, max_slots,
+      *row]`` a slot keeps whatever its length, whose "table" is the
+      slots a call's rows are (:class:`~paddle_tpu.models.ConvMoELM`: a
+      ``conv`` group of its short convolutions' last inputs).
     - ``decode_step(token, kv, positions, active, attn_impl=)`` and
       ``decode_span(tokens, kv, start, n, active, attn_impl=,
       write_from=)`` with ``kv = (*pools in declared order, tables)``,
@@ -235,7 +239,11 @@ class DecodeEngine:
     ``export_slot`` / ``adopt_slot`` (and with them
     ``serve/transport.py``'s wire format) when called. A WINDOW group
     refuses besides, at build: prefix sharing with its copy-on-write,
-    speculation, and a prefill chunk longer than the window.
+    speculation, and a prefill chunk longer than the window; a STATE
+    group refuses prefix sharing and speculation at build, and no path
+    keeps a slot's pages without its state: an eviction frees both (a
+    prefill from position 0 starts from a zero state) and the page
+    export of a handoff refuses it.
 
     **Starved time** (``starved_s``, cumulative seconds beside ``ticks``
     and ``tokens_generated``): each compiled call takes the pools the call
@@ -397,10 +405,13 @@ class DecodeEngine:
         kv_pools = self.pool_names == ("k", "v")
         windows = [int(g["window"]) for g in (groups or {}).values()
                    if g.get("window")]
+        states = [g for g, decl in (groups or {}).items()
+                  if decl.get("state")]
         if share_prefix is None:
-            share_prefix = not windows
-        # what has not been carried over to other pools, or to a window
-        # group's ring, fails here and not in a compiled program
+            share_prefix = not (windows or states)
+        # what has not been carried over to other pools, to a window
+        # group's ring or to a state group, fails here and not in a
+        # compiled program
         refused = []
         if not kv_pools:
             refused += [
@@ -408,11 +419,13 @@ class DecodeEngine:
                 (mesh is not None, "mesh="),
                 (prefill_chunk is None,
                  "the one-shot prefill (prefill_chunk=None)")]
-        if windows:
+        if windows or states:
             refused += [
                 (share_prefix, "share_prefix=True (prefix sharing and "
                  "copy-on-write)"),
-                (speculative > 0, "speculative > 0"),
+                (speculative > 0, "speculative > 0")]
+        if windows:
+            refused += [
                 (prefill_chunk is not None and prefill_chunk > min(windows),
                  f"a prefill chunk longer than the window {min(windows)}")]
         for given, what in refused:
@@ -425,6 +438,10 @@ class DecodeEngine:
                        f"slot, which no other slot shares, a rejected draft "
                        f"cannot be taken out of and a longer chunk wraps"
                        if windows else "")
+                    + (f" with the state group(s) {states}: a slot's state "
+                       f"at the end of its positions, which a shared page "
+                       f"does not carry and a rejected draft cannot be "
+                       f"taken out of" if states else "")
                     + ": int8 rows, head sharding, the prefill scatter, "
                     "export_slot / adopt_slot and the transport's wire "
                     "format know [heads, head size] rows in ONE pool "

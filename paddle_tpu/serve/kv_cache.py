@@ -786,13 +786,17 @@ class PoolGroup:
     one KIND. ``ring`` is the blocks a slot owns of a window group
     (``ceil(window / block_size) + 1``: a window of positions touches at
     most that many pages), None for a group that grows with the context;
-    ``rows`` the shape of one token's row in one layer, a pool."""
+    ``rows`` the shape of one token's row in one layer, a pool. A STATE
+    group (``state``) is no paged group: ``rows`` is then the state ONE
+    SLOT keeps in one layer, whatever its length, and the group has no
+    blocks and no table."""
     name: str
     layers: int
     window: Optional[int]
     ring: Optional[int]
     num_blocks: int
     rows: Dict[str, Tuple[int, ...]]
+    state: bool = False
 
     def row_bytes(self, dtype) -> int:
         """Bytes one token leaves in ONE layer of the group."""
@@ -844,7 +848,21 @@ class PagedKVCache:
     ``window`` (what lies there has been overwritten). It has no
     allocator and nothing to free, and it never refuses an admission.
     Prefix sharing and copy-on-write do not reach a ring (the engine
-    refuses them at build)."""
+    refuses them at build).
+
+    A STATE group (``{"layers": n, "pools": {name: row}, "state": True}``)
+    holds what a layer keeps of a slot's past whatever its length (a short
+    convolution's last inputs): each pool is ONE array ``[n, max_slots,
+    *row]``, a slot's rows at its own index, with no blocks, no allocator
+    and no table. A call's rows are consecutive slots, and the entry
+    points take the first of them, a scalar, as that group's "table": 0
+    for a tick (every slot), the slot for a prefill chunk
+    (:meth:`device_tables`, :meth:`slot_tables`). Nothing here resets or copies a slot's state: a
+    prefill that starts at position 0 starts from zeros, inside the
+    program that writes it. Prefix sharing does not reach it (a shared
+    page would need the state at its end), and :meth:`group_facts` and
+    ``kv_bytes_per_token`` count the paged groups only; :meth:`state_facts`
+    counts it."""
 
     def __init__(self, num_layers: Optional[int], num_heads: Optional[int],
                  head_dim: Optional[int],
@@ -859,19 +877,22 @@ class PagedKVCache:
             assert row_shapes is None, "declare pools or groups, not both"
             for g, spec in groups.items():
                 window = spec.get("window")
+                state = bool(spec.get("state"))
                 ring = None if window is None \
                     else -(-int(window) // block_size) + 1
                 self.groups[g] = PoolGroup(
                     g, int(spec["layers"]), window, ring,
+                    max_slots if state else
                     num_blocks if ring is None else max_slots * ring + 1,
                     {n: tuple(int(d) for d in r)
-                     for n, r in spec["pools"].items()})
+                     for n, r in spec["pools"].items()}, state)
             num_layers = sum(g.layers for g in self.groups.values())
             row_shapes = {f"{g.name}/{n}": r for g in self.groups.values()
                           for n, r in g.rows.items()}
-            if share_prefix and any(g.ring for g in self.groups.values()):
+            if share_prefix and any(g.ring or g.state
+                                    for g in self.groups.values()):
                 raise ValueError("prefix sharing does not reach a window "
-                                 "group's ring")
+                                 "group's ring or a state group")
         self.num_layers = num_layers
         self.kv_pools = row_shapes is None
         if self.kv_pools:
@@ -913,7 +934,8 @@ class PagedKVCache:
             group = self.groups.get(name.split("/")[0])
             layers, blocks = ((num_layers, num_blocks) if group is None
                               else (group.layers, group.num_blocks))
-            shape = (layers, blocks, *row[:-1], block_size, row[-1])
+            shape = ((layers, blocks, *row) if group and group.state
+                     else (layers, blocks, *row[:-1], block_size, row[-1]))
             if self.quantized:
                 # int8 value pages + per-block scale pages (one f32 per
                 # token row per head) — quantize-on-scatter writes both
@@ -936,6 +958,9 @@ class PagedKVCache:
             for g in self.groups.values() if g.ring}
         self._ring_device = {n: jnp.asarray(t)
                              for n, t in self._ring_tables.items()}
+        # a state group's operand: the first of the consecutive slots a
+        # call's rows are (a tick's rows are every slot, from 0)
+        self._first_slot = jnp.zeros((), jnp.int32)
         self._owned: List[List[int]] = [[] for _ in range(max_slots)]
         # COW bookkeeping: which table indices this slot ADOPTED (vs
         # allocated), and the admission-reserved fork target
@@ -1015,9 +1040,10 @@ class PagedKVCache:
         is the number a device's HBM budget divides by — capacity scales
         with the mesh."""
         if self.groups:
-            # what GROWS with the context: the groups without a window
+            # what GROWS with the context: the paged groups without a
+            # window
             return sum(g.layers * g.row_bytes(self.dtype)
-                       for g in self.groups.values() if not g.ring)
+                       for g in self._paged_groups() if not g.ring)
         if not self.kv_pools:
             return self.num_layers * jnp.dtype(self.dtype).itemsize * sum(
                 int(np.prod(r)) for r in self.row_shapes.values())
@@ -1046,10 +1072,10 @@ class PagedKVCache:
         most) and those LIVE (the blocks the slots' lengths reach, in
         either: what holds a position, without the reservation for
         positions to come), beside the positions themselves. Nothing for
-        a cache without groups."""
+        a cache without groups; a state group is :meth:`state_facts`'s."""
         reach = -(-self.lengths.astype(np.int64) // self.block_size)
         facts = {}
-        for g in self.groups.values():
+        for g in self._paged_groups():
             live = int(np.minimum(reach, g.ring).sum() if g.ring
                        else reach.sum())
             facts[g.name] = {
@@ -1061,6 +1087,18 @@ class PagedKVCache:
                 self.num_blocks - 1 - self.allocator.reclaimable,
                 "live_tokens": int(self.lengths.sum())}
         return facts
+
+    def _paged_groups(self) -> List[PoolGroup]:
+        return [g for g in self.groups.values() if not g.state]
+
+    def state_facts(self) -> Dict[str, Dict[str, int]]:
+        """Each STATE group's account: its layers, the bytes one slot's
+        state takes in all of them and the bytes of the whole group."""
+        return {g.name: {"layers": g.layers,
+                         "slot_bytes": g.layers * g.row_bytes(self.dtype),
+                         "bytes": g.layers * g.row_bytes(self.dtype)
+                         * self.max_slots}
+                for g in self.groups.values() if g.state}
 
     def owned_count(self, slot: int) -> int:
         """How many pool blocks ``slot`` currently holds — the size of
@@ -1301,21 +1339,24 @@ class PagedKVCache:
         PR 29: 17 of 40 runs of one scenario served another token; none
         of 40 with the copies taken here)."""
         return (self._tables(jnp.asarray(self.tables.copy()),
-                             self._ring_device),
+                             self._ring_device, self._first_slot),
                 jnp.asarray(self.lengths.copy()))
 
-    def _tables(self, grown, rings):
+    def _tables(self, grown, rings, first):
         """``grown``, the table of the account that grows, as the entry
         points take it: itself, or with declared groups ``{group: table}``
-        (a window group's is its ring's, which never changes)."""
+        (a window group's is its ring's, which never changes; a state
+        group's the scalar ``first`` slot of the call's rows, which are
+        consecutive slots)."""
         if not self.groups:
             return grown
-        return {g.name: rings[g.name] if g.ring else grown
-                for g in self.groups.values()}
+        return {g.name: first if g.state else rings[g.name] if g.ring
+                else grown for g in self.groups.values()}
 
     def slot_tables(self, slot: int):
         """One slot's ``[1, MB]`` table(s), a prefill chunk's operand."""
         rows = slice(slot, slot + 1)
         return self._tables(
             jnp.asarray(self.tables[rows]),
-            {n: jnp.asarray(t[rows]) for n, t in self._ring_tables.items()})
+            {n: jnp.asarray(t[rows]) for n, t in self._ring_tables.items()},
+            jnp.asarray(slot, jnp.int32))

@@ -28,7 +28,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu.core import bfloat16_compute, mesh as mesh_lib, use_policy
-from paddle_tpu.models import (LatentMoELM, ShortcutMoEBlock,
+from paddle_tpu.models import (ConvMoELM, LatentMoELM, ShortcutMoEBlock,
                                TransformerLM, WindowMoELM)
 from paddle_tpu.nn import pallas_attention
 from paddle_tpu.nn import MultiHeadAttention, pallas_mode
@@ -515,6 +515,71 @@ def test_window_groups_programs_leave_every_pool_in_place(one_chip,
         assert not moved, f"{name}: pool-sized results: {moved}"
         # the chunk's page writes of the four pools fuse into in-place
         # updates with several results; the tick's row writes stand alone
+        assert " dynamic-update-slice(" in text, name
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes == pool_bytes, name
+        assert memory.temp_size_in_bytes < pools["full/k"].nbytes, name
+
+
+def toy_conv_engine(blocks):
+    """A toy of the convolution-and-attention expert model at the lane
+    tile's widths: ``[conv, full, conv]`` layers, 4 query heads on 2 KV
+    heads of 64 (one 128-lane pool row), one dense layer and two of 8
+    experts (all held), bfloat16 weights, pools and state, a chunked
+    prefill."""
+    model = ConvMoELM(
+        vocab=512, dim=256, layer_types=["conv", "full_attention", "conv"],
+        num_heads=4, num_kv_heads=2, head_dim=64, dense_hidden=512,
+        expert_hidden=256, num_experts=8, top_k=2, max_len=1024)
+    variables = jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.bfloat16),
+        model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    return DecodeEngine(model, variables, max_slots=4, block_size=BS,
+                        num_blocks=blocks, attention="paged",
+                        max_blocks_per_seq=64, prefill_chunk=32,
+                        dtype="bfloat16")
+
+
+def test_state_group_programs_leave_the_state_and_pools_in_place(
+        one_chip, monkeypatch):
+    """The tick AND the prefill chunk of a toy engine with a paged group
+    and a STATE group, compiled for the TPU: the state and both pools are
+    arguments aliased to results, nothing but the carry's plumbing and
+    the in-place writes has a result the size of the state, of a pool or
+    of one layer of either, the temporaries are smaller than one pool,
+    and the tick's head-64 attention is the Mosaic kernel."""
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+    engine = toy_conv_engine(blocks=40001)
+    pools = engine.cache.pools
+    state = pools["conv/state"]
+    assert state.shape == (2, 4, 512)
+    assert pools["full/k"].shape == (1, 40001, 1, BS, 128)
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip), tree)
+    # a tick writes a whole layer of the state (every slot's rows): that
+    # update is the program's, and the state's own size is what must not
+    # be copied
+    sizes = {p.size // div for n, p in pools.items()
+             for div in ((1,) if n == "conv/state" else (1, p.shape[0]))}
+    pool_bytes = sum(p.nbytes for p in pools.values())
+    for name, fn, args in (
+            ("tick", engine._tick_fn, engine._tick_args()),
+            ("prefill", engine._prefill_fn, engine._prefill_args())):
+        compiled = fn.lower(*on_chip(args)).compile()
+        text = compiled.as_text()
+        assert 'custom_call_target="tpu_custom_call"' in text or \
+            name == "prefill"
+        # a layer's state write fuses with its select into ONE in-place
+        # update of the whole state
+        in_place = fusions_updating_in_place(text)
+        held = pool_sized_results(text, sizes)
+        moved = [h for h in held if h[0] not in _MAY_HOLD_A_POOL
+                 and not (h[0] == "fusion" and h[1] in in_place)]
+        assert not moved, f"{name}: pool-sized results: {moved}"
+        copied = [h for h in results(text) if h[0] == "copy"
+                  and int(np.prod(h[2])) == state.size // state.shape[0]]
+        assert not copied, f"{name}: a layer of the state copied: {copied}"
         assert " dynamic-update-slice(" in text, name
         memory = compiled.memory_analysis()
         assert memory.alias_size_in_bytes == pool_bytes, name
